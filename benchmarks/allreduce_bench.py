@@ -34,8 +34,6 @@ def bench_mesh(sizes_mb, dtype_name="bfloat16", iters=20):
     from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
     from ray_tpu._private import runtime_metrics
-    from ray_tpu.util.jax_compat import shard_map as _shard_map
-
     devices = jax.devices()
     n = len(devices)
     mesh = Mesh(devices, ("x",))
@@ -43,7 +41,7 @@ def bench_mesh(sizes_mb, dtype_name="bfloat16", iters=20):
 
     @jax.jit
     def allreduce(x):
-        return _shard_map(
+        return jax.shard_map(
             lambda s: jax.lax.psum(s, "x"),
             mesh=mesh,
             in_specs=P("x"),
@@ -266,8 +264,6 @@ def bench_bucketed_overlap(sizes_mb, bucket_mb, iters=10):
     import jax.numpy as jnp
     from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-    from ray_tpu.util.jax_compat import shard_map as _shard_map
-
     devices = jax.devices()
     world = len(devices)
     mesh = Mesh(np.array(devices), ("x",))
@@ -283,7 +279,7 @@ def bench_bucketed_overlap(sizes_mb, bucket_mb, iters=10):
 
         @jax.jit
         def fused(v):
-            return _shard_map(lambda s: jax.lax.psum(s, "x"), mesh=mesh,
+            return jax.shard_map(lambda s: jax.lax.psum(s, "x"), mesh=mesh,
                               in_specs=P("x"), out_specs=P())(v)
 
         @jax.jit
@@ -298,7 +294,7 @@ def bench_bucketed_overlap(sizes_mb, bucket_mb, iters=10):
                     outs.append(c)
                 return jnp.concatenate(outs)
 
-            return _shard_map(body, mesh=mesh, in_specs=P("x"),
+            return jax.shard_map(body, mesh=mesh, in_specs=P("x"),
                               out_specs=P())(v)
 
         def timeit(fn):

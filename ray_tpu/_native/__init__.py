@@ -84,6 +84,15 @@ def load(name: str) -> ctypes.CDLL | None:
         return lib
 
 
+def status() -> dict:
+    """Which components this process asked for, and how each came out:
+    ``"native"`` (built or reused, and loaded) or ``"fallback"`` (the
+    pure-Python implementation stands in)."""
+    with _build_lock:
+        return {name: "native" if lib is not None else "fallback"
+                for name, lib in sorted(_cache.items())}
+
+
 def load_sched_policy() -> ctypes.CDLL | None:
     lib = load("sched_policy")
     if lib is None:

@@ -1,0 +1,37 @@
+"""Where compiled programs are kept between processes.
+
+JAX's persistent compilation cache is placed from OUTSIDE: where
+``JAX_COMPILATION_CACHE_DIR`` is set, JAX reads it by itself and this module
+does nothing.  Where it is not, the cache lives at ``<checkout>/.jax_cache`` —
+a fixed path, because the path is part of what makes a cache hit, so a
+directory named after a pid, a time or a temporary file never hits.
+
+Called by the worker entry (``workers_main``) and by scripts that compile in
+their own process; worker processes inherit the variable from whoever
+started the node, through the raylet's (and the zygote's rebuilt) environment.
+"""
+
+from __future__ import annotations
+
+import os
+import sys
+
+_ENV = "JAX_COMPILATION_CACHE_DIR"
+_CHECKOUT = os.path.dirname(os.path.dirname(os.path.dirname(
+    os.path.abspath(__file__))))
+
+
+def configure() -> str:
+    """Returns the cache directory in force.  Safe before or after
+    ``import jax``; touches no backend."""
+    path = os.environ.get(_ENV)
+    if path:
+        return path
+    path = os.path.join(_CHECKOUT, ".jax_cache")
+    # children (and a jax not imported yet) read the variable ...
+    os.environ[_ENV] = path
+    jax = sys.modules.get("jax")
+    if jax is not None:
+        # ... a jax already imported read it too early
+        jax.config.update("jax_compilation_cache_dir", path)
+    return path

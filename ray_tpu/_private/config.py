@@ -83,9 +83,8 @@ class RayTpuConfig:
     scheduler_spread_threshold: float = 0.5
     # --- worker pool ---
     num_prestart_workers: int = 0
-    # fork workers off a warm pre-imported zygote process (linux): ~50 ms
-    # per spawn vs ~2.3 s full interpreter startup on images whose
-    # sitecustomize imports jax everywhere (see _private/zygote.py)
+    # fork workers off a warm pre-imported zygote process (linux) instead
+    # of paying interpreter + import startup per spawn (_private/zygote.py)
     enable_worker_zygote: bool = True
     maximum_startup_concurrency: int = 4
     idle_worker_kill_timeout_s: float = 300.0

@@ -59,15 +59,17 @@ from ray_tpu._private.analysis.lock_witness import make_lock
 # folds every row under this prefix; serve/_private/replica.py publishes)
 UTIL_KV_PREFIX = "util:"
 
-# peak bf16 FLOPs/s per chip by device kind (bench.py and the MFU gauges
-# share this table so the roofline denominator is declared once)
+# Published peak bf16 FLOP/s of ONE chip, keyed by ``device.device_kind``
+# exactly as JAX reports it.  The one table: bench.py and the MFU gauges
+# both read it.  Source: Google Cloud TPU documentation, system
+# architecture pages "TPU v4", "TPU v5e", "TPU v5p", "TPU v6e".  A device
+# that is not here is an error, never a default: a utilization over a
+# guessed peak is not a measurement.
 PEAK_FLOPS = {
-    "v5 lite": 197e12,  # v5e
-    "v5e": 197e12,
-    "v5p": 459e12,
-    "v4": 275e12,
-    "v6 lite": 918e12,  # trillium
-    "cpu": 1e12,  # nominal, for smoke runs off-TPU
+    "TPU v4": 275e12,
+    "TPU v5 lite": 197e12,  # v5e
+    "TPU v5p": 459e12,
+    "TPU v6 lite": 918e12,  # v6e
 }
 
 
@@ -78,19 +80,20 @@ def enabled() -> bool:
 
 
 def peak_flops(device=None) -> float:
-    """Peak bf16 FLOPs/s for ``device`` (default: first local device)."""
+    """Published peak bf16 FLOP/s of ``device`` (default: this process's
+    first device).  Raises for a device the table does not know."""
     if device is None:
-        try:
-            import jax
+        import jax
 
-            device = jax.devices()[0]
-        except Exception:  # noqa: BLE001 — no backend: nominal CPU figure
-            return PEAK_FLOPS["cpu"]
-    kind = str(getattr(device, "device_kind", "cpu")).lower()
-    for k, v in PEAK_FLOPS.items():
-        if k in kind:
-            return v
-    return PEAK_FLOPS["v5e"]
+        device = jax.devices()[0]
+    kind = str(device.device_kind)
+    try:
+        return PEAK_FLOPS[kind]
+    except KeyError:
+        raise ValueError(
+            f"no published peak FLOP/s for device_kind {kind!r}: add it to "
+            "device_telemetry.PEAK_FLOPS with its source, or pass the peak "
+            "explicitly") from None
 
 
 # ---------------------------------------------------------------------------

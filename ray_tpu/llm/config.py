@@ -111,8 +111,9 @@ class LLMConfig:
     plasma_kv_cache_blocks: int = 0
     # True -> the pallas TPU paged-attention kernel for decode (single-chip
     # TPU, head_dim % 128 == 0, pp == 1). None = auto: ON where supported
-    # (measured v5e b32: ties the XLA block-gather at span 256, 2.2x faster
-    # at span 1024 — benchmarks/paged_bisect.py). True forces it (raises
+    # (its speed against the XLA block-gather: not measured on this
+    # round's code; chip_smoke.py shows it selected and agreeing with the
+    # float32 reference). True forces it (raises
     # off-TPU); False forces the gather path; "interpret" is a test hook
     # that runs the kernel in pallas interpret mode off-TPU.
     paged_attention_kernel: Optional[Any] = None
@@ -151,7 +152,27 @@ class LLMConfig:
         if chips is None:
             chips = (self.tensor_parallel_size * self.pipeline_parallel_size
                      * self.data_parallel_size)
+            # a one-chip replica holds its chip through the lease wherever
+            # there are chips: the lease is what binds TPU_VISIBLE_CHIPS,
+            # and without it four such replicas on a four-chip host would
+            # each open all four.  Where there is no chip (the CPU lanes)
+            # it asks for none and still schedules.
+            if chips == 1 and not _cluster_has_tpu():
+                chips = 0
         res: Dict[str, float] = {"CPU": 1.0}
-        if chips > 0 and (chips > 1 or self.chips_per_replica is not None):
+        if chips > 0:
             res["TPU"] = float(chips)
         return res
+
+
+def _cluster_has_tpu() -> bool:
+    """Chips the connected cluster advertises; this machine's own (device
+    files, no JAX) when no cluster is connected yet."""
+    import ray_tpu
+
+    if ray_tpu.is_initialized():
+        return ray_tpu.cluster_resources().get("TPU", 0) > 0
+    from ray_tpu._private.accelerators import get_accelerator_manager
+
+    return get_accelerator_manager(
+        "TPU").get_current_node_num_accelerators() > 0

@@ -129,6 +129,83 @@ class LLMServer:
             row["deployment"] = self._slo_label
         return row
 
+    def device_report(self) -> Dict[str, Any]:
+        """What this replica's process holds and chose, for whoever has to
+        show it from outside (``chip_smoke.py``): the devices JAX gave it,
+        the chips its lease bound, which attention path the engine took,
+        whether ``warmup()`` ran, allocator peaks per device, and which
+        native components this process loaded."""
+        import os
+
+        import jax
+
+        from ray_tpu import _native
+
+        eng = self._engine
+        devices = jax.devices()
+        memory = []
+        for d in devices:
+            stats = d.memory_stats() or {}
+            memory.append({k: int(stats[k]) for k in
+                           ("bytes_in_use", "peak_bytes_in_use", "bytes_limit")
+                           if k in stats})
+        if not getattr(eng, "_use_kernel", False):
+            attention = "gather"
+        elif getattr(eng, "_kernel_interpret", False):
+            attention = "kernel-interpret"
+        else:
+            attention = "kernel"
+        return {
+            "pid": os.getpid(),
+            "platform": devices[0].platform,
+            "device_kind": devices[0].device_kind,
+            "device_count": len(devices),
+            "device_ids": [d.id for d in devices],
+            "visible_chips": os.environ.get("TPU_VISIBLE_CHIPS"),
+            "engine": type(eng).__name__,
+            "n_layers": eng.cfg.n_layers,
+            "num_blocks": getattr(eng, "num_blocks", None),
+            "paged_attention": attention,
+            "warmup": getattr(eng, "warmup_report", None),
+            "memory": memory,
+            "compile_cache_dir": jax.config.jax_compilation_cache_dir,
+            "native": _native.status(),
+            "utilization": self.utilization(),
+        }
+
+    def reference_check(self, prompt: Sequence[int],
+                        served: Sequence[int]) -> Dict[str, Any]:
+        """Hold greedy tokens this replica served for ``prompt`` against
+        the plain float32 forward of the same weights
+        (``models/llama_reference.py``), teacher-forced over prompt +
+        served: for each served token, the reference logit it gives up
+        against the reference's own argmax at that position (0 where they
+        agree).  The caller states the tolerance."""
+        import numpy as np
+
+        from ray_tpu.models.llama_reference import reference_logits
+
+        eng = self._engine
+        seq = list(prompt) + list(served)
+        rows = np.asarray(reference_logits(eng.cfg, eng.params, seq[:-1]))[
+            len(prompt) - 1:]
+        served = np.asarray(served)
+        gaps = rows.max(-1) - rows[np.arange(len(served)), served]
+        argmax = rows.argmax(-1)
+        diverged = np.nonzero(argmax != served)[0]
+        return {
+            "finite": bool(np.isfinite(rows).all()),
+            "reference_argmax": argmax.tolist(),
+            "logit_gaps": gaps.tolist(),
+            "max_logit_gap": float(gaps.max()),
+            "first_divergent": int(diverged[0]) if len(diverged) else None,
+            "logit_std": float(rows.std()),
+        }
+
+    def first_decode_logits(self, prompt: Sequence[int]):
+        """The base engine's ``first_decode_logits`` (paged engine)."""
+        return self._engine.first_decode_logits(prompt)
+
     def prefix_digest(self) -> Dict[str, Any]:
         """Cache-aware routing surface (serve/handle.py): the base engine's
         prefix-chain digest plus the adapter ids this replica has loaded

@@ -27,7 +27,7 @@ from jax import lax
 from jax.ad_checkpoint import checkpoint_name
 from jax.sharding import Mesh, PartitionSpec as P
 
-from ray_tpu.ops.attention import multi_head_attention
+from ray_tpu.ops.attention import mesh_attention, multi_head_attention
 from ray_tpu.ops.norms import rms_norm
 from ray_tpu.ops.ring_attention import ring_attention
 from ray_tpu.ops.rope import apply_rope, rope_frequencies
@@ -248,9 +248,7 @@ def _layer(cfg: LlamaConfig, x, lp, cos, sin, mesh, context_parallel):
             k_r = apply_rope(k_, cos, sin, positions=pos)
             return ring_attention(q_r, k_r, v_, "context", causal=True)
 
-        from ray_tpu.util.jax_compat import shard_map as _shard_map
-
-        attn = _shard_map(
+        attn = jax.shard_map(
             attn_fn,
             mesh=mesh,
             axis_names={"context"},
@@ -260,7 +258,7 @@ def _layer(cfg: LlamaConfig, x, lp, cos, sin, mesh, context_parallel):
     else:
         q = apply_rope(q, cos[:s], sin[:s])
         k = apply_rope(k, cos[:s], sin[:s])
-        attn = multi_head_attention(q, k, v, causal=True)
+        attn = mesh_attention(q, k, v, mesh=mesh, batch_axes=BATCH_AXES)
     attn = attn.reshape(b, s, cfg.n_heads * cfg.head_dim)
     attn = checkpoint_name(attn, "attn_out")
     x = x + (attn @ lp["wo"].astype(cdt))
@@ -592,15 +590,13 @@ def _tp_out_proj(a, w, tp_plan: Optional["TPPlan"], token):
     world = int(mesh.shape.get(axis, 1))
     if world <= 1:
         return a @ w, token
-    from ray_tpu.util.jax_compat import shard_map as _shard_map
-
     def body(a_, w_):
         return _tp_allreduce_local(a_ @ w_, axis, world, tp_plan.algorithm)
 
     a_spec = P(*([None] * (a.ndim - 1) + [axis]))
-    out = _shard_map(body, mesh=mesh, in_specs=(a_spec, P(axis, None)),
-                     out_specs=P(*([None] * a.ndim)),
-                     check_rep=False)(a, w)
+    out = jax.shard_map(body, mesh=mesh, in_specs=(a_spec, P(axis, None)),
+                        out_specs=P(*([None] * a.ndim)),
+                        check_vma=False)(a, w)
     if token is not None:
         out, token = lax.optimization_barrier((out, token))
     return out, token
@@ -624,18 +620,17 @@ def _paged_attend(cfg: LlamaConfig, q, ck, cv, span_mask):
 
 
 def paged_kernel_supported(cfg: LlamaConfig) -> bool:
-    """Whether the fused pallas paged-attention kernel applies: TPU backend,
-    lane-aligned head_dim, and the kernel import available."""
+    """Whether the fused pallas paged-attention kernel applies: TPU backend
+    and lane-aligned head_dim.  On a TPU backend a kernel that cannot be
+    imported is an error, never a reason to take the gather path."""
     if jax.default_backend() != "tpu":
         return False
     if cfg.head_dim % 128:
         return False
-    try:
-        from ray_tpu.ops.paged_attention import (  # noqa: F401
-            paged_decode_attention,
-        )
-    except ImportError:
-        return False
+    from ray_tpu.ops.paged_attention import (  # noqa: F401
+        paged_decode_attention,
+    )
+
     return True
 
 
@@ -702,13 +697,11 @@ def decode_step_paged(cfg: LlamaConfig, params: Params, tokens: jnp.ndarray,
             kern = partial(paged_decode_attention,
                            interpret=kernel_interpret)
             if mesh is not None and mesh.shape.get("tensor", 1) > 1:
-                from jax.experimental.shard_map import shard_map
-
                 t = P(None, None, None, "tensor")
-                kern = shard_map(
+                kern = jax.shard_map(
                     kern, mesh=mesh,
                     in_specs=(P(None, "tensor", None), t, t, P(), P(), P()),
-                    out_specs=P(None, "tensor"), check_rep=False)
+                    out_specs=P(None, "tensor"), check_vma=False)
             attn = kern(q[:, 0], pk_all, pv_all, li, table, lengths)
         else:
             ck = pk_all[li, table].reshape(b, w * bs, cfg.n_kv_heads,

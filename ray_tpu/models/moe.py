@@ -32,7 +32,7 @@ from jax import lax
 from jax.ad_checkpoint import checkpoint_name
 from jax.sharding import Mesh, PartitionSpec as P
 
-from ray_tpu.ops.attention import multi_head_attention
+from ray_tpu.ops.attention import mesh_attention
 from ray_tpu.ops.norms import rms_norm
 from ray_tpu.ops.rope import apply_rope, rope_frequencies
 
@@ -206,15 +206,15 @@ def _gmm_supported(cfg: MoEConfig, n_rows: int, mesh) -> bool:
     """Whether the pallas megablox grouped-matmul kernel applies: TPU
     backend, UNSHARDED (a pallas custom call has no GSPMD partitioning
     rule — under a mesh the partitionable lax.ragged_dot HLO must stay),
-    lane-aligned dims, and row count divisible by the m-tile."""
+    lane-aligned dims, and row count divisible by the m-tile.  On a TPU
+    backend a kernel that cannot be imported is an error, never a reason
+    to take the other path."""
     if mesh is not None or jax.default_backend() != "tpu":
         return False
     if cfg.dim % 128 or cfg.ffn_dim % 128 or n_rows % _GMM_TILE_M:
         return False
-    try:
-        from jax.experimental.pallas.ops.tpu.megablox.ops import gmm  # noqa: F401
-    except ImportError:
-        return False
+    from jax.experimental.pallas.ops.tpu.megablox.ops import gmm  # noqa: F401
+
     return True
 
 
@@ -407,7 +407,7 @@ def _layer(cfg: MoEConfig, carry, lp, cos, sin, mesh):
     kk = _constraint(kk, P(MOE_BATCH_AXES, None, "tensor", None), mesh)
     q = apply_rope(q, cos[:s], sin[:s])
     kk = apply_rope(kk, cos[:s], sin[:s])
-    attn = multi_head_attention(q, kk, v, causal=True)
+    attn = mesh_attention(q, kk, v, mesh=mesh, batch_axes=MOE_BATCH_AXES)
     attn = attn.reshape(b, s, cfg.n_heads * cfg.head_dim)
     attn = checkpoint_name(attn, "attn_out")
     x = x + (attn @ lp["wo"].astype(cdt))

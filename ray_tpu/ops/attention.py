@@ -18,6 +18,7 @@ import functools
 
 import jax
 import jax.numpy as jnp
+from jax.sharding import PartitionSpec as P
 
 
 def _repeat_kv(x: jnp.ndarray, n_rep: int) -> jnp.ndarray:
@@ -103,3 +104,22 @@ def multi_head_attention(
             q, k, v, causal=causal, scale=scale, block_q=block_q, block_k=block_k
         )
     return reference_attention(q, k, v, causal=causal, segment_ids=segment_ids, scale=scale)
+
+
+def mesh_attention(q, k, v, *, mesh, batch_axes, causal: bool = True):
+    """``multi_head_attention`` for activations sharded over ``mesh``
+    (batch over ``batch_axes``, heads over ``"tensor"``).
+
+    A Mosaic kernel has no GSPMD partitioning rule ("Mosaic kernels cannot
+    be automatically partitioned"), so on a TPU backend, where the flash
+    kernel is what gets selected, the call runs per shard under
+    ``shard_map``: attention is independent per batch row and per kv-head
+    group, so each shard's result is its slice of the whole.  Without a
+    mesh, or off-TPU (the jnp path, which XLA partitions by itself), this
+    is the plain call."""
+    if mesh is None or jax.default_backend() != "tpu":
+        return multi_head_attention(q, k, v, causal=causal)
+    spec = P(batch_axes, None, "tensor", None)
+    return jax.shard_map(
+        functools.partial(multi_head_attention, causal=causal), mesh=mesh,
+        in_specs=(spec,) * 3, out_specs=spec, check_vma=False)(q, k, v)

@@ -80,9 +80,7 @@ def ring_attention(
     is the concatenation over the ring in axis order. Returns the local
     output shard [B, S_local, H, D].
     """
-    from ray_tpu.util.jax_compat import axis_size as _axis_size
-
-    n = _axis_size(axis_name)
+    n = lax.axis_size(axis_name)
     idx = lax.axis_index(axis_name)
     b, s_local, hq, d = q.shape
     if scale is None:

@@ -85,17 +85,10 @@ class MeshSpec:
                     for d in devices}
         if (self.num_slices > 1 or self.pipeline > 1) and len(granules) > 1:
             return self._build_hybrid(devices, shape)
-        try:
-            # Auto axis types: shardings flow via with_sharding_constraint +
-            # XLA propagation (jax >= 0.8 defaults new meshes to Explicit).
-            # Older jax lacks AxisType (AttributeError) or the axis_types
-            # kwarg (TypeError) — both take the plain-Mesh path.
-            auto = (jax.sharding.AxisType.Auto,) * len(MESH_AXES)
-            return jax.make_mesh(shape, MESH_AXES, devices=devices, axis_types=auto)
-        except (TypeError, AttributeError):
-            import numpy as np
-
-            return Mesh(np.asarray(devices).reshape(shape), MESH_AXES)
+        # Auto axis types: shardings flow via with_sharding_constraint +
+        # XLA propagation (make_mesh would default new meshes to Explicit)
+        auto = (jax.sharding.AxisType.Auto,) * len(MESH_AXES)
+        return jax.make_mesh(shape, MESH_AXES, devices=devices, axis_types=auto)
 
     def _build_hybrid(self, devices: Sequence, shape) -> Mesh:
         """ICI×DCN mesh: per-slice shape × across-slice shape."""

@@ -137,12 +137,9 @@ def make_pipeline_loss(num_microbatches: int):
 
             init = (buf0, jnp.zeros((), jnp.float32), jnp.zeros((), jnp.int32))
             # the carry becomes device-varying through ppermute/axis_index;
-            # the initial values must carry the same vma type (identity on
-            # old jax, which has no vma typing — see jax_compat.pcast)
-            from ray_tpu.util.jax_compat import pcast as _pcast
-
+            # the initial values must carry the same vma type
             init = jax.tree.map(
-                lambda x: _pcast(x, ("pipeline",), to="varying"), init)
+                lambda x: lax.pcast(x, ("pipeline",), to="varying"), init)
             (_, loss_sum, n), _ = lax.scan(
                 tick, init, jnp.arange(m + pp - 1))
             total = lax.psum(loss_sum, "pipeline")
@@ -152,9 +149,7 @@ def make_pipeline_loss(num_microbatches: int):
         layer_specs = jax.tree.map(
             lambda a: P(*(("pipeline",) + (None,) * (a.ndim - 1))),
             params["layers"])
-        from ray_tpu.util.jax_compat import shard_map as _shard_map
-
-        return _shard_map(
+        return jax.shard_map(
             staged,
             mesh=mesh,
             axis_names={"pipeline"},

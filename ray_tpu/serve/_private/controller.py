@@ -38,7 +38,8 @@ def _cfg_hash(cfg: dict) -> str:
     """Identity of a deployment's code+config (replicas restart when it
     changes; num_replicas alone does not force a restart)."""
     import hashlib
-    import pickle
+
+    import cloudpickle
 
     blob = cfg.get("serialized_callable") or b""
     digest = _digest_cache.get(blob)
@@ -50,7 +51,11 @@ def _cfg_hash(cfg: dict) -> str:
     key = (digest, cfg.get("init_args"),
            cfg.get("init_kwargs"), cfg.get("user_config"),
            cfg.get("ray_actor_options"), cfg.get("max_ongoing_requests"))
-    return hashlib.sha1(pickle.dumps(key)).hexdigest()
+    # cloudpickle, like every other hop of the init args: plain pickle
+    # cannot reach a class defined in the deploying script's __main__ (a
+    # user's tokenizer), and the reconcile loop then failed forever while
+    # serve.run() waited in silence
+    return hashlib.sha1(cloudpickle.dumps(key)).hexdigest()
 
 
 class ServeController:

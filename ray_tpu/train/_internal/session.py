@@ -248,9 +248,14 @@ class _TrainSession:
         if mf and last is not None and now > last:
             from ray_tpu._private import device_telemetry
 
-            mfu = device_telemetry.note_train_step(
-                self.run_name, model_flops=float(mf), wall_s=now - last)
-            metrics.setdefault("mfu", round(mfu, 4))
+            try:
+                mfu = device_telemetry.note_train_step(
+                    self.run_name, model_flops=float(mf), wall_s=now - last)
+                metrics.setdefault("mfu", round(mfu, 4))
+            except ValueError:
+                # a device with no published peak (the CPU lanes): no MFU
+                # is booked and none rides the report — absent, not guessed
+                pass
         iw = self.consume_input_wait()
         if iw > 0 and "input_wait_s" not in metrics:
             # measured buffer-empty seconds ride every report; an explicit

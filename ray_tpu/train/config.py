@@ -40,7 +40,14 @@ class ScalingConfig:
             if chips is None:
                 from ray_tpu._private.accelerators import get_accelerator_manager
 
-                chips = get_accelerator_manager("TPU").get_current_node_num_accelerators() or 4
+                chips = get_accelerator_manager(
+                    "TPU").get_current_node_num_accelerators()
+                if chips <= 0:
+                    raise ValueError(
+                        "ScalingConfig(use_tpu=True) found no TPU chip on "
+                        "this node (no /dev/accel* or /dev/vfio/<n>): set "
+                        "chips_per_worker or resources_per_worker to say "
+                        "how many each worker holds elsewhere")
             res["TPU"] = float(chips)
         if self.accelerator_type:
             res[f"accelerator_type:{self.accelerator_type}"] = 0.001

@@ -30,21 +30,13 @@ _PSUM_OPS = {
 }
 
 
-from ray_tpu.util.jax_compat import shard_map as _shard_map  # noqa: E402
-
-
 def _shard_map_unchecked(f, **kw):
     """shard_map without replication checking: the quantized/hierarchical
     programs end in all_gathers whose outputs are replicated in VALUE but
-    not provably so to check_rep, so the checker must be off for out_specs
-    P().  Older/newer jax spell the flag differently; fall back to the
-    checked path if neither spelling exists."""
-    for flag in ("check_rep", "check_vma"):
-        try:
-            return _shard_map(f, **kw, **{flag: False})
-        except TypeError:
-            continue
-    return _shard_map(f, **kw)
+    not provably so to the checker, so it must be off for out_specs P()."""
+    import jax
+
+    return jax.shard_map(f, **kw, check_vma=False)
 
 
 def build_quantized_allreduce(mesh, axis_name: str, world_size: int,
@@ -338,7 +330,7 @@ class XLAGroup(BaseGroup):
                 return getattr(jax.lax, op_name)(x, "world")[0]
 
             fn = jax.jit(
-                _shard_map(body, mesh=self._mesh, in_specs=P("world"), out_specs=P())
+                jax.shard_map(body, mesh=self._mesh, in_specs=P("world"), out_specs=P())
             )
             self._fn_cache[("allreduce", op_name)] = fn
         return fn
@@ -357,7 +349,7 @@ class XLAGroup(BaseGroup):
                 return jax.lax.dynamic_slice_in_dim(summed, idx * shard, shard, axis=0)
 
             fn = jax.jit(
-                _shard_map(body, mesh=self._mesh, in_specs=P("world"), out_specs=P("world"))
+                jax.shard_map(body, mesh=self._mesh, in_specs=P("world"), out_specs=P("world"))
             )
             self._fn_cache[("reducescatter", op_name)] = fn
         return fn
@@ -656,7 +648,7 @@ class XLAGroup(BaseGroup):
                 return jax.lax.ppermute(x, "pair", [(0, 1)])
 
             fn = jax.jit(
-                _shard_map(body, mesh=mesh, in_specs=P("pair"), out_specs=P("pair"))
+                jax.shard_map(body, mesh=mesh, in_specs=P("pair"), out_specs=P("pair"))
             )
             self._fn_cache[key] = fn
             self._fn_cache[("p2p_mesh", src_rank, dst_rank)] = mesh

@@ -1,0 +1,155 @@
+"""The main path's Pallas kernels, compiled for a described TPU v5e at the
+widths ``chip_smoke.py`` runs them at.
+
+No chip is attached: the TPU compiler that is installed here compiles for a
+topology that is described (``v5e:2x2``), and refuses what the chip's would
+refuse — a misaligned slice, too much fast memory, a kernel that cannot be
+partitioned.  Nothing runs, so nothing here says anything about results or
+speed.  Each compile takes a second or two.
+
+The topology is described inside a module-scoped fixture and nowhere else:
+only one process may hold the TPU library, so nothing at import,
+``skipif`` or ``parametrize`` time may touch it, and every compile happens
+in this test's own process.  All such tests live in this one file.
+"""
+
+import functools
+import os
+
+import pytest
+
+os.environ.setdefault("TPU_LOG_DIR", "disabled")
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+from jax.sharding import Mesh, NamedSharding, PartitionSpec as P, SingleDeviceSharding
+
+BF16 = jnp.bfloat16
+
+
+@pytest.fixture(scope="module")
+def topo():
+    from jax.experimental import topologies
+
+    try:
+        return topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001 — no TPU compiler here: skip, say why
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture(scope="module")
+def tensor_mesh(topo):
+    return Mesh(np.asarray(topo.devices).reshape(4), ("tensor",))
+
+
+@pytest.fixture(autouse=True)
+def _no_compile_cache():
+    """A compile for a described device is written to the persistent cache
+    but cannot be read back without a chip: keep the cache out of it."""
+    from jax.experimental.compilation_cache import compilation_cache as cc
+
+    before = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    cc.reset_cache()
+    yield
+    jax.config.update("jax_enable_compilation_cache", before)
+    cc.reset_cache()
+
+
+def _spec(shape, dtype, sharding):
+    return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
+
+
+def _assert_kernel(fn, *args):
+    text = jax.jit(fn).lower(*args).compile().as_text()
+    assert "tpu_custom_call" in text
+
+
+# -- paged decode attention: Llama-3-8B heads (32 q / 8 kv, head_dim 128) ----
+
+_B, _NH, _KV, _HD, _L, _NB = 8, 32, 8, 128, 8, 2048
+
+
+def _paged_args(bs, w, sh_q, sh_pool, sh_rep):
+    pool = _spec((_L, _NB, bs, _KV * _HD), BF16, sh_pool)
+    return (_spec((_B, _NH, _HD), BF16, sh_q), pool, pool,
+            _spec((), jnp.int32, sh_rep), _spec((_B, w), jnp.int32, sh_rep),
+            _spec((_B,), jnp.int32, sh_rep))
+
+
+@pytest.mark.parametrize("bs", [16, 32])
+@pytest.mark.parametrize("w", [8, 64])
+def test_paged_decode_attention_compiles(one_chip, bs, w):
+    from ray_tpu.ops.paged_attention import paged_decode_attention
+
+    _assert_kernel(paged_decode_attention,
+                   *_paged_args(bs, w, one_chip, one_chip, one_chip))
+
+
+@pytest.mark.parametrize("bs", [16, 32])
+@pytest.mark.parametrize("w", [8, 64])
+def test_paged_decode_attention_compiles_under_4_shard_map(tensor_mesh, bs, w):
+    """The tensor-parallel engine's wrap (models/llama.py decode_step_paged):
+    kv heads over "tensor", per-shard kv*hd = 256."""
+    from ray_tpu.ops.paged_attention import paged_decode_attention
+
+    t = P(None, None, None, "tensor")
+    kern = jax.shard_map(
+        paged_decode_attention, mesh=tensor_mesh,
+        in_specs=(P(None, "tensor", None), t, t, P(), P(), P()),
+        out_specs=P(None, "tensor"), check_vma=False)
+    ns = functools.partial(NamedSharding, tensor_mesh)
+    _assert_kernel(kern, *_paged_args(
+        bs, w, ns(P(None, "tensor", None)), ns(t), ns(P())))
+
+
+# -- flash attention: the 1.14 B train shape and the 8 B widths ----------------
+
+_FLASH = {"train_1b": (8, 2048, 16, 8), "llama3_8b": (1, 2048, 32, 8)}
+
+
+def _flash_args(name, sharding):
+    b, s, hq, hkv = _FLASH[name]
+    return (_spec((b, s, hq, _HD), BF16, sharding),
+            _spec((b, s, hkv, _HD), BF16, sharding),
+            _spec((b, s, hkv, _HD), BF16, sharding))
+
+
+@pytest.mark.parametrize("name", sorted(_FLASH))
+def test_flash_attention_forward_compiles(one_chip, name):
+    from ray_tpu.ops.flash_attention import flash_attention
+
+    _assert_kernel(functools.partial(flash_attention, causal=True),
+                   *_flash_args(name, one_chip))
+
+
+@pytest.mark.parametrize("name", sorted(_FLASH))
+def test_flash_attention_forward_backward_compiles(one_chip, name):
+    from ray_tpu.ops.flash_attention import flash_attention
+
+    def loss(q, k, v):
+        return flash_attention(q, k, v, causal=True).astype(jnp.float32).sum()
+
+    _assert_kernel(jax.grad(loss, argnums=(0, 1, 2)),
+                   *_flash_args(name, one_chip))
+
+
+# -- grouped matmul (megablox) at the expert model's tiling -------------------
+
+
+def test_gmm_compiles_at_moe_tiling(one_chip):
+    from jax.experimental.pallas.ops.tpu.megablox.ops import gmm
+
+    _assert_kernel(
+        functools.partial(gmm, preferred_element_type=BF16,
+                          tiling=(512, 512, 2048)),
+        _spec((65536, 2048), BF16, one_chip),
+        _spec((8, 2048, 4096), BF16, one_chip),
+        _spec((8,), jnp.int32, one_chip))

@@ -183,7 +183,8 @@ def test_disabled_path_books_nothing(reset_telemetry):
         assert dt.engine_telemetry_for("some-dep") is None
         # every recorder goes quiet (the snapshot APIs still work)
         dt.record_hbm()
-        dt.note_train_step("off-run", model_flops=1e12, wall_s=1.0)
+        dt.note_train_step("off-run", model_flops=1e12, wall_s=1.0,
+                           peak=1e12)
         dt.note_serving_rate("off-dep", 500.0)
         dt.note_trace("off-program", shape_key=(1,))
         dt._watch.note_compile("off-program", 0.25)
@@ -472,9 +473,12 @@ def test_profile_roundtrip_and_storm_in_diagnose(ray_start_regular,
 
 
 def _round(tmp_path, name, parsed):
+    """One round in the driver's wrapper shape: {n, cmd, rc, tail, parsed},
+    where ``tail`` is the end of bench.py's output (its last line is the
+    result document) and ``parsed`` that document."""
     p = tmp_path / name
     p.write_text(json.dumps({"n": 1, "cmd": "bench", "rc": 0,
-                             "parsed": parsed}))
+                             "tail": json.dumps(parsed), "parsed": parsed}))
     return str(p)
 
 
@@ -526,12 +530,21 @@ def test_bench_diff_tolerates_partial_rounds(tmp_path):
     assert main(["--dir", str(tmp_path), "--threshold", "1000"]) == 0
 
 
-def test_bench_diff_reads_checked_in_rounds():
+def test_bench_diff_reads_checked_in_rounds(tmp_path):
     from tools.bench_diff import run
 
-    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-    report = run(os.path.join(root, "BENCH_r01.json"),
-                 os.path.join(root, "BENCH_r03.json"), threshold=0.5)
+    # two rounds of the record's shape and trajectory (ROADMAP "What the
+    # record holds": r01 and r03), written here: the record files
+    # themselves left the tree with the device link they were taken through
+    def r(mfu, tps, step):
+        return {"metric": "llama1b_train_mfu_1chip", "value": mfu,
+                "unit": "MFU", "vs_baseline": round(mfu / 0.40, 4),
+                "extra": {"tokens_per_sec": tps, "step_time_s": step,
+                          "device": "TPU v5 lite"}}
+
+    report = run(_round(tmp_path, "BENCH_r01.json", r(0.6461, 16631.9, 0.9851)),
+                 _round(tmp_path, "BENCH_r03.json", r(0.6452, 16609.2, 0.9865)),
+                 threshold=0.5)
     # the real trajectory: headline leaves shared and compared
     assert "headline" in report["sections"]
     metrics = {r["metric"] for r in report["sections"]["headline"]}

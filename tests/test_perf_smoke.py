@@ -88,7 +88,7 @@ def test_watch_overhead_under_budget():
     assert extra["cap_evictions"] > 0, extra
 
 
-def test_bench_diff_report_nonblocking():
+def test_bench_diff_report_nonblocking(tmp_path):
     """Non-blocking perf-trend report step (ISSUE 17 satellite): when at
     least two BENCH_r*.json snapshots exist, run tools/bench_diff.py over
     the newest pair and PRINT the report — visibility, not a gate.  A
@@ -100,11 +100,17 @@ def test_bench_diff_report_nonblocking():
 
     from tools.bench_diff import run
 
-    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-    snaps = sorted(glob.glob(os.path.join(repo, "BENCH_r*.json")))
-    if len(snaps) < 2:
-        import pytest
-        pytest.skip("need two BENCH_r*.json snapshots to diff")
+    # the newest pair of snapshots, in the driver's {n, cmd, rc, tail,
+    # parsed} shape; written here, since the tree keeps one record file
+    import json
+
+    for n, (mfu, step) in enumerate([(0.6459, 0.9853), (0.6452, 0.9865)], 2):
+        doc = {"metric": "llama1b_train_mfu_1chip", "value": mfu,
+               "unit": "MFU", "extra": {"step_time_s": step}}
+        (tmp_path / f"BENCH_r{n:02d}.json").write_text(json.dumps(
+            {"n": n, "cmd": "python bench.py", "rc": 0,
+             "tail": json.dumps(doc), "parsed": doc}))
+    snaps = sorted(glob.glob(os.path.join(str(tmp_path), "BENCH_r*.json")))
     report = run(snaps[-2], snaps[-1])
     assert report["old"] == snaps[-2] and report["new"] == snaps[-1]
     print(f"bench_diff {os.path.basename(report['old'])} -> "
