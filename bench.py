@@ -1847,19 +1847,6 @@ def _bench_control_plane() -> dict:
     return {"rows": rows}
 
 
-def _trace_summary_snapshot() -> dict:
-    """Process-local tracing telemetry (enabled flags, spans emitted, last
-    trace id + its critical-path summary when a cluster is connected) — so
-    BENCH_*.json records whether the run was traced and what the causal
-    breakdown looked like, alongside collective_metrics."""
-    try:
-        from ray_tpu.util import tracing
-
-        return tracing.trace_summary_snapshot()
-    except Exception as e:  # noqa: BLE001
-        return {"error": str(e)[:200]}
-
-
 def _collective_metrics_snapshot() -> dict:
     """This process's built-in collective metric points (see
     runtime_metrics.collective_snapshot): {op/wsN: {bytes_total, ops,
@@ -2174,7 +2161,6 @@ def main():
         "collective_metrics": _collective_metrics_snapshot(),
         "compressed_collective": _compression_snapshot(),
         "collective_plan": _plan_snapshot(),
-        "trace_summary": _trace_summary_snapshot(),
         "goodput": _goodput_snapshot(),
         "ingest": _ingest_snapshot(),
         "rl": _rl_snapshot(),

@@ -12,9 +12,14 @@ This module observes the *chip* and the programs running on it:
   2. **Engine utilization & headroom** — :class:`EngineTelemetry`, the
      per-engine recorder the paged/static engines drive from ``step()``:
      decode slot occupancy, KV block occupancy, chunked-prefill budget
-     spend, and step duty cycle (device-dispatch seconds over wall).
-     Values are captured under the engine lock into locals and booked
-     AFTER release (the PhaseRecorder discipline).  Per-replica rows fold
+     spend, and the step duty cycle: the engine LOOP's occupancy of host
+     wall time (seconds inside ``step()`` over seconds since the last
+     step ended), which the pool autoscaler reads as "is this replica's
+     loop ever idle".  It is not a device figure: a step blocks on device
+     reads and does host booking alike.  For the device, read the
+     engine's ``counters`` (``device_wait_s`` against ``host_s``) or a
+     ``state.jax_profile`` trace.  Values are captured under the engine
+     lock into locals and booked AFTER release.  Per-replica rows fold
      into ``state.utilization()`` / ``/api/utilization`` — the
      SLO-feedback autoscaler's input surface (ROADMAP item 1).
   3. **Compile watch** — a process-wide jit-compile observer.
@@ -384,6 +389,16 @@ def storm_report(threshold: Optional[int] = None,
     return _watch.storm_report(threshold, window_s)
 
 
+def compile_totals() -> Tuple[int, float]:
+    """``(backend compiles, their seconds)`` this process has seen since
+    the first call registered the listener; an engine subtracts what it
+    read when ``warmup()`` returned."""
+    _install_listener()
+    with _watch._lock:
+        return (sum(_watch._compile_counts.values()),
+                sum(_watch._compile_seconds.values()))
+
+
 def compile_snapshot() -> dict:
     return _watch.snapshot()
 
@@ -405,9 +420,8 @@ def _on_jax_event(key: str, seconds: float, **_kw) -> None:
         _heartbeat_stamp()
 
 
-def install() -> None:
-    """Register the jax.monitoring compile listener and start the
-    telemetry heartbeat (both once per process, both best-effort)."""
+def _install_listener() -> None:
+    """Register the jax.monitoring compile listener, once per process."""
     global _installed
     if _installed:
         return
@@ -422,6 +436,12 @@ def install() -> None:
         except Exception:  # noqa: BLE001 — jax absent/too old: trace-only
             pass
         _installed = True
+
+
+def install() -> None:
+    """Register the jax.monitoring compile listener and start the
+    telemetry heartbeat (both once per process, both best-effort)."""
+    _install_listener()
     if enabled():
         _start_heartbeat()
 
@@ -536,10 +556,12 @@ class EngineTelemetry:
                   free_blocks: int, total_blocks: int, pending: int,
                   prefill_spent: int, prefill_budget: int,
                   busy_s: float, now: float) -> None:
-        """Book one engine step.  ``busy_s`` is the device-dispatch time
-        of the step body; wall is measured here as the time since the
-        previous step ended, so idle gaps between steps depress the duty
-        cycle exactly as they depress chip utilization."""
+        """Book one engine step.  ``busy_s`` is the wall time of the step
+        body (host booking, dispatch and blocking device reads together:
+        the engine's ``host_s + device_wait_s`` of this step); wall is
+        measured here as the time since the previous step ended, so the
+        duty cycle is the loop's occupancy of host wall time: idle gaps
+        between steps depress it, a slow device does not."""
         wall = now - self._last_step_end
         self._last_step_end = now
         self.active_slots = active_slots

@@ -543,7 +543,7 @@ RL_POLICY_LAG = Histogram(
 # scales on (ROADMAP item 1), the process-wide jit-compile watch, and the
 # MFU/roofline gauges.  Everything here is recorded OUTSIDE engine locks
 # (the note_step values are captured under the lock into locals and booked
-# after release, same discipline as the PhaseRecorder stamps).
+# after release).
 DEVICE_HBM_BYTES = Gauge(
     "ray_tpu_device_hbm_bytes",
     "Per-device HBM bytes by kind: used = live bytes in use (device "
@@ -575,9 +575,10 @@ ENGINE_PREFILL_SPEND = Gauge(
     tag_keys=("deployment",))
 ENGINE_STEP_DUTY = Gauge(
     "ray_tpu_engine_step_duty_cycle",
-    "Engine step duty cycle per deployment: device-dispatch seconds over "
+    "Engine loop duty cycle per deployment: seconds inside step() over "
     "wall seconds since the previous step ended (1.0 = the engine loop "
-    "never idles; low values with queued work indicate a stalled loop)",
+    "never idles; low values with queued work indicate a stalled loop); "
+    "host occupancy, not a device figure",
     tag_keys=("deployment",))
 JIT_COMPILES = Counter(
     "ray_tpu_jit_compiles_total",

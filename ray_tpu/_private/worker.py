@@ -27,7 +27,7 @@ import traceback
 import weakref
 from collections import defaultdict, deque
 from ray_tpu._private.analysis.lock_witness import make_lock, make_rlock
-from ray_tpu._private.utils import DaemonExecutor, fast_getpid
+from ray_tpu._private.utils import DaemonExecutor, fast_getpid, name_os_thread
 from typing import Any, Dict, List, Optional, Set, Tuple
 
 from ray_tpu._private import flight_recorder, runtime_metrics, serialization
@@ -1125,6 +1125,7 @@ class CoreWorker:
         server = self.server
 
         def run():
+            name_os_thread()
             try:
                 import jax
 

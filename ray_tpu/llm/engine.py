@@ -41,6 +41,7 @@ from ray_tpu._private import device_telemetry
 from ray_tpu.llm.config import GenerationConfig, LLMConfig
 from ray_tpu.models import llama
 from ray_tpu.ops.rope import rope_frequencies
+from ray_tpu.util import tracing
 
 
 # stop-token ids travel to the device as a fixed-width padded row per slot
@@ -81,6 +82,7 @@ def _masked_scaled(logits, temps, top_ks):
     return jnp.where((top_ks[:, None] > 0) & (scaled < kth), -1e30, scaled)
 
 
+@jax.named_scope("sample")
 def _sample(logits, key, temps, top_ks):
     """Sample [B] token ids from [B, V] logits with *per-slot* traced
     sampling params — one compiled program serves any mix of greedy /
@@ -283,8 +285,6 @@ class JaxLLMEngine:
         # readback overlaps the next chunk's compute, like the paged
         # engine.  (em_dev, active_slots).
         self._inflight = None
-        # monotonic ts of the last traced step's phase spans (rate limit)
-        self._last_phase_span = float("-inf")
         # serving deployment name (set via the replica's set_slo_label
         # threading); assigning one attaches device telemetry.  None
         # (direct engine use) keeps the disabled path: one attribute
@@ -471,21 +471,13 @@ class JaxLLMEngine:
 
         Returns {request_id: [tokens emitted this step]}.
         """
-        from ray_tpu.util import tracing
-
-        # PhaseRecorder: spans stamped under the lock, emitted after
-        # release (an emit_span GCS flush must not stall the decode path).
-        # Rate-limited per engine (~5 span sets/s) so a steady traced
-        # serving loop can't cycle the bounded GCS task sink.
-        rec = tracing.PhaseRecorder()
         now = time.monotonic()
-        traced = rec.active and now - self._last_phase_span >= 0.2
-        if traced:
-            self._last_phase_span = now
         # device telemetry: one attribute read + None check when disabled
         tel = self._telemetry
-        tel_active = tel_pending = 0
-        with self._lock:
+        with tracing.region(
+                "engine.step", pending=len(self._pending),
+                active=self.max_batch - self._slot_req.count(None),
+                inflight=int(self._inflight is not None)), self._lock:
             before = {id(r): len(r.out_tokens)
                       for r in self._requests.values()}
             if self._pending:
@@ -493,10 +485,9 @@ class JaxLLMEngine:
                 # after any in-flight chunk on the cache dataflow, and the
                 # new slot was inactive in that chunk (garbage rows are
                 # overwritten by the decode step that first uses them)
-                t_pf = time.time() if traced else 0.0
-                self._admit_locked()
-                if traced:
-                    rec.stamp("engine.admit_prefill", t_pf)
+                with tracing.region("engine.admit",
+                                    pending=len(self._pending)):
+                    self._admit_locked()
             active = [s for s in range(self.max_batch)
                       if self._slot_req[s] is not None]
             if active and decode:
@@ -532,32 +523,27 @@ class JaxLLMEngine:
                 # temperature / top-k callers share a single forward.
                 # PIPELINED: the chunk dispatched here is collected next
                 # step, its readback riding under this dispatch's compute.
-                t_dec = time.time() if traced else 0.0
-                (em_dev, self._d_next, self.cache, self._d_lengths,
-                 self._d_active, self._d_remaining, self._d_key) = \
-                    self._decode(
-                        self.params, self._d_next, self.cache,
-                        self._d_lengths, self._d_active, self._d_remaining,
-                        self._d_stops, self._d_key, self._d_temp,
-                        self._d_topk, self.config.decode_chunk)
+                with tracing.region("engine.decode_dispatch",
+                                    slots=len(active),
+                                    chunk=self.config.decode_chunk):
+                    (em_dev, self._d_next, self.cache, self._d_lengths,
+                     self._d_active, self._d_remaining, self._d_key) = \
+                        self._decode(
+                            self.params, self._d_next, self.cache,
+                            self._d_lengths, self._d_active,
+                            self._d_remaining, self._d_stops, self._d_key,
+                            self._d_temp, self._d_topk,
+                            self.config.decode_chunk)
                 prev, self._inflight = self._inflight, (em_dev, active)
                 if prev is not None:
                     self._book_chunk_locked(*prev)
-                if traced:
-                    rec.stamp("engine.decode", t_dec,
-                              {"active_slots": len(active),
-                               "chunk": self.config.decode_chunk})
             else:
                 self._collect_inflight_locked()
             emitted = self._gather_emitted_locked(before)
-            if tel is not None:
-                # captured under the lock into locals; booked after
-                # release next to rec.emit() (PhaseRecorder discipline)
-                tel_active = sum(1 for r in self._slot_req
-                                 if r is not None)
-                tel_pending = len(self._pending)
-        rec.emit()
+            tel_active = self.max_batch - self._slot_req.count(None)
+            tel_pending = len(self._pending)
         if tel is not None:
+            # booked after release, from the locals captured under the lock
             t_end = time.monotonic()
             tel.note_step(
                 active_slots=tel_active, max_slots=self.max_batch,
@@ -568,7 +554,8 @@ class JaxLLMEngine:
         return emitted
 
     def _book_chunk_locked(self, em_dev, active):
-        em = np.asarray(em_dev)  # [chunk, B] — the single sync
+        with tracing.region("engine.collect", slots=len(active)):
+            em = np.asarray(em_dev)  # [chunk, B] — the single sync
         for t in range(em.shape[0]):
             for s in active:
                 req = self._slot_req[s]

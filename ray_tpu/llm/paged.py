@@ -52,6 +52,7 @@ from ray_tpu.llm.engine import (
 from ray_tpu._private.prefix_hash import chain_hash, prefix_chain_hashes
 from ray_tpu.models import llama
 from ray_tpu.ops.rope import rope_frequencies
+from ray_tpu.util import tracing
 
 
 class BlockManager:
@@ -91,6 +92,14 @@ class BlockManager:
     def alloc(self, n: int) -> Optional[List[int]]:
         if n > self.num_free():
             return None
+        if n > len(self.free_plain):
+            # the plain free list cannot cover it: prefix-cache entries
+            # are evicted (and demoted, one device read-back each)
+            with tracing.region("kv.alloc", blocks=n):
+                return self._alloc(n)
+        return self._alloc(n)
+
+    def _alloc(self, n: int) -> List[int]:
         out = []
         for _ in range(n):
             if self.free_plain:
@@ -275,9 +284,16 @@ class _PagedReq(_Request):
     admitted_order: int = 0   # preemption picks the youngest
     # request-lifecycle stamps (serving SLO layer; only read when the
     # engine carries an slo_label — direct engine use books nothing)
+    t_submit: float = 0.0     # add_request entered (before the lock)
     t_enqueue: float = 0.0
-    t_admit: float = 0.0
+    t_admit: float = 0.0      # FIRST admission (a preemption keeps it)
+    t_prefill_end: float = 0.0  # final prompt chunk dispatched (first time)
     t_first_emit: float = 0.0
+    t_done: float = 0.0
+    prompt_tokens: int = 0    # as submitted (preemption grows .prompt)
+    prefix_hit_tokens: int = 0
+    prefill_chunks: int = 0
+    preempted: int = 0
     # --- speculative decoding (engine._spec is not None) ---
     # draft-pool blocks mirroring this request's KV in the draft model's
     # pool; draft_prefill_pos tracks the draft's own chunked prefill
@@ -291,6 +307,15 @@ class _PagedReq(_Request):
     # acceptance bookkeeping (per-request speedup/acceptance metering)
     spec_proposed: int = 0
     spec_accepted: int = 0
+
+
+# integer engine counters (cumulative; see PagedJaxLLMEngine.counters)
+_COUNTERS = ("steps", "prefill_chunks", "prefill_tokens",
+             "prefill_padded_tokens", "prefix_hit_tokens",
+             "decode_dispatches", "decode_dispatches_pipelined",
+             "decode_token_steps", "tokens_emitted", "preemptions",
+             "kv_demotions")
+_TRACKED_MAX = 4096
 
 
 def _bucket_pow2(n: int, lo: int = 1) -> int:
@@ -564,8 +589,26 @@ class PagedJaxLLMEngine:
         # (em_dev, active_slots, spec_slots): collected lazily by
         # _drain_locked(); spec_slots is () on the non-speculative path.
         self._inflight: Optional[Tuple] = None
-        # monotonic ts of the last traced step's phase spans (rate limit)
-        self._last_phase_span = float("-inf")
+        # cumulative engine counters (utilization()["counters"], and the
+        # replica's ledger row under "engine"): plain numbers, written
+        # under self._lock (loop_idle_s: by the server's loop thread
+        # alone), read without it — a reader subtracts two reads
+        self._c: Dict[str, float] = dict.fromkeys(_COUNTERS, 0)
+        self._c.update(host_s=0.0, device_wait_s=0.0, loop_idle_s=0.0)
+        self._drains: Dict[str, int] = {}
+        # why the device mirrors went stale (first cause since the last
+        # refresh): names the drain that the refresh forces
+        self._dirty_cause: Optional[str] = None
+        # seconds this step spent blocked in device reads (collect /
+        # first-token reads); reset at step entry
+        self._step_wait = 0.0
+        # backend compiles the process had seen when warmup() returned
+        # (or at init, where it never runs): counters report the excess
+        self._compile_base = device_telemetry.compile_totals()
+        # rid -> request, for the serving layer's per-request stage row
+        # (LLMServer reads t_first_emit at the first yield and pops the
+        # row at the end); only filled under an slo_label, bounded
+        self._tracked: Dict[int, _PagedReq] = {}
         # a finished prefill's sampled first token stays a DEVICE future
         # until the next drain point: a synchronous int(ids[0]) per request
         # serialized a ~100 ms readback behind every queued program
@@ -733,13 +776,22 @@ class PagedJaxLLMEngine:
             # replicas additionally publish rows to the GCS KV
             device_telemetry.register_utilization_object(
                 f"{name}:{id(self):x}", self)
+        # cumulative counters ride the replica's ledger row ("engine")
+        from ray_tpu.serve._private import slo
+
+        slo.register_engine(name, self)
 
     def utilization(self) -> dict:
         """Exact engine bookkeeping for ``state.utilization()``: slot and
         KV-block occupancy read from the live structures under the lock,
         plus the step-derived rates and HBM split when telemetry is
-        attached.  Block 0 is the sink (never allocated), so capacity is
-        ``num_blocks - 1``."""
+        attached, and the cumulative ``counters``.  Block 0 is the sink
+        (never allocated), so capacity is ``num_blocks - 1``.
+        ``duty_cycle`` is the engine LOOP's occupancy of host wall time
+        (time inside ``step()`` over time since the last step ended),
+        not a device figure: for the device read ``counters``'
+        ``device_wait_s`` against ``host_s``, or a ``state.jax_profile``
+        trace."""
         with self._lock:
             active = sum(1 for r in self._slot_req if r is not None)
             free = self.blocks.num_free()
@@ -753,6 +805,7 @@ class PagedJaxLLMEngine:
             "kv_blocks": {"total": total, "free": free,
                           "used": total - free},
             "pending": pending,
+            "counters": self.counters(),
         }
         tel = self._telemetry
         if tel is not None:
@@ -779,6 +832,68 @@ class PagedJaxLLMEngine:
                 "planned_collectives": self._tp_collectives,
             }
         return row
+
+    def counters(self) -> dict:
+        """Cumulative counters since the engine was built; nothing ever
+        decreases, so two reads subtract to a window.  Takes no lock (the
+        ledger's publisher calls it): a read beside a running step may
+        be one step behind in some keys.
+
+        ``steps``; ``prefill_chunks``, ``prefill_tokens`` (prompt tokens
+        run through the model, recompute after a preemption included),
+        ``prefill_padded_tokens`` (bucket padding run but not asked
+        for), ``prefix_hit_tokens`` (prompt tokens admission found in the
+        cache); ``decode_dispatches``, ``decode_dispatches_pipelined``
+        (the previous chunk was still in flight at the dispatch, so the
+        device never waited for the host), ``decode_token_steps``;
+        ``tokens_emitted``; ``drains`` by cause (an in-flight chunk
+        collected before the next dispatch could be queued behind it);
+        ``preemptions``, ``kv_demotions``; ``host_s`` / ``device_wait_s``
+        (each step's wall time: blocked in the device reads of
+        ``engine.collect`` / ``engine.drain``, and everything else);
+        ``loop_idle_s`` (the serving loop found no work); ``compiles`` /
+        ``compile_s`` (backend compiles in this process since
+        ``warmup()`` returned: anything above zero ran inside serving).
+        """
+        out = dict(self._c)
+        out["drains"] = dict(self._drains)
+        n, sec = device_telemetry.compile_totals()
+        out["compiles"] = n - self._compile_base[0]
+        out["compile_s"] = round(sec - self._compile_base[1], 6)
+        return out
+
+    def note_loop_idle(self, seconds: float) -> None:
+        """The serving loop (its only caller and this key's only writer)
+        waited ``seconds`` because no engine had work."""
+        self._c["loop_idle_s"] += seconds
+
+    def tracked_request(self, request_id: int) -> Optional["_PagedReq"]:
+        """The request's stamps for the serving layer (None: unlabeled
+        engine, unknown id, or already popped).  No lock: the caller
+        reads fields the engine has finished writing (``t_first_emit``
+        once a token of the request is visible)."""
+        return self._tracked.get(request_id)
+
+    def pop_request_row(self, request_id: int) -> Optional[dict]:
+        """Stage times and counts of one FINISHED request, engine side;
+        the entry is dropped (None if it was never tracked, or is not
+        finished: an aborted stream leaves no row)."""
+        req = self._tracked.pop(request_id, None)
+        if req is None or not req.t_done or not req.t_first_emit:
+            return None
+        return {
+            "engine_rid": req.request_id,
+            "prompt_tokens": req.prompt_tokens,
+            "prefix_hit_tokens": req.prefix_hit_tokens,
+            "prefill_chunks": req.prefill_chunks,
+            "enqueue_wait_s": round(req.t_enqueue - req.t_submit, 6),
+            "queue_wait_s": round(req.t_admit - req.t_enqueue, 6),
+            "prefill_s": round(req.t_prefill_end - req.t_admit, 6),
+            "first_emit_s": round(req.t_first_emit - req.t_prefill_end, 6),
+            "decode_s": round(req.t_done - req.t_first_emit, 6),
+            "decode_tokens": len(req.out_tokens),
+            "preempted": req.preempted,
+        }
 
     # -- planner-routed TP collectives ---------------------------------
 
@@ -1037,15 +1152,28 @@ class PagedJaxLLMEngine:
             raise ValueError(
                 f"request needs up to {worst} KV blocks but the pool has "
                 f"{self.num_blocks} — raise num_blocks or lower max_new_tokens")
+        # step() holds the lock for its whole body: a caller can wait
+        # most of a step here before its request is even queued
+        t_submit = time.monotonic() if self.slo_label is not None else 0.0
         with self._lock:
             self._req_counter += 1
             req = _PagedReq(self._req_counter, list(prompt), gen)
             req.spec_enabled = self._spec is not None
-            if self.slo_label is not None:
+            req.prompt_tokens = len(req.prompt)
+            if t_submit:
+                req.t_submit = t_submit
                 req.t_enqueue = time.monotonic()
+                self._tracked[req.request_id] = req
+                if len(self._tracked) > _TRACKED_MAX:  # nobody popped them
+                    del self._tracked[next(iter(self._tracked))]
             self._requests[req.request_id] = req
             self._pending.append(req)
-            return req.request_id
+        if t_submit:
+            from ray_tpu.serve._private import slo
+
+            slo.record_stage(self.slo_label, "enqueue_wait",
+                             req.t_enqueue - t_submit)
+        return req.request_id
 
     def has_work(self) -> bool:
         with self._lock:
@@ -1062,9 +1190,11 @@ class PagedJaxLLMEngine:
         never written by in-flight programs, so the read is consistent."""
         from ray_tpu._private import runtime_metrics
 
-        k = np.asarray(self.pool["k"][:, block])
-        v = np.asarray(self.pool["v"][:, block])
-        self._host_cache.put(h, k, v)
+        with tracing.region("kv.demote", blocks=1):
+            k = np.asarray(self.pool["k"][:, block])
+            v = np.asarray(self.pool["v"][:, block])
+            self._host_cache.put(h, k, v)
+        self._c["kv_demotions"] += 1
         runtime_metrics.add_prefix_cache_evictions("hbm")
 
     def _match_prefix_tiered(self, prompt: Sequence[int]):
@@ -1162,6 +1292,16 @@ class PagedJaxLLMEngine:
         the prompt up front (instead of chunk-by-chunk) makes the system
         livelock-free: a mid-prefill request can never stall on allocation,
         so every admitted request reaches the preemptible decode state."""
+        if not self._pending:
+            return
+        with tracing.region("engine.admit") as span:
+            admitted, hit = self._admit_pending_locked()
+            if span is not None:
+                span.set_metadata(admitted=admitted, prefix_hit_tokens=hit)
+
+    def _admit_pending_locked(self) -> Tuple[int, int]:
+        """``(requests admitted, prefix-hit tokens)``."""
+        admitted = hit = 0
         for slot in range(self.max_batch):
             if not self._pending or self._slot_req[slot] is not None:
                 continue
@@ -1177,7 +1317,7 @@ class PagedJaxLLMEngine:
             fresh = self.blocks.alloc(need)
             if fresh is None:
                 self.blocks.release(shared)
-                return  # pool full: keep FIFO order, retry next step
+                break  # pool full: keep FIFO order, retry next step
             if req.spec_enabled:
                 # the draft prefills the WHOLE prompt (no prefix cache in
                 # the draft pool), so it needs the full chunk-padded cover
@@ -1206,17 +1346,22 @@ class PagedJaxLLMEngine:
             self._admit_counter += 1
             req.admitted_order = self._admit_counter
             self._slot_req[slot] = req
-            if self.slo_label is not None and req.t_enqueue:
-                # first admission only: a preempted request re-queues with
-                # t_admit already set — its queue_wait was booked once
-                if not req.t_admit:
-                    from ray_tpu.serve._private import slo
+            admitted += 1
+            hit += matched
+            req.prefix_hit_tokens += matched
+            self._c["prefix_hit_tokens"] += matched
+            if (self.slo_label is not None and req.t_enqueue
+                    and not req.t_admit):
+                # first admission only: a preempted request re-queues
+                # with t_admit set, and its stages were booked once (its
+                # recompute shows in first_emit or decode, where the
+                # client waits for it)
+                from ray_tpu.serve._private import slo
 
-                    req.t_admit = time.monotonic()
-                    slo.record_stage(self.slo_label, "queue_wait",
-                                     req.t_admit - req.t_enqueue)
-                else:
-                    req.t_admit = time.monotonic()
+                req.t_admit = time.monotonic()
+                slo.record_stage(self.slo_label, "queue_wait",
+                                 req.t_admit - req.t_enqueue)
+        return admitted, hit
 
     def _decode_ready(self, req: _PagedReq) -> bool:
         """A slot joins the decode batch only when its target prefill —
@@ -1319,12 +1464,18 @@ class PagedJaxLLMEngine:
                 table[0, :len(req.blocks)] = req.blocks
                 is_last = p0 + take >= plen
                 sample_idx = (plen - 1 - p0) if is_last else 0
-                ids, self.pool, self._d_key = self._prefill_chunk(
-                    self.params, self._put(tokens), self.pool,
-                    self._put(table), self._put(p0, np.int32),
-                    self._put(sample_idx, np.int32), self._d_key,
-                    self._put([req.gen.temperature], np.float32),
-                    self._put([req.gen.top_k], np.int32))
+                with tracing.region("engine.prefill_chunk", tokens=take,
+                                    bucket=c, is_last=int(is_last)):
+                    ids, self.pool, self._d_key = self._prefill_chunk(
+                        self.params, self._put(tokens), self.pool,
+                        self._put(table), self._put(p0, np.int32),
+                        self._put(sample_idx, np.int32), self._d_key,
+                        self._put([req.gen.temperature], np.float32),
+                        self._put([req.gen.top_k], np.int32))
+                req.prefill_chunks += 1
+                self._c["prefill_chunks"] += 1
+                self._c["prefill_tokens"] += take
+                self._c["prefill_padded_tokens"] += c - take
                 if self._tp_collectives is not None:
                     self._book_tp_collectives(
                         "prefill",
@@ -1338,11 +1489,13 @@ class PagedJaxLLMEngine:
                     self._draft_prefill_chunk_locked(req)
                 progress = True
                 if is_last:
-                    if self.slo_label is not None and req.t_admit:
+                    if (self.slo_label is not None and req.t_admit
+                            and not req.t_prefill_end):
                         from ray_tpu.serve._private import slo
 
+                        req.t_prefill_end = time.monotonic()
                         slo.record_stage(self.slo_label, "prefill",
-                                         time.monotonic() - req.t_admit)
+                                         req.t_prefill_end - req.t_admit)
                     # trim chunk-padding blocks; decode's ensure pass
                     # re-allocates
                     keep = math.ceil(plen / self.bs)
@@ -1354,14 +1507,27 @@ class PagedJaxLLMEngine:
                     self._slot_temp[slot] = req.gen.temperature
                     self._slot_topk[slot] = req.gen.top_k
                     self._first_pending.append((slot, req, ids))
-                    self._dirty = True
+                    self._mark_dirty("final_prefill")
                 budget -= take
                 self._tel_prefill_spent += take
 
+    def _mark_dirty(self, cause: str):
+        """The device mirrors are stale; ``cause`` (the first since the
+        last refresh) names the drain that refreshing them forces."""
+        self._dirty = True
+        if self._dirty_cause is None:
+            self._dirty_cause = cause
+
     def _emit_locked(self, req: _PagedReq, token: int):
         req.out_tokens.append(token)
+        self._c["tokens_emitted"] += 1
         if self.slo_label is not None and not req.t_first_emit:
+            from ray_tpu.serve._private import slo
+
             req.t_first_emit = time.monotonic()
+            if req.t_prefill_end:
+                slo.record_stage(self.slo_label, "first_emit",
+                                 req.t_first_emit - req.t_prefill_end)
         if (token in req.gen.stop_token_ids
                 or len(req.out_tokens) >= req.gen.max_new_tokens
                 or self._lengths[req.slot] + 1 >= self.max_seq):
@@ -1369,8 +1535,9 @@ class PagedJaxLLMEngine:
             if self.slo_label is not None and req.t_first_emit:
                 from ray_tpu.serve._private import slo
 
+                req.t_done = time.monotonic()
                 slo.record_stage(self.slo_label, "decode",
-                                 time.monotonic() - req.t_first_emit)
+                                 req.t_done - req.t_first_emit)
             if self._spec is not None and req.spec_proposed:
                 # retain per-request acceptance for the serving layer's
                 # recent-request rows (bounded ring; read via
@@ -1390,7 +1557,7 @@ class PagedJaxLLMEngine:
         self._slot_req[req.slot] = None
         self._lengths[req.slot] = 0
         req.slot = -1
-        self._dirty = True
+        self._mark_dirty("finish")
 
     def _preempt_locked(self, exclude_slot: int = -1) -> bool:
         """Evict the youngest decode-active request by recompute: free its
@@ -1408,16 +1575,21 @@ class PagedJaxLLMEngine:
                      key=lambda c: c.admitted_order, default=None)
         if victim is None:
             return False
-        victim.prompt = victim.prompt + victim.out_tokens
-        victim.prefill_pos = 0
-        victim.draft_prefill_pos = 0
-        self._free_slot_locked(victim)
-        # recompute re-prefills the draft pool too, so a request degraded
-        # by earlier draft-pool pressure gets a fresh chance to speculate
-        victim.spec_enabled = self._spec is not None
-        victim.done = False
-        self._pending.appendleft(victim)
-        self._dirty = True
+        with tracing.region("engine.preempt",
+                            tokens_discarded=int(self._lengths[victim.slot])):
+            self._c["preemptions"] += 1
+            victim.preempted += 1
+            victim.prompt = victim.prompt + victim.out_tokens
+            victim.prefill_pos = 0
+            victim.draft_prefill_pos = 0
+            self._mark_dirty("preempt")
+            self._free_slot_locked(victim)
+            # recompute re-prefills the draft pool too, so a request
+            # degraded by earlier draft-pool pressure gets a fresh chance
+            # to speculate
+            victim.spec_enabled = self._spec is not None
+            victim.done = False
+            self._pending.appendleft(victim)
         return True
 
     # -- decode ---------------------------------------------------------
@@ -1426,6 +1598,10 @@ class PagedJaxLLMEngine:
         """Every decode-active slot's table must cover lengths + margin
         appends before dispatch (allocation is host-side; the device program
         is static). Returns the decode-active slot list."""
+        with tracing.region("engine.ensure_blocks", margin=margin):
+            return self._cover_decode_blocks_locked(margin)
+
+    def _cover_decode_blocks_locked(self, margin: int) -> List[int]:
         restart = True
         while restart:
             restart = False
@@ -1455,7 +1631,7 @@ class PagedJaxLLMEngine:
                         # off slots already validated this pass, so every
                         # coverage decision so far is stale: restart the
                         # whole pass (the drain can happen at most once).
-                        self._drain_locked()
+                        self._drain_locked("preempt")
                         restart = True
                         break
                     if not self._preempt_locked():
@@ -1520,7 +1696,10 @@ class PagedJaxLLMEngine:
         exactly for short generations) BEFORE the emit loop, so a
         request finishing mid-collect reports final stats at its
         terminal booking.  Dead slots (zero emissions) book nothing."""
-        em = np.asarray(em_dev)  # fences this chunk (a later one may run on)
+        with tracing.region("engine.collect", slots=len(active)):
+            t0 = time.monotonic()
+            em = np.asarray(em_dev)  # fences this chunk (a later may run on)
+            self._step_wait += time.monotonic() - t0
         if spec_slots:
             acc = np.asarray(acc_dev)
             proposed = accepted = 0
@@ -1577,19 +1756,27 @@ class PagedJaxLLMEngine:
             if self._slot_req[slot] is not req:
                 continue  # preempted before its first token surfaced:
                 # recompute will re-sample it (it was never emitted)
+            t0 = time.monotonic()
             first = int(np.asarray(ids)[0])
+            self._step_wait += time.monotonic() - t0
             self._next_tok[slot] = first
             self._emit_locked(req, first)
 
-    def _drain_locked(self):
+    def _drain_locked(self, cause: str):
         """Collect the in-flight decode chunk, if any, and any pending
-        first tokens."""
-        if self._inflight is not None:
-            em_dev, active, spec_slots, acc_dev = self._inflight
-            self._inflight = None
-            self._collect_locked(em_dev, active, margin=0,
-                                 spec_slots=spec_slots, acc_dev=acc_dev)
-        self._resolve_first_tokens_locked()
+        first tokens.  A drain that finds a chunk in flight counts under
+        ``cause``: the next dispatch cannot queue behind it."""
+        if self._inflight is None and not self._first_pending:
+            return
+        with tracing.region("engine.drain", cause=cause,
+                            inflight=int(self._inflight is not None)):
+            if self._inflight is not None:
+                self._drains[cause] = self._drains.get(cause, 0) + 1
+                em_dev, active, spec_slots, acc_dev = self._inflight
+                self._inflight = None
+                self._collect_locked(em_dev, active, margin=0,
+                                     spec_slots=spec_slots, acc_dev=acc_dev)
+            self._resolve_first_tokens_locked()
 
     def step(self, decode: bool = True) -> Dict[int, List[int]]:
         """One engine step: admit, one prefill chunk, one decode chunk.
@@ -1601,25 +1788,15 @@ class PagedJaxLLMEngine:
         drains the in-flight chunk first — correctness never depends on
         the lagged view.  ``decode=False`` runs admission/prefill only
         (ramp control)."""
-        emitted: Dict[int, List[int]] = {}
-        # engine phases become children of the active trace (a serve
-        # request / task span); untraced steps pay one thread-local read.
-        # PhaseRecorder: stamped under the lock, emitted after release.
-        from ray_tpu.util import tracing
-
-        rec = tracing.PhaseRecorder()
-        # per-engine rate limit (~5 span sets/s): a steady traced serving
-        # loop must not cycle the bounded GCS task sink with per-step
-        # spans — phase durations are steady-state, sampling keeps signal
         now = time.monotonic()
-        traced = rec.active and now - self._last_phase_span >= 0.2
-        if traced:
-            self._last_phase_span = now
         # device telemetry: one attribute read + None check when disabled
         tel = self._telemetry
-        tel_active = tel_free = tel_pending = 0
-        with self._lock:
+        with tracing.region(
+                "engine.step", pending=len(self._pending),
+                active=self.max_batch - self._slot_req.count(None),
+                inflight=int(self._inflight is not None)), self._lock:
             self._tel_prefill_spent = 0
+            self._step_wait = 0.0
             before = self._emit_snapshot_locked()
             if self._pending or any(
                     r is not None and not self._decode_ready(r)
@@ -1630,11 +1807,8 @@ class PagedJaxLLMEngine:
                 # and prefill dispatches chain after the decode on the pool
                 # dataflow.  Only a final prefill chunk (_dirty → refresh)
                 # forces a drain, below.
-                t_pf = time.time() if traced else 0.0
                 self._admit_locked()
                 self._prefill_step_locked()
-                if traced:
-                    rec.stamp("paged.admit_prefill", t_pf)
             chunk = self.config.decode_chunk
             # device appends per dispatch: a speculative cycle writes up
             # to k+1 positions (k drafted + the bonus slot), a plain
@@ -1648,7 +1822,7 @@ class PagedJaxLLMEngine:
                 active = []
             if active:
                 if self._dirty:
-                    self._drain_locked()
+                    self._drain_locked(self._dirty_cause or "other")
                     self._refresh_mirrors_locked()
                     # the drain invalidated the ensure pass above: it
                     # advances lengths AND _trim_locked(margin=0) releases
@@ -1665,31 +1839,7 @@ class PagedJaxLLMEngine:
                         active = [s for s in active
                                   if self._slot_req[s] is not None]
             if active:
-                t_dec = time.time() if traced else 0.0
-                w = _bucket_pow2(max(len(self._slot_req[s].blocks)
-                                     for s in active))
-                table = np.zeros((self.max_batch, w), np.int32)
-                for s in active:
-                    blks = self._slot_req[s].blocks
-                    table[s, :len(blks)] = blks
-                if self._spec is not None:
-                    em_dev, acc_dev, spec_slots = self._spec_step_locked(
-                        table, active)
-                    prev, self._inflight = (
-                        self._inflight,
-                        (em_dev, active, spec_slots, acc_dev))
-                else:
-                    (em_dev, self._d_next, self.pool, self._d_lengths,
-                     self._d_active, self._d_remaining, self._d_key) = \
-                        self._decode(
-                            self.params, self._d_next, self.pool,
-                            self._put(table), self._d_lengths,
-                            self._d_active, self._d_remaining,
-                            self._d_stops, self._d_key,
-                            self._d_temp, self._d_topk, chunk)
-                    self._book_tp_collectives("decode", chunk)
-                    prev, self._inflight = (self._inflight,
-                                            (em_dev, active, (), None))
+                prev = self._dispatch_decode_locked(active, chunk)
                 if prev is not None:
                     # collect chunk N while chunk N+1 computes: the fence
                     # latency rides under the new dispatch.  The device is
@@ -1697,23 +1847,21 @@ class PagedJaxLLMEngine:
                     self._collect_locked(prev[0], prev[1], margin=app,
                                          spec_slots=prev[2],
                                          acc_dev=prev[3])
-                if traced:
-                    rec.stamp("paged.decode", t_dec,
-                              {"active_slots": len(active), "chunk": chunk,
-                               "spec_k": self._spec_k})
             else:
-                self._drain_locked()
+                self._drain_locked("idle")
             emitted = self._gather_emitted_locked(before)
-            if tel is not None:
-                # captured under the lock into locals; booked after
-                # release next to rec.emit() (PhaseRecorder discipline)
-                tel_active = sum(1 for r in self._slot_req
-                                 if r is not None)
-                tel_free = self.blocks.num_free()
-                tel_pending = len(self._pending)
-        rec.emit()
-        if tel is not None:
+            tel_active = self.max_batch - self._slot_req.count(None)
+            tel_free = self.blocks.num_free()
+            tel_pending = len(self._pending)
+            # the step's wall time, split: blocked in device reads, and
+            # the rest (waiting for this lock included)
             t_end = time.monotonic()
+            c = self._c
+            c["steps"] += 1
+            c["device_wait_s"] += self._step_wait
+            c["host_s"] += t_end - now - self._step_wait
+        if tel is not None:
+            # booked after release, from the locals captured under the lock
             tel.note_step(
                 active_slots=tel_active, max_slots=self.max_batch,
                 free_blocks=tel_free, total_blocks=self.num_blocks - 1,
@@ -1722,6 +1870,41 @@ class PagedJaxLLMEngine:
                 prefill_budget=self._tel_prefill_budget,
                 busy_s=t_end - now, now=t_end)
         return emitted
+
+    def _dispatch_decode_locked(self, active: List[int], chunk: int):
+        """Build the block table and dispatch one decode chunk (or one
+        speculative cycle) for ``active``; it becomes the chunk in flight.
+        Returns the chunk that was in flight before, still to collect."""
+        w = _bucket_pow2(max(len(self._slot_req[s].blocks) for s in active))
+        with tracing.region("engine.decode_dispatch", slots=len(active),
+                            w=w, chunk=chunk):
+            table = np.zeros((self.max_batch, w), np.int32)
+            for s in active:
+                blks = self._slot_req[s].blocks
+                table[s, :len(blks)] = blks
+            if self._spec is not None:
+                em_dev, acc_dev, spec_slots = self._spec_step_locked(
+                    table, active)
+                steps = self._spec_k + 1
+            else:
+                (em_dev, self._d_next, self.pool, self._d_lengths,
+                 self._d_active, self._d_remaining, self._d_key) = \
+                    self._decode(
+                        self.params, self._d_next, self.pool,
+                        self._put(table), self._d_lengths,
+                        self._d_active, self._d_remaining,
+                        self._d_stops, self._d_key,
+                        self._d_temp, self._d_topk, chunk)
+                self._book_tp_collectives("decode", chunk)
+                acc_dev, spec_slots, steps = None, (), chunk
+        prev, self._inflight = (self._inflight,
+                                (em_dev, active, spec_slots, acc_dev))
+        c = self._c
+        c["decode_dispatches"] += 1
+        c["decode_token_steps"] += steps
+        if prev is not None:
+            c["decode_dispatches_pipelined"] += 1
+        return prev
 
     def _spec_step_locked(self, table, active: List[int]):
         """One speculative decode cycle: draft proposes k tokens per
@@ -1783,7 +1966,7 @@ class PagedJaxLLMEngine:
         """Collect any in-flight decode chunk and return its tokens."""
         with self._lock:
             before = self._emit_snapshot_locked()
-            self._drain_locked()
+            self._drain_locked("flush")
             return self._gather_emitted_locked(before)
 
     def cancel_request(self, request_id: int) -> bool:
@@ -1795,6 +1978,7 @@ class PagedJaxLLMEngine:
         from ray_tpu._private import flight_recorder
 
         with self._lock:
+            self._tracked.pop(request_id, None)  # an aborted stream: no row
             req = self._requests.get(request_id)
             if req is None:
                 return False
@@ -1809,8 +1993,9 @@ class PagedJaxLLMEngine:
                 # request owns — never free them under it (the same
                 # argument as preemption's drain)
                 if self._inflight is not None:
-                    self._drain_locked()
+                    self._drain_locked("cancel")
                 if req.slot >= 0 and self._slot_req[req.slot] is req:
+                    self._mark_dirty("cancel")
                     self._free_slot_locked(req)
             req.done = True
             flight_recorder.record("request", self.slo_label or "paged",
@@ -1848,7 +2033,7 @@ class PagedJaxLLMEngine:
         the source's TP degree, so single↔sharded and 2-way↔4-way
         migrations all interoperate; the importer re-shards on entry."""
         with self._lock:
-            self._drain_locked()  # resolve the in-flight chunk's tokens
+            self._drain_locked("export")  # the in-flight chunk's tokens
             req = self._requests.get(request_id)
             if req is None or req.done or req.slot < 0:
                 raise KeyError(
@@ -1993,7 +2178,7 @@ class PagedJaxLLMEngine:
             self._next_tok[slot] = hist[-1]
             self._slot_temp[slot] = gen.temperature
             self._slot_topk[slot] = gen.top_k
-            self._dirty = True
+            self._mark_dirty("import")
             # the source sampled these tokens; they count toward the
             # output budget exactly as in the monolithic flow.  The
             # history prefix is pre-seeded WITHOUT emission (a resumed
@@ -2019,6 +2204,10 @@ class PagedJaxLLMEngine:
         return emitted
 
     def _refresh_mirrors_locked(self):
+        with tracing.region("engine.refresh"):
+            self._upload_mirrors_locked()
+
+    def _upload_mirrors_locked(self):
         self._resolve_first_tokens_locked()  # _next_tok must be current
         decode_ready = [
             0 if (r is None or not self._decode_ready(r)) else 1
@@ -2043,6 +2232,7 @@ class PagedJaxLLMEngine:
         self._d_remaining = self._put(remaining)
         self._d_stops = self._put(stops)
         self._dirty = False
+        self._dirty_cause = None
 
     # -- warmup ---------------------------------------------------------
 
@@ -2076,7 +2266,7 @@ class PagedJaxLLMEngine:
 
         stops = self._put(np.full((b, _MAX_STOP_IDS), -1, np.int32))
         with self._lock:
-            self._drain_locked()
+            self._drain_locked("flush")
             w = 1
             while True:
                 # donate the REAL pool and recapture it: a second full-size
@@ -2137,6 +2327,8 @@ class PagedJaxLLMEngine:
                     break
                 c *= 2
         # what ran, for whoever has to show that it did (device_report)
+        # compiles from here on ran inside serving
+        self._compile_base = device_telemetry.compile_totals()
         self.warmup_report = {
             "seconds": time.monotonic() - t0, "batch": b,
             "decode_table_widths": decode_widths,
@@ -2171,7 +2363,7 @@ class PagedJaxLLMEngine:
             return logits[0], pool
 
         with self._lock:
-            self._drain_locked()
+            self._drain_locked("flush")
             blocks = self.blocks.alloc(c // self.bs + 1)
             if blocks is None:
                 raise RuntimeError("no free KV blocks for the diagnostic")

@@ -10,11 +10,14 @@ requests share one decode batch (continuous batching across callers).
 
 from __future__ import annotations
 
+import contextlib
 import threading
 import time
 from typing import Any, Dict, List, Optional, Sequence  # noqa: F401
 
+from ray_tpu._private.utils import name_os_thread
 from ray_tpu.llm.config import GenerationConfig, LLMConfig
+from ray_tpu.util import tracing
 
 
 def _jax_backend() -> str:
@@ -103,6 +106,54 @@ class LLMServer:
                 eng.slo_label = name
             except Exception:  # noqa: BLE001 — static engine variants
                 pass
+
+    @contextlib.contextmanager
+    def _hold_step_lock(self, who: str):
+        """``_step_lock``, with the wait for it marked on the profiler's
+        timeline (``who``: ``loop``, ``cancel``, ``export``)."""
+        with tracing.region("serve.step_lock_wait", who=who):
+            self._step_lock.acquire()
+        try:
+            yield
+        finally:
+            self._step_lock.release()
+
+    def _engine_of(self, wkey):
+        """The engine that runs ``wkey``'s request (None: evicted or
+        rebuilt since)."""
+        model, gen_id, _rid = wkey
+        if model is None:
+            return self._engine
+        with self._engines_lock:
+            return (self._engines.get(model)
+                    if self._engine_gen.get(model, 0) == gen_id else None)
+
+    def _note_first_yield(self, wkey) -> Optional[float]:
+        """Book the ``stream_out`` stage (first token emitted by the
+        engine -> its chunk handed to the replica's stream) and return
+        it; None where the engine tracks no stamps (unlabeled server)."""
+        req = getattr(self._engine_of(wkey), "tracked_request",
+                      lambda _rid: None)(wkey[2])
+        if req is None or not req.t_first_emit:
+            return None
+        from ray_tpu.serve._private import slo
+
+        dt = time.monotonic() - req.t_first_emit
+        slo.record_stage(self._slo_label, "stream_out", dt)
+        return dt
+
+    def _note_request_row(self, wkey, stream_out_s: Optional[float]) -> None:
+        """A finished request's stage times and token counts into this
+        process's ledger ring (``state.recent_requests()``)."""
+        row = getattr(self._engine_of(wkey), "pop_request_row",
+                      lambda _rid: None)(wkey[2])
+        if row is None:
+            return
+        from ray_tpu.serve._private import slo
+
+        if stream_out_s is not None:
+            row["stream_out_s"] = round(stream_out_s, 6)
+        slo.record_engine_request(self._slo_label, row)
 
     def utilization(self) -> Optional[Dict[str, Any]]:
         """Device-telemetry utilization row for the hosting replica's
@@ -239,6 +290,7 @@ class LLMServer:
                     self._cv.wait(timeout=0.1)
                 buf = self._done.pop(wkey)
             self._note_specdec(wkey)
+            self._note_request_row(wkey, None)
             return buf
         finally:
             with self._cv:
@@ -255,17 +307,9 @@ class LLMServer:
         streaming and handle-level callers under ``slo.activate``; a
         cluster-mode replica process has no tracker and relies on the
         ledger fold + metric families for the acceptance signal."""
-        model, gen_id, rid = wkey
         try:
-            if model is None:
-                eng = self._engine
-            else:
-                with self._engines_lock:
-                    eng = (self._engines.get(model)
-                           if self._engine_gen.get(model, 0) == gen_id
-                           else None)
-            stats = getattr(eng, "specdec_request_stats",
-                            lambda _rid: None)(rid)
+            stats = getattr(self._engine_of(wkey), "specdec_request_stats",
+                            lambda _rid: None)(wkey[2])
         except Exception:  # noqa: BLE001
             stats = None
         if stats:
@@ -283,6 +327,7 @@ class LLMServer:
         immediately instead of decoding to max_new_tokens for nobody."""
         sent = 0
         completed = False
+        stream_out_s = None
         try:
             while True:
                 with self._cv:
@@ -303,10 +348,13 @@ class LLMServer:
                     if done:
                         self._done.pop(wkey, None)
                 if chunk:
+                    if sent == len(chunk):  # the request's first chunk
+                        stream_out_s = self._note_first_yield(wkey)
                     yield chunk
                 if done:
                     completed = True
                     self._note_specdec(wkey)
+                    self._note_request_row(wkey, stream_out_s)
                     return
         finally:
             if not completed:
@@ -323,7 +371,7 @@ class LLMServer:
             # the cancel's drain resolves the in-flight chunk for EVERY
             # slot — run it atomically vs the loop's step+apply and
             # reconcile bystander buffers after (see _reap_drained)
-            with self._step_lock:
+            with self._hold_step_lock("cancel"):
                 try:
                     cancel = getattr(self._engine, "cancel_request", None)
                     if cancel is not None:
@@ -372,8 +420,11 @@ class LLMServer:
             engine restarts its request-id counter, and without the gen a
             new request could collide with an abandoned one's buffers."""
         if not model or model not in self._adapters:
-            # base engine is never evicted, so its waiters need no registry
-            return (None, 0, self._engine.add_request(prompt, gen))
+            # base engine is never evicted, so its waiters need no
+            # registry.  A step holds the engine's lock for its whole
+            # body: the request waits here for the step in progress
+            with tracing.region("serve.step_lock_wait", who="add_request"):
+                return (None, 0, self._engine.add_request(prompt, gen))
         built = None
         while True:
             with self._engines_lock:
@@ -450,6 +501,7 @@ class LLMServer:
                         del self._waiters[wkey]
 
     def _run(self):
+        name_os_thread()
         while not self._stop:
             with self._engines_lock:
                 engines = list(self._engines.items())
@@ -463,7 +515,7 @@ class LLMServer:
                 # drains: a drain between the step's snapshot-delta
                 # gather and this apply would reconcile the buffer to
                 # full history and then have the stale delta re-appended
-                with self._step_lock:
+                with self._hold_step_lock("loop"):
                     try:
                         emitted = engine.step()
                     except BaseException as e:  # noqa: BLE001 — fail waiters, not hang
@@ -472,7 +524,8 @@ class LLMServer:
                             self._cv.notify_all()
                         return
                     if emitted:
-                        with self._cv:
+                        with tracing.region("serve.apply",
+                                            requests=len(emitted)), self._cv:
                             for rid, toks in emitted.items():
                                 wk = (key, gen_id, rid)
                                 if wk in self._migrating:
@@ -499,7 +552,12 @@ class LLMServer:
                                         self._done[wkey] = buf
                             self._cv.notify_all()
             if not worked:
-                time.sleep(0.002)
+                t0 = time.monotonic()
+                with tracing.region("serve.loop_idle"):
+                    time.sleep(0.002)
+                note = getattr(self._engine, "note_loop_idle", None)
+                if note is not None:
+                    note(time.monotonic() - t0)
 
     # -- live KV migration (serve/_private/kv_migration.py) -------------
     #
@@ -542,7 +600,7 @@ class LLMServer:
         wkey = (None, 0, rid)
         with self._cv:
             self._migrating.add(wkey)
-        with self._step_lock:
+        with self._hold_step_lock("export"):
             try:
                 h = self._engine.export_request(rid)
             except BaseException:

@@ -231,44 +231,46 @@ def _layer(cfg: LlamaConfig, x, lp, cos, sin, mesh, context_parallel):
     cdt = cfg.compute_dtype
     seq_axis = "context" if context_parallel else None
 
-    h = rms_norm(x, lp["attn_norm"], cfg.rms_norm_eps)
-    q = (h @ lp["wq"].astype(cdt)).reshape(b, s, cfg.n_heads, cfg.head_dim)
-    k = (h @ lp["wk"].astype(cdt)).reshape(b, s, cfg.n_kv_heads, cfg.head_dim)
-    v = (h @ lp["wv"].astype(cdt)).reshape(b, s, cfg.n_kv_heads, cfg.head_dim)
-    q = _constraint(q, P(BATCH_AXES, seq_axis, "tensor", None), mesh)
-    k = _constraint(k, P(BATCH_AXES, seq_axis, "tensor", None), mesh)
-    if context_parallel:
-        # positions are global: offset by this shard's slot in the ring.
-        # rope is applied inside the shard_map so positions line up.
-        def attn_fn(q_, k_, v_):
-            idx = lax.axis_index("context")
-            s_local = q_.shape[1]
-            pos = idx * s_local + jnp.arange(s_local)
-            q_r = apply_rope(q_, cos, sin, positions=pos)
-            k_r = apply_rope(k_, cos, sin, positions=pos)
-            return ring_attention(q_r, k_r, v_, "context", causal=True)
+    with jax.named_scope("attention"):
+        h = rms_norm(x, lp["attn_norm"], cfg.rms_norm_eps)
+        q = (h @ lp["wq"].astype(cdt)).reshape(b, s, cfg.n_heads, cfg.head_dim)
+        k = (h @ lp["wk"].astype(cdt)).reshape(b, s, cfg.n_kv_heads, cfg.head_dim)
+        v = (h @ lp["wv"].astype(cdt)).reshape(b, s, cfg.n_kv_heads, cfg.head_dim)
+        q = _constraint(q, P(BATCH_AXES, seq_axis, "tensor", None), mesh)
+        k = _constraint(k, P(BATCH_AXES, seq_axis, "tensor", None), mesh)
+        if context_parallel:
+            # positions are global: offset by this shard's slot in the ring.
+            # rope is applied inside the shard_map so positions line up.
+            def attn_fn(q_, k_, v_):
+                idx = lax.axis_index("context")
+                s_local = q_.shape[1]
+                pos = idx * s_local + jnp.arange(s_local)
+                q_r = apply_rope(q_, cos, sin, positions=pos)
+                k_r = apply_rope(k_, cos, sin, positions=pos)
+                return ring_attention(q_r, k_r, v_, "context", causal=True)
 
-        attn = jax.shard_map(
-            attn_fn,
-            mesh=mesh,
-            axis_names={"context"},
-            in_specs=(P(None, "context"),) * 3,
-            out_specs=P(None, "context"),
-        )(q, k, v)
-    else:
-        q = apply_rope(q, cos[:s], sin[:s])
-        k = apply_rope(k, cos[:s], sin[:s])
-        attn = mesh_attention(q, k, v, mesh=mesh, batch_axes=BATCH_AXES)
-    attn = attn.reshape(b, s, cfg.n_heads * cfg.head_dim)
-    attn = checkpoint_name(attn, "attn_out")
-    x = x + (attn @ lp["wo"].astype(cdt))
-    x = _constraint(x, P(BATCH_AXES, seq_axis, None), mesh)
+            attn = jax.shard_map(
+                attn_fn,
+                mesh=mesh,
+                axis_names={"context"},
+                in_specs=(P(None, "context"),) * 3,
+                out_specs=P(None, "context"),
+            )(q, k, v)
+        else:
+            q = apply_rope(q, cos[:s], sin[:s])
+            k = apply_rope(k, cos[:s], sin[:s])
+            attn = mesh_attention(q, k, v, mesh=mesh, batch_axes=BATCH_AXES)
+        attn = attn.reshape(b, s, cfg.n_heads * cfg.head_dim)
+        attn = checkpoint_name(attn, "attn_out")
+        x = x + (attn @ lp["wo"].astype(cdt))
+        x = _constraint(x, P(BATCH_AXES, seq_axis, None), mesh)
 
-    h = rms_norm(x, lp["mlp_norm"], cfg.rms_norm_eps)
-    gate = h @ lp["w_gate"].astype(cdt)
-    up = h @ lp["w_up"].astype(cdt)
-    ffn = (jax.nn.silu(gate) * up) @ lp["w_down"].astype(cdt)
-    x = x + ffn
+    with jax.named_scope("ffn"):
+        h = rms_norm(x, lp["mlp_norm"], cfg.rms_norm_eps)
+        gate = h @ lp["w_gate"].astype(cdt)
+        up = h @ lp["w_up"].astype(cdt)
+        ffn = (jax.nn.silu(gate) * up) @ lp["w_down"].astype(cdt)
+        x = x + ffn
     return _constraint(x, P(BATCH_AXES, seq_axis, None), mesh)
 
 
@@ -303,9 +305,10 @@ def forward(
         return layer(x, lp), None
 
     x, _ = lax.scan(body, x, params["layers"])
-    x = rms_norm(x, params["final_norm"], cfg.rms_norm_eps)
-    head = params["embed"].T if cfg.tie_embeddings else params["lm_head"]
-    logits = (x @ head.astype(cfg.compute_dtype)).astype(jnp.float32)
+    with jax.named_scope("head"):
+        x = rms_norm(x, params["final_norm"], cfg.rms_norm_eps)
+        head = params["embed"].T if cfg.tie_embeddings else params["lm_head"]
+        logits = (x @ head.astype(cfg.compute_dtype)).astype(jnp.float32)
     return _constraint(logits, P(BATCH_AXES, seq_axis, "tensor"), mesh)
 
 
@@ -368,24 +371,27 @@ def prefill(cfg: LlamaConfig, params: Params, tokens: jnp.ndarray,
     x = jnp.take(params["embed"], tokens, axis=0).astype(cdt)
 
     def body(x, lp):
-        h = rms_norm(x, lp["attn_norm"], cfg.rms_norm_eps)
-        q = (h @ lp["wq"].astype(cdt)).reshape(b, s, cfg.n_heads, cfg.head_dim)
-        k = (h @ lp["wk"].astype(cdt)).reshape(b, s, cfg.n_kv_heads, cfg.head_dim)
-        v = (h @ lp["wv"].astype(cdt)).reshape(b, s, cfg.n_kv_heads, cfg.head_dim)
-        q = apply_rope(q, cos[:s], sin[:s])
-        k = apply_rope(k, cos[:s], sin[:s])
-        attn = multi_head_attention(q, k, v, causal=True)
-        attn = attn.reshape(b, s, cfg.n_heads * cfg.head_dim)
-        x = x + (attn @ lp["wo"].astype(cdt))
-        h = rms_norm(x, lp["mlp_norm"], cfg.rms_norm_eps)
-        ffn = (jax.nn.silu(h @ lp["w_gate"].astype(cdt))
-               * (h @ lp["w_up"].astype(cdt))) @ lp["w_down"].astype(cdt)
+        with jax.named_scope("attention"):
+            h = rms_norm(x, lp["attn_norm"], cfg.rms_norm_eps)
+            q = (h @ lp["wq"].astype(cdt)).reshape(b, s, cfg.n_heads, cfg.head_dim)
+            k = (h @ lp["wk"].astype(cdt)).reshape(b, s, cfg.n_kv_heads, cfg.head_dim)
+            v = (h @ lp["wv"].astype(cdt)).reshape(b, s, cfg.n_kv_heads, cfg.head_dim)
+            q = apply_rope(q, cos[:s], sin[:s])
+            k = apply_rope(k, cos[:s], sin[:s])
+            attn = multi_head_attention(q, k, v, causal=True)
+            attn = attn.reshape(b, s, cfg.n_heads * cfg.head_dim)
+            x = x + (attn @ lp["wo"].astype(cdt))
+        with jax.named_scope("ffn"):
+            h = rms_norm(x, lp["mlp_norm"], cfg.rms_norm_eps)
+            ffn = (jax.nn.silu(h @ lp["w_gate"].astype(cdt))
+                   * (h @ lp["w_up"].astype(cdt))) @ lp["w_down"].astype(cdt)
         return x + ffn, (k, v)
 
     x, (ks, vs) = lax.scan(body, x, params["layers"])
-    x = rms_norm(x, params["final_norm"], cfg.rms_norm_eps)
-    head = params["embed"].T if cfg.tie_embeddings else params["lm_head"]
-    logits = (x @ head.astype(cdt)).astype(jnp.float32)
+    with jax.named_scope("head"):
+        x = rms_norm(x, params["final_norm"], cfg.rms_norm_eps)
+        head = params["embed"].T if cfg.tie_embeddings else params["lm_head"]
+        logits = (x @ head.astype(cdt)).astype(jnp.float32)
     return logits, {"k": ks, "v": vs}
 
 
@@ -431,41 +437,44 @@ def decode_step(cfg: LlamaConfig, params: Params, tokens: jnp.ndarray,
     def body(carry, inp):
         x, ck_all, cv_all = carry
         lp, li = inp
-        h = rms_norm(x, lp["attn_norm"], cfg.rms_norm_eps)
-        q = (h @ lp["wq"].astype(cdt)).reshape(b, 1, cfg.n_heads, cfg.head_dim)
-        k = (h @ lp["wk"].astype(cdt)).reshape(b, 1, cfg.n_kv_heads, cfg.head_dim)
-        v = (h @ lp["wv"].astype(cdt)).reshape(b, 1, cfg.n_kv_heads, cfg.head_dim)
-        q = apply_rope(q, cos, sin, positions=lengths[:, None])[:, 0]  # [B,nh,hd]
-        k = apply_rope(k, cos, sin, positions=lengths[:, None])[:, 0]
-        ck_all = ck_all.at[li, batch_idx, lengths].set(k.astype(ck_all.dtype))
-        cv_all = cv_all.at[li, batch_idx, lengths].set(v[:, 0].astype(cv_all.dtype))
-        ck = ck_all[li]
-        cv = cv_all[li]
-        # GQA attention against the cache, masked to valid positions.
-        # bf16 operands + fp32 ACCUMULATION (preferred_element_type): an
-        # .astype(f32) on the cache would materialize a full-span fp32 copy
-        # per decode step — 2x the HBM bytes of the weight-bound roofline
-        qg = q.reshape(b, cfg.n_kv_heads, group, cfg.head_dim)
-        scores = jnp.einsum("bkgd,bskd->bkgs", qg, ck,
-                            preferred_element_type=jnp.float32)
-        scores = scores / math.sqrt(cfg.head_dim)
-        scores = jnp.where(pos_mask[:, None, None, :], scores, -1e30)
-        probs = jax.nn.softmax(scores, axis=-1)
-        attn = jnp.einsum("bkgs,bskd->bkgd", probs.astype(ck.dtype), cv,
-                          preferred_element_type=jnp.float32)
-        attn = attn.reshape(b, cfg.n_heads * cfg.head_dim).astype(cdt)
-        x = x + attn @ lp["wo"].astype(cdt)
-        h = rms_norm(x, lp["mlp_norm"], cfg.rms_norm_eps)
-        ffn = (jax.nn.silu(h @ lp["w_gate"].astype(cdt))
-               * (h @ lp["w_up"].astype(cdt))) @ lp["w_down"].astype(cdt)
+        with jax.named_scope("attention"):
+            h = rms_norm(x, lp["attn_norm"], cfg.rms_norm_eps)
+            q = (h @ lp["wq"].astype(cdt)).reshape(b, 1, cfg.n_heads, cfg.head_dim)
+            k = (h @ lp["wk"].astype(cdt)).reshape(b, 1, cfg.n_kv_heads, cfg.head_dim)
+            v = (h @ lp["wv"].astype(cdt)).reshape(b, 1, cfg.n_kv_heads, cfg.head_dim)
+            q = apply_rope(q, cos, sin, positions=lengths[:, None])[:, 0]  # [B,nh,hd]
+            k = apply_rope(k, cos, sin, positions=lengths[:, None])[:, 0]
+            ck_all = ck_all.at[li, batch_idx, lengths].set(k.astype(ck_all.dtype))
+            cv_all = cv_all.at[li, batch_idx, lengths].set(v[:, 0].astype(cv_all.dtype))
+            ck = ck_all[li]
+            cv = cv_all[li]
+            # GQA attention against the cache, masked to valid positions.
+            # bf16 operands + fp32 ACCUMULATION (preferred_element_type): an
+            # .astype(f32) on the cache would materialize a full-span fp32 copy
+            # per decode step — 2x the HBM bytes of the weight-bound roofline
+            qg = q.reshape(b, cfg.n_kv_heads, group, cfg.head_dim)
+            scores = jnp.einsum("bkgd,bskd->bkgs", qg, ck,
+                                preferred_element_type=jnp.float32)
+            scores = scores / math.sqrt(cfg.head_dim)
+            scores = jnp.where(pos_mask[:, None, None, :], scores, -1e30)
+            probs = jax.nn.softmax(scores, axis=-1)
+            attn = jnp.einsum("bkgs,bskd->bkgd", probs.astype(ck.dtype), cv,
+                              preferred_element_type=jnp.float32)
+            attn = attn.reshape(b, cfg.n_heads * cfg.head_dim).astype(cdt)
+            x = x + attn @ lp["wo"].astype(cdt)
+        with jax.named_scope("ffn"):
+            h = rms_norm(x, lp["mlp_norm"], cfg.rms_norm_eps)
+            ffn = (jax.nn.silu(h @ lp["w_gate"].astype(cdt))
+                   * (h @ lp["w_up"].astype(cdt))) @ lp["w_down"].astype(cdt)
         return (x + ffn, ck_all, cv_all), None
 
     (x, ks, vs), _ = lax.scan(
         body, (x, cache["k"], cache["v"]),
         (params["layers"], jnp.arange(cfg.n_layers)))
-    x = rms_norm(x, params["final_norm"], cfg.rms_norm_eps)
-    head = params["embed"].T if cfg.tie_embeddings else params["lm_head"]
-    logits = (x @ head.astype(cdt)).astype(jnp.float32)  # [B, V]
+    with jax.named_scope("head"):
+        x = rms_norm(x, params["final_norm"], cfg.rms_norm_eps)
+        head = params["embed"].T if cfg.tie_embeddings else params["lm_head"]
+        logits = (x @ head.astype(cdt)).astype(jnp.float32)  # [B, V]
     return logits, {"k": ks, "v": vs}
 
 
@@ -681,43 +690,45 @@ def decode_step_paged(cfg: LlamaConfig, params: Params, tokens: jnp.ndarray,
         else:
             (x, pk_all, pv_all), tok = carry, None
         lp, li = inp
-        h = rms_norm(x, lp["attn_norm"], cfg.rms_norm_eps)
-        q = (h @ lp["wq"].astype(cdt)).reshape(b, 1, cfg.n_heads, cfg.head_dim)
-        k = (h @ lp["wk"].astype(cdt)).reshape(b, 1, cfg.n_kv_heads, cfg.head_dim)
-        v = (h @ lp["wv"].astype(cdt)).reshape(b, 1, cfg.n_kv_heads, cfg.head_dim)
-        q = apply_rope(q, cos, sin, positions=lengths[:, None])
-        k = apply_rope(k, cos, sin, positions=lengths[:, None])[:, 0]
-        pk_all = pk_all.at[li, cur_blk, cur_off].set(
-            k.reshape(b, -1).astype(pk_all.dtype))
-        pv_all = pv_all.at[li, cur_blk, cur_off].set(
-            v[:, 0].reshape(b, -1).astype(pv_all.dtype))
-        if use_kernel:
-            from ray_tpu.ops.paged_attention import paged_decode_attention
+        with jax.named_scope("attention"):
+            h = rms_norm(x, lp["attn_norm"], cfg.rms_norm_eps)
+            q = (h @ lp["wq"].astype(cdt)).reshape(b, 1, cfg.n_heads, cfg.head_dim)
+            k = (h @ lp["wk"].astype(cdt)).reshape(b, 1, cfg.n_kv_heads, cfg.head_dim)
+            v = (h @ lp["wv"].astype(cdt)).reshape(b, 1, cfg.n_kv_heads, cfg.head_dim)
+            q = apply_rope(q, cos, sin, positions=lengths[:, None])
+            k = apply_rope(k, cos, sin, positions=lengths[:, None])[:, 0]
+            pk_all = pk_all.at[li, cur_blk, cur_off].set(
+                k.reshape(b, -1).astype(pk_all.dtype))
+            pv_all = pv_all.at[li, cur_blk, cur_off].set(
+                v[:, 0].reshape(b, -1).astype(pv_all.dtype))
+            if use_kernel:
+                from ray_tpu.ops.paged_attention import paged_decode_attention
 
-            kern = partial(paged_decode_attention,
-                           interpret=kernel_interpret)
-            if mesh is not None and mesh.shape.get("tensor", 1) > 1:
-                t = P(None, None, None, "tensor")
-                kern = jax.shard_map(
-                    kern, mesh=mesh,
-                    in_specs=(P(None, "tensor", None), t, t, P(), P(), P()),
-                    out_specs=P(None, "tensor"), check_vma=False)
-            attn = kern(q[:, 0], pk_all, pv_all, li, table, lengths)
-        else:
-            ck = pk_all[li, table].reshape(b, w * bs, cfg.n_kv_heads,
-                                           cfg.head_dim)
-            cv = pv_all[li, table].reshape(b, w * bs, cfg.n_kv_heads,
-                                           cfg.head_dim)
-            attn = _paged_attend(cfg, q, ck, cv, span_mask)[:, 0]
-        out, tok = _tp_out_proj(attn.astype(cdt), lp["wo"].astype(cdt),
-                                tp_plan, tok)
-        x = x + out
-        h = rms_norm(x, lp["mlp_norm"], cfg.rms_norm_eps)
-        gated = (jax.nn.silu(h @ lp["w_gate"].astype(cdt))
-                 * (h @ lp["w_up"].astype(cdt)))
-        ffn, tok = _tp_out_proj(gated, lp["w_down"].astype(cdt),
-                                tp_plan, tok)
-        carry = (x + ffn, pk_all, pv_all)
+                kern = partial(paged_decode_attention,
+                               interpret=kernel_interpret)
+                if mesh is not None and mesh.shape.get("tensor", 1) > 1:
+                    t = P(None, None, None, "tensor")
+                    kern = jax.shard_map(
+                        kern, mesh=mesh,
+                        in_specs=(P(None, "tensor", None), t, t, P(), P(), P()),
+                        out_specs=P(None, "tensor"), check_vma=False)
+                attn = kern(q[:, 0], pk_all, pv_all, li, table, lengths)
+            else:
+                ck = pk_all[li, table].reshape(b, w * bs, cfg.n_kv_heads,
+                                               cfg.head_dim)
+                cv = pv_all[li, table].reshape(b, w * bs, cfg.n_kv_heads,
+                                               cfg.head_dim)
+                attn = _paged_attend(cfg, q, ck, cv, span_mask)[:, 0]
+            out, tok = _tp_out_proj(attn.astype(cdt), lp["wo"].astype(cdt),
+                                    tp_plan, tok)
+            x = x + out
+        with jax.named_scope("ffn"):
+            h = rms_norm(x, lp["mlp_norm"], cfg.rms_norm_eps)
+            gated = (jax.nn.silu(h @ lp["w_gate"].astype(cdt))
+                     * (h @ lp["w_up"].astype(cdt)))
+            ffn, tok = _tp_out_proj(gated, lp["w_down"].astype(cdt),
+                                    tp_plan, tok)
+            carry = (x + ffn, pk_all, pv_all)
         return (carry + (tok,) if overlap else carry), None
 
     carry0 = (x, pool["k"], pool["v"])
@@ -726,9 +737,10 @@ def decode_step_paged(cfg: LlamaConfig, params: Params, tokens: jnp.ndarray,
     carry, _ = lax.scan(
         body, carry0, (params["layers"], jnp.arange(cfg.n_layers)))
     x, ks, vs = carry[:3]
-    x = rms_norm(x, params["final_norm"], cfg.rms_norm_eps)
-    head = params["embed"].T if cfg.tie_embeddings else params["lm_head"]
-    logits = (x @ head.astype(cdt)).astype(jnp.float32)
+    with jax.named_scope("head"):
+        x = rms_norm(x, params["final_norm"], cfg.rms_norm_eps)
+        head = params["embed"].T if cfg.tie_embeddings else params["lm_head"]
+        logits = (x @ head.astype(cdt)).astype(jnp.float32)
     return logits, {"k": ks, "v": vs}
 
 
@@ -786,36 +798,38 @@ def decode_window_paged(cfg: LlamaConfig, params: Params,
         else:
             (x, pk_all, pv_all), tok = carry, None
         lp, li = inp
-        h = rms_norm(x, lp["attn_norm"], cfg.rms_norm_eps)
-        q = (h @ lp["wq"].astype(cdt)).reshape(b, t, cfg.n_heads,
-                                               cfg.head_dim)
-        k = (h @ lp["wk"].astype(cdt)).reshape(b, t, cfg.n_kv_heads,
-                                               cfg.head_dim)
-        v = (h @ lp["wv"].astype(cdt)).reshape(b, t, cfg.n_kv_heads,
-                                               cfg.head_dim)
-        q = apply_rope(q, cos, sin, positions=safe)
-        k = apply_rope(k, cos, sin, positions=safe)
-        # [B, T] fancy-index scatter; duplicate sink indices collide with
-        # garbage values only (no slot's table references block 0 inside
-        # its live span)
-        pk_all = pk_all.at[li, blk, off].set(
-            k.reshape(b, t, -1).astype(pk_all.dtype))
-        pv_all = pv_all.at[li, blk, off].set(
-            v.reshape(b, t, -1).astype(pv_all.dtype))
-        ck = pk_all[li, table].reshape(b, w * bs, cfg.n_kv_heads,
-                                       cfg.head_dim)
-        cv = pv_all[li, table].reshape(b, w * bs, cfg.n_kv_heads,
-                                       cfg.head_dim)
-        attn = _paged_attend(cfg, q, ck, cv, span_mask)
-        out, tok = _tp_out_proj(attn.astype(cdt), lp["wo"].astype(cdt),
-                                tp_plan, tok)
-        x = x + out
-        h = rms_norm(x, lp["mlp_norm"], cfg.rms_norm_eps)
-        gated = (jax.nn.silu(h @ lp["w_gate"].astype(cdt))
-                 * (h @ lp["w_up"].astype(cdt)))
-        ffn, tok = _tp_out_proj(gated, lp["w_down"].astype(cdt),
-                                tp_plan, tok)
-        carry = (x + ffn, pk_all, pv_all)
+        with jax.named_scope("attention"):
+            h = rms_norm(x, lp["attn_norm"], cfg.rms_norm_eps)
+            q = (h @ lp["wq"].astype(cdt)).reshape(b, t, cfg.n_heads,
+                                                   cfg.head_dim)
+            k = (h @ lp["wk"].astype(cdt)).reshape(b, t, cfg.n_kv_heads,
+                                                   cfg.head_dim)
+            v = (h @ lp["wv"].astype(cdt)).reshape(b, t, cfg.n_kv_heads,
+                                                   cfg.head_dim)
+            q = apply_rope(q, cos, sin, positions=safe)
+            k = apply_rope(k, cos, sin, positions=safe)
+            # [B, T] fancy-index scatter; duplicate sink indices collide with
+            # garbage values only (no slot's table references block 0 inside
+            # its live span)
+            pk_all = pk_all.at[li, blk, off].set(
+                k.reshape(b, t, -1).astype(pk_all.dtype))
+            pv_all = pv_all.at[li, blk, off].set(
+                v.reshape(b, t, -1).astype(pv_all.dtype))
+            ck = pk_all[li, table].reshape(b, w * bs, cfg.n_kv_heads,
+                                           cfg.head_dim)
+            cv = pv_all[li, table].reshape(b, w * bs, cfg.n_kv_heads,
+                                           cfg.head_dim)
+            attn = _paged_attend(cfg, q, ck, cv, span_mask)
+            out, tok = _tp_out_proj(attn.astype(cdt), lp["wo"].astype(cdt),
+                                    tp_plan, tok)
+            x = x + out
+        with jax.named_scope("ffn"):
+            h = rms_norm(x, lp["mlp_norm"], cfg.rms_norm_eps)
+            gated = (jax.nn.silu(h @ lp["w_gate"].astype(cdt))
+                     * (h @ lp["w_up"].astype(cdt)))
+            ffn, tok = _tp_out_proj(gated, lp["w_down"].astype(cdt),
+                                    tp_plan, tok)
+            carry = (x + ffn, pk_all, pv_all)
         return (carry + (tok,) if overlap else carry), None
 
     carry0 = (x, pool["k"], pool["v"])
@@ -824,9 +838,10 @@ def decode_window_paged(cfg: LlamaConfig, params: Params,
     carry, _ = lax.scan(
         body, carry0, (params["layers"], jnp.arange(cfg.n_layers)))
     x, ks, vs = carry[:3]
-    x = rms_norm(x, params["final_norm"], cfg.rms_norm_eps)
-    head = params["embed"].T if cfg.tie_embeddings else params["lm_head"]
-    logits = (x @ head.astype(cdt)).astype(jnp.float32)
+    with jax.named_scope("head"):
+        x = rms_norm(x, params["final_norm"], cfg.rms_norm_eps)
+        head = params["embed"].T if cfg.tie_embeddings else params["lm_head"]
+        logits = (x @ head.astype(cdt)).astype(jnp.float32)
     return logits, {"k": ks, "v": vs}
 
 
@@ -870,29 +885,31 @@ def prefill_chunk_paged(cfg: LlamaConfig, params: Params, tokens: jnp.ndarray,
         else:
             (x, pk_all, pv_all), tok = carry, None
         lp, li = inp
-        h = rms_norm(x, lp["attn_norm"], cfg.rms_norm_eps)
-        q = (h @ lp["wq"].astype(cdt)).reshape(b, c, cfg.n_heads, cfg.head_dim)
-        k = (h @ lp["wk"].astype(cdt)).reshape(b, c, cfg.n_kv_heads, cfg.head_dim)
-        v = (h @ lp["wv"].astype(cdt)).reshape(b, c, cfg.n_kv_heads, cfg.head_dim)
-        q = apply_rope(q, cos, sin, positions=positions[None, :])
-        k = apply_rope(k, cos, sin, positions=positions[None, :])
-        # [1, C, kv, hd] -> [C/bs, bs, kv*hd] block-major slab writes
-        pk_all = pk_all.at[li, chunk_blocks].set(
-            k[0].reshape(c // bs, bs, -1).astype(pk_all.dtype))
-        pv_all = pv_all.at[li, chunk_blocks].set(
-            v[0].reshape(c // bs, bs, -1).astype(pv_all.dtype))
-        ck = pk_all[li, table].reshape(b, w * bs, cfg.n_kv_heads, cfg.head_dim)
-        cv = pv_all[li, table].reshape(b, w * bs, cfg.n_kv_heads, cfg.head_dim)
-        attn = _paged_attend(cfg, q, ck, cv, span_mask)
-        out, tok = _tp_out_proj(attn.astype(cdt), lp["wo"].astype(cdt),
-                                tp_plan, tok)
-        x = x + out
-        h = rms_norm(x, lp["mlp_norm"], cfg.rms_norm_eps)
-        gated = (jax.nn.silu(h @ lp["w_gate"].astype(cdt))
-                 * (h @ lp["w_up"].astype(cdt)))
-        ffn, tok = _tp_out_proj(gated, lp["w_down"].astype(cdt),
-                                tp_plan, tok)
-        carry = (x + ffn, pk_all, pv_all)
+        with jax.named_scope("attention"):
+            h = rms_norm(x, lp["attn_norm"], cfg.rms_norm_eps)
+            q = (h @ lp["wq"].astype(cdt)).reshape(b, c, cfg.n_heads, cfg.head_dim)
+            k = (h @ lp["wk"].astype(cdt)).reshape(b, c, cfg.n_kv_heads, cfg.head_dim)
+            v = (h @ lp["wv"].astype(cdt)).reshape(b, c, cfg.n_kv_heads, cfg.head_dim)
+            q = apply_rope(q, cos, sin, positions=positions[None, :])
+            k = apply_rope(k, cos, sin, positions=positions[None, :])
+            # [1, C, kv, hd] -> [C/bs, bs, kv*hd] block-major slab writes
+            pk_all = pk_all.at[li, chunk_blocks].set(
+                k[0].reshape(c // bs, bs, -1).astype(pk_all.dtype))
+            pv_all = pv_all.at[li, chunk_blocks].set(
+                v[0].reshape(c // bs, bs, -1).astype(pv_all.dtype))
+            ck = pk_all[li, table].reshape(b, w * bs, cfg.n_kv_heads, cfg.head_dim)
+            cv = pv_all[li, table].reshape(b, w * bs, cfg.n_kv_heads, cfg.head_dim)
+            attn = _paged_attend(cfg, q, ck, cv, span_mask)
+            out, tok = _tp_out_proj(attn.astype(cdt), lp["wo"].astype(cdt),
+                                    tp_plan, tok)
+            x = x + out
+        with jax.named_scope("ffn"):
+            h = rms_norm(x, lp["mlp_norm"], cfg.rms_norm_eps)
+            gated = (jax.nn.silu(h @ lp["w_gate"].astype(cdt))
+                     * (h @ lp["w_up"].astype(cdt)))
+            ffn, tok = _tp_out_proj(gated, lp["w_down"].astype(cdt),
+                                    tp_plan, tok)
+            carry = (x + ffn, pk_all, pv_all)
         return (carry + (tok,) if overlap else carry), None
 
     carry0 = (x, pool["k"], pool["v"])
@@ -901,9 +918,10 @@ def prefill_chunk_paged(cfg: LlamaConfig, params: Params, tokens: jnp.ndarray,
     carry, _ = lax.scan(
         body, carry0, (params["layers"], jnp.arange(cfg.n_layers)))
     x, ks, vs = carry[:3]
-    x = rms_norm(x, params["final_norm"], cfg.rms_norm_eps)
-    head = params["embed"].T if cfg.tie_embeddings else params["lm_head"]
-    logits = (x @ head.astype(cdt)).astype(jnp.float32)
+    with jax.named_scope("head"):
+        x = rms_norm(x, params["final_norm"], cfg.rms_norm_eps)
+        head = params["embed"].T if cfg.tie_embeddings else params["lm_head"]
+        logits = (x @ head.astype(cdt)).astype(jnp.float32)
     return logits, {"k": ks, "v": vs}
 
 

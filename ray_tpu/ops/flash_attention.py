@@ -164,6 +164,7 @@ def _flash_fwd(q3, k3, v3, *, scale, causal, block_q, block_k, n_rep, interpret)
             jax.ShapeDtypeStruct((bh, s, 128), jnp.float32),
         ],
         interpret=interpret,
+        name="flash_attention_fwd",
     )(q3, k3, v3)
     return o, lse
 
@@ -198,6 +199,7 @@ def _flash_bwd(q3, k3, v3, o, lse, do, *, scale, causal, block_q, block_k, n_rep
             jax.ShapeDtypeStruct((bh_kv, s, d), jnp.float32),
         ],
         interpret=interpret,
+        name="flash_attention_bwd",  # one fused kernel: dq, dk and dv
     )(q3, k3, v3, o, do, lse)
     return dq, dk, dv
 

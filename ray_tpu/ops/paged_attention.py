@@ -150,6 +150,7 @@ def _paged_decode_attention(q, pk_all, pv_all, li, table, lengths,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b, nh, hd), jnp.float32),
         interpret=interpret,
+        name="paged_attention",  # the kernel's name in a profiler trace
     )(jnp.asarray(li, jnp.int32).reshape(1), table, lengths,
       q, pk_all, pv_all)
     return out.reshape(b, nh * hd)

@@ -16,8 +16,16 @@ layer everything else reads:
     ring (post-mortem for free), the mergeable latency sketches
     (_private/latency_sketch.py via runtime_metrics), the burn-rate
     windows, and a recent-requests forensics ring.  Replica/engine-side
-    stage durations (queue_wait, prefill, handoff, decode) book through
-    ``record_stage`` under the deployment's label.
+    stage durations book through ``record_stage`` under the deployment's
+    label: ``enqueue_wait`` (the caller waits for the engine's lock),
+    then ``queue_wait``, ``prefill``, ``first_emit`` and ``stream_out``,
+    which partition enqueued -> first token handed to the replica's
+    stream; ``decode``; ``handoff``.  The sketches carry sum and count,
+    so a stage's mean between two reads is exact.  The replica process
+    also keeps one row per finished engine request (``kind: "engine"``,
+    the same stage times and the request's token counts) in its recent
+    ring, and publishes its engines' cumulative counters under
+    ``engine`` (``register_engine``).
   - **Per-tenant metering**: TTFT/ITL sketches and terminal-status
     counters are tagged ``{deployment, tenant}`` — exactly the substrate
     ROADMAP item 5's per-tenant admission control meters against.
@@ -59,6 +67,7 @@ from typing import Any, Dict, List, Optional
 from ray_tpu._private.analysis.lock_witness import make_lock
 from ray_tpu._private import flight_recorder, runtime_metrics
 from ray_tpu._private.latency_sketch import merge_points, summary
+from ray_tpu._private.utils import name_os_thread
 
 SLO_KV_PREFIX = "slo:"
 SLO_CONF_KV_PREFIX = "sloconf:"
@@ -483,10 +492,13 @@ class ServingSLOLedger:
                     tr.spec_accepted / tr.spec_proposed, 4)
             if tr.trace_id:
                 row["trace_id"] = tr.trace_id
-            self._recent.append(row)
-            if len(self._recent) > self._recent_cap:
-                del self._recent[:len(self._recent) - self._recent_cap]
+            self._push_recent_locked(row)
         self.maybe_publish()
+
+    def _push_recent_locked(self, row: dict) -> None:
+        self._recent.append(row)
+        if len(self._recent) > self._recent_cap:
+            del self._recent[:len(self._recent) - self._recent_cap]
 
     def _win(self, deployment: str, objective: str) -> _Windows:
         w = self._windows.get((deployment, objective))
@@ -503,6 +515,17 @@ class ServingSLOLedger:
             tot = self._specdec.setdefault(deployment, [0, 0])
             tot[0] += int(proposed)
             tot[1] += int(accepted)
+
+    def record_engine_request(self, deployment: str, row: dict) -> None:
+        """One finished engine request's stage times and token counts
+        (``PagedJaxLLMEngine.pop_request_row`` plus the server's
+        ``stream_out_s``) into the recent ring, beside the ingress rows;
+        ``kind: "engine"`` tells them apart.  No publish attempt (see
+        ``record_stage``)."""
+        row = {"kind": "engine", "deployment": deployment,
+               "time": self.wall(), **row}
+        with self._lock:
+            self._push_recent_locked(row)
 
     def record_stage(self, deployment: str, stage: str,
                      seconds: float) -> None:
@@ -558,6 +581,9 @@ class ServingSLOLedger:
                "status": status, "recent": recent}
         if specdec:
             row["specdec"] = specdec
+        engine = engine_counters()
+        if engine:
+            row["engine"] = engine
         return row
 
     def snapshot(self) -> dict:
@@ -586,6 +612,7 @@ class ServingSLOLedger:
                 return False   # the serving path down
 
         def _bg():
+            name_os_thread()
             try:
                 self._publish()
             except Exception:  # noqa: BLE001 — publish retries on the next completion
@@ -622,6 +649,52 @@ class ServingSLOLedger:
             "key": SLO_KV_PREFIX + _metrics.reporter_id(),
             "value": json.dumps(self.row(), default=str),
         }, timeout=5)
+
+
+# ---------------------------------------------------------------------------
+# Engine counters (replica processes; published under the row's "engine")
+# ---------------------------------------------------------------------------
+
+# (deployment, weakref to an object with .counters()) — engines register
+# when they are labelled (llm/paged.py slo_label), as they do with
+# device_telemetry.register_utilization_object
+_engines: List[tuple] = []
+_engines_lock = make_lock("slo._engines_lock")
+
+
+def register_engine(deployment: str, obj: Any) -> None:
+    """Publish ``obj.counters()`` (cumulative numbers, ``drains`` a dict
+    by cause) in this process's row under ``engine[deployment]``.  Held
+    by weakref; several engines of one deployment (LoRA adapters) sum."""
+    import weakref
+
+    with _engines_lock:
+        _engines[:] = [(d, r) for d, r in _engines
+                       if r() is not None and r() is not obj]
+        _engines.append((deployment, weakref.ref(obj)))
+
+
+def engine_counters() -> Dict[str, dict]:
+    """``{deployment: summed counters}`` of this process's live engines."""
+    with _engines_lock:
+        live = [(d, r()) for d, r in _engines]
+    out: Dict[str, dict] = {}
+    for dep, eng in live:
+        if eng is None:
+            continue
+        try:
+            got = eng.counters()
+        except Exception:  # noqa: BLE001 — a dying engine books nothing
+            continue
+        tot = out.setdefault(dep, {})
+        for k, v in got.items():
+            if isinstance(v, dict):
+                sub = tot.setdefault(k, {})
+                for kk, vv in v.items():
+                    sub[kk] = sub.get(kk, 0) + vv
+            else:
+                tot[k] = tot.get(k, 0) + v
+    return out
 
 
 # ---------------------------------------------------------------------------
@@ -769,6 +842,15 @@ def record_stage(deployment: Optional[str], stage: str,
     if deployment is None or not enabled():
         return
     get_ledger().record_stage(deployment, stage, seconds)
+
+
+def record_engine_request(deployment: Optional[str], row: dict) -> None:
+    """One finished engine request's row into the replica's recent ring
+    (``state.recent_requests()``).  No label or disabled layer => books
+    nothing."""
+    if deployment is None or not enabled():
+        return
+    get_ledger().record_engine_request(deployment, row)
 
 
 def note_specdec(deployment: Optional[str], proposed: int,

@@ -19,8 +19,15 @@ Here the context is a per-thread ``(trace_id, span_id)`` pair:
   - serve's HTTP proxy ingests/emits the context as a W3C ``traceparent``
     header (``ingest()`` / ``format_traceparent()``).
 
-Everything is gated by ``task_events_enabled and tracing_enabled``; the
-disabled fast path is one config read plus one thread-local read.
+Everything above is gated by ``task_events_enabled and tracing_enabled``;
+the disabled fast path is one config read plus one thread-local read.
+
+``region()`` is the other clock: a span for the JAX profiler, not for the
+GCS.  Host work the device waits on (an engine step, a drain, a lock wait)
+is marked with it and lands in the XPlane that ``state.jax_profile``
+captures, beside the device's ``XLA Ops``, so host and device are read off
+one timeline.  It books nothing anywhere else and has no switch: with no
+capture running it costs the profiler's own flag test.
 """
 
 from __future__ import annotations
@@ -33,11 +40,6 @@ import uuid
 from typing import Any, Dict, Iterator, Optional, Tuple
 
 _local = threading.local()
-
-# process-local telemetry (bench.py trace_summary snapshot)
-_spans_emitted = 0
-_last_trace_id: Optional[str] = None
-
 
 def _enabled() -> bool:
     from ray_tpu._private.config import global_config
@@ -166,8 +168,8 @@ def emit_span(name: str, start: float, end: float, *,
               flush: bool = False) -> Optional[str]:
     """Record an already-completed span (wall-clock ``start``/``end``).
 
-    The cheap recorder used by built-in hot paths (collectives, engine
-    step phases, data operators): when no explicit ``trace_id`` is given
+    The cheap recorder used by built-in hot paths (collectives, data
+    operators): when no explicit ``trace_id`` is given
     it no-ops unless a context is active, so the disabled/untraced cost
     is two attribute reads.  Returns the span_id, or None if dropped.
     """
@@ -207,9 +209,6 @@ def emit_span(name: str, start: float, end: float, *,
           **({"attributes": attributes} if attributes else {})},
          {**base, "state": "FINISHED", "time": end}],
         flush=flush)
-    global _spans_emitted, _last_trace_id
-    _spans_emitted += 1
-    _last_trace_id = trace_id
     return sid
 
 
@@ -279,36 +278,31 @@ def activate_span(ctx3: Optional[Tuple[str, str, Optional[str]]], name: str,
                   trace_id=trace_id, parent_span_id=parent, span_id=sid)
 
 
-class PhaseRecorder:
-    """Stamp-under-lock / emit-after-release span recording for engine-style
-    hot loops: ``emit_span`` may flush to the GCS (socket I/O), which must
-    never run while holding a serving lock.  Stamp phases while locked,
-    call ``emit()`` once outside.
+# -- profiler regions (XPlane host plane; no GCS) ---------------------------
 
-        rec = tracing.PhaseRecorder()
-        with self._lock:
-            if rec.active:
-                t0 = time.time()
-            ...work...
-            if rec.active:
-                rec.stamp("engine.decode", t0, {"chunk": n})
-        rec.emit()
-    """
+_annotation = None
 
-    __slots__ = ("active", "_spans")
 
-    def __init__(self):
-        self.active = context_active()
-        self._spans = []
+def region(name: str, **attrs):
+    """A named region on the JAX profiler's host plane.
 
-    def stamp(self, name: str, start: float,
-              attributes: Optional[Dict[str, Any]] = None):
-        self._spans.append((name, start, time.time(), attributes))
+        with tracing.region("engine.collect", slots=len(active)):
+            em = np.asarray(em_dev)
 
-    def emit(self, kind: str = "engine"):
-        for name, t0, t1, attrs in self._spans:
-            emit_span(name, t0, t1, kind=kind, attributes=attrs)
-        self._spans.clear()
+    ``attrs`` (plain ints / strings) become the event's stats in xprof.
+    Names are a fixed vocabulary (README, Observability): the benchmark's
+    ``idle_attributed_pct`` and PERF.md's idle-by-span table read them."""
+    global _annotation
+    if _annotation is None:
+        try:
+            from jax.profiler import TraceAnnotation as _annotation
+        except ImportError:  # no jax in this process: nothing to capture
+            _annotation = _null_region
+    return _annotation(name, **attrs)
+
+
+def _null_region(name, **attrs):
+    return contextlib.nullcontext()
 
 
 def trace_function(fn=None, *, name: Optional[str] = None):
@@ -324,21 +318,3 @@ def trace_function(fn=None, *, name: Optional[str] = None):
         return wrapper
 
     return deco(fn) if fn is not None else deco
-
-
-def trace_summary_snapshot() -> dict:
-    """Process-local tracing telemetry for bench.py's JSON line; includes
-    a critical-path summary of the last trace when a cluster is up."""
-    out = {
-        "enabled": _enabled(),
-        "spans_emitted": _spans_emitted,
-        "last_trace_id": _last_trace_id,
-    }
-    if _last_trace_id and _worker() is not None:
-        try:
-            from ray_tpu.util.state import summarize_trace
-
-            out["last_trace_summary"] = summarize_trace(_last_trace_id)
-        except Exception as e:  # noqa: BLE001 — snapshot must never fail
-            out["last_trace_summary"] = {"error": str(e)[:200]}
-    return out

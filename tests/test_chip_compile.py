@@ -141,6 +141,55 @@ def test_flash_attention_forward_backward_compiles(one_chip, name):
                    *_flash_args(name, one_chip))
 
 
+# -- the kernels' names, as a profiler trace of the chip will show them --------
+
+
+def _kernel_instructions(fn, *args):
+    """Names of the compiled program's ``tpu_custom_call`` instructions."""
+    import re
+
+    text = jax.jit(fn).lower(*args).compile().as_text()
+    return [m.group(1) for m in re.finditer(
+        r"%([\w.\-]+) = [^\n]*custom_call_target=\"tpu_custom_call\"", text)]
+
+
+@pytest.mark.parametrize("kernel", ["paged_attention", "flash_attention_fwd",
+                                    "flash_attention_bwd"])
+def test_kernel_name_reaches_the_compiled_instruction(one_chip, kernel):
+    """``pl.pallas_call(name=)`` names the HLO instruction, and an ``XLA
+    Ops`` event of a trace is named by its instruction: the benchmark's
+    ``breakdown.device_ops`` then shows ``paged_attention.N`` where it had
+    ``closed_call.N``.  Inside the model's layer scan and name scopes too;
+    under ``jax.grad`` the name comes wrapped (``jvp_flash_attention_fwd_``,
+    ``transpose_jvp_flash_attention_bwd__``)."""
+    if kernel == "paged_attention":
+        from ray_tpu.ops.paged_attention import paged_decode_attention
+
+        def fn(q, pk, pv, li, table, lengths):
+            def body(c, _):
+                with jax.named_scope("attention"):
+                    return c + paged_decode_attention(
+                        q, pk, pv, li, table, lengths), None
+
+            return jax.lax.scan(
+                body, jnp.zeros((_B, _NH * _HD), jnp.float32), None,
+                length=2)[0]
+
+        args = _paged_args(16, 8, one_chip, one_chip, one_chip)
+    else:
+        from ray_tpu.ops.flash_attention import flash_attention
+
+        def loss(q, k, v):
+            return flash_attention(q, k, v, causal=True).astype(
+                jnp.float32).sum()
+
+        fn = jax.grad(loss, argnums=(0, 1, 2))
+        args = _flash_args("train_1b", one_chip)
+    names = _kernel_instructions(fn, *args)
+    assert names and all("closed_call" not in n for n in names), names
+    assert any(kernel in n for n in names), names
+
+
 # -- grouped matmul (megablox) at the expert model's tiling -------------------
 
 

@@ -1,0 +1,256 @@
+"""The engine's own measurement (ISSUE 24): profiler regions on the XPlane's
+host plane, first-token stages that partition enqueue -> first yield, and
+cumulative engine counters in ``utilization()`` and the replica's ledger
+row.  Toy engine on the CPU; one parametrised test per group."""
+
+import glob
+import threading
+import time
+
+import jax
+import numpy as np
+import pytest
+
+from ray_tpu.llm import GenerationConfig, LLMConfig, PagedJaxLLMEngine
+from ray_tpu.models.llama import LlamaConfig, init_params
+
+STAGES = ("enqueue_wait", "queue_wait", "prefill", "first_emit", "stream_out")
+
+
+@pytest.fixture(scope="module")
+def tiny():
+    mcfg = LlamaConfig.tiny()
+    return mcfg, init_params(mcfg, jax.random.PRNGKey(0))
+
+
+def _engine(tiny, **kw):
+    mcfg, params = tiny
+    kw = {"max_batch_size": 4, "max_seq_len": 128, "block_size": 8,
+          "prefill_chunk": 16, "num_blocks": 40, "decode_chunk": 4, **kw}
+    return PagedJaxLLMEngine(LLMConfig(model_config=mcfg, **kw),
+                             params=params)
+
+
+def _prompts(n, size):
+    return [list(np.random.RandomState(s).randint(1, 255, size=size))
+            for s in range(n)]
+
+
+# -- (a) profiler regions ------------------------------------------------------
+
+
+@pytest.fixture(scope="module")
+def host_events(tiny, tmp_path_factory):
+    """``{line name: [(name, start_ns, end_ns, stats)]}`` of the region
+    events of a capture around a few engine steps."""
+    from jax.profiler import ProfileData
+
+    eng = _engine(tiny)
+    eng.generate(_prompts(1, 12), GenerationConfig(max_new_tokens=4))  # compile
+    logdir = str(tmp_path_factory.mktemp("xplane"))
+    jax.profiler.start_trace(logdir)
+    try:
+        eng.generate(_prompts(2, 20), GenerationConfig(max_new_tokens=12))
+    finally:
+        jax.profiler.stop_trace()
+    (path,) = glob.glob(f"{logdir}/**/*.xplane.pb", recursive=True)
+    lines = {}
+    for plane in ProfileData.from_file(path).planes:
+        if not plane.name.startswith("/host:"):
+            continue
+        for line in plane.lines:
+            evs = [(e.name, e.start_ns, e.start_ns + e.duration_ns,
+                    dict(e.stats)) for e in line.events
+                   if e.name.split(".")[0] in ("engine", "kv", "serve")]
+            if evs:
+                lines.setdefault(line.name, []).extend(evs)
+    return lines
+
+
+@pytest.mark.parametrize("name", ["engine.step", "engine.admit",
+                                  "engine.prefill_chunk",
+                                  "engine.ensure_blocks", "engine.refresh",
+                                  "engine.decode_dispatch", "engine.collect",
+                                  "engine.drain"])
+def test_region_lands_in_the_xplane_inside_a_step(host_events, name):
+    found = [(line, ev) for line, evs in host_events.items() for ev in evs
+             if ev[0] == name]
+    assert found, sorted({e[0] for evs in host_events.values() for e in evs})
+    if name == "engine.step":
+        assert all({"pending", "active", "inflight"} <= set(ev[3])
+                   for _, ev in found)
+        return
+    for line, (_, t0, t1, _stats) in found:
+        assert any(s0 <= t0 and t1 <= s1 for n, s0, s1, _ in host_events[line]
+                   if n == "engine.step"), (name, t0, t1)
+
+
+# -- (b) first-token stages ----------------------------------------------------
+
+
+@pytest.fixture(scope="module")
+def staged(tiny):
+    """A labelled server that streamed six requests: per request the
+    engine's enqueue stamp, the clock at its first yield, and its ledger
+    row; and the stage sketches' counts."""
+    from ray_tpu.llm.serve import LLMServer
+    from ray_tpu.serve._private import slo
+
+    mcfg, params = tiny
+    dep = "stage-partition"
+    slo.reset_ledger()
+    server = LLMServer(
+        LLMConfig(model_config=mcfg, max_batch_size=4, decode_chunk=4,
+                  kv_cache="paged", block_size=8, prefill_chunk=16,
+                  max_seq_len=128, num_blocks=40), params)
+    server.set_slo_label(dep)
+    yields = {}
+    first_yield = server._note_first_yield
+
+    def spy(wkey):
+        req = server._engine.tracked_request(wkey[2])
+        out = first_yield(wkey)
+        yields[wkey[2]] = (req.t_enqueue, time.monotonic())
+        return out
+
+    server._note_first_yield = spy
+    got = {}
+
+    def client(i, prompt):
+        got[i] = [t for chunk in server.generate_stream(
+            prompt, max_new_tokens=6 + i) for t in chunk]
+
+    try:
+        threads = [threading.Thread(target=client, args=(i, p))
+                   for i, p in enumerate(_prompts(6, 40))]
+        for t in threads:
+            t.start()
+        for t in threads:
+            t.join(timeout=120)
+        ledger = slo.get_ledger()
+        rows = {r["engine_rid"]: r for r in ledger.recent()
+                if r.get("kind") == "engine" and r["deployment"] == dep}
+        counts = {p["tags"]["stage"]: p["count"]
+                  for p in ledger.row()["points"]
+                  if p["name"] == "ray_tpu_serve_stage_seconds"
+                  and p["tags"].get("deployment") == dep}
+        return {"got": got, "yields": yields, "rows": rows, "counts": counts,
+                "published": ledger.row().get("engine", {}).get(dep),
+                "counters": server.utilization()["counters"]}
+    finally:
+        server.shutdown()
+        slo.reset_ledger()
+
+
+@pytest.mark.parametrize("what", ("partition",) + STAGES)
+def test_first_token_stages_partition_enqueue_to_first_yield(staged, what):
+    rows = staged["rows"]
+    assert sorted(len(v) for v in staged["got"].values()) == list(range(6, 12))
+    assert len(rows) == 6 and set(rows) == set(staged["yields"])
+    if what != "partition":
+        # one sample of every stage per request
+        assert staged["counts"].get(what) == 6, staged["counts"]
+        return
+    for rid, row in rows.items():
+        t_enqueue, t_yield = staged["yields"][rid]
+        parts = (row["queue_wait_s"] + row["prefill_s"] + row["first_emit_s"]
+                 + row["stream_out_s"])
+        assert abs(parts - (t_yield - t_enqueue)) < 1e-3, (row, parts)
+        assert min(row["enqueue_wait_s"], row["queue_wait_s"],
+                   row["prefill_s"], row["first_emit_s"],
+                   row["stream_out_s"]) >= 0.0
+        assert row["prompt_tokens"] == 40 and row["prefill_chunks"] == 3
+        assert row["decode_tokens"] == len(staged["got"][rid - 1])
+        assert row["preempted"] == 0
+
+
+# -- (c) counters ----------------------------------------------------------------
+
+
+@pytest.fixture(scope="module")
+def counted(tiny):
+    """Counter reads after each phase of one engine's life: a batch with a
+    shared prefix, a drain forced by a late arrival, a cancel."""
+    eng = _engine(tiny)
+    reads = [eng.counters()]
+    shared = list(range(1, 25))  # three full blocks
+    prompts = [shared + [50 + i] * 6 for i in range(3)]
+    gen = GenerationConfig(max_new_tokens=9)
+    # the first registers the shared blocks, the others then hit them
+    outs = eng.generate(prompts[:1], gen) + eng.generate(prompts[1:], gen)
+    reads.append(eng.counters())
+    # a request decoding alone pipelines; a late arrival's final prefill
+    # chunk then forces exactly one drain of the chunk in flight
+    eng.add_request([7] * 12, GenerationConfig(max_new_tokens=40))
+    while eng.counters()["decode_dispatches_pipelined"] \
+            == reads[-1]["decode_dispatches_pipelined"]:
+        eng.step()
+    mid = eng.counters()
+    rid = eng.add_request([9] * 12, GenerationConfig(max_new_tokens=40))
+    eng.step()
+    reads.append(eng.counters())
+    eng.cancel_request(rid)
+    while eng.has_work():
+        eng.step()
+    eng.flush()
+    reads.append(eng.counters())
+    return {"reads": reads, "mid": mid, "prompts": prompts, "outs": outs}
+
+
+@pytest.fixture(scope="module")
+def preempted(tiny):
+    eng = _engine(tiny, num_blocks=14, enable_prefix_caching=False)
+    outs = eng.generate(_prompts(3, 16), GenerationConfig(max_new_tokens=40))
+    return eng.counters(), outs
+
+
+def _flat(counters):
+    out = {k: v for k, v in counters.items() if not isinstance(v, dict)}
+    out.update({f"drains.{k}": v for k, v in counters["drains"].items()})
+    return out
+
+
+@pytest.mark.parametrize("case", ["prefill_tokens", "tokens_emitted",
+                                  "forced_drain", "forced_preemption",
+                                  "never_decrease", "ledger_row"])
+def test_engine_counters(counted, preempted, staged, case):
+    reads = counted["reads"]
+    first = {k: reads[1][k] - reads[0][k] for k in _flat(reads[1])
+             if k in reads[0] and not isinstance(reads[1][k], dict)}
+    if case == "prefill_tokens":
+        asked = sum(len(p) for p in counted["prompts"])
+        assert first["prefix_hit_tokens"] > 0
+        assert first["prefill_tokens"] == asked - first["prefix_hit_tokens"]
+        assert first["prefill_padded_tokens"] >= 0
+        assert first["prefill_chunks"] >= 3
+    elif case == "tokens_emitted":
+        assert first["tokens_emitted"] == sum(len(o) for o in counted["outs"])
+        assert first["decode_token_steps"] == 4 * first["decode_dispatches"]
+        assert first["steps"] > 0 and first["host_s"] > 0
+        assert first["device_wait_s"] > 0
+    elif case == "forced_drain":
+        before, after = counted["mid"]["drains"], reads[2]["drains"]
+        assert after.get("final_prefill", 0) \
+            == before.get("final_prefill", 0) + 1
+        assert sum(after.values()) == sum(before.values()) + 1
+    elif case == "forced_preemption":
+        got, outs = preempted
+        assert all(len(o) == 40 for o in outs)
+        assert got["preemptions"] >= 1
+        assert got["drains"].get("preempt", 0) <= got["preemptions"]
+        assert got["tokens_emitted"] == 120  # recompute re-emits nothing
+        assert got["prefill_tokens"] > 3 * 16  # the victim's recompute
+    elif case == "never_decrease":
+        for a, b in zip(reads, reads[1:]):
+            fa, fb = _flat(a), _flat(b)
+            assert all(fb[k] >= v for k, v in fa.items()), (fa, fb)
+        assert reads[-1]["compiles"] >= 0
+    else:
+        # the server's engine: utilization() and the published ledger row
+        pub, live = staged["published"], staged["counters"]
+        assert pub is not None
+        assert set(pub) == set(live)
+        assert pub["tokens_emitted"] == sum(
+            len(v) for v in staged["got"].values())
+        assert pub["prefill_tokens"] + pub["prefix_hit_tokens"] == 6 * 40
+        assert live["loop_idle_s"] >= 0.0
