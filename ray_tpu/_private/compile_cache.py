@@ -17,6 +17,12 @@ import os
 import sys
 
 _ENV = "JAX_COMPILATION_CACHE_DIR"
+# JAX keeps a program only if it took this long to compile (default 1 s).
+# The serving replica's fourteen warm-up programs compile in 0.6 to 2 s
+# each, so by the default a warm start found some and compiled the others
+# again (4.5 s of a 58 s set-up, PERF.md PR 25): every program is kept.
+# An operator's own setting of JAX's variable stands.
+_KEEP_ENV = "JAX_PERSISTENT_CACHE_MIN_COMPILE_TIME_SECS"
 _CHECKOUT = os.path.dirname(os.path.dirname(os.path.dirname(
     os.path.abspath(__file__))))
 
@@ -24,14 +30,17 @@ _CHECKOUT = os.path.dirname(os.path.dirname(os.path.dirname(
 def configure() -> str:
     """Returns the cache directory in force.  Safe before or after
     ``import jax``; touches no backend."""
+    # children (and a jax not imported yet) read the variables ...
+    keep = float(os.environ.setdefault(_KEEP_ENV, "0"))
+    jax = sys.modules.get("jax")
+    if jax is not None:
+        # ... a jax already imported read them too early
+        jax.config.update("jax_persistent_cache_min_compile_time_secs", keep)
     path = os.environ.get(_ENV)
     if path:
         return path
     path = os.path.join(_CHECKOUT, ".jax_cache")
-    # children (and a jax not imported yet) read the variable ...
     os.environ[_ENV] = path
-    jax = sys.modules.get("jax")
     if jax is not None:
-        # ... a jax already imported read it too early
         jax.config.update("jax_compilation_cache_dir", path)
     return path

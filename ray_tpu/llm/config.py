@@ -109,11 +109,13 @@ class LLMConfig:
     # many blocks.  0 (default) disables; requires an initialized ray_tpu
     # worker — without one the host tier simply drops its evictions.
     plasma_kv_cache_blocks: int = 0
-    # True -> the pallas TPU paged-attention kernel for decode (single-chip
-    # TPU, head_dim % 128 == 0, pp == 1). None = auto: ON where supported
-    # (its speed against the XLA block-gather: not measured on this
-    # round's code; chip_smoke.py shows it selected and agreeing with the
-    # float32 reference). True forces it (raises
+    # True -> the pallas TPU paged-attention kernel for decode (TPU,
+    # head_dim % 128 == 0, pp == 1). None = auto: ON where supported. Its
+    # time follows the decoding rows' live pages (0.10 ms a layer-call at 20
+    # rows of 450 tokens in a 64 x 128 table, v5e; PERF.md, PR 25); against
+    # the XLA block-gather it has not been measured on this round's code
+    # (chip_smoke.py shows it selected and agreeing with the float32
+    # reference). True forces it (raises
     # off-TPU); False forces the gather path; "interpret" is a test hook
     # that runs the kernel in pallas interpret mode off-TPU.
     paged_attention_kernel: Optional[Any] = None

@@ -313,8 +313,8 @@ class _PagedReq(_Request):
 _COUNTERS = ("steps", "prefill_chunks", "prefill_tokens",
              "prefill_padded_tokens", "prefix_hit_tokens",
              "decode_dispatches", "decode_dispatches_pipelined",
-             "decode_token_steps", "tokens_emitted", "preemptions",
-             "kv_demotions")
+             "decode_token_steps", "decode_table_pages", "decode_live_pages",
+             "tokens_emitted", "preemptions", "kv_demotions")
 _TRACKED_MAX = 4096
 
 
@@ -617,9 +617,10 @@ class PagedJaxLLMEngine:
         self._first_pending: List[Tuple[int, _PagedReq, jnp.ndarray]] = []
 
         # fused pallas paged-attention kernel (ray_tpu/ops/paged_attention):
-        # DMAs only each sequence's live pages — no gather materialization.
-        # Default ON where supported (speed against the XLA gather: not
-        # measured on this round's code).
+        # DMAs only the decoding slots' live pages — no gather
+        # materialization, no work for idle slots or table padding (the
+        # decode chunk hands it the scan carry's `active`).  Default ON
+        # where supported (its record: the kernel module's docstring).
         # Composes with TP via shard_map (kv heads over "tensor"); PP still
         # uses the gather path (the layer scan spans all stages, so a
         # pipeline-sharded pool cannot feed per-shard page DMAs).
@@ -846,6 +847,11 @@ class PagedJaxLLMEngine:
         cache); ``decode_dispatches``, ``decode_dispatches_pipelined``
         (the previous chunk was still in flight at the dispatch, so the
         device never waited for the host), ``decode_token_steps``;
+        ``decode_table_pages`` / ``decode_live_pages`` (per dispatch: the
+        padded block table handed to the decode program, ``max_batch`` x
+        its bucketed width, and the blocks the decoding slots really hold:
+        the share of that table a kernel that follows the live pages
+        touches);
         ``tokens_emitted``; ``drains`` by cause (an in-flight chunk
         collected before the next dispatch could be queued behind it);
         ``preemptions``, ``kv_demotions``; ``host_s`` / ``device_wait_s``
@@ -987,7 +993,7 @@ class PagedJaxLLMEngine:
                 self.cfg, params, tokens, pool, table, lengths,
                 rope_cache=self._rope, use_kernel=self._use_kernel,
                 mesh=self.mesh, kernel_interpret=self._kernel_interpret,
-                tp_plan=self._tp_plan)
+                tp_plan=self._tp_plan, active=active)
             key, sub = jax.random.split(key)
             ids = _sample(logits, sub, temps, top_ks)
             emitted = jnp.where(active > 0, ids, -1)
@@ -1902,6 +1908,9 @@ class PagedJaxLLMEngine:
         c = self._c
         c["decode_dispatches"] += 1
         c["decode_token_steps"] += steps
+        c["decode_table_pages"] += self.max_batch * w
+        c["decode_live_pages"] += sum(
+            len(self._slot_req[s].blocks) for s in active)
         if prev is not None:
             c["decode_dispatches_pipelined"] += 1
         return prev

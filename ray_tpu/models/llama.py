@@ -494,8 +494,9 @@ def decode_step(cfg: LlamaConfig, params: Params, tokens: jnp.ndarray,
 # fused XLA gather/scatter whose leading index is the (scalar) layer id —
 # `pool[li, table]` / `pool.at[li, blk, off].set(...)` — so no layer slice
 # is ever materialized and the pool is never restacked.  (The previous
-# xs/ys design restacked the full pool every token-step: measured 6.8 ms of
-# the 11.5 ms/token-step at b32 on v5e — see benchmarks/paged_bisect.py.)
+# xs/ys design restacked the full pool every token-step: 6.8 ms of the
+# 11.5 ms/token-step at b32 on v5e in round 5's notes, a toy model and a
+# script since removed; not reproduced on this round's code.)
 # Sharding: the kv-head axis shards over "tensor" exactly as the dense
 # cache, layer axis over "pipeline", block/table axes replicated.
 # ---------------------------------------------------------------------------
@@ -649,17 +650,22 @@ def decode_step_paged(cfg: LlamaConfig, params: Params, tokens: jnp.ndarray,
                       rope_cache: Optional[tuple] = None,
                       use_kernel: bool = False, mesh=None,
                       kernel_interpret: bool = False,
-                      tp_plan: Optional[TPPlan] = None):
+                      tp_plan: Optional[TPPlan] = None,
+                      active: Optional[jnp.ndarray] = None):
     """One-token decode for every slot, KV in a paged pool.
 
     tokens [B] int32; table [B, W] block ids covering each slot's sequence
     (host guarantees coverage through position lengths[b]); lengths [B].
     ``use_kernel`` (static): pallas fused paged-attention — reads ONLY each
     sequence's live pages instead of materializing the XLA block gather
-    (measured on v5e b32: 5.2 vs 5.3 ms/token-step at span 256, 8.0 vs 17.4
-    at span 1024 — benchmarks/paged_bisect.py).  With ``mesh``, the kernel
-    runs under shard_map with kv heads sharded over the "tensor" axis, so
-    it composes with TP.  With ``tp_plan``, the per-layer partial-sum
+    (0.10 ms a layer-call for 20 decoding rows of 450 tokens in a 64 x 128
+    table on a v5e, 1.04 for 64 full rows: ops/paged_attention.py has the
+    record; against the gather path: not measured).  ``active`` [B] (kernel
+    path only; None: all): rows with 0 do no attention work and get zeros
+    for it, so their logits mean nothing; the engine's decode chunk passes
+    its scan carry, in which a row that finished mid-chunk is already 0.
+    With ``mesh``, the kernel runs under shard_map with kv heads sharded
+    over the "tensor" axis, so it composes with TP.  With ``tp_plan``, the per-layer partial-sum
     reductions route through the planner's chosen algorithm explicitly
     (see :class:`TPPlan`).  Returns (logits [B, V] fp32, updated pool).
     """
@@ -675,7 +681,9 @@ def decode_step_paged(cfg: LlamaConfig, params: Params, tokens: jnp.ndarray,
     bidx = jnp.arange(b)
     cur_blk = table[bidx, lengths // bs]  # [B] physical block of the write
     cur_off = lengths % bs
-    if not use_kernel:  # the kernel masks from `lengths` internally
+    if use_kernel:  # masks from `lengths` internally
+        active = jnp.ones_like(lengths) if active is None else active
+    else:
         span_mask = (jnp.arange(w * bs)[None, None, :]
                      <= lengths[:, None, None])  # [B, 1, W*bs]
     x = jnp.take(params["embed"], tokens, axis=0).astype(cdt)
@@ -710,9 +718,11 @@ def decode_step_paged(cfg: LlamaConfig, params: Params, tokens: jnp.ndarray,
                     t = P(None, None, None, "tensor")
                     kern = jax.shard_map(
                         kern, mesh=mesh,
-                        in_specs=(P(None, "tensor", None), t, t, P(), P(), P()),
+                        in_specs=(P(None, "tensor", None), t, t,
+                                  P(), P(), P(), P()),
                         out_specs=P(None, "tensor"), check_vma=False)
-                attn = kern(q[:, 0], pk_all, pv_all, li, table, lengths)
+                attn = kern(q[:, 0], pk_all, pv_all, li, table, lengths,
+                            active)
             else:
                 ck = pk_all[li, table].reshape(b, w * bs, cfg.n_kv_heads,
                                                cfg.head_dim)

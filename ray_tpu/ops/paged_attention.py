@@ -7,9 +7,23 @@ attention einsums read it back — ~3x the span bytes of the information-
 theoretic floor.  This kernel DMAs each sequence's pages HBM -> VMEM
 directly off the block table (double-buffered, page-granular) and runs
 flash-style GQA attention in VMEM, so the span is read exactly once for k
-and once for v.  Rows shorter than the bucketed table width skip the DMA
-of chunks wholly beyond their live span (compute over those lanes still
-runs, masked — it is VPU-cheap; the HBM traffic is what the skip saves).
+and once for v.
+
+Its time follows the live pages of the decoding rows, not batch x table
+width: the engine's table is ``max_batch_size`` rows by a power-of-two
+bucket of the longest row, and in a serving step most of that is padding
+(``decode_table_live_pct``).  A row with ``active == 0`` starts no DMA and
+runs no chunk; a row's chunk loop runs ``cdiv(nvalid, chunk tokens)`` times;
+inside a chunk only the pages below ``cdiv(nvalid, bs)`` are fetched and
+waited for (the v rows of the others are zeroed, their scores masked before
+exp); and a row's last chunk hides the fetch of the next decoding row's
+first.  On a v5e at batch 64, 32 / 8 heads of 128, 16-token pages, table
+width 128 (my chip run, PR 25; `benchmarks/paged_kernel_bench.py`): 20
+decoding rows of 450 tokens among 44 idle ones 0.100 ms a call (0.955 before
+this), 64 rows of 100 tokens 0.141 (0.945), 64 rows of 2,040 tokens 1.041
+(1.051).  The static unroll over every chunk of every row that this
+replaced skipped only the DMA of a dead chunk, and its masked compute was
+what a call cost: 1.85 us a chunk, 512 chunks.
 
 Pool layout (canonical, see `models/llama.py init_paged_kv_cache`):
 [L, NB, bs, kv*hd] — one page is a contiguous [bs, kv*hd] slab whose
@@ -33,62 +47,79 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 
-def _kernel(li_ref, tbl_ref, len_ref, q_ref, k_hbm, v_hbm, o_ref,
-            kbuf, vbuf, sems, *, kv, hd, bs, cw, n_chunks, scale):
-    """One grid step = one batch row: DMA its pages, flash-attend.
+def _kernel(li_ref, tbl_ref, len_ref, act_ref, q_ref, k_hbm, v_hbm, o_ref,
+            kbuf, vbuf, sems, nxt_ref, *, kv, hd, bs, cw, scale):
+    """One grid step = one batch row: DMA its live pages, flash-attend.
 
     kbuf/vbuf: [2, CW, bs, kv*hd] double buffers; sems: [2, 2, CW] DMA sems
-    (dims: k/v, buffer slot, page).
+    (dims: k/v, buffer slot, page); nxt_ref: [2] SMEM, carried from row to
+    row (the grid runs in order).  Every bound comes from the row's own
+    operands: a row with ``active == 0`` does nothing but write zeros, the
+    chunk loop runs ``cdiv(nvalid, CW*bs)`` times, and inside a chunk only
+    pages below ``cdiv(nvalid, bs)`` are fetched.
     """
     b = pl.program_id(0)
+    nrows = pl.num_programs(0)
     li = li_ref[0]
     nvalid = len_ref[b] + 1  # freshly written token at position lengths[b]
     group = q_ref.shape[1] // kv
     span_c = cw * bs
+    # never past the table, whatever `lengths` holds
+    n_pages = jnp.minimum(lax.div(nvalid + (bs - 1), bs), tbl_ref.shape[1])
+    n_chunks = lax.div(n_pages + (cw - 1), cw)
 
-    def chunk_live(c):
-        # chunk c holds positions [c*span_c, (c+1)*span_c): it has data to
-        # fetch iff its first position is inside the row's live span.  Rows
-        # shorter than the bucketed table width skip the dead pages' DMA
-        # entirely (their lanes are masked in compute, so stale VMEM is
-        # harmless: masked scores are replaced by -1e30 before exp).
-        return c * span_c < nvalid
+    def decodes(row):
+        return jnp.logical_and(act_ref[row] != 0, len_ref[row] >= 0)
 
-    def start_chunk(c, slot):
-        dmas = []
-        for j in range(cw):
-            page = tbl_ref[b, c * cw + j]
-            for src, buf, i in ((k_hbm, kbuf, 0), (v_hbm, vbuf, 1)):
-                dmas.append(pltpu.make_async_copy(
-                    src.at[li, page], buf.at[slot, j], sems.at[i, slot, j]))
+    def page_copies(page, slot, j):
+        return [pltpu.make_async_copy(
+            src.at[li, page], buf.at[slot, j], sems.at[i, slot, j])
+            for src, buf, i in ((k_hbm, kbuf, 0), (v_hbm, vbuf, 1))]
 
-        @pl.when(chunk_live(c))
-        def _():
-            for dma in dmas:
+    def each_page(lo, hi, fn):
+        """``fn(j)`` for pages ``lo <= j < hi`` of a chunk: unrolled where
+        both bounds are static, a loop where they come from the row."""
+        if isinstance(lo, int) and isinstance(hi, int):
+            for j in range(lo, hi):
+                fn(j)
+        else:
+            def body(j, carry):
+                fn(j)
+                return carry
+
+            lax.fori_loop(lo, hi, body, 0)
+
+    def start_chunk(row, c, slot, n):
+        def start(j):
+            for dma in page_copies(tbl_ref[row, c * cw + j], slot, j):
                 dma.start()
 
-        return dmas
+        each_page(0, n, start)
 
-    inflight = start_chunk(0, 0)
-    m = [jnp.full((group, 1), -1e30, jnp.float32) for _ in range(kv)]
-    l = [jnp.zeros((group, 1), jnp.float32) for _ in range(kv)]
-    acc = [jnp.zeros((group, hd), jnp.float32) for _ in range(kv)]
-
-    for c in range(n_chunks):
-        slot = c % 2
-        done, inflight = inflight, []
-        if c + 1 < n_chunks:
-            inflight = start_chunk(c + 1, (c + 1) % 2)
-
-        @pl.when(chunk_live(c))
-        def _():
-            for dma in done:
+    def land_chunk(slot, n):
+        def wait(j):
+            # a wait needs the semaphore and the size, not the source
+            for dma in page_copies(0, slot, j):
                 dma.wait()
 
+        def zero(j):
+            # a page not fetched holds whatever an earlier chunk or row
+            # left (NaN at worst): its lanes get p == 0 exactly, and
+            # 0 * NaN is NaN, so its v rows are zeroed.  Its k rows may
+            # stay: their scores are replaced before exp.
+            vbuf[slot, j] = jnp.zeros(vbuf.shape[2:], vbuf.dtype)
+
+        each_page(0, n, wait)
+        each_page(n, cw, zero)
+
+    def live_pages(c):
+        return jnp.clip(n_pages - c * cw, 0, cw)
+
+    def attend(c, slot, carry):
+        m, l, acc = (list(x) for x in carry)
         kc = kbuf[slot]  # [CW, bs, kv*hd]
         vc = vbuf[slot]
-        pos = c * span_c + lax.broadcasted_iota(
-            jnp.int32, (1, span_c), 1)
+        pos = c * span_c + lax.broadcasted_iota(jnp.int32, (1, span_c), 1)
         mask = pos < nvalid
         for h in range(kv):
             kh = kc[:, :, h * hd:(h + 1) * hd].reshape(span_c, hd)
@@ -105,18 +136,89 @@ def _kernel(li_ref, tbl_ref, len_ref, q_ref, k_hbm, v_hbm, o_ref,
             pv = lax.dot_general(
                 p.astype(vh.dtype), vh, (((1,), (0,)), ((), ())),
                 preferred_element_type=jnp.float32)  # [G, hd]
-            # a DMA-skipped chunk's buffer may hold NaN garbage: p is
-            # exactly 0 there, but 0 * NaN = NaN — zero the contribution
-            pv = jnp.where(chunk_live(c), pv, 0.0)
             acc[h] = acc[h] * corr + pv
             m[h] = m_new
+        return tuple(m), tuple(l), tuple(acc)
 
-    for h in range(kv):
-        o_ref[0, h * group:(h + 1) * group, :] = acc[h] / l[h]
+    @pl.when(b == 0)
+    def _():
+        nxt_ref[0] = 0
+        nxt_ref[1] = -1
+
+    # a row's chunk 0 lands in the slot the decoding row before it left
+    # free, so that row's last chunk can hide the fetch: nxt_ref[0] is that
+    # slot, nxt_ref[1] the row whose chunk 0 is already on its way
+    base = nxt_ref[0]
+
+    def chunk(c, carry):
+        slot = (c + base) & 1
+
+        def all_live():
+            start_chunk(b, c + 1, 1 - slot, cw)
+            land_chunk(slot, cw)
+
+        def row_end():
+            start_chunk(b, c + 1, 1 - slot, live_pages(c + 1))
+
+            @pl.when(c + 1 == n_chunks)
+            def _():
+                last = nrows - 1
+                nb = lax.while_loop(
+                    lambda r: jnp.logical_and(
+                        r < nrows,
+                        jnp.logical_not(decodes(jnp.minimum(r, last)))),
+                    lambda r: r + 1, b + 1)
+
+                @pl.when(nb < nrows)
+                def _():
+                    row = jnp.minimum(nb, last)
+                    pages = lax.div(len_ref[row] + bs, bs)
+                    start_chunk(row, 0, 1 - slot, jnp.minimum(pages, cw))
+                    nxt_ref[1] = row
+
+            land_chunk(slot, live_pages(c))
+
+        # this chunk and the next all live (every chunk of a long row but
+        # its last two): one branch, every page unrolled
+        lax.cond((c + 2) * cw <= n_pages, all_live, row_end)
+        return attend(c, slot, carry)
+
+    @pl.when(jnp.logical_not(decodes(b)))
+    def _():
+        o_ref[...] = jnp.zeros(o_ref.shape, o_ref.dtype)
+
+    @pl.when(decodes(b))
+    def _():
+        @pl.when(nxt_ref[1] != b)
+        def _():
+            start_chunk(b, 0, base, live_pages(0))
+
+        init = (tuple(jnp.full((group, 1), -1e30, jnp.float32)
+                      for _ in range(kv)),
+                tuple(jnp.zeros((group, 1), jnp.float32) for _ in range(kv)),
+                tuple(jnp.zeros((group, hd), jnp.float32) for _ in range(kv)))
+        # nvalid >= 1 here: at least one chunk, and l >= 1
+        _, l, acc = lax.fori_loop(0, n_chunks, chunk, init)
+        nxt_ref[0] = (base + n_chunks) & 1
+        for h in range(kv):
+            o_ref[0, h * group:(h + 1) * group, :] = acc[h] / l[h]
 
 
-def _paged_decode_attention(q, pk_all, pv_all, li, table, lengths,
-                            interpret=False):
+def paged_decode_attention(q, pk_all, pv_all, li, table, lengths,
+                           active=None, interpret=False):
+    """GQA paged decode attention.
+
+    q [B, nh, hd] (unscaled); pk/pv [L, NB, bs, kv*hd]; li scalar layer id;
+    table [B, W] block ids; lengths [B] — valid span = lengths + 1 (the
+    freshly written token attends to itself); active [B], nonzero for the
+    rows that decode (None: all).  A row with ``active == 0`` (or a negative
+    length) costs a grid step and returns zeros whatever its ``lengths``
+    and table row hold.
+    kv-head count is derived from the pool's folded last dim, so per-shard
+    calls under shard_map (kv heads sharded over "tensor") need no extra
+    plumbing.  Returns [B, nh*hd] fp32, numerically matching
+    `_paged_attend` on the active rows.
+    """
     b, nh, hd = q.shape
     kv = pk_all.shape[3] // hd  # per-shard kv heads under shard_map
     bs = pk_all.shape[2]
@@ -126,9 +228,8 @@ def _paged_decode_attention(q, pk_all, pv_all, li, table, lengths,
     cw = min(max(1, w // 2), max(1, 256 // bs))
     while w % cw:
         cw //= 2
-    n_chunks = w // cw
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=3,
+        num_scalar_prefetch=4,
         grid=(b,),
         in_specs=[
             pl.BlockSpec((1, nh, hd), lambda i, *_: (i, 0, 0)),
@@ -140,32 +241,22 @@ def _paged_decode_attention(q, pk_all, pv_all, li, table, lengths,
             pltpu.VMEM((2, cw, bs, kv * hd), pk_all.dtype),
             pltpu.VMEM((2, cw, bs, kv * hd), pv_all.dtype),
             pltpu.SemaphoreType.DMA((2, 2, cw)),
+            pltpu.SMEM((2,), jnp.int32),
         ],
     )
     kern = functools.partial(
-        _kernel, kv=kv, hd=hd, bs=bs, cw=cw, n_chunks=n_chunks,
-        scale=1.0 / math.sqrt(hd))
+        _kernel, kv=kv, hd=hd, bs=bs, cw=cw, scale=1.0 / math.sqrt(hd))
+    if active is None:
+        active = jnp.ones_like(lengths)
     out = pl.pallas_call(
         kern,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b, nh, hd), jnp.float32),
+        # rows in order: each hands the next its buffer slot and first fetch
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",)),
         interpret=interpret,
         name="paged_attention",  # the kernel's name in a profiler trace
     )(jnp.asarray(li, jnp.int32).reshape(1), table, lengths,
-      q, pk_all, pv_all)
+      active.astype(jnp.int32), q, pk_all, pv_all)
     return out.reshape(b, nh * hd)
-
-
-def paged_decode_attention(q, pk_all, pv_all, li, table, lengths,
-                           interpret=False):
-    """GQA paged decode attention.
-
-    q [B, nh, hd] (unscaled); pk/pv [L, NB, bs, kv*hd]; li scalar layer id;
-    table [B, W] block ids; lengths [B] — valid span = lengths + 1 (the
-    freshly written token attends to itself).  kv-head count is derived
-    from the pool's folded last dim, so per-shard calls under shard_map
-    (kv heads sharded over "tensor") need no extra plumbing.
-    Returns [B, nh*hd] fp32, numerically matching `_paged_attend`.
-    """
-    return _paged_decode_attention(
-        q, pk_all, pv_all, li, table, lengths, interpret=interpret)

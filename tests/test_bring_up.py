@@ -87,6 +87,8 @@ def test_compile_cache_is_placed_from_outside(monkeypatch, tmp_path):
     from ray_tpu._private import compile_cache
 
     before = jax.config.jax_compilation_cache_dir
+    keep = "jax_persistent_cache_min_compile_time_secs"
+    kept = getattr(jax.config, keep)
     try:
         monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
         assert compile_cache.configure() == str(tmp_path)
@@ -99,6 +101,32 @@ def test_compile_cache_is_placed_from_outside(monkeypatch, tmp_path):
         assert jax.config.jax_compilation_cache_dir == want
     finally:
         jax.config.update("jax_compilation_cache_dir", before)
+        jax.config.update(keep, kept)
+        os.environ.pop("JAX_PERSISTENT_CACHE_MIN_COMPILE_TIME_SECS", None)
+
+
+@pytest.mark.parametrize("outside", [None, "2.5"])
+def test_compile_cache_keeps_every_program(monkeypatch, tmp_path, outside):
+    """JAX's default keeps only programs that took a second to compile; the
+    replica's warm-up programs are around that, so a warm start compiled
+    some again.  ``configure`` keeps all, unless JAX's variable says else."""
+    from ray_tpu._private import compile_cache
+
+    name = "JAX_PERSISTENT_CACHE_MIN_COMPILE_TIME_SECS"
+    flag = "jax_persistent_cache_min_compile_time_secs"
+    before = getattr(jax.config, flag)
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
+    try:
+        if outside is None:
+            monkeypatch.delenv(name, raising=False)
+        else:
+            monkeypatch.setenv(name, outside)
+        compile_cache.configure()
+        assert os.environ[name] == (outside or "0")  # children inherit it
+        assert getattr(jax.config, flag) == float(outside or 0)
+    finally:
+        jax.config.update(flag, before)
+        monkeypatch.delenv(name, raising=False)
 
 
 def test_nothing_else_in_the_tree_sets_a_cache_directory():

@@ -77,11 +77,27 @@ def _assert_kernel(fn, *args):
 _B, _NH, _KV, _HD, _L, _NB = 8, 32, 8, 128, 8, 2048
 
 
-def _paged_args(bs, w, sh_q, sh_pool, sh_rep):
+def _paged_args(bs, w, sh_q, sh_pool, sh_rep, b=_B):
+    """q, k pool, v pool, layer id, table, lengths, active."""
     pool = _spec((_L, _NB, bs, _KV * _HD), BF16, sh_pool)
-    return (_spec((_B, _NH, _HD), BF16, sh_q), pool, pool,
-            _spec((), jnp.int32, sh_rep), _spec((_B, w), jnp.int32, sh_rep),
-            _spec((_B,), jnp.int32, sh_rep))
+    return (_spec((b, _NH, _HD), BF16, sh_q), pool, pool,
+            _spec((), jnp.int32, sh_rep), _spec((b, w), jnp.int32, sh_rep),
+            _spec((b,), jnp.int32, sh_rep), _spec((b,), jnp.int32, sh_rep))
+
+
+def _paged_under_4_shard_map(mesh):
+    """The tensor-parallel engine's wrap (models/llama.py decode_step_paged):
+    kv heads over "tensor", per-shard kv*hd = 256; table, lengths and active
+    replicated."""
+    from ray_tpu.ops.paged_attention import paged_decode_attention
+
+    t = P(None, None, None, "tensor")
+    kern = jax.shard_map(
+        paged_decode_attention, mesh=mesh,
+        in_specs=(P(None, "tensor", None), t, t, P(), P(), P(), P()),
+        out_specs=P(None, "tensor"), check_vma=False)
+    ns = functools.partial(NamedSharding, mesh)
+    return kern, (ns(P(None, "tensor", None)), ns(t), ns(P()))
 
 
 @pytest.mark.parametrize("bs", [16, 32])
@@ -96,18 +112,28 @@ def test_paged_decode_attention_compiles(one_chip, bs, w):
 @pytest.mark.parametrize("bs", [16, 32])
 @pytest.mark.parametrize("w", [8, 64])
 def test_paged_decode_attention_compiles_under_4_shard_map(tensor_mesh, bs, w):
-    """The tensor-parallel engine's wrap (models/llama.py decode_step_paged):
-    kv heads over "tensor", per-shard kv*hd = 256."""
+    kern, shardings = _paged_under_4_shard_map(tensor_mesh)
+    _assert_kernel(kern, *_paged_args(bs, w, *shardings))
+
+
+# the benchmark's serving cell (m7b-d16.chat_steady): batch 64, 16-token
+# pages, the two table widths its decode steps run at.  The chunk loop's trip
+# count and the page DMAs come from the row's operands, so a Mosaic lowering
+# or VMEM failure of the dynamic loop shows here.
+
+
+@pytest.mark.parametrize("w", [128, 256])
+def test_paged_decode_attention_compiles_at_cell_shapes(one_chip, w):
     from ray_tpu.ops.paged_attention import paged_decode_attention
 
-    t = P(None, None, None, "tensor")
-    kern = jax.shard_map(
-        paged_decode_attention, mesh=tensor_mesh,
-        in_specs=(P(None, "tensor", None), t, t, P(), P(), P()),
-        out_specs=P(None, "tensor"), check_vma=False)
-    ns = functools.partial(NamedSharding, tensor_mesh)
-    _assert_kernel(kern, *_paged_args(
-        bs, w, ns(P(None, "tensor", None)), ns(t), ns(P())))
+    _assert_kernel(paged_decode_attention,
+                   *_paged_args(16, w, one_chip, one_chip, one_chip, b=64))
+
+
+def test_paged_decode_attention_compiles_at_cell_shapes_under_4_shard_map(
+        tensor_mesh):
+    kern, shardings = _paged_under_4_shard_map(tensor_mesh)
+    _assert_kernel(kern, *_paged_args(16, 128, *shardings, b=64))
 
 
 # -- flash attention: the 1.14 B train shape and the 8 B widths ----------------
@@ -165,11 +191,11 @@ def test_kernel_name_reaches_the_compiled_instruction(one_chip, kernel):
     if kernel == "paged_attention":
         from ray_tpu.ops.paged_attention import paged_decode_attention
 
-        def fn(q, pk, pv, li, table, lengths):
+        def fn(q, pk, pv, li, table, lengths, active):
             def body(c, _):
                 with jax.named_scope("attention"):
                     return c + paged_decode_attention(
-                        q, pk, pv, li, table, lengths), None
+                        q, pk, pv, li, table, lengths, active), None
 
             return jax.lax.scan(
                 body, jnp.zeros((_B, _NH * _HD), jnp.float32), None,

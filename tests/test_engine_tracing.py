@@ -204,6 +204,44 @@ def preempted(tiny):
     return eng.counters(), outs
 
 
+@pytest.fixture(scope="module")
+def dispatched(tiny):
+    """One row per decode dispatch of a ragged batch: how far the two page
+    counters moved, the decoding slots' block counts, the table's width."""
+    from ray_tpu.llm.paged import _bucket_pow2
+
+    eng = _engine(tiny)
+    rows, inner = [], eng._dispatch_decode_locked
+
+    def spy(active, chunk):
+        blocks = [len(eng._slot_req[s].blocks) for s in active]
+        before = eng.counters()
+        out = inner(active, chunk)
+        after = eng.counters()
+        rows.append({k: after[k] - before[k]
+                     for k in ("decode_table_pages", "decode_live_pages")}
+                    | {"blocks": blocks, "w": _bucket_pow2(max(blocks))})
+        return out
+
+    eng._dispatch_decode_locked = spy
+    prompts = [p[:n] for p, n in zip(_prompts(3, 40), (9, 22, 40))]
+    eng.generate(prompts, GenerationConfig(max_new_tokens=30))
+    return eng, rows
+
+
+@pytest.mark.parametrize("counter", ["decode_table_pages",
+                                     "decode_live_pages"])
+def test_decode_page_counters_follow_each_dispatch(dispatched, counter):
+    eng, rows = dispatched
+    assert len(rows) > 4 and len({r["w"] for r in rows}) > 1
+    assert any(len(r["blocks"]) < eng.max_batch for r in rows)
+    for r in rows:
+        want = (eng.max_batch * r["w"] if counter == "decode_table_pages"
+                else sum(r["blocks"]))
+        assert r[counter] == want, r
+    assert eng.counters()[counter] == sum(r[counter] for r in rows)
+
+
 def _flat(counters):
     out = {k: v for k, v in counters.items() if not isinstance(v, dict)}
     out.update({f"drains.{k}": v for k, v in counters["drains"].items()})
