@@ -11,9 +11,23 @@ unpacked under a git-ignored directory) in the same call.
 
     python benchmarks/paged_kernel_bench.py [--root DIR] [--widths 64,128,256]
 
+``--prefill`` times the paged prefill chunk program instead
+(``models/llama.py prefill_chunk_paged``: Mistral-7B widths, 16 layers, a pool
+of 6,000 blocks, the engine's fixed table of 264 blocks): ms a call for chunks
+of 64 and 256 tokens at ``p0`` 0, 256, 1792 and 3840, so live prefixes from a
+sixteenth of the table to all of it.  The question is the same: does a call's
+time follow the table's width or the live prefix?  ``--tiles 256,512,1024``
+times the loop at other KV tiles than the code's constant (where the checkout
+under test takes one).  ``--profile`` adds, for the 256-token chunk at ``p0``
+0 and 1792, one profiler capture of a few calls: the program's device time a
+call by scope (``attention`` / ``ffn`` / ``head``, from the compiled
+instructions' ``op_name``) and its largest operations by name.
+
+    python benchmarks/paged_kernel_bench.py --prefill [--root DIR] [--tiles ...]
+
 Needs a TPU: a time from the Pallas interpreter says nothing.  ``--rehearse``
-walks the same control flow on the CPU (interpret mode, a few calls, no time
-printed) and exits 3.
+walks the same control flow on the CPU (interpret mode, a few calls, toy
+sizes for ``--prefill``, no time printed) and exits 3.
 """
 
 from __future__ import annotations
@@ -30,15 +44,143 @@ LAYERS, NB = 4, 4096
 CALLS = 64 * LAYERS  # kernel calls in one timed program
 
 
+def prefill_main(args) -> int:
+    """One row a (tile, chunk, p0): ms a call of the whole chunk program."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from ray_tpu.models import llama
+    from ray_tpu.ops.rope import rope_frequencies
+
+    dev = jax.devices()[0]
+    if dev.platform != "tpu" and not args.rehearse:
+        print("paged_kernel_bench needs a TPU", file=sys.stderr)
+        return 2
+    if args.rehearse:
+        cfg = llama.LlamaConfig.tiny(max_seq_len=4096,
+                                     param_dtype=jnp.bfloat16,
+                                     compute_dtype=jnp.bfloat16)
+        nb, reps = 300, 1
+    else:
+        cfg = llama.LlamaConfig(
+            vocab_size=32768, dim=NH * HD, n_layers=16, n_heads=NH,
+            n_kv_heads=KV, ffn_dim=14336, max_seq_len=4096, rope_theta=1e6,
+            param_dtype=jnp.bfloat16)
+        nb, reps = 6000, 10
+    width = 264
+    takes_tile = "kv_tile" in inspect.signature(
+        llama.prefill_chunk_paged).parameters
+    tiles = [int(t) for t in args.tiles.split(",") if t] if takes_tile else []
+    cos, sin = rope_frequencies(cfg.head_dim, cfg.max_seq_len, cfg.rope_theta)
+    rope = (jnp.asarray(cos), jnp.asarray(sin))
+    # made on the device by one program: eager jax.random calls take minutes
+    params = jax.jit(lambda: llama.init_params(cfg, jax.random.PRNGKey(0)))()
+    kk, kv_ = jax.random.split(jax.random.PRNGKey(1))
+    shape = (cfg.n_layers, nb, BS, cfg.n_kv_heads * cfg.head_dim)
+    pool = {"k": jax.random.normal(kk, shape, jnp.bfloat16),
+            "v": jax.random.normal(kv_, shape, jnp.bfloat16)}
+    rng = np.random.default_rng(0)
+    for tile in [None] + tiles:
+        extra = {} if tile is None else {"kv_tile": tile}
+        fn = jax.jit(
+            lambda p, t, pl, tb, p0, extra=extra: llama.prefill_chunk_paged(
+                cfg, p, t, pl, tb, p0, rope_cache=rope, **extra),
+            donate_argnums=2)
+        for c in (64, 256):
+            tokens = jnp.asarray(rng.integers(1, cfg.vocab_size, (1, c)),
+                                 jnp.int32)
+            for p0 in (0, 256, 1792, 3840):
+                live = (p0 + c) // BS
+                table = np.zeros((1, width), np.int32)
+                table[0, :live] = rng.permutation(np.arange(1, nb))[:live]
+                a = (jnp.asarray(table), jnp.int32(p0))
+                logits, pool = fn(params, tokens, pool, *a)
+                logits.block_until_ready()
+                times = []
+                for _ in range(reps):
+                    t0 = time.perf_counter()
+                    logits, pool = fn(params, tokens, pool, *a)
+                    logits.block_until_ready()
+                    times.append(time.perf_counter() - t0)
+                print(json.dumps({
+                    "tag": args.tag, "mode": "prefill", "kv_tile": tile,
+                    "chunk": c, "p0": p0, "live_pages": live,
+                    "table_pages": width,
+                    "ms_per_call": None if args.rehearse else (
+                        sorted(times)[len(times) // 2] * 1e3),
+                    "ms_min": None if args.rehearse else min(times) * 1e3,
+                    "logits_finite": bool(jnp.isfinite(logits).all()),
+                    "logits_abs_mean": float(jnp.abs(logits).mean()),
+                    "device": dev.device_kind}), flush=True)
+                if args.profile and tile is None and c == 256 and p0 in (
+                        0, 1792):
+                    row, pool = _profile_chunk(
+                        fn, (params, tokens, pool, *a),
+                        calls=1 if args.rehearse else 4)
+                    print(json.dumps({"tag": args.tag, "mode": "prefill_ops",
+                                      "chunk": c, "p0": p0, **row}),
+                          flush=True)
+    return 3 if args.rehearse else 0
+
+
+def _profile_chunk(fn, call_args, calls):
+    """One capture of ``calls`` runs of the chunk program: device ms a call
+    by scope and the twelve largest operations, self time (a ``while`` does
+    not count its body).  An operation's scope is the first of ``attention``,
+    ``ffn``, ``head`` in its compiled instruction's ``op_name``."""
+    import glob
+    import re
+    import tempfile
+
+    import jax
+
+    from chipbench import trace_reduce
+
+    text = fn.lower(*call_args).compile().as_text()
+    scope_of = {}
+    for m in re.finditer(r"%([\w.\-]+) = [^\n]*?op_name=\"([^\"]*)\"", text):
+        hit = re.search(r"/(attention|ffn|head)(/|$)", m.group(2))
+        scope_of[m.group(1)] = hit.group(1) if hit else "other"
+    params, tokens, pool, table, p0 = call_args
+    logdir = tempfile.mkdtemp(prefix="prefill_ops_")
+    with jax.profiler.trace(logdir):
+        for _ in range(calls):
+            logits, pool = fn(params, tokens, pool, table, p0)
+        logits.block_until_ready()
+    files = glob.glob(os.path.join(logdir, "**", "*.xplane.pb"),
+                      recursive=True)
+    plane = trace_reduce.first_device(trace_reduce.load(files[0]))
+    if plane is None:  # the rehearsal: a CPU trace has no device plane
+        return {"ms_per_call": None, "by_scope_ms": {}, "top_ops_ms": []}, pool
+    by_scope, by_name = {}, {}
+    for name, own, _ in trace_reduce.self_times(trace_reduce.op_events(plane)):
+        scope = scope_of.get(name.split(" ")[0], "other")
+        by_scope[scope] = by_scope.get(scope, 0.0) + own
+        key = f"{scope}: {name}"
+        by_name[key] = by_name.get(key, 0.0) + own
+    per_call = 1e3 / calls
+    top = sorted(by_name.items(), key=lambda kv: -kv[1])[:12]
+    return {"ms_per_call": sum(by_scope.values()) * per_call,
+            "by_scope_ms": {k: round(v * per_call, 3)
+                            for k, v in sorted(by_scope.items())},
+            "top_ops_ms": [[k, round(v * per_call, 3)] for k, v in top]}, pool
+
+
 def main() -> int:
     ap = argparse.ArgumentParser()
     ap.add_argument("--root", default=None)
     ap.add_argument("--widths", default="64,128,256")
     ap.add_argument("--tag", default="change")
     ap.add_argument("--rehearse", action="store_true")
+    ap.add_argument("--prefill", action="store_true")
+    ap.add_argument("--tiles", default="")
+    ap.add_argument("--profile", action="store_true")
     args = ap.parse_args()
     sys.path.insert(0, args.root or os.path.dirname(
         os.path.dirname(os.path.abspath(__file__))))
+    if args.prefill:
+        return prefill_main(args)
 
     import jax
     import jax.numpy as jnp

@@ -15,7 +15,8 @@ TPU-native rebuild provides the equivalent itself:
   - long prompts prefill in `prefill_chunk`-token pieces interleaved with
     decode chunks, so one long prompt never stalls the running batch
     (`models/llama.py prefill_chunk_paged` reads earlier chunks back from
-    the pool — no growing inter-chunk state)
+    the pool, a KV tile at a time and only the tiles that hold the live
+    prefix — no growing inter-chunk state, no cost for the table's width)
   - full prompt blocks are chain-hashed and shared across requests
     (refcounted; matches capped at plen-1 so sampling always has a logit)
   - pool exhaustion preempts the youngest running request by RECOMPUTE:
@@ -312,6 +313,7 @@ class _PagedReq(_Request):
 # integer engine counters (cumulative; see PagedJaxLLMEngine.counters)
 _COUNTERS = ("steps", "prefill_chunks", "prefill_tokens",
              "prefill_padded_tokens", "prefix_hit_tokens",
+             "prefill_live_pages", "prefill_visited_pages",
              "decode_dispatches", "decode_dispatches_pipelined",
              "decode_token_steps", "decode_table_pages", "decode_live_pages",
              "tokens_emitted", "preemptions", "kv_demotions")
@@ -460,7 +462,9 @@ class PagedJaxLLMEngine:
         # widths no warmup predicted — measured as multi-second XLA
         # compiles inside the serving window.  One width = at most
         # log2(prefill_chunk/bs) prefill programs, all warmed at init.
-        # The masked overhang costs ~16% chunk compute at max_seq 1024.
+        # The width costs nothing past the live prefix: the chunk's
+        # attention loops over the table's first cdiv(p0 + C, tile) KV
+        # tiles (llama._prefill_attend_tiles) and never reads the rest.
         # Width = the simulated worst case over every prompt length and
         # chunk start (see _prefill_table_width) — pow2 chunk bucketing
         # can cover past max_blocks_per_seq + 2.
@@ -844,7 +848,11 @@ class PagedJaxLLMEngine:
         run through the model, recompute after a preemption included),
         ``prefill_padded_tokens`` (bucket padding run but not asked
         for), ``prefix_hit_tokens`` (prompt tokens admission found in the
-        cache); ``decode_dispatches``, ``decode_dispatches_pipelined``
+        cache), ``prefill_live_pages`` / ``prefill_visited_pages`` (per
+        chunk dispatch: the blocks that hold the prompt through this chunk,
+        and the blocks of the whole KV tiles the chunk's attention loop
+        visits: what the tile's rounding and the chunk's padding add);
+        ``decode_dispatches``, ``decode_dispatches_pipelined``
         (the previous chunk was still in flight at the dispatch, so the
         device never waited for the host), ``decode_token_steps``;
         ``decode_table_pages`` / ``decode_live_pages`` (per dispatch: the
@@ -1482,6 +1490,11 @@ class PagedJaxLLMEngine:
                 self._c["prefill_chunks"] += 1
                 self._c["prefill_tokens"] += take
                 self._c["prefill_padded_tokens"] += c - take
+                self._c["prefill_live_pages"] += math.ceil(
+                    (p0 + take) / self.bs)
+                tile = llama.PREFILL_KV_TILE
+                self._c["prefill_visited_pages"] += (
+                    math.ceil((p0 + c) / tile) * tile // self.bs)
                 if self._tp_collectives is not None:
                     self._book_tp_collectives(
                         "prefill",

@@ -629,6 +629,62 @@ def _paged_attend(cfg: LlamaConfig, q, ck, cv, span_mask):
     return attn.reshape(b, t, cfg.n_heads * cfg.head_dim)
 
 
+# KV positions one iteration of the prefill chunk's attention loop attends
+# (whole pages of the table).  Chosen on the chip among 256, 512 and 1024;
+# PERF.md section 6 (PR 28) has the readings.
+PREFILL_KV_TILE = 512
+
+
+def _prefill_attend_tiles(cfg: LlamaConfig, q, pk_all, pv_all, li, row,
+                          positions, tile: int):
+    """Causal GQA attention of one chunk's queries q [C, nh, hd], at global
+    ``positions`` [C] (rising), over the sequence's KV in the pool.
+
+    ``row`` [n * tile/bs] is the sequence's block table, whole tiles wide.
+    A device loop with the DYNAMIC trip count cdiv(positions[-1] + 1, tile)
+    gathers one tile's pages of layer ``li`` an iteration and folds it into
+    an online softmax (float32 scores from bf16 operands, float32 running
+    max, sum and accumulator): work and HBM traffic follow the live prefix,
+    and table entries in tiles past it are never read.  Inside a visited
+    tile, positions past a query's own are masked to exactly zero weight;
+    position 0 is visible to every query, so after the first tile every
+    running max is a real score.  Returns [C, nh * hd] float32.
+    """
+    c = q.shape[0]
+    bs = pk_all.shape[2]
+    kv, hd = cfg.n_kv_heads, cfg.head_dim
+    group = cfg.n_heads // kv
+    pages = tile // bs
+    qg = q.reshape(c, kv, group, hd)
+    scale = 1.0 / math.sqrt(hd)
+    offs = jnp.arange(tile)
+
+    def fold(i, state):
+        m, l, acc = state
+        blocks = lax.dynamic_slice(row, (i * pages,), (pages,))
+        ck = pk_all[li, blocks].reshape(tile, kv, hd)
+        cv = pv_all[li, blocks].reshape(tile, kv, hd)
+        s = jnp.einsum("ckgd,skd->kgcs", qg, ck,
+                       preferred_element_type=jnp.float32) * scale
+        visible = (i * tile + offs)[None, :] <= positions[:, None]  # [C, T]
+        s = jnp.where(visible, s, -1e30)
+        m_new = jnp.maximum(m, s.max(axis=-1))
+        alpha = jnp.exp(m - m_new)
+        p = jnp.exp(s - m_new[..., None])
+        l = alpha * l + p.sum(axis=-1)
+        acc = alpha[..., None] * acc + jnp.einsum(
+            "kgcs,skd->kgcd", p.astype(cv.dtype), cv,
+            preferred_element_type=jnp.float32)
+        return m_new, l, acc
+
+    state = (jnp.full((kv, group, c), -1e30, jnp.float32),
+             jnp.zeros((kv, group, c), jnp.float32),
+             jnp.zeros((kv, group, c, hd), jnp.float32))
+    _, l, acc = lax.fori_loop(0, positions[-1] // tile + 1, fold, state)
+    attn = acc / l[..., None]
+    return attn.transpose(2, 0, 1, 3).reshape(c, cfg.n_heads * hd)
+
+
 def paged_kernel_supported(cfg: LlamaConfig) -> bool:
     """Whether the fused pallas paged-attention kernel applies: TPU backend
     and lane-aligned head_dim.  On a TPU backend a kernel that cannot be
@@ -859,16 +915,21 @@ def prefill_chunk_paged(cfg: LlamaConfig, params: Params, tokens: jnp.ndarray,
                         pool: Dict[str, jnp.ndarray], table: jnp.ndarray,
                         p0: jnp.ndarray,
                         rope_cache: Optional[tuple] = None,
-                        tp_plan: Optional[TPPlan] = None):
+                        tp_plan: Optional[TPPlan] = None,
+                        kv_tile: int = PREFILL_KV_TILE):
     """Prefill ONE chunk of a single sequence into its pool blocks.
 
     tokens [1, C] (C a multiple of block_size; tail garbage-padded — padded
     positions write blocks the sequence owns and are masked by length
     thereafter); p0 = global position of tokens[0, 0] (multiple of
-    block_size); table [1, W] covers positions [0, p0 + C).  Attention is
-    causal over the whole prefix: earlier chunks' KV is read back from the
-    pool, so chunked prefill needs no growing-activation state between
-    chunks (chunk compute is O(C * (p0 + C))).
+    block_size); table [1, W] covers positions [0, p0 + C), W whatever
+    fixed width the caller compiles for (padded here to whole tiles).
+    Attention is causal over the whole prefix: earlier chunks' KV is read
+    back from the pool, so chunked prefill needs no growing-activation state
+    between chunks.  Chunk compute is O(C * (p0 + C)), not O(C * W):
+    ``_prefill_attend_tiles`` visits the table's first cdiv(p0 + C, kv_tile)
+    tiles and no others.  ``kv_tile`` is for tests (several tiles at a tiny
+    ``max_seq_len``); every caller in the tree leaves the default.
     Returns (logits [1, C, V] fp32, updated pool).
     """
     if rope_cache is None:
@@ -878,13 +939,16 @@ def prefill_chunk_paged(cfg: LlamaConfig, params: Params, tokens: jnp.ndarray,
         cos, sin = rope_cache
     b, c = tokens.shape
     bs = pool["k"].shape[2]
-    w = table.shape[1]
     cdt = cfg.compute_dtype
+    if kv_tile % bs:
+        raise ValueError(f"kv_tile ({kv_tile}) must be a multiple of the "
+                         f"block size ({bs})")
     positions = p0 + jnp.arange(c)  # [C] global positions
     # the C/bs physical blocks this chunk writes
     chunk_blocks = lax.dynamic_slice(table[0], (p0 // bs,), (c // bs,))
-    span_mask = (jnp.arange(w * bs)[None, None, :]
-                 <= positions[None, :, None])  # [1, C, W*bs] causal
+    # whole tiles: a tile's slice of the row never clamps.  The padding
+    # lies past p0 + C, in tiles the loop never visits
+    row = jnp.pad(table[0], (0, -table.shape[1] % (kv_tile // bs)))
     x = jnp.take(params["embed"], tokens, axis=0).astype(cdt)
     overlap = tp_plan is not None and tp_plan.overlap
 
@@ -907,9 +971,10 @@ def prefill_chunk_paged(cfg: LlamaConfig, params: Params, tokens: jnp.ndarray,
                 k[0].reshape(c // bs, bs, -1).astype(pk_all.dtype))
             pv_all = pv_all.at[li, chunk_blocks].set(
                 v[0].reshape(c // bs, bs, -1).astype(pv_all.dtype))
-            ck = pk_all[li, table].reshape(b, w * bs, cfg.n_kv_heads, cfg.head_dim)
-            cv = pv_all[li, table].reshape(b, w * bs, cfg.n_kv_heads, cfg.head_dim)
-            attn = _paged_attend(cfg, q, ck, cv, span_mask)
+            # the chunk's own KV is in the pool now: its tiles are read
+            # back like any other
+            attn = _prefill_attend_tiles(cfg, q[0], pk_all, pv_all, li, row,
+                                         positions, kv_tile)[None]
             out, tok = _tp_out_proj(attn.astype(cdt), lp["wo"].astype(cdt),
                                     tp_plan, tok)
             x = x + out

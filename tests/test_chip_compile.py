@@ -136,6 +136,67 @@ def test_paged_decode_attention_compiles_at_cell_shapes_under_4_shard_map(
     _assert_kernel(kern, *_paged_args(16, 128, *shardings, b=64))
 
 
+# -- the paged prefill chunk at the serving cell's shapes ---------------------
+# Mistral-7B widths at 16 layers, 6,000 blocks of 16 tokens, the engine's
+# fixed table of 264 blocks: the loop over KV tiles has a trip count the
+# device reads (p0 is an operand) and gathers pages of a pool that rides the
+# layer scan's carry.  A pool the compiler would rather copy than alias shows
+# as 6 GB of temporaries, which the chip has no room for.
+
+
+def _prefill_chunk_program(c, params_sh, pool_sh, rep_sh, tp_plan=None):
+    from ray_tpu.models import llama
+
+    cfg = llama.LlamaConfig(
+        vocab_size=32768, dim=4096, n_layers=16, n_heads=32, n_kv_heads=8,
+        ffn_dim=14336, max_seq_len=4096, rope_theta=1e6,
+        param_dtype=BF16)
+    shapes = jax.eval_shape(
+        lambda: llama.init_params(cfg, jax.random.PRNGKey(0)))
+    if callable(params_sh):
+        params = params_sh(cfg, shapes)
+    else:
+        params = jax.tree.map(lambda x: _spec(x.shape, x.dtype, params_sh),
+                              shapes)
+    pool = {n: _spec((16, 6000, 16, 8 * 128), BF16, pool_sh)
+            for n in ("k", "v")}
+    fn = jax.jit(
+        lambda p, t, pl, tb, p0: llama.prefill_chunk_paged(
+            cfg, p, t, pl, tb, p0, tp_plan=tp_plan), donate_argnums=2)
+    return fn.lower(params, _spec((1, c), jnp.int32, rep_sh), pool,
+                    _spec((1, 264), jnp.int32, rep_sh),
+                    _spec((), jnp.int32, rep_sh)).compile()
+
+
+@pytest.mark.parametrize("c", [64, 256])
+def test_paged_prefill_chunk_compiles_at_cell_shapes(one_chip, c):
+    compiled = _prefill_chunk_program(c, one_chip, one_chip, one_chip)
+    assert "while" in compiled.as_text()
+    assert compiled.memory_analysis().temp_size_in_bytes < 1 << 30
+
+
+@pytest.mark.parametrize("overlap", [True, False])
+def test_paged_prefill_chunk_compiles_for_4_shards(tensor_mesh, overlap):
+    """The tensor-parallel engine's layout: KV heads of the pool and the
+    projections over "tensor", the planned collectives explicit."""
+    from ray_tpu.models import llama
+
+    ns = functools.partial(NamedSharding, tensor_mesh)
+
+    def sharded(cfg, shapes):
+        return jax.tree.map(lambda x, s: _spec(x.shape, x.dtype, ns(s)),
+                            shapes, llama.inference_param_specs(cfg))
+
+    plan = llama.TPPlan(mesh=tensor_mesh, axis="tensor", algorithm="flat",
+                        overlap=overlap)
+    compiled = _prefill_chunk_program(
+        256, sharded, ns(P(None, None, None, "tensor")), ns(P()),
+        tp_plan=plan)
+    assert "while" in compiled.as_text()
+    # a shard holds a quarter of the pool and copies none of it
+    assert compiled.memory_analysis().temp_size_in_bytes < 1 << 30
+
+
 # -- flash attention: the 1.14 B train shape and the 8 B widths ----------------
 
 _FLASH = {"train_1b": (8, 2048, 16, 8), "llama3_8b": (1, 2048, 32, 8)}

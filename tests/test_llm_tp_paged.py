@@ -88,6 +88,35 @@ def test_overlap_off_bit_equal(setup):
         PROMPTS, GEN) == ref
 
 
+@pytest.fixture(scope="module")
+def tiles_ref(setup):
+    """One device, a table of three KV tiles, a prompt that crosses one."""
+    cfg, params, *_ = setup
+    assert 3 * llama.PREFILL_KV_TILE == 1536
+    prompts = [PROMPTS[0],
+               list(np.random.RandomState(28).randint(1, 255, size=600))]
+    kw = dict(max_seq_len=1536, block_size=16, prefill_chunk=256,
+              num_blocks=128)
+    gen = GenerationConfig(max_new_tokens=6)
+    return kw, prompts, gen, _mk(cfg, params, 1, **kw).generate(prompts, gen)
+
+
+@pytest.mark.parametrize("overlap", [True, False])
+def test_tp4_prefill_tile_loop_bit_identical(setup, tiles_ref, overlap):
+    """The prefill chunk's loop over KV tiles (dynamic trip count, online
+    softmax state carried between tiles) compiles for four shards with the
+    KV heads split over them, with the planned collectives chained through
+    the token and without, and emits the single-device tokens."""
+    cfg, params, *_ = setup
+    kw, prompts, gen, ref = tiles_ref
+    eng = _mk(cfg, params, 4, tp_overlap_collectives=overlap, **kw)
+    assert eng.generate(prompts, gen) == ref
+    c = eng.counters()
+    # 5 tokens: one tile; 600: chunks ending at 256, 512 and 640 (two tiles)
+    assert c["prefill_visited_pages"] == 32 * (1 + 1 + 1 + 2)
+    assert c["prefill_live_pages"] == 1 + 16 + 32 + 38
+
+
 def test_forced_ring_bit_equal(setup):
     """The tp_collective_algorithm force knob routes the ring program
     (psum_scatter + all_gather) — bitwise-equal to flat psum, so forcing
