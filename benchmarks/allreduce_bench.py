@@ -64,7 +64,7 @@ def bench_mesh(sizes_mb, dtype_name="bfloat16", iters=20):
         size = count * dtype.itemsize
         busbw = (2 * (n - 1) / max(n, 1)) * size / dt if n > 1 else size / dt
         # book the measured op into the built-in collective metrics so
-        # bench.py's JSON line (and any scrape) picks the numbers up for free
+        # collective_snapshot() (and any scrape) picks the numbers up for free
         runtime_metrics.record_collective(
             "allreduce", "xla_mesh", n, size, dt, dtype_name)
         results.append({

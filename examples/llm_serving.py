@@ -16,7 +16,6 @@ def main():
     cfg = LLMConfig(
         model_config=LlamaConfig.tiny(compute_dtype=jnp.float32),
         max_batch_size=4, max_seq_len=128,
-        kv_cache="paged",       # the default; "static" = per-slot cache
         block_size=8, prefill_chunk=16, enable_prefix_caching=True)
     engine = make_engine(cfg)
 

@@ -371,7 +371,7 @@ def all_rules() -> List[Rule]:
 def run_analysis(root: str, paths: Optional[Sequence[str]] = None,
                  rules: Optional[Sequence[Rule]] = None,
                  partial: bool = False) -> Tuple[List[Finding], "Engine"]:
-    """THE entry-point recipe (lint CLI, bench.py and the gate all route
+    """THE entry-point recipe (lint CLI and the gate both route
     here so they can never drift apart): ``root`` anchors repo-relative
     paths; ``paths`` defaults to ``<root>/ray_tpu``.  Returns (findings,
     engine) — the engine carries ``files_seen`` for reporting."""

@@ -256,7 +256,7 @@ flagged:
                     if isinstance(n, ast.Call):
                         self.visit_Call(n, ctx)
         # helper-liveness: which declared vars have a registry recorder
-        # function that runtime code actually calls.  Callers in bench.py /
+        # function that runtime code actually calls.  Callers in
         # benchmarks/ count (they are runtime consumers outside the linted
         # tree); callers only in tests/ do not — a family recorded solely
         # by its own test is still dead on every real code path.  Needs
@@ -264,10 +264,6 @@ flagged:
         # so the never-recorded verdict is skipped there.
         check_liveness = not engine.partial
         called = set(self._called)
-        for extra in ("bench.py",):
-            path = os.path.join(engine.root, extra)
-            if os.path.exists(path):
-                called.update(_call_names(path))
         bench_dir = os.path.join(engine.root, "benchmarks")
         if os.path.isdir(bench_dir):
             for fn in os.listdir(bench_dir):

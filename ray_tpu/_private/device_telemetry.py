@@ -65,8 +65,8 @@ from ray_tpu._private.analysis.lock_witness import make_lock
 UTIL_KV_PREFIX = "util:"
 
 # Published peak bf16 FLOP/s of ONE chip, keyed by ``device.device_kind``
-# exactly as JAX reports it.  The one table: bench.py and the MFU gauges
-# both read it.  Source: Google Cloud TPU documentation, system
+# exactly as JAX reports it.  The one table the MFU gauges read.
+# Source: Google Cloud TPU documentation, system
 # architecture pages "TPU v4", "TPU v5e", "TPU v5p", "TPU v6e".  A device
 # that is not here is an error, never a default: a utilization over a
 # guessed peak is not a measurement.

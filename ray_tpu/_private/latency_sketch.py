@@ -277,7 +277,7 @@ def point_quantiles(point: dict, qs: Sequence[float]) -> List[float]:
 
 def summary(sketch_or_point, qs: Sequence[float] = (0.5, 0.95, 0.99)) -> dict:
     """{"p50": .., "p95": .., "p99": .., "count": .., "mean": ..} — the
-    shape bench.py and state.serving_slo() embed."""
+    shape state.serving_slo() embeds."""
     s = (sketch_or_point if isinstance(sketch_or_point, LatencySketch)
          else LatencySketch.from_point(sketch_or_point))
     out = {}

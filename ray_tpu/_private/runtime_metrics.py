@@ -795,8 +795,7 @@ def set_goodput_ratio(run: str, ratio: float) -> None:
 
 
 def goodput_metrics_snapshot() -> dict:
-    """This process's goodput gauge points for bench.py's JSON line:
-    per run, seconds by bucket + the derived goodput ratio (the gauges
+    """This process's goodput gauge points: per run, seconds by bucket + the derived goodput ratio (the gauges
     mirror each ledger's buckets, so these sum to wall-clock exactly)."""
     out: dict = {}
     for p in TRAIN_GOODPUT_SECONDS._snapshot():
@@ -833,8 +832,7 @@ def set_snapshot_inflight(n: int) -> None:
 
 
 def snapshot_metrics_snapshot() -> dict:
-    """Process-local checkpoint-subsystem counters for bench.py's
-    ``checkpoint`` block: bytes by kind + total training-thread stall."""
+    """Process-local checkpoint-subsystem counters: bytes by kind + total training-thread stall."""
     out: dict = {"bytes_total": {}}
     for p in TRAIN_SNAPSHOT_BYTES._snapshot():
         k = p["tags"].get("kind", "?")
@@ -874,7 +872,7 @@ def sync_snapshot() -> dict:
     """Process-local cluster-view sync accounting: bytes shipped by reply
     kind, relay-publish sends by role, and the current view version.
     Hermetic (this process's counters only) — the perf-smoke delta-budget
-    gate and bench.py's control_plane section both read it."""
+    gate reads it."""
     out = {"full_bytes": 0.0, "delta_bytes": 0.0, "relay_publishes": {},
            "version": 0.0}
     for tags_key, v in dict(GCS_SYNC_BYTES._points).items():
@@ -952,8 +950,7 @@ def inc_lease_revoked() -> None:
 def lease_snapshot() -> dict:
     """Process-local lease fast-path accounting: requests issued, reuse
     hit/new assignment counts and the derived hit rate.  Hermetic (reads
-    this process's counters only) — the perf-smoke budget test and
-    bench.py's core_perf block both read it."""
+    this process's counters only) — the perf-smoke budget test reads it."""
     requests = sum(dict(LEASE_REQUESTS._points).values())
     hit = hits = 0.0
     for tags_key, v in dict(LEASE_REUSE._points).items():
@@ -1038,7 +1035,7 @@ def inc_collective_plan(algorithm: str, reason: str) -> None:
 
 
 def plan_snapshot() -> dict:
-    """Planner-decision counts for bench.py / the multichip dryrun:
+    """Planner-decision counts for the multichip dryrun:
     "algorithm/reason" -> count."""
     out: Dict[str, float] = {}
     for p in COLLECTIVE_PLAN._snapshot():
@@ -1157,7 +1154,7 @@ def route_decision_snapshot() -> dict:
 
 
 def serving_sketch_snapshot() -> dict:
-    """Process-local serving latency sketches for bench.py and the perf
+    """Process-local serving latency sketches for the perf
     tests: per deployment, TTFT/ITL percentiles overall and split by
     tenant, plus per-stage percentiles.  Hermetic — this process's
     sketches only (cluster-wide folds go through state.serving_slo())."""
@@ -1191,7 +1188,7 @@ def serving_sketch_snapshot() -> dict:
 
 
 def prefix_cache_snapshot() -> dict:
-    """Process-local tiered prefix-cache accounting for bench.py and the
+    """Process-local tiered prefix-cache accounting for the
     perf tests: per-tier hit/miss/eviction block counts plus the derived
     overall hit rate.  Hermetic — reads this process's counters only."""
     out: dict = {"hits": {}, "misses": 0.0, "evictions": {}}
@@ -1234,7 +1231,7 @@ def kv_handoff_snapshot() -> dict:
 
 
 def kv_migration_snapshot() -> dict:
-    """Process-local live-migration accounting for bench.py and the perf
+    """Process-local live-migration accounting for the perf
     tests: outcome counts per reason plus per-phase latency count / sum /
     mean.  Hermetic — this process's counters only."""
     out: dict = {"outcomes": {}, "phases": {}}
@@ -1279,7 +1276,7 @@ def observe_tp_collective(deployment: str, algorithm: str, *,
 
 
 def tp_collective_snapshot() -> dict:
-    """Process-local TP serving-collective accounting for bench.py and
+    """Process-local TP serving-collective accounting for
     the tier-1 pins: {deployment: {algorithm: {bytes, seconds}}}."""
     out: dict = {}
     for tags_key, v in dict(SERVE_TP_COLLECTIVE_BYTES._points).items():
@@ -1296,7 +1293,7 @@ def tp_collective_snapshot() -> dict:
 
 
 def specdec_snapshot() -> dict:
-    """Process-local speculative-decoding accounting for bench.py and the
+    """Process-local speculative-decoding accounting for the
     perf tests: per-deployment proposed/accepted token counts plus the
     derived acceptance rate.  Hermetic — this process's counters only."""
     out: dict = {}
@@ -1365,7 +1362,7 @@ def observe_rl_policy_lag(lag: float) -> None:
 
 
 def rl_snapshot() -> dict:
-    """Process-local RL execution-path accounting for bench.py and the
+    """Process-local RL execution-path accounting for the
     perf gates: env steps per path, the Sebulba sample queue's last
     depth, and the policy-lag distribution (count / sum / mean).
     Hermetic — this process's counters only."""
@@ -1423,7 +1420,7 @@ def set_serve_tokens_per_chip(deployment: str, tok_per_s: float) -> None:
 
 
 def device_telemetry_snapshot() -> dict:
-    """Process-local device-telemetry accounting for bench.py and the perf
+    """Process-local device-telemetry accounting for the perf
     gates: per-device HBM gauges, per-deployment engine HBM split and
     utilization gauges, jit-compile counts/seconds per program, and the
     MFU / tok-per-chip gauges.  Hermetic — this process's points only."""
@@ -1457,7 +1454,7 @@ def device_telemetry_snapshot() -> dict:
 
 
 def ingest_snapshot() -> dict:
-    """Process-local data-plane accounting for bench.py and the perf
+    """Process-local data-plane accounting for the perf
     gates: ingest rows, view vs copied bytes per source, buffer-empty
     wait seconds, and backpressure event counts.  Hermetic — this
     process's counters only."""
@@ -1481,8 +1478,8 @@ def ingest_snapshot() -> dict:
 
 
 def collective_snapshot() -> dict:
-    """Summarize this process's collective metric points for bench.py's JSON
-    line.  Keys carry the FULL tag-set (op/backend/world_size/dtype) so two
+    """Summarize this process's collective metric points
+    (benchmarks/allreduce_bench.py).  Keys carry the FULL tag-set (op/backend/world_size/dtype) so two
     series (e.g. float32 grads and bfloat16 params) never blend into one
     internally-inconsistent entry: per key, total bytes, op count, mean
     latency, and the last derived bus bandwidth."""
@@ -1511,7 +1508,7 @@ def collective_snapshot() -> dict:
 
 def compression_snapshot() -> dict:
     """Summarize this process's compressed-collective metric points for
-    bench.py's JSON line and the multichip dryrun: per
+    the multichip dryrun: per
     op/backend/ws/algorithm/scheme/group key, logical vs wire byte totals,
     the savings ratio, and the last quant error."""
     def _key(tags: Dict[str, str]) -> str:

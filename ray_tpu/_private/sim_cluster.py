@@ -25,8 +25,8 @@ one process:
 Metering rides the production metric families
 (``ray_tpu_gcs_sync_bytes_total{kind}``,
 ``ray_tpu_pubsub_relay_publishes_total{role}``,
-``ray_tpu_gcs_sync_version``) — the same counters the perf-smoke gate and
-bench.py's ``control_plane`` section read.
+``ray_tpu_gcs_sync_version``) — the same counters the perf-smoke gate
+reads.
 """
 
 from __future__ import annotations

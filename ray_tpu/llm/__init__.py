@@ -3,9 +3,8 @@
 reference: python/ray/llm/ (~20.8k LoC) — batch Processor/stages and
 LLMServer deployments on vLLM.  Here the engine is framework-native:
 KV-cache decode with continuous batching, jitted prefill/decode, mesh-based
-parallelism degrees.  Two cache layouts behind ``make_engine``:
-PagedJaxLLMEngine (block-pool KV, chunked prefill, prefix caching — the
-default) and JaxLLMEngine (static per-slot cache).
+parallelism degrees.  One engine, built by ``make_engine``:
+PagedJaxLLMEngine (block-pool KV, chunked prefill, prefix caching).
 """
 
 from ray_tpu.llm.batch import Processor, ProcessorConfig, build_llm_processor
@@ -16,7 +15,7 @@ from ray_tpu.llm.disagg import (
     PrefillServer,
     build_disagg_llm_deployment,
 )
-from ray_tpu.llm.engine import JaxLLMEngine, make_engine
+from ray_tpu.llm.engine import make_engine
 from ray_tpu.llm.paged import BlockAllocator, BlockManager, PagedJaxLLMEngine
 from ray_tpu.llm.lora import LoRAConfig, LoRAManager, init_lora, merge_lora
 from ray_tpu.llm.openai_api import ByteTokenizer, OpenAICompatServer, build_openai_app
@@ -30,7 +29,6 @@ __all__ = [
     "PrefillServer",
     "build_disagg_llm_deployment",
     "GenerationConfig",
-    "JaxLLMEngine",
     "LLMConfig",
     "PagedJaxLLMEngine",
     "make_engine",

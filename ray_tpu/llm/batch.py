@@ -26,7 +26,7 @@ class ProcessorConfig:
 
 
 class _EngineStage:
-    """Actor-pool stage: owns one JaxLLMEngine, maps prompt batches."""
+    """Actor-pool stage: owns one engine (``make_engine``), maps prompt batches."""
 
     def __init__(self, llm_config: LLMConfig, max_new_tokens: int,
                  temperature: float):

@@ -23,7 +23,7 @@ class GenerationConfig:
 
 @dataclasses.dataclass
 class SpeculativeConfig:
-    """Draft-model speculative decoding (paged engine only).
+    """Draft-model speculative decoding.
 
     A small draft model proposes ``num_speculative_tokens`` tokens per
     slot per step; the target model batch-verifies all of them in ONE
@@ -72,12 +72,12 @@ class LLMConfig:
     # --- KV cache layout (reference capability boundary: paged attention /
     # chunked prefill / prefix caching come from vLLM engine_kwargs,
     # vllm_models.py:177-186; here the engine provides them natively) ---
-    # "paged": block-pool cache, HBM ∝ actual request lengths, memory-based
-    # admission, chunked prefill, prefix caching. "static": per-slot
-    # max_seq_len cache (lowest bookkeeping overhead for tiny batches).
-    kv_cache: str = "paged"
+    # a pool of `num_blocks` blocks of `block_size` positions: HBM ∝ actual
+    # request lengths, memory-based admission, chunked prefill, prefix
+    # caching.
     block_size: int = 16
-    # pool size in blocks; None → half the HBM the static cache would use
+    # pool size in blocks; None → max_batch_size x max_seq_len / 2
+    # positions (every slot at half its full length)
     num_blocks: Optional[int] = None
     # prompt tokens prefilled per step (multiple of block_size); long
     # prompts interleave with decode instead of stalling it
@@ -90,15 +90,12 @@ class LLMConfig:
     # burst-arrival serving: a 32-client burst otherwise ramps one chunk
     # per step, serializing admission.
     prefill_token_budget: Optional[int] = None
-    # deprecated alias for prefill_token_budget (pre-ISSUE-11 name); the
-    # new knob wins when both are set
-    prefill_budget_tokens: Optional[int] = None
-    # draft-model speculative decoding (paged engine only; see
-    # SpeculativeConfig). None disables — the disabled path is untouched:
-    # no draft pool, no extra device programs, no metrics booked.
+    # draft-model speculative decoding (see SpeculativeConfig). None
+    # disables — the disabled path is untouched: no draft pool, no extra
+    # device programs, no metrics booked.
     speculative_config: Optional[SpeculativeConfig] = None
     enable_prefix_caching: bool = True
-    # --- tiered prefix cache (paged engine) ---
+    # --- tiered prefix cache ---
     # host-RAM tier under the HBM chain-hash pool: full prompt blocks
     # evicted from HBM under pressure demote here (one small device
     # readback per eviction) and revive without recompute on a later
@@ -130,7 +127,7 @@ class LLMConfig:
     # (e.g. a placement-group slice).  Must carry a "tensor" axis of size
     # tensor_parallel_size (and "pipeline" of pipeline_parallel_size).
     mesh: Optional[Any] = None
-    # --- tensor-parallel collective routing (paged engine, tp > 1) ---
+    # --- tensor-parallel collective routing (tp > 1) ---
     # route the per-layer decode allreduces through the α-β collective
     # planner as EXPLICIT shard_map programs (flat psum / ring / tree
     # chosen per message size and link class, decision metered into

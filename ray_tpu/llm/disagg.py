@@ -59,8 +59,6 @@ class PrefillServer:
 
         from ray_tpu.llm.engine import make_engine
 
-        if llm_config.kv_cache != "paged":
-            raise ValueError("disaggregated serving requires kv_cache='paged'")
         if llm_config.speculative_config is not None:
             # prefill never decodes: a draft pool here would burn HBM and
             # every prompt would pay a pointless draft prefill.  The
@@ -70,7 +68,7 @@ class PrefillServer:
                                              speculative_config=None)
         self._config = llm_config
         self._engine = make_engine(llm_config, params)
-        if hasattr(self._engine, "warmup") and _jax_backend() == "tpu":
+        if _jax_backend() == "tpu":
             self._engine.warmup()
         self._inflight = 0
         self._lock = threading.Lock()
@@ -78,10 +76,7 @@ class PrefillServer:
     def set_slo_label(self, name: str) -> None:
         """Serving SLO threading (serve/_private/replica.py): engine-side
         lifecycle stages book under the prefill deployment's name."""
-        try:
-            self._engine.slo_label = name
-        except Exception:  # noqa: BLE001 — engine variants without SLO threading are legal
-            pass
+        self._engine.slo_label = name
 
     def prefix_digest(self) -> Dict[str, Any]:
         digest = self._engine.prefix_digest()
@@ -89,10 +84,9 @@ class PrefillServer:
         digest["qlen"] = self._inflight
         return digest
 
-    def utilization(self) -> Optional[Dict[str, Any]]:
+    def utilization(self) -> Dict[str, Any]:
         """Device-telemetry row (replica publish / state.utilization())."""
-        util = getattr(self._engine, "utilization", None)
-        return util() if util is not None else None
+        return self._engine.utilization()
 
     def queue_depth(self) -> int:
         return self._inflight

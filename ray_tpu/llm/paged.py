@@ -11,7 +11,7 @@ TPU-native rebuild provides the equivalent itself:
     memory-based (free blocks), not slot-count
   - the device sees a padded block TABLE [B, W] per decode chunk, W bucketed
     to the max blocks any active slot uses: short batches read a SMALLER
-    attention span than the static engine ever could
+    attention span than max_seq
   - long prompts prefill in `prefill_chunk`-token pieces interleaved with
     decode chunks, so one long prompt never stalls the running batch
     (`models/llama.py prefill_chunk_paged` reads earlier chunks back from
@@ -49,6 +49,9 @@ from ray_tpu.llm.engine import (
     _Request,
     _sample,
     _sample_dist,
+    build_engine_mesh,
+    pp_cache_spec,
+    pp_param_specs,
 )
 from ray_tpu._private.prefix_hash import chain_hash, prefix_chain_hashes
 from ray_tpu.models import llama
@@ -421,7 +424,10 @@ def _prefill_table_width(max_seq: int, chunk: int, bs: int) -> int:
 
 
 class PagedJaxLLMEngine:
-    """Drop-in engine with the static engine's API over a paged KV pool.
+    """The serving engine: continuous batching over a paged KV pool.
+
+    API: ``add_request() -> id``, ``step() -> {id: [new tokens]}``,
+    ``generate()`` (sync convenience driving step() to completion).
 
     With ``config.speculative_config`` set, decode runs draft-model
     speculative: a small draft proposes k tokens per slot per step and
@@ -452,8 +458,8 @@ class PagedJaxLLMEngine:
                 f"of block_size ({self.bs})")
         nb = config.num_blocks
         if nb is None:
-            # default pool: half the HBM the static cache would have used —
-            # the demonstrable economics win; override via config.num_blocks
+            # default pool: max_batch x max_seq / 2 positions (half of
+            # every slot at full length); override via config.num_blocks
             nb = max(4, (self.max_batch * self.max_seq) // (2 * self.bs))
         self.num_blocks = nb
         self.max_blocks_per_seq = math.ceil(self.max_seq / self.bs)
@@ -484,12 +490,6 @@ class PagedJaxLLMEngine:
 
         cos, sin = rope_frequencies(cfg.head_dim, self.max_seq, cfg.rope_theta)
         self._rope = (jnp.asarray(cos), jnp.asarray(sin))
-
-        from ray_tpu.llm.engine import (
-            build_engine_mesh,
-            pp_cache_spec,
-            pp_param_specs,
-        )
 
         pp = config.pipeline_parallel_size
         self.mesh = build_engine_mesh(cfg, config.tensor_parallel_size, pp,
@@ -558,7 +558,7 @@ class PagedJaxLLMEngine:
                 and pp <= 1 and config.tp_planned_collectives):
             self._init_tp_planning()
 
-        # host slot state (mirrors the static engine)
+        # host slot state
         self._slot_req: List[Optional[_PagedReq]] = [None] * self.max_batch
         self._lengths = np.zeros(self.max_batch, np.int32)
         self._next_tok = np.zeros(self.max_batch, np.int32)
@@ -583,7 +583,6 @@ class PagedJaxLLMEngine:
         self._telemetry: Optional[device_telemetry.EngineTelemetry] = None
         # chunked-prefill budget spend, tracked per step for telemetry
         self._tel_prefill_budget = (config.prefill_token_budget
-                                    or config.prefill_budget_tokens
                                     or config.prefill_chunk)
         self._tel_prefill_spent = 0
         # one decode chunk may stay IN FLIGHT while the host books the
@@ -674,6 +673,7 @@ class PagedJaxLLMEngine:
         self.warmup_report: Optional[dict] = None  # set by warmup()
         self._spec = config.speculative_config
         self._spec_k = 0
+        self._draft_params = None
         if self._spec is not None:
             dcfg = self._spec.draft_model_config
             if dcfg is None:
@@ -821,8 +821,7 @@ class PagedJaxLLMEngine:
         if self.mesh is not None:
             # mesh-aware view: KV/weights bytes PER DEVICE (the pool
             # shards its kv-head dim over "tensor"), plus the planned
-            # collective decisions — what bench.py's busbw column and the
-            # disagg digests read
+            # collective decisions — what the disagg digests read
             row["tp"] = {
                 "degree": self.config.tensor_parallel_size,
                 "pipeline": self.config.pipeline_parallel_size,
@@ -922,7 +921,7 @@ class PagedJaxLLMEngine:
         Each decision is metered into ``ray_tpu_collective_plan_total``
         (algorithm + reason — flat/tree's "latency_bound" is decode's
         regime) and the full ``plan_explain`` row is kept for
-        ``utilization()`` and bench.py's busbw column."""
+        ``utilization()``."""
         from ray_tpu.util.collective import planner as _planner
         from ray_tpu.util.collective.compression import CompressionSpec
 
@@ -1438,7 +1437,6 @@ class PagedJaxLLMEngine:
         fraction of it; a target prefix-cache hit makes the draft replay
         the matched region, still cheap at draft size)."""
         budget = (self.config.prefill_token_budget
-                  or self.config.prefill_budget_tokens
                   or self.config.prefill_chunk)
         self._tel_prefill_budget = budget
         progress = True

@@ -3,7 +3,7 @@
 reference: python/ray/llm/_internal/serve/deployments/llm/ — LLMServer
 deployments on vLLM with per-replica placement groups sized from the
 engine's TP/PP degrees (vllm_models.py:177-186, :241-259).  Here the
-replica owns a JaxLLMEngine; concurrent requests enqueue into the engine
+replica owns a PagedJaxLLMEngine; concurrent requests enqueue into the engine
 and a background thread drives ``engine.step()``, so all in-flight
 requests share one decode batch (continuous batching across callers).
 """
@@ -46,9 +46,8 @@ class LLMServer:
         # the MATERIALIZED draft weights (the engine random-initializes
         # when draft_params is None): per-adapter draft merges apply to
         # what actually runs, not the constructor argument
-        self._draft_params = getattr(self._engine, "_draft_params",
-                                     draft_params)
-        if hasattr(self._engine, "warmup") and _jax_backend() == "tpu":
+        self._draft_params = self._engine._draft_params
+        if _jax_backend() == "tpu":
             # compile every decode (B, W) bucket before serving traffic —
             # a bucket transition otherwise costs a multi-second XLA
             # compile inside the latency path (vLLM warms shapes at
@@ -102,10 +101,7 @@ class LLMServer:
         under it.  Unlabeled servers (direct library use) book nothing."""
         self._slo_label = name
         for eng in list(self._engines.values()):
-            try:
-                eng.slo_label = name
-            except Exception:  # noqa: BLE001 — static engine variants
-                pass
+            eng.slo_label = name
 
     @contextlib.contextmanager
     def _hold_step_lock(self, who: str):
@@ -132,8 +128,8 @@ class LLMServer:
         """Book the ``stream_out`` stage (first token emitted by the
         engine -> its chunk handed to the replica's stream) and return
         it; None where the engine tracks no stamps (unlabeled server)."""
-        req = getattr(self._engine_of(wkey), "tracked_request",
-                      lambda _rid: None)(wkey[2])
+        eng = self._engine_of(wkey)
+        req = eng.tracked_request(wkey[2]) if eng is not None else None
         if req is None or not req.t_first_emit:
             return None
         from ray_tpu.serve._private import slo
@@ -145,8 +141,8 @@ class LLMServer:
     def _note_request_row(self, wkey, stream_out_s: Optional[float]) -> None:
         """A finished request's stage times and token counts into this
         process's ledger ring (``state.recent_requests()``)."""
-        row = getattr(self._engine_of(wkey), "pop_request_row",
-                      lambda _rid: None)(wkey[2])
+        eng = self._engine_of(wkey)
+        row = eng.pop_request_row(wkey[2]) if eng is not None else None
         if row is None:
             return
         from ray_tpu.serve._private import slo
@@ -155,25 +151,16 @@ class LLMServer:
             row["stream_out_s"] = round(stream_out_s, 6)
         slo.record_engine_request(self._slo_label, row)
 
-    def utilization(self) -> Optional[Dict[str, Any]]:
+    def utilization(self) -> Dict[str, Any]:
         """Device-telemetry utilization row for the hosting replica's
         publish loop and the local-mode fold (state.utilization()): the
         base engine's exact bookkeeping, plus any live adapter engines'
-        rows under ``adapters``.  ``None`` when the engine variant has no
-        utilization surface."""
-        base = getattr(self._engine, "utilization", None)
-        row = base() if base is not None else None
-        if row is None:
-            return None
+        rows under ``adapters``."""
+        row = self._engine.utilization()
         with self._engines_lock:
             extras = [(m, e) for m, e in self._engines.items()
                       if m is not None]
-        adapters = {}
-        for model, eng in extras:
-            try:
-                adapters[model] = eng.utilization()
-            except Exception:  # noqa: BLE001 — engine variants without one
-                pass
+        adapters = {model: eng.utilization() for model, eng in extras}
         if adapters:
             row["adapters"] = adapters
         if self._slo_label is not None:
@@ -200,9 +187,9 @@ class LLMServer:
             memory.append({k: int(stats[k]) for k in
                            ("bytes_in_use", "peak_bytes_in_use", "bytes_limit")
                            if k in stats})
-        if not getattr(eng, "_use_kernel", False):
+        if not eng._use_kernel:
             attention = "gather"
-        elif getattr(eng, "_kernel_interpret", False):
+        elif eng._kernel_interpret:
             attention = "kernel-interpret"
         else:
             attention = "kernel"
@@ -215,9 +202,9 @@ class LLMServer:
             "visible_chips": os.environ.get("TPU_VISIBLE_CHIPS"),
             "engine": type(eng).__name__,
             "n_layers": eng.cfg.n_layers,
-            "num_blocks": getattr(eng, "num_blocks", None),
+            "num_blocks": eng.num_blocks,
             "paged_attention": attention,
-            "warmup": getattr(eng, "warmup_report", None),
+            "warmup": eng.warmup_report,
             "memory": memory,
             "compile_cache_dir": jax.config.jax_compilation_cache_dir,
             "native": _native.status(),
@@ -254,7 +241,7 @@ class LLMServer:
         }
 
     def first_decode_logits(self, prompt: Sequence[int]):
-        """The base engine's ``first_decode_logits`` (paged engine)."""
+        """The base engine's ``first_decode_logits``."""
         return self._engine.first_decode_logits(prompt)
 
     def prefix_digest(self) -> Dict[str, Any]:
@@ -262,17 +249,14 @@ class LLMServer:
         prefix-chain digest plus the adapter ids this replica has loaded
         (LoRA affinity) and the live request depth.  Published to the GCS
         KV by the hosting replica (throttled, versioned)."""
-        digest = getattr(self._engine, "prefix_digest", lambda: {})() or {}
+        digest = self._engine.prefix_digest()
         with self._engines_lock:
             engines = list(self._engines.values())
             models = [m for m in self._engine_order]
         qlen = 0
         for eng in engines:
-            try:
-                with eng._lock:
-                    qlen += len(eng._requests)
-            except Exception:  # noqa: BLE001 — engine variants without a request table are legal
-                pass
+            with eng._lock:
+                qlen += len(eng._requests)
         digest["models"] = models
         digest["qlen"] = qlen
         return digest
@@ -308,8 +292,9 @@ class LLMServer:
         cluster-mode replica process has no tracker and relies on the
         ledger fold + metric families for the acceptance signal."""
         try:
-            stats = getattr(self._engine_of(wkey), "specdec_request_stats",
-                            lambda _rid: None)(wkey[2])
+            eng = self._engine_of(wkey)
+            stats = (eng.specdec_request_stats(wkey[2])
+                     if eng is not None else None)
         except Exception:  # noqa: BLE001
             stats = None
         if stats:
@@ -373,9 +358,7 @@ class LLMServer:
             # reconcile bystander buffers after (see _reap_drained)
             with self._hold_step_lock("cancel"):
                 try:
-                    cancel = getattr(self._engine, "cancel_request", None)
-                    if cancel is not None:
-                        cancel(rid)
+                    self._engine.cancel_request(rid)
                 except Exception:  # noqa: BLE001 — abort must never mask the close
                     pass
                 with self._cv:
@@ -391,9 +374,8 @@ class LLMServer:
                 eng = (self._engines.get(model)
                        if self._engine_gen.get(model, 0) == gen_id
                        else None)
-            cancel = getattr(eng, "cancel_request", None)
-            if cancel is not None:
-                cancel(rid)
+            if eng is not None:
+                eng.cancel_request(rid)
         except Exception:  # noqa: BLE001 — abort must never mask the close
             pass
         with self._cv:
@@ -432,10 +414,7 @@ class LLMServer:
                 if eng is None and built is not None:
                     self._engine_gen[model] = self._engine_gen.get(model, 0) + 1
                     if self._slo_label is not None:
-                        try:
-                            built.slo_label = self._slo_label
-                        except Exception:  # noqa: BLE001 — engine variants without SLO threading are legal
-                            pass
+                        built.slo_label = self._slo_label
                     self._engines[model] = eng = built
                 if eng is not None:
                     rid = eng.add_request(prompt, gen)
@@ -469,7 +448,7 @@ class LLMServer:
             # devices" could pick different chips than a placement-group
             # pinned base, double-committing HBM on one slice while the
             # reserved one idles
-            base_mesh = getattr(self._engine, "mesh", None)
+            base_mesh = self._engine.mesh
             if base_mesh is not None and cfg.mesh is None:
                 cfg = dataclasses.replace(cfg, mesh=base_mesh)
             dparams = self._draft_params
@@ -555,9 +534,7 @@ class LLMServer:
                 t0 = time.monotonic()
                 with tracing.region("serve.loop_idle"):
                     time.sleep(0.002)
-                note = getattr(self._engine, "note_loop_idle", None)
-                if note is not None:
-                    note(time.monotonic() - t0)
+                self._engine.note_loop_idle(time.monotonic() - t0)
 
     # -- live KV migration (serve/_private/kv_migration.py) -------------
     #
@@ -577,8 +554,6 @@ class LLMServer:
         listed — they carry no base-pool KV and resume on a destination
         by recompute through the planner's recompute path."""
         eng = self._engine
-        if not hasattr(eng, "export_request"):
-            return []
         out: List[int] = []
         with eng._lock:
             for rid, req in eng._requests.items():
@@ -718,8 +693,7 @@ class LLMServer:
         model = handoff.get("model")
         emitted = [int(t) for t in handoff["emitted"]]
         res = None
-        if (not model and handoff.get("k") is not None
-                and hasattr(self._engine, "import_request")):
+        if not model and handoff.get("k") is not None:
             try:
                 res = self._engine.import_request(
                     handoff["prompt"], handoff["first_token"],

@@ -27,7 +27,7 @@ from jax import lax
 from jax.ad_checkpoint import checkpoint_name
 from jax.sharding import Mesh, PartitionSpec as P
 
-from ray_tpu.ops.attention import mesh_attention, multi_head_attention
+from ray_tpu.ops.attention import mesh_attention
 from ray_tpu.ops.norms import rms_norm
 from ray_tpu.ops.ring_attention import ring_attention
 from ray_tpu.ops.rope import apply_rope, rope_frequencies
@@ -197,14 +197,6 @@ def inference_param_specs(cfg: LlamaConfig) -> Params:
     return specs
 
 
-def kv_cache_spec() -> Dict[str, P]:
-    """KV cache [L, B, S, n_kv, hd] shards the kv-head axis over "tensor",
-    matching wk/wv column sharding — cache writes and attention reads then
-    never reshard."""
-    spec = P(None, None, None, "tensor", None)
-    return {"k": spec, "v": spec}
-
-
 def _constraint(x, spec, mesh):
     if mesh is None:
         return x
@@ -339,146 +331,6 @@ def loss_fn(
 
 
 # ---------------------------------------------------------------------------
-# Incremental decoding (KV cache) — the compute path under ray_tpu.llm's
-# engine (reference analog: the vLLM engine Ray LLM delegates to,
-# llm/_internal/serve/deployments/llm/vllm/).  TPU-first: static cache
-# shapes [L, B, S_max, ...], per-slot scatter via .at[] (lowers to
-# dynamic-update-slice), one fused decode program for the whole batch.
-# ---------------------------------------------------------------------------
-
-
-def init_kv_cache(cfg: LlamaConfig, max_batch: int, max_seq: int,
-                  dtype=None) -> Dict[str, jnp.ndarray]:
-    """Static-shape KV cache for `max_batch` sequence slots."""
-    dtype = dtype or cfg.compute_dtype
-    shape = (cfg.n_layers, max_batch, max_seq, cfg.n_kv_heads, cfg.head_dim)
-    return {"k": jnp.zeros(shape, dtype), "v": jnp.zeros(shape, dtype)}
-
-
-def prefill(cfg: LlamaConfig, params: Params, tokens: jnp.ndarray,
-            rope_cache: Optional[tuple] = None):
-    """Full-sequence forward that also returns per-layer K/V.
-
-    tokens [B, S] -> (logits [B, S, V] fp32, kv {"k","v"} [L, B, S, kv, hd])
-    """
-    if rope_cache is None:
-        cos, sin = rope_frequencies(cfg.head_dim, cfg.max_seq_len, cfg.rope_theta)
-        cos, sin = jnp.asarray(cos), jnp.asarray(sin)
-    else:
-        cos, sin = rope_cache
-    b, s = tokens.shape
-    cdt = cfg.compute_dtype
-    x = jnp.take(params["embed"], tokens, axis=0).astype(cdt)
-
-    def body(x, lp):
-        with jax.named_scope("attention"):
-            h = rms_norm(x, lp["attn_norm"], cfg.rms_norm_eps)
-            q = (h @ lp["wq"].astype(cdt)).reshape(b, s, cfg.n_heads, cfg.head_dim)
-            k = (h @ lp["wk"].astype(cdt)).reshape(b, s, cfg.n_kv_heads, cfg.head_dim)
-            v = (h @ lp["wv"].astype(cdt)).reshape(b, s, cfg.n_kv_heads, cfg.head_dim)
-            q = apply_rope(q, cos[:s], sin[:s])
-            k = apply_rope(k, cos[:s], sin[:s])
-            attn = multi_head_attention(q, k, v, causal=True)
-            attn = attn.reshape(b, s, cfg.n_heads * cfg.head_dim)
-            x = x + (attn @ lp["wo"].astype(cdt))
-        with jax.named_scope("ffn"):
-            h = rms_norm(x, lp["mlp_norm"], cfg.rms_norm_eps)
-            ffn = (jax.nn.silu(h @ lp["w_gate"].astype(cdt))
-                   * (h @ lp["w_up"].astype(cdt))) @ lp["w_down"].astype(cdt)
-        return x + ffn, (k, v)
-
-    x, (ks, vs) = lax.scan(body, x, params["layers"])
-    with jax.named_scope("head"):
-        x = rms_norm(x, params["final_norm"], cfg.rms_norm_eps)
-        head = params["embed"].T if cfg.tie_embeddings else params["lm_head"]
-        logits = (x @ head.astype(cdt)).astype(jnp.float32)
-    return logits, {"k": ks, "v": vs}
-
-
-def write_cache_slot(cache: Dict[str, jnp.ndarray], kv: Dict[str, jnp.ndarray],
-                     slot: jnp.ndarray) -> Dict[str, jnp.ndarray]:
-    """Write one prefilled sequence (batch dim 1) into cache slot `slot`."""
-    out = {}
-    for name in ("k", "v"):
-        out[name] = lax.dynamic_update_slice(
-            cache[name], kv[name].astype(cache[name].dtype),
-            (0, slot, 0, 0, 0))
-    return out
-
-
-def decode_step(cfg: LlamaConfig, params: Params, tokens: jnp.ndarray,
-                cache: Dict[str, jnp.ndarray], lengths: jnp.ndarray,
-                rope_cache: Optional[tuple] = None):
-    """One-token decode for every cache slot.
-
-    tokens [B] int32 (the token at position lengths[b]); lengths [B] int32.
-    Returns (logits [B, V] fp32, updated cache).  Slots with lengths == 0
-    compute garbage but write only their own slot — callers mask them.
-
-    The cache rides the layer scan as CARRY with per-layer one-token DUS
-    writes — scanning it as xs/ys would RESTACK the whole [L, B, S, kv, hd]
-    cache every step (a full cache write per token: measured 22.3 ->
-    8.1 ms/token-step at batch 32 on v5e, ~71% of the params+cache-read
-    HBM roofline).
-    """
-    if rope_cache is None:
-        cos, sin = rope_frequencies(cfg.head_dim, cfg.max_seq_len, cfg.rope_theta)
-        cos, sin = jnp.asarray(cos), jnp.asarray(sin)
-    else:
-        cos, sin = rope_cache
-    b = tokens.shape[0]
-    s_max = cache["k"].shape[2]
-    cdt = cfg.compute_dtype
-    group = cfg.n_heads // cfg.n_kv_heads
-    x = jnp.take(params["embed"], tokens, axis=0).astype(cdt)  # [B, d]
-    batch_idx = jnp.arange(b)
-    pos_mask = (jnp.arange(s_max)[None, :] <= lengths[:, None])  # [B, S]
-
-    def body(carry, inp):
-        x, ck_all, cv_all = carry
-        lp, li = inp
-        with jax.named_scope("attention"):
-            h = rms_norm(x, lp["attn_norm"], cfg.rms_norm_eps)
-            q = (h @ lp["wq"].astype(cdt)).reshape(b, 1, cfg.n_heads, cfg.head_dim)
-            k = (h @ lp["wk"].astype(cdt)).reshape(b, 1, cfg.n_kv_heads, cfg.head_dim)
-            v = (h @ lp["wv"].astype(cdt)).reshape(b, 1, cfg.n_kv_heads, cfg.head_dim)
-            q = apply_rope(q, cos, sin, positions=lengths[:, None])[:, 0]  # [B,nh,hd]
-            k = apply_rope(k, cos, sin, positions=lengths[:, None])[:, 0]
-            ck_all = ck_all.at[li, batch_idx, lengths].set(k.astype(ck_all.dtype))
-            cv_all = cv_all.at[li, batch_idx, lengths].set(v[:, 0].astype(cv_all.dtype))
-            ck = ck_all[li]
-            cv = cv_all[li]
-            # GQA attention against the cache, masked to valid positions.
-            # bf16 operands + fp32 ACCUMULATION (preferred_element_type): an
-            # .astype(f32) on the cache would materialize a full-span fp32 copy
-            # per decode step — 2x the HBM bytes of the weight-bound roofline
-            qg = q.reshape(b, cfg.n_kv_heads, group, cfg.head_dim)
-            scores = jnp.einsum("bkgd,bskd->bkgs", qg, ck,
-                                preferred_element_type=jnp.float32)
-            scores = scores / math.sqrt(cfg.head_dim)
-            scores = jnp.where(pos_mask[:, None, None, :], scores, -1e30)
-            probs = jax.nn.softmax(scores, axis=-1)
-            attn = jnp.einsum("bkgs,bskd->bkgd", probs.astype(ck.dtype), cv,
-                              preferred_element_type=jnp.float32)
-            attn = attn.reshape(b, cfg.n_heads * cfg.head_dim).astype(cdt)
-            x = x + attn @ lp["wo"].astype(cdt)
-        with jax.named_scope("ffn"):
-            h = rms_norm(x, lp["mlp_norm"], cfg.rms_norm_eps)
-            ffn = (jax.nn.silu(h @ lp["w_gate"].astype(cdt))
-                   * (h @ lp["w_up"].astype(cdt))) @ lp["w_down"].astype(cdt)
-        return (x + ffn, ck_all, cv_all), None
-
-    (x, ks, vs), _ = lax.scan(
-        body, (x, cache["k"], cache["v"]),
-        (params["layers"], jnp.arange(cfg.n_layers)))
-    with jax.named_scope("head"):
-        x = rms_norm(x, params["final_norm"], cfg.rms_norm_eps)
-        head = params["embed"].T if cfg.tie_embeddings else params["lm_head"]
-        logits = (x @ head.astype(cdt)).astype(jnp.float32)  # [B, V]
-    return logits, {"k": ks, "v": vs}
-
-
-# ---------------------------------------------------------------------------
 # Paged KV cache programs (reference capability boundary: the paged-attention
 # engine Ray LLM gets by delegating to vLLM, vllm_models.py:177-186 — here
 # TPU-native).  The cache is a POOL of fixed-size blocks laid out
@@ -497,8 +349,8 @@ def decode_step(cfg: LlamaConfig, params: Params, tokens: jnp.ndarray,
 # xs/ys design restacked the full pool every token-step: 6.8 ms of the
 # 11.5 ms/token-step at b32 on v5e in round 5's notes, a toy model and a
 # script since removed; not reproduced on this round's code.)
-# Sharding: the kv-head axis shards over "tensor" exactly as the dense
-# cache, layer axis over "pipeline", block/table axes replicated.
+# Sharding: the folded kv-head axis shards over "tensor" (as wk/wv's
+# columns do), layer axis over "pipeline", block/table axes replicated.
 # ---------------------------------------------------------------------------
 
 

@@ -587,7 +587,7 @@ class ServingSLOLedger:
         return row
 
     def snapshot(self) -> dict:
-        """Local fold (bench.py, local-testing mode): same shape as
+        """Local fold (local-testing mode): same shape as
         ``state.serving_slo()`` but over this process only."""
         return fold_rows([self.row()], now_wall=self.wall())
 

@@ -27,8 +27,7 @@ Surfaces: ``ray_tpu_train_goodput_seconds`` (a gauge mirroring the
 ledger's buckets exactly — reclassification moves seconds between
 buckets, which a monotonic counter could not follow) /
 ``ray_tpu_train_goodput_ratio``, ``state.goodput(run)`` (published to
-the GCS KV), the dashboard ``/api/goodput`` view, and a ``goodput``
-block in bench.py's JSON line.
+the GCS KV), and the dashboard ``/api/goodput`` view.
 """
 
 from __future__ import annotations
@@ -184,7 +183,7 @@ class GoodputLedger:
             return False
 
 
-# -- process-local registry (bench.py goodput block) ------------------------
+# -- process-local registry --------------------------------------------------
 
 _ledgers: Dict[str, GoodputLedger] = {}
 _registry_lock = threading.Lock()
@@ -197,8 +196,7 @@ def register(ledger: GoodputLedger) -> GoodputLedger:
 
 
 def goodput_snapshot() -> dict:
-    """Every ledger this process created, snapshotted — bench.py embeds
-    this as its ``goodput`` block."""
+    """Every ledger this process created, snapshotted."""
     with _registry_lock:
         ledgers = list(_ledgers.values())
     return {led.run: led.snapshot() for led in ledgers}

@@ -149,3 +149,38 @@ def mock_tpu_host(monkeypatch):
     monkeypatch.setenv("TPU_ACCELERATOR_TYPE", "v5p-8")
     monkeypatch.setenv("TPU_TOPOLOGY", "2x2x1")
     yield
+
+
+@pytest.fixture(scope="session")
+def greedy_reference():
+    """The serving engine's reference: greedy argmax over a full re-run of
+    ``llama.forward`` (the trainer's path: no cache, no paging, no chunks)
+    a token at a time.  ``run(cfg, params, prompts, n_new)`` returns each
+    prompt's ``n_new`` tokens.  Prompts are padded to one length so the
+    forward compiles once; causal attention keeps a row's logit at its
+    last real position independent of the padding behind it.  Token
+    equality with the engine holds for float32 fixtures."""
+    import functools
+
+    import jax.numpy as jnp
+    import numpy as np
+
+    from ray_tpu.models import llama
+
+    def run(cfg, params, prompts, n_new):
+        fwd = jax.jit(functools.partial(llama.forward, cfg))
+        lens = np.array([len(p) for p in prompts])
+        width = -(-(int(lens.max()) + n_new) // 32) * 32
+        seqs = np.zeros((len(prompts), width), np.int32)
+        for row, prompt in zip(seqs, prompts):
+            row[:len(prompt)] = prompt
+        rows = np.arange(len(prompts))
+        for _ in range(n_new):
+            logits = fwd(params, jnp.asarray(seqs))
+            seqs[rows, lens] = np.asarray(
+                jnp.argmax(logits[rows, lens - 1], axis=-1))
+            lens = lens + 1
+        return [seqs[i, len(p):len(p) + n_new].tolist()
+                for i, p in enumerate(prompts)]
+
+    return run

@@ -356,27 +356,6 @@ def test_paged_engine_utilization_matches_internal_books(reset_telemetry):
     assert state.utilization("no-such-dep")["deployments"] == {}
 
 
-def test_static_engine_utilization_headroom(reset_telemetry):
-    import jax
-
-    from ray_tpu.llm import JaxLLMEngine, LLMConfig
-    from ray_tpu.models.llama import init_params
-
-    cfg = _micro_cfg()
-    eng = JaxLLMEngine(
-        LLMConfig(model_config=cfg, kv_cache="static", max_batch_size=3,
-                  max_seq_len=48),
-        params=init_params(cfg, jax.random.PRNGKey(0)))
-    eng.slo_label = "tel-static"
-    u = eng.utilization()
-    assert u["engine"] == "static"
-    assert u["deployment"] == "tel-static"
-    assert u["slots"] == {"active": 0, "max": 3, "free": 3}
-    # static KV: a slot owns its whole max_seq stripe, so block
-    # accounting degenerates to slot accounting
-    assert u["kv_blocks"] == {"total": 3, "free": 3, "used": 0}
-
-
 def test_disagg_local_app_utilization_fold(reset_telemetry):
     """state.utilization() on a live disagg-shaped app: both stage
     deployments fold with per-replica internal-books-exact rows (the
@@ -474,7 +453,7 @@ def test_profile_roundtrip_and_storm_in_diagnose(ray_start_regular,
 
 def _round(tmp_path, name, parsed):
     """One round in the driver's wrapper shape: {n, cmd, rc, tail, parsed},
-    where ``tail`` is the end of bench.py's output (its last line is the
+    where ``tail`` is the end of the round's output (its last line is the
     result document) and ``parsed`` that document."""
     p = tmp_path / name
     p.write_text(json.dumps({"n": 1, "cmd": "bench", "rc": 0,

@@ -464,7 +464,7 @@ def test_ledger_preemption_replay_from_injected_notice():
 
 
 def test_goodput_metrics_snapshot_shape():
-    """bench.py's goodput block derives ratio/wall from the counter points."""
+    """The snapshot derives ratio/wall from the counter points."""
     from ray_tpu._private import runtime_metrics as rm
 
     clock = FakeClock()

@@ -101,7 +101,7 @@ def staged(tiny):
     slo.reset_ledger()
     server = LLMServer(
         LLMConfig(model_config=mcfg, max_batch_size=4, decode_chunk=4,
-                  kv_cache="paged", block_size=8, prefill_chunk=16,
+                  block_size=8, prefill_chunk=16,
                   max_seq_len=128, num_blocks=40), params)
     server.set_slo_label(dep)
     yields = {}

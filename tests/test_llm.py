@@ -11,10 +11,10 @@ import pytest
 
 from ray_tpu.llm import (
     GenerationConfig,
-    JaxLLMEngine,
     LLMConfig,
     ProcessorConfig,
     build_llm_processor,
+    make_engine,
 )
 from ray_tpu.models.llama import LlamaConfig, init_params
 
@@ -28,8 +28,12 @@ def tiny_cfg():
 
 @pytest.fixture(scope="module")
 def engine(tiny_cfg):
-    return JaxLLMEngine(LLMConfig(model_config=tiny_cfg, max_batch_size=4,
-                                  max_seq_len=128))
+    return _engine(tiny_cfg, max_batch_size=4, max_seq_len=128)
+
+
+def _engine(cfg, params=None, **kw):
+    return make_engine(LLMConfig(model_config=cfg, block_size=8,
+                                 prefill_chunk=16, **kw), params)
 
 
 def test_decode_matches_full_forward(tiny_cfg):
@@ -40,15 +44,14 @@ def test_decode_matches_full_forward(tiny_cfg):
     prompt = list(np.random.RandomState(0).randint(1, 255, size=7))
     n_new = 8
 
-    # reference: full forward re-run each step
+    # reference: full forward re-run each step, unpadded and unjitted
     seq = list(prompt)
     for _ in range(n_new):
         logits = llama.forward(tiny_cfg, params, jnp.asarray([seq]))
         seq.append(int(jnp.argmax(logits[0, -1])))
     expected = seq[len(prompt):]
 
-    eng = JaxLLMEngine(LLMConfig(model_config=tiny_cfg, max_batch_size=2,
-                                 max_seq_len=64), params=params)
+    eng = _engine(tiny_cfg, params, max_batch_size=2, max_seq_len=64)
     out = eng.generate([prompt], GenerationConfig(max_new_tokens=n_new))[0]
     assert out == expected
 
@@ -66,8 +69,8 @@ def test_engine_continuous_batching_join(tiny_cfg):
     decode_chunk=1: this test paces generation token-by-token to land a
     second request mid-flight; the default chunked stepping would finish
     the first request within one step()."""
-    engine = JaxLLMEngine(LLMConfig(model_config=tiny_cfg, max_batch_size=4,
-                                    max_seq_len=128, decode_chunk=1))
+    engine = _engine(tiny_cfg, max_batch_size=4, max_seq_len=128,
+                     decode_chunk=1)
     done = {}
 
     def pump(n):
@@ -87,8 +90,7 @@ def test_engine_continuous_batching_join(tiny_cfg):
 
 
 def test_engine_more_requests_than_slots(tiny_cfg):
-    eng = JaxLLMEngine(LLMConfig(model_config=tiny_cfg, max_batch_size=2,
-                                 max_seq_len=64))
+    eng = _engine(tiny_cfg, max_batch_size=2, max_seq_len=64)
     outs = eng.generate([[i + 1] for i in range(5)],
                         GenerationConfig(max_new_tokens=3))
     assert len(outs) == 5
@@ -109,9 +111,8 @@ def test_engine_stop_token_truncates_mid_chunk(tiny_cfg):
     """In-program stop handling: the device scan must deactivate a slot the
     moment it emits a stop id, suppressing the rest of the chunk."""
     params = init_params(tiny_cfg, jax.random.PRNGKey(3))
-    eng = JaxLLMEngine(LLMConfig(model_config=tiny_cfg, max_batch_size=2,
-                                 max_seq_len=128, decode_chunk=8),
-                       params=params)
+    eng = _engine(tiny_cfg, params, max_batch_size=2, max_seq_len=128,
+                  decode_chunk=8)
     prompt = [5, 6, 7]
     free = eng.generate([prompt], GenerationConfig(max_new_tokens=24))[0]
     assert len(free) == 24
@@ -168,8 +169,7 @@ def test_engine_mixed_sampling_single_batch(tiny_cfg):
     from ray_tpu.models import llama
 
     params = llama.init_params(tiny_cfg, jax.random.PRNGKey(0))
-    eng = JaxLLMEngine(LLMConfig(model_config=tiny_cfg, max_batch_size=4,
-                                 max_seq_len=64), params=params)
+    eng = _engine(tiny_cfg, params, max_batch_size=4, max_seq_len=64)
     r_greedy = eng.add_request([1, 2, 3], GenerationConfig(max_new_tokens=6))
     r_hot = eng.add_request([1, 2, 3],
                             GenerationConfig(max_new_tokens=6, temperature=1.5,
@@ -183,7 +183,6 @@ def test_engine_mixed_sampling_single_batch(tiny_cfg):
     assert len(done[r_greedy]) == 6 and len(done[r_hot]) == 6
 
     # greedy slot must match a solo greedy run exactly
-    solo = JaxLLMEngine(LLMConfig(model_config=tiny_cfg, max_batch_size=1,
-                                  max_seq_len=64), params=params)
+    solo = _engine(tiny_cfg, params, max_batch_size=1, max_seq_len=64)
     expected = solo.generate([[1, 2, 3]], GenerationConfig(max_new_tokens=6))[0]
     assert done[r_greedy] == expected

@@ -1,5 +1,6 @@
-"""Paged KV cache engine: block manager, token parity vs the static engine,
-chunked prefill, prefix caching, memory-based admission, preemption.
+"""Paged KV cache engine: block manager, prefix caching, memory-based
+admission, preemption.  (Token parity with the full forward, over decode
+and prefill chunk sizes, runs on every commit: tests/test_llm_engine_parity.py.)
 
 reference capability boundary: paged attention / chunked prefill / prefix
 caching arrive via vLLM engine_kwargs (llm/_internal/serve/deployments/llm/
@@ -13,7 +14,6 @@ import pytest
 from ray_tpu.llm import (
     BlockManager,
     GenerationConfig,
-    JaxLLMEngine,
     LLMConfig,
     PagedJaxLLMEngine,
     make_engine,
@@ -78,41 +78,7 @@ def test_block_manager_prefix_match_and_revive():
     bm.release(all_blocks)
 
 
-# -- token parity vs the static engine --------------------------------------
-
-
-def test_paged_matches_static_engine(tiny_cfg, tiny_params):
-    """Same params, same prompts, greedy: token streams must be identical
-    between cache layouts (the paged gather/scatter is a data-movement
-    change, not a math change)."""
-    prompts = [list(np.random.RandomState(s).randint(1, 255, size=n))
-               for s, n in [(0, 7), (1, 19), (2, 33), (3, 4)]]
-    static = JaxLLMEngine(
-        LLMConfig(model_config=tiny_cfg, kv_cache="static", max_batch_size=4,
-                  max_seq_len=128), params=tiny_params)
-    paged = PagedJaxLLMEngine(
-        LLMConfig(model_config=tiny_cfg, max_batch_size=4, max_seq_len=128,
-                  block_size=8, prefill_chunk=16), params=tiny_params)
-    want = static.generate(prompts, _gen(max_new_tokens=10))
-    got = paged.generate(prompts, _gen(max_new_tokens=10))
-    assert got == want
-
-
-def test_chunked_prefill_long_prompt(tiny_cfg, tiny_params):
-    """A prompt longer than prefill_chunk accretes over multiple steps and
-    still matches the static engine's output."""
-    prompt = list(np.random.RandomState(7).randint(1, 255, size=70))
-    static = JaxLLMEngine(
-        LLMConfig(model_config=tiny_cfg, kv_cache="static", max_batch_size=2,
-                  max_seq_len=128), params=tiny_params)
-    paged = PagedJaxLLMEngine(
-        LLMConfig(model_config=tiny_cfg, max_batch_size=2, max_seq_len=128,
-                  block_size=8, prefill_chunk=16), params=tiny_params)
-    want = static.generate([prompt], _gen(max_new_tokens=6))
-    got = paged.generate([prompt], _gen(max_new_tokens=6))
-    assert got == want
-    # prefill really was chunked: 70 tokens / 16-token chunks = 5 chunks
-    assert len(prompt) > paged.config.prefill_chunk
+# -- scheduling, prefix cache, admission, preemption -------------------------
 
 
 def test_prefill_completes_while_decode_pipelines(tiny_cfg, tiny_params):
@@ -192,10 +158,10 @@ def test_memory_based_admission_not_slot_count(tiny_cfg, tiny_params):
     assert eng.blocks.num_free() == 11
 
 
-def test_preemption_recompute(tiny_cfg, tiny_params):
+def test_preemption_recompute(tiny_cfg, tiny_params, greedy_reference):
     """When the pool runs dry mid-decode, the youngest request is evicted
     and recomputed — every request still finishes with full output and no
-    token is ever re-emitted.  Streams match the static engine exactly up
+    token is ever re-emitted.  Streams match the full forward exactly up
     to each request's last preemption point; beyond it, recompute rewrites
     the victim's KV via chunked prefill whose reduction order differs in
     the last ulp from decode-written KV, so a later near-tie logit may
@@ -205,12 +171,9 @@ def test_preemption_recompute(tiny_cfg, tiny_params):
                   block_size=8, prefill_chunk=16, num_blocks=14,
                   decode_chunk=4, enable_prefix_caching=False),
         params=tiny_params)
-    static = JaxLLMEngine(
-        LLMConfig(model_config=tiny_cfg, kv_cache="static", max_batch_size=4,
-                  max_seq_len=128), params=tiny_params)
     prompts = [list(np.random.RandomState(s).randint(1, 255, size=16))
                for s in range(3)]
-    want = static.generate(prompts, _gen(max_new_tokens=40))
+    want = greedy_reference(tiny_cfg, tiny_params, prompts, 40)
 
     preempted_at: dict = {}  # request_id -> emitted count at last eviction
     orig = eng._preempt_locked
@@ -231,7 +194,7 @@ def test_preemption_recompute(tiny_cfg, tiny_params):
     for i, (g, w) in enumerate(zip(got, want)):
         cut = preempted_at.get(i + 1, 40)  # request ids are 1-based
         assert g[:cut] == w[:cut], f"request {i} diverged BEFORE preemption"
-    # non-preempted requests must match the static engine exactly
+    # non-preempted requests must match the reference exactly
     for i, (g, w) in enumerate(zip(got, want)):
         if (i + 1) not in preempted_at:
             assert g == w, f"non-preempted request {i} diverged"
@@ -239,28 +202,22 @@ def test_preemption_recompute(tiny_cfg, tiny_params):
 
 
 def test_paged_hbm_economics(tiny_cfg):
-    """The pool is smaller than the static cache for the same workload: the
-    default sizes it at half, and a batch of short requests fits easily."""
+    """The default pool holds half of max_batch x max_seq positions, and
+    a batch of short requests fits easily."""
     cfg = LLMConfig(model_config=tiny_cfg, max_batch_size=32, max_seq_len=128)
     eng = make_engine(cfg)
     assert isinstance(eng, PagedJaxLLMEngine)
-    static_slots_tokens = 32 * 128
     pool_tokens = eng.num_blocks * eng.bs
-    assert pool_tokens <= static_slots_tokens // 2
+    assert pool_tokens <= 32 * 128 // 2
     prompts = [[i + 1, i + 2, i + 3] for i in range(32)]
     outs = eng.generate(prompts, _gen(max_new_tokens=4))
     assert all(len(o) == 4 for o in outs)
 
 
 def test_make_engine_factory(tiny_cfg):
-    assert isinstance(
-        make_engine(LLMConfig(model_config=tiny_cfg, kv_cache="static")),
-        JaxLLMEngine)
-    with pytest.raises(ValueError, match="kv_cache"):
-        make_engine(LLMConfig(model_config=tiny_cfg, kv_cache="bogus"))
     with pytest.raises(ValueError, match="multiple"):
-        PagedJaxLLMEngine(LLMConfig(model_config=tiny_cfg, block_size=16,
-                                    prefill_chunk=24))
+        make_engine(LLMConfig(model_config=tiny_cfg, block_size=16,
+                              prefill_chunk=24))
 
 
 def test_prefill_table_width_covers_chunk_overhang(tiny_cfg, tiny_params):

@@ -1,8 +1,10 @@
 """Tensor-parallel LLM inference (VERDICT r2 directive #1).
 
 The engine builds a real `tensor`-axis mesh from tensor_parallel_size and
-GSPMD-partitions prefill/decode from the param + KV-cache shardings
-(ray_tpu/models/llama.py inference_param_specs / kv_cache_spec).
+partitions prefill/decode from the param + KV-pool shardings
+(ray_tpu/models/llama.py inference_param_specs / paged_kv_cache_spec).
+The bit-identity gate for tp = 2 and 4 runs on every commit
+(tests/test_llm_tp_paged.py); this file is the slow lane for the rest.
 
 reference: python/ray/llm/_internal/serve/deployments/llm/vllm/
 vllm_models.py:177-186,241-259 — TP/PP degrees wired from engine_kwargs
@@ -14,7 +16,7 @@ import numpy as np
 import pytest
 
 from ray_tpu.llm.config import GenerationConfig, LLMConfig
-from ray_tpu.llm.engine import JaxLLMEngine
+from ray_tpu.llm.engine import make_engine
 from ray_tpu.models import llama
 
 pytestmark = pytest.mark.slow  # compiles on the 8-device CPU mesh
@@ -29,20 +31,9 @@ def tiny_setup():
 
 
 def _engine(cfg, params, tp, **kw):
-    return JaxLLMEngine(
+    return make_engine(
         LLMConfig(model_config=cfg, tensor_parallel_size=tp,
                   max_batch_size=4, **kw), params=params)
-
-
-def test_tp_greedy_decode_identical_tokens(tiny_setup):
-    """TP=2 and TP=4 must produce exactly the tokens TP=1 produces for a
-    fixed seed — the acceptance gate for sharded inference."""
-    cfg, params, prompts = tiny_setup
-    gen = GenerationConfig(max_new_tokens=12)
-    ref = _engine(cfg, params, 1).generate(prompts, gen)
-    for tp in (2, 4):
-        out = _engine(cfg, params, tp).generate(prompts, gen)
-        assert out == ref, f"tp={tp} diverged"
 
 
 def test_tp_params_actually_sharded(tiny_setup):
@@ -55,7 +46,9 @@ def test_tp_params_actually_sharded(tiny_setup):
     assert len({s.device for s in shards}) == 2
     # column-sharded over tensor: each shard holds half the output dim
     assert shards[0].data.shape[-1] == wq.shape[-1] // 2
-    k = eng.cache["k"]
+    # the pool's folded kv-head axis too (paged_kv_cache_spec)
+    k = eng.pool["k"]
+    assert len({s.device for s in k.addressable_shards}) == 2
     assert k.addressable_shards[0].data.shape[3] == k.shape[3] // 2
 
 
@@ -117,7 +110,7 @@ def test_pp_greedy_decode_identical_tokens(tiny_setup):
     cfg, params, prompts = tiny_setup
     gen = GenerationConfig(max_new_tokens=12)
     ref = _engine(cfg, params, 1).generate(prompts, gen)
-    eng = JaxLLMEngine(
+    eng = make_engine(
         LLMConfig(model_config=cfg, pipeline_parallel_size=2,
                   max_batch_size=4), params=params)
     assert eng.generate(prompts, gen) == ref
@@ -128,7 +121,7 @@ def test_pp_greedy_decode_identical_tokens(tiny_setup):
 
 
 def test_pp_tp_compose_paged(tiny_setup):
-    """PP x TP on the paged engine: 2x2 mesh, tokens identical to 1x1."""
+    """PP x TP: 2x2 mesh, tokens identical to 1x1."""
     from ray_tpu.llm.paged import PagedJaxLLMEngine
 
     cfg, params, prompts = tiny_setup
@@ -169,8 +162,8 @@ def test_tp_paged_kernel_composes(tiny_setup):
 def test_pp_validation(tiny_setup):
     cfg, params, _ = tiny_setup
     with pytest.raises(ValueError, match="does not divide n_layers"):
-        JaxLLMEngine(LLMConfig(model_config=cfg, pipeline_parallel_size=3),
-                     params=params)
+        make_engine(LLMConfig(model_config=cfg, pipeline_parallel_size=3),
+                    params=params)
 
 
 def test_pp_in_placement_sizing(tiny_setup):

@@ -4,9 +4,10 @@ engine — while the per-layer decode allreduces provably route through the
 α-β collective planner (ISSUE 10) and the new TP metric families book only
 on the sharded path.
 
-tests/test_llm_tp.py covers the STATIC engine's GSPMD sharding (slow lane,
-file-wide marker); this file is the tier-1 lane for the paged engine's
-explicit planned collectives, so the parity pins run on every commit.
+tests/test_llm_tp.py is the slow lane (file-wide marker: pipeline stages,
+mid-stream admission, sampling, validation); this file is the tier-1 lane
+for the engine's explicit planned collectives, so the parity pins run on
+every commit.
 Engines are module-scoped — the 8-virtual-device CPU mesh compile is paid
 once per variant, not per test.
 """
@@ -52,12 +53,14 @@ def setup():
     return cfg, params, e1, e2, ref, plan_delta
 
 
-def test_tp2_greedy_bit_identical(setup):
+@pytest.mark.parametrize("tp", [2, 4])
+def test_tp_greedy_bit_identical(setup, tp):
     """The acceptance gate: sharded decode (explicit planned collectives,
     overlap on — the defaults) emits exactly the single-device tokens,
     across chunked-prefill boundaries and continuous batching."""
     cfg, params, e1, e2, ref, _ = setup
-    assert e2.generate(PROMPTS, GEN) == ref
+    eng = e2 if tp == 2 else _mk(cfg, params, tp)
+    assert eng.generate(PROMPTS, GEN) == ref
 
 
 def test_plan_counters_name_algorithm_and_reason(setup):

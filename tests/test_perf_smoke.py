@@ -108,7 +108,7 @@ def test_bench_diff_report_nonblocking(tmp_path):
         doc = {"metric": "llama1b_train_mfu_1chip", "value": mfu,
                "unit": "MFU", "extra": {"step_time_s": step}}
         (tmp_path / f"BENCH_r{n:02d}.json").write_text(json.dumps(
-            {"n": n, "cmd": "python bench.py", "rc": 0,
+            {"n": n, "cmd": "bench", "rc": 0,
              "tail": json.dumps(doc), "parsed": doc}))
     snaps = sorted(glob.glob(os.path.join(str(tmp_path), "BENCH_r*.json")))
     report = run(snaps[-2], snaps[-1])
@@ -368,7 +368,7 @@ def test_overlap_off_emits_zero_new_metric_families():
     assert rtm.plan_snapshot() == before
 
 
-def test_specdec_disabled_path_budget_and_byte_identity():
+def test_specdec_disabled_path_budget_and_byte_identity(greedy_reference):
     """Speculative decoding off (the default) must cost the non-spec
     engine NOTHING measurable and change NOTHING observable (ISSUE 11):
 
@@ -376,10 +376,10 @@ def test_specdec_disabled_path_budget_and_byte_identity():
         branch evaluations (`self._spec is None` + the appends-per-step
         select) — gated at < 1 µs per step, orders of magnitude under
         the ~ms step itself;
-      - a spec-disabled paged engine's greedy output stays byte-identical
-        to the static engine's (whose decode path this PR did not touch
-        beyond the shared ``_sample``, itself pinned to exact argmax in
-        tests/test_specdec.py) — the pre-PR output pin;
+      - a spec-disabled engine's greedy output stays token-identical to
+        argmax over the full forward (conftest's ``greedy_reference``;
+        the shared ``_sample`` is itself pinned to exact argmax in
+        tests/test_specdec.py) — the output pin;
       - the specdec metric families book nothing.
     """
     import time
@@ -389,8 +389,7 @@ def test_specdec_disabled_path_budget_and_byte_identity():
     import numpy as np
 
     from ray_tpu._private import runtime_metrics as rtm
-    from ray_tpu.llm import GenerationConfig, JaxLLMEngine, LLMConfig, \
-        PagedJaxLLMEngine
+    from ray_tpu.llm import GenerationConfig, LLMConfig, PagedJaxLLMEngine
     from ray_tpu.models.llama import LlamaConfig, init_params
 
     cfg = LlamaConfig.tiny(vocab_size=48, dim=32, n_layers=1, n_heads=2,
@@ -411,22 +410,20 @@ def test_specdec_disabled_path_budget_and_byte_identity():
             else paged.config.decode_chunk
     dt_ns = (time.perf_counter() - t0) / n * 1e9
     assert app == 4 and dt_ns < 1_000, dt_ns
-    # byte-identity pin vs the untouched static decode path
+    # token-identity pin vs the full forward
     prompts = [list(np.random.RandomState(s).randint(1, 47, size=7))
                for s in (0, 1)]
-    static = JaxLLMEngine(
-        LLMConfig(model_config=cfg, kv_cache="static", max_batch_size=2,
-                  max_seq_len=48), params=params)
     gen = GenerationConfig(max_new_tokens=8)
-    assert paged.generate(prompts, gen) == static.generate(prompts, gen)
+    assert paged.generate(prompts, gen) == greedy_reference(
+        cfg, params, prompts, 8)
     assert rtm.specdec_snapshot() == before
 
 
 def test_anakin_steps_per_sec_budget():
     """Perf-smoke for the co-located RL path (ISSUE 15): steady-state
     (post-compile) env-steps/s on the 8-device CPU mesh must stay within
-    budget.  The bench.py rl_throughput section records the real figure
-    (~1-3M steps/s on this box); the gate sits 10x+ below it so scheduler
+    budget.  The real figure was ~1-3M steps/s on this box (round 15's
+    notes); the gate sits 10x+ below it so scheduler
     noise can't flake the lane while an order-of-magnitude regression
     (e.g. a host round-trip sneaking into the rollout) still fails."""
     import time

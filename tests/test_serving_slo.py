@@ -740,7 +740,7 @@ def tiny_llm():
     mcfg = LlamaConfig.tiny()
     params = init_params(mcfg, jax.random.PRNGKey(0))
     lcfg = LLMConfig(model_config=mcfg, max_batch_size=4, decode_chunk=4,
-                     kv_cache="paged", block_size=8, prefill_chunk=16,
+                     block_size=8, prefill_chunk=16,
                      max_seq_len=256, num_blocks=40)
     return lcfg, params
 

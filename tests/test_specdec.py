@@ -27,7 +27,6 @@ from ray_tpu.llm import (
     LLMConfig,
     PagedJaxLLMEngine,
     SpeculativeConfig,
-    make_engine,
 )
 from ray_tpu.llm.engine import _sample, _sample_dist
 from ray_tpu.llm.paged import _spec_accept
@@ -407,8 +406,7 @@ def test_chunked_prefill_no_starvation(tiny_cfg, tiny_params):
 
 @pytest.mark.timeout(240)
 def test_prefill_token_budget_knob(tiny_cfg, tiny_params):
-    """config.prefill_token_budget bounds prefill tokens per STEP (and
-    wins over the deprecated prefill_budget_tokens alias)."""
+    """config.prefill_token_budget bounds prefill tokens per STEP."""
     eng = PagedJaxLLMEngine(
         _lcfg(tiny_cfg, max_batch_size=2, num_blocks=32, prefill_chunk=16),
         params=tiny_params)
@@ -421,7 +419,6 @@ def test_prefill_token_budget_knob(tiny_cfg, tiny_params):
 
     eng._prefill_chunk = spy
     eng.config.prefill_token_budget = 16
-    eng.config.prefill_budget_tokens = 64  # the alias must NOT win
     eng.add_request(_prompts([64], seed=29)[0], _gen(max_new_tokens=2))
     eng.step(decode=False)
     assert sum(calls) == 1  # 16-token budget = one 16-token chunk
@@ -553,14 +550,6 @@ def test_adapter_speculation_overrides():
     assert cfg is base and ad == {"x": 1}
     cfg, ad = adapter_speculation(base, "unknown")
     assert cfg is base and ad is None
-
-
-def test_static_engine_rejects_speculation(tiny_cfg):
-    with pytest.raises(ValueError, match="paged"):
-        make_engine(LLMConfig(
-            model_config=tiny_cfg, kv_cache="static",
-            speculative_config=SpeculativeConfig(
-                draft_model_config=tiny_cfg)))
 
 
 def test_spec_config_validation(tiny_cfg):

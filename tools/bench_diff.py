@@ -1,11 +1,8 @@
 #!/usr/bin/env python
 """Mechanical reader for the BENCH_r*.json trajectory.
 
-Each bench round (bench.py) emits one JSON document — headline MFU plus
-per-section figures under ``extra`` — and the repo accumulates them as
-``BENCH_r01.json`` .. ``BENCH_rNN.json``.  Until now nothing read two
-rounds side by side; a serving regression had to be eyeballed out of raw
-JSON.  This tool compares two rounds (newest vs previous by default),
+Each ``BENCH_rNN.json`` at the repo root holds one round's JSON document —
+headline MFU plus per-section figures under ``extra``.  This tool compares two rounds (newest vs previous by default),
 prints per-section deltas for every shared numeric leaf, and exits
 nonzero when a metric moved past the regression threshold in its bad
 direction.
@@ -67,7 +64,7 @@ def classify(path: str) -> Optional[bool]:
 
 def load_round(path: str) -> dict:
     """A round's parsed result — accepts both the driver wrapper
-    ({n, cmd, rc, parsed}) and a bare bench.py document."""
+    ({n, cmd, rc, parsed}) and a bare result document."""
     with open(path) as f:
         doc = json.load(f)
     if isinstance(doc, dict) and "parsed" in doc:
