@@ -63,11 +63,19 @@ class LLMConfig:
 
     model_config: Any = None  # a models.llama.LlamaConfig (or compatible)
     max_batch_size: int = 8
-    # tokens decoded per dispatch (multi-step scheduling): the whole chunk
-    # runs as ONE device program with stop/budget handling in-program, so
-    # per-dispatch host latency is amortized over `decode_chunk` tokens.
-    # 1 = sync every token (lowest streaming latency).
-    decode_chunk: int = 8
+    # the decode quantum: token-steps one decode dispatch carries.  The
+    # whole chunk is ONE device program with stop/budget handling
+    # in-program, and step() pipelines at every value (it dispatches chunk
+    # N+1, then reads chunk N), so the host's work a step hides behind the
+    # device as long as one chunk outlasts it.  What the quantum trades: a
+    # request that arrives waits for the chunk in flight and the one queued
+    # behind it before any of its own work runs, and for one more per
+    # further prompt chunk, so time to first token counts quanta; against
+    # that, whatever a dispatch costs beyond its token-steps is paid once a
+    # quantum, and a model whose token-step is shorter than the host's step
+    # needs a longer quantum to keep the device fed.  The measured curve
+    # (ms a token-step at 1, 2, 4, 8) is in PERF.md section 6, PR 30.
+    decode_chunk: int = 2
     max_seq_len: Optional[int] = None  # default: model_config.max_seq_len
     # --- KV cache layout (reference capability boundary: paged attention /
     # chunked prefill / prefix caching come from vLLM engine_kwargs,

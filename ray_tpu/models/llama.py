@@ -608,8 +608,15 @@ def decode_step_paged(cfg: LlamaConfig, params: Params, tokens: jnp.ndarray,
         lp, li = inp
         with jax.named_scope("attention"):
             h = rms_norm(x, lp["attn_norm"], cfg.rms_norm_eps)
-            q = (h @ lp["wq"].astype(cdt)).reshape(b, 1, cfg.n_heads, cfg.head_dim)
-            k = (h @ lp["wk"].astype(cdt)).reshape(b, 1, cfg.n_kv_heads, cfg.head_dim)
+            # the barrier keeps the two projections plain [B, dim] x [dim, n]
+            # products: without it the compiler folds the head reshape and
+            # the rotation's split into them as one product a head, which
+            # wants wq and wk transposed, and it transposes the whole
+            # stacked weights once a dispatch (PERF.md section 6, PR 30)
+            q, k = lax.optimization_barrier(
+                (h @ lp["wq"].astype(cdt), h @ lp["wk"].astype(cdt)))
+            q = q.reshape(b, 1, cfg.n_heads, cfg.head_dim)
+            k = k.reshape(b, 1, cfg.n_kv_heads, cfg.head_dim)
             v = (h @ lp["wv"].astype(cdt)).reshape(b, 1, cfg.n_kv_heads, cfg.head_dim)
             q = apply_rope(q, cos, sin, positions=lengths[:, None])
             k = apply_rope(k, cos, sin, positions=lengths[:, None])[:, 0]

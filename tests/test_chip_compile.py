@@ -144,7 +144,9 @@ def test_paged_decode_attention_compiles_at_cell_shapes_under_4_shard_map(
 # as 6 GB of temporaries, which the chip has no room for.
 
 
-def _prefill_chunk_program(c, params_sh, pool_sh, rep_sh, tp_plan=None):
+def _cell_model(params_sh, pool_sh):
+    """``(cfg, params, pool)`` of the serving cell as shapes: ``params_sh`` is
+    one sharding for every weight, or ``f(cfg, shapes)`` that places them."""
     from ray_tpu.models import llama
 
     cfg = llama.LlamaConfig(
@@ -160,6 +162,13 @@ def _prefill_chunk_program(c, params_sh, pool_sh, rep_sh, tp_plan=None):
                               shapes)
     pool = {n: _spec((16, 6000, 16, 8 * 128), BF16, pool_sh)
             for n in ("k", "v")}
+    return cfg, params, pool
+
+
+def _prefill_chunk_program(c, params_sh, pool_sh, rep_sh, tp_plan=None):
+    from ray_tpu.models import llama
+
+    cfg, params, pool = _cell_model(params_sh, pool_sh)
     fn = jax.jit(
         lambda p, t, pl, tb, p0: llama.prefill_chunk_paged(
             cfg, p, t, pl, tb, p0, tp_plan=tp_plan), donate_argnums=2)
@@ -195,6 +204,44 @@ def test_paged_prefill_chunk_compiles_for_4_shards(tensor_mesh, overlap):
     assert "while" in compiled.as_text()
     # a shard holds a quarter of the pool and copies none of it
     assert compiled.memory_analysis().temp_size_in_bytes < 1 << 30
+
+
+# -- the engine's decode program at the serving cell's shapes -------------------
+
+
+@pytest.mark.parametrize("quantum", ["default", 8])
+def test_decode_chunk_program_transposes_no_weights(one_chip, quantum):
+    """``PagedJaxLLMEngine._decode_chunk_impl`` (the engine's own function,
+    its ``self`` a stand-in: nothing can be placed on a described device)
+    over batch 64 and the 32-block table: the kernel is in the program, and
+    the program keeps no copy of a stacked weight.  Before PR 30 it
+    transposed all of ``wq`` and ``wk`` (0.63 GB of temporaries) at the start
+    of every dispatch, which is what a short decode quantum paid for."""
+    import types
+
+    from ray_tpu.llm import LLMConfig
+    from ray_tpu.llm.engine import _MAX_STOP_IDS
+    from ray_tpu.llm.paged import PagedJaxLLMEngine
+
+    cfg, params, pool = _cell_model(one_chip, one_chip)
+    eng = types.SimpleNamespace(
+        cfg=cfg, max_seq=cfg.max_seq_len, mesh=None, _rope=None,
+        _use_kernel=True, _kernel_interpret=False, _tp_plan=None)
+    key = jax.eval_shape(lambda: jax.random.PRNGKey(0))
+    b, w = 64, 32
+
+    def i32(*shape):
+        return _spec(shape, jnp.int32, one_chip)
+
+    steps = LLMConfig().decode_chunk if quantum == "default" else quantum
+    compiled = jax.jit(
+        functools.partial(PagedJaxLLMEngine._decode_chunk_impl, eng),
+        donate_argnums=2, static_argnums=11).lower(
+            params, i32(b), pool, i32(b, w), i32(b), i32(b), i32(b),
+            i32(b, _MAX_STOP_IDS), _spec(key.shape, key.dtype, one_chip),
+            _spec((b,), jnp.float32, one_chip), i32(b), steps).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+    assert compiled.memory_analysis().temp_size_in_bytes < 64 << 20
 
 
 # -- flash attention: the 1.14 B train shape and the 8 B widths ----------------
