@@ -61,7 +61,10 @@ class SpeculativeConfig:
 class LLMConfig:
     """reference analog: llm/_internal LLMConfig + vLLM engine_kwargs."""
 
-    model_config: Any = None  # a models.llama.LlamaConfig (or compatible)
+    # the config dataclass of a served model family; its TYPE picks the
+    # family (models/family.py family_of): llama.LlamaConfig,
+    # pangu_moe.PanguMoEConfig
+    model_config: Any = None
     max_batch_size: int = 8
     # the decode quantum: token-steps one decode dispatch carries.  The
     # whole chunk is ONE device program with stop/budget handling
@@ -114,8 +117,8 @@ class LLMConfig:
     # many blocks.  0 (default) disables; requires an initialized ray_tpu
     # worker — without one the host tier simply drops its evictions.
     plasma_kv_cache_blocks: int = 0
-    # True -> the pallas TPU paged-attention kernel for decode (TPU,
-    # head_dim % 128 == 0, pp == 1). None = auto: ON where supported. Its
+    # True -> the family's pallas TPU decode-attention kernel (where its
+    # kernel_supported holds, pp == 1). None = auto: ON where supported. Its
     # time follows the decoding rows' live pages (0.10 ms a layer-call at 20
     # rows of 450 tokens in a 64 x 128 table, v5e; PERF.md, PR 25); against
     # the XLA block-gather it has not been measured on this round's code

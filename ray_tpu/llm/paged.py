@@ -6,7 +6,8 @@ vllm/vllm_models.py:177-186 passes engine_kwargs straight through); a
 TPU-native rebuild provides the equivalent itself:
 
   - the KV cache is a POOL of fixed-size HBM blocks shared by every request
-    (`models/llama.py init_paged_kv_cache`); a request's HBM cost is
+    (the model family's ``init_paged_cache``, `models/family.py`: a dict of
+    arrays the engine handles leaf by leaf); a request's HBM cost is
     proportional to its ACTUAL length, not max_seq — admission is
     memory-based (free blocks), not slot-count
   - the device sees a padded block TABLE [B, W] per decode chunk, W bucketed
@@ -14,9 +15,9 @@ TPU-native rebuild provides the equivalent itself:
     attention span than max_seq
   - long prompts prefill in `prefill_chunk`-token pieces interleaved with
     decode chunks, so one long prompt never stalls the running batch
-    (`models/llama.py prefill_chunk_paged` reads earlier chunks back from
-    the pool, a KV tile at a time and only the tiles that hold the live
-    prefix — no growing inter-chunk state, no cost for the table's width)
+    (the family's ``prefill_chunk`` reads earlier chunks back from the
+    pool, a KV tile at a time and only the tiles that hold the live prefix
+    — no growing inter-chunk state, no cost for the table's width)
   - full prompt blocks are chain-hashed and shared across requests
     (refcounted; matches capped at plen-1 so sampling always has a logit)
   - pool exhaustion preempts the youngest running request by RECOMPUTE:
@@ -55,7 +56,7 @@ from ray_tpu.llm.engine import (
 )
 from ray_tpu._private.prefix_hash import chain_hash, prefix_chain_hashes
 from ray_tpu.models import llama
-from ray_tpu.ops.rope import rope_frequencies
+from ray_tpu.models.family import family_of
 from ray_tpu.util import tracing
 
 
@@ -197,7 +198,7 @@ class HostBlockCache:
         self._cap = max(0, capacity_bytes)
         self._plasma_cap = max(0, plasma_blocks)
         self._entries: "collections.OrderedDict[int, Tuple]" = (
-            collections.OrderedDict())  # hash -> (k_np, v_np)
+            collections.OrderedDict())  # hash -> the block's cache leaves
         self._bytes = 0
         self._plasma: "collections.OrderedDict[int, object]" = (
             collections.OrderedDict())  # hash -> ObjectRef
@@ -215,31 +216,31 @@ class HostBlockCache:
         with self._lock:
             return list(self._plasma) + list(self._entries)
 
-    def put(self, h: int, k, v):
-        """Demote one block's KV into the host tier (LRU-evicting over the
-        byte cap into plasma, or dropping when plasma is off/full)."""
+    def put(self, h: int, *leaves):
+        """Demote one block's cache leaves (a Llama block: k and v) into
+        the host tier (LRU-evicting over the byte cap into plasma, or
+        dropping when plasma is off/full)."""
         if self._cap <= 0:
             return
         from ray_tpu._private import runtime_metrics
 
-        nbytes = k.nbytes + v.nbytes
         spill = []
         with self._lock:
             if h in self._entries:
                 self._entries.move_to_end(h)
                 return
             self._plasma.pop(h, None)  # promoted copy supersedes the spill
-            self._entries[h] = (k, v)
-            self._bytes += nbytes
+            self._entries[h] = leaves
+            self._bytes += sum(x.nbytes for x in leaves)
             while self._bytes > self._cap and len(self._entries) > 1:
-                eh, (ek, ev) = self._entries.popitem(last=False)
-                self._bytes -= ek.nbytes + ev.nbytes
-                spill.append((eh, ek, ev))
-        for eh, ek, ev in spill:
+                eh, gone = self._entries.popitem(last=False)
+                self._bytes -= sum(x.nbytes for x in gone)
+                spill.append((eh, gone))
+        for eh, gone in spill:
             runtime_metrics.add_prefix_cache_evictions("host")
-            self._spill_to_plasma(eh, ek, ev)
+            self._spill_to_plasma(eh, gone)
 
-    def _spill_to_plasma(self, h: int, k, v):
+    def _spill_to_plasma(self, h: int, leaves):
         from ray_tpu._private import runtime_metrics
 
         if self._plasma_cap <= 0:
@@ -249,7 +250,7 @@ class HostBlockCache:
 
             if not ray_tpu.is_initialized():
                 return
-            ref = ray_tpu.put((k, v))
+            ref = ray_tpu.put(tuple(leaves))
         except Exception:  # noqa: BLE001 — tiering is best-effort
             return
         with self._lock:
@@ -259,26 +260,27 @@ class HostBlockCache:
                 runtime_metrics.add_prefix_cache_evictions("plasma")
 
     def get(self, h: int):
-        """(k, v, tier) for a cached block, or None.  A plasma hit is
-        promoted back into the host tier (it is about to be hot)."""
+        """(*leaves, tier) for a cached block (a Llama block: k, v, tier),
+        or None.  A plasma hit is promoted back into the host tier (it is
+        about to be hot)."""
         with self._lock:
             got = self._entries.get(h)
             if got is not None:
                 self._entries.move_to_end(h)
-                return got[0], got[1], "host"
+                return (*got, "host")
             ref = self._plasma.get(h)
         if ref is None:
             return None
         try:
             import ray_tpu
 
-            k, v = ray_tpu.get(ref, timeout=5)
+            leaves = ray_tpu.get(ref, timeout=5)
         except Exception:  # noqa: BLE001 — lost spill: treat as a miss
             with self._lock:
                 self._plasma.pop(h, None)
             return None
-        self.put(h, k, v)
-        return k, v, "plasma"
+        self.put(h, *leaves)
+        return (*leaves, "plasma")
 
 
 @dataclasses.dataclass
@@ -446,6 +448,19 @@ class PagedJaxLLMEngine:
         if cfg is None:
             raise ValueError("LLMConfig.model_config is required")
         self.cfg = cfg
+        # everything architecture-specific comes through the family seam
+        fam = self.family = family_of(cfg)
+        if fam.param_specs is None and (
+                config.tensor_parallel_size > 1
+                or config.pipeline_parallel_size > 1):
+            raise ValueError(
+                f"the {fam.name} family supplies no tensor- or "
+                "pipeline-parallel layout: tensor_parallel_size and "
+                "pipeline_parallel_size must be 1")
+        if fam.decode_window is None and config.speculative_config is not None:
+            raise ValueError(
+                f"the {fam.name} family supplies no decode window: "
+                "speculative_config is not supported")
         self.max_batch = config.max_batch_size
         self.max_seq = config.max_seq_len or cfg.max_seq_len
         self.bs = config.block_size
@@ -470,7 +485,7 @@ class PagedJaxLLMEngine:
         # log2(prefill_chunk/bs) prefill programs, all warmed at init.
         # The width costs nothing past the live prefix: the chunk's
         # attention loops over the table's first cdiv(p0 + C, tile) KV
-        # tiles (llama._prefill_attend_tiles) and never reads the rest.
+        # tiles (the family's prefill_chunk) and never reads the rest.
         # Width = the simulated worst case over every prompt length and
         # chunk start (see _prefill_table_width) — pow2 chunk bucketing
         # can cover past max_blocks_per_seq + 2.
@@ -488,8 +503,7 @@ class PagedJaxLLMEngine:
             on_evict=(self._demote_block if self._host_cache is not None
                       else None))
 
-        cos, sin = rope_frequencies(cfg.head_dim, self.max_seq, cfg.rope_theta)
-        self._rope = (jnp.asarray(cos), jnp.asarray(sin))
+        self._rope = fam.rope_cache(cfg, self.max_seq)
 
         pp = config.pipeline_parallel_size
         self.mesh = build_engine_mesh(cfg, config.tensor_parallel_size, pp,
@@ -499,14 +513,14 @@ class PagedJaxLLMEngine:
         decode_out = prefill_out = None  # out_shardings: jit's default
         if self.mesh is None:
             self.params = (params if params is not None
-                           else llama.init_params(cfg, pkey))
-            self.pool = llama.init_paged_kv_cache(cfg, nb, self.bs)
+                           else fam.init_params(cfg, pkey))
+            self.pool = fam.init_paged_cache(cfg, nb, self.bs)
         else:
             from jax.sharding import NamedSharding, PartitionSpec
 
             from ray_tpu.parallel.mesh import shard_pytree
 
-            pspecs = pp_param_specs(llama.inference_param_specs(cfg), pp)
+            pspecs = pp_param_specs(fam.param_specs(cfg), pp)
             if params is not None:
                 self.params = shard_pytree(params, pspecs, self.mesh)
             else:
@@ -515,7 +529,7 @@ class PagedJaxLLMEngine:
                 # never be materialized on one device first.  Same values
                 # as the unsharded init (partitionable threefry).
                 self.params = jax.jit(
-                    lambda k: llama.init_params(cfg, k),
+                    lambda k: fam.init_params(cfg, k),
                     out_shardings=jax.tree.map(
                         lambda s: NamedSharding(self.mesh, s), pspecs))(pkey)
             # the paged pool shards on the folded kv-head dim, matching
@@ -527,9 +541,9 @@ class PagedJaxLLMEngine:
             # sharded like the weights: a pool sized to fill N chips does
             # not fit the first one.
             pool_sh = {k: NamedSharding(self.mesh, s) for k, s in
-                       pp_cache_spec(llama.paged_kv_cache_spec(), pp).items()}
+                       pp_cache_spec(fam.paged_cache_spec(), pp).items()}
             self.pool = jax.jit(
-                lambda: llama.init_paged_kv_cache(cfg, nb, self.bs),
+                lambda: fam.init_paged_cache(cfg, nb, self.bs),
                 out_shardings=pool_sh)()
             # Every small array a program takes or returns is COMMITTED,
             # replicated over the mesh: what the host uploads (_put) and
@@ -596,7 +610,8 @@ class PagedJaxLLMEngine:
         # replica's ledger row under "engine"): plain numbers, written
         # under self._lock (loop_idle_s: by the server's loop thread
         # alone), read without it — a reader subtracts two reads
-        self._c: Dict[str, float] = dict.fromkeys(_COUNTERS, 0)
+        self._c: Dict[str, float] = dict.fromkeys(
+            _COUNTERS + fam.decode_counters, 0)
         self._c.update(host_s=0.0, device_wait_s=0.0, loop_idle_s=0.0)
         self._drains: Dict[str, int] = {}
         # why the device mirrors went stale (first cause since the last
@@ -628,7 +643,7 @@ class PagedJaxLLMEngine:
         # uses the gather path (the layer scan spans all stages, so a
         # pipeline-sharded pool cannot feed per-shard page DMAs).
         self._kernel_interpret = False
-        supported = (llama.paged_kernel_supported(cfg)
+        supported = (fam.kernel_supported(cfg)
                      and config.pipeline_parallel_size <= 1)
         want = config.paged_attention_kernel
         if want is None:
@@ -645,8 +660,10 @@ class PagedJaxLLMEngine:
             self._kernel_interpret = jax.default_backend() != "tpu"
         elif want and not supported:
             raise ValueError(
-                "paged_attention_kernel=True needs a TPU backend, "
-                "head_dim % 128 == 0, and pipeline_parallel_size == 1")
+                f"paged_attention_kernel=True: the {fam.name} family's "
+                "decode kernel does not apply here (its kernel_supported "
+                "wants a TPU backend and the family's own shape rules) or "
+                "pipeline_parallel_size > 1")
         else:
             self._use_kernel = bool(want)
         self._decode = jax.jit(self._decode_chunk_impl, donate_argnums=2,
@@ -654,18 +671,19 @@ class PagedJaxLLMEngine:
         self._prefill_chunk = jax.jit(self._prefill_chunk_impl,
                                       donate_argnums=2,
                                       out_shardings=prefill_out)
+        # the pool's leaves, in the order the host tier and the handoff
+        # carry them (a Llama pool: k, v)
+        self.cache_leaves: Tuple[str, ...] = tuple(sorted(self.pool))
+
+        def scatter_blocks(pool, idx, blocks):
+            return {n: pool[n].at[:, idx].set(blocks[n]) for n in pool}
+
         # tier revival: scatter one host-cached block back into the pool
         # (fixed shapes -> exactly one compile)
-        self._upload_block = jax.jit(
-            lambda pool, b, k, v: {"k": pool["k"].at[:, b].set(k),
-                                   "v": pool["v"].at[:, b].set(v)},
-            donate_argnums=0)
+        self._upload_block = jax.jit(scatter_blocks, donate_argnums=0)
         # disaggregated handoff import: scatter a request's blocks (padded
         # to a pow2 count; pad rows land in sink block 0) into the pool
-        self._import_blocks = jax.jit(
-            lambda pool, idx, k, v: {"k": pool["k"].at[:, idx].set(k),
-                                     "v": pool["v"].at[:, idx].set(v)},
-            donate_argnums=0)
+        self._import_blocks = jax.jit(scatter_blocks, donate_argnums=0)
 
         # --- draft-model speculative decoding ---------------------------
         # The disabled path (speculative_config=None) stops HERE: no draft
@@ -689,13 +707,12 @@ class PagedJaxLLMEngine:
                     f"num_speculative_tokens must be >= 1 (got {k})")
             self._spec_k = k
             self._draft_cfg = dcfg
+            dfam = self._draft_family = family_of(dcfg)
             if draft_params is None:
-                draft_params = llama.init_params(
+                draft_params = dfam.init_params(
                     dcfg, key or jax.random.PRNGKey(1))
             self._draft_params = draft_params
-            dcos, dsin = rope_frequencies(dcfg.head_dim, self.max_seq,
-                                          dcfg.rope_theta)
-            self._draft_rope = (jnp.asarray(dcos), jnp.asarray(dsin))
+            self._draft_rope = dfam.rope_cache(dcfg, self.max_seq)
             dnb = self._spec.draft_num_blocks or nb
             self._draft_num_blocks = dnb
             # no prefix caching in the draft pool: draft KV is never
@@ -703,7 +720,7 @@ class PagedJaxLLMEngine:
             # and chain bookkeeping would double the admission work)
             self.draft_blocks = BlockManager(dnb, self.bs,
                                              prefix_caching=False)
-            self._draft_pool = llama.init_paged_kv_cache(dcfg, dnb, self.bs)
+            self._draft_pool = dfam.init_paged_cache(dcfg, dnb, self.bs)
             if self.mesh is not None:
                 from jax.sharding import PartitionSpec as P
 
@@ -716,17 +733,18 @@ class PagedJaxLLMEngine:
                 # collectives in every draft program, while the target's
                 # verify window runs fully sharded.
                 rep = jax.tree_util.tree_map(
-                    lambda _: P(), llama.inference_param_specs(dcfg),
+                    lambda _: P(), dfam.param_specs(dcfg),
                     is_leaf=lambda x: isinstance(x, P))
                 self._draft_params = shard_pytree(
                     self._draft_params, rep, self.mesh)
                 self._draft_pool = shard_pytree(
-                    self._draft_pool, {"k": P(), "v": P()}, self.mesh)
+                    self._draft_pool,
+                    {n: P() for n in self._draft_pool}, self.mesh)
             self._d_spec = None  # device mirror of per-slot spec enable
             # draft chunked prefill: same chunk/table geometry as the
             # target (block_size is shared, so the fixed width carries)
             self._draft_prefill = jax.jit(
-                lambda p, tok, pool, tab, p0: llama.prefill_chunk_paged(
+                lambda p, tok, pool, tab, p0: dfam.prefill_chunk(
                     self._draft_cfg, p, tok, pool, tab, p0,
                     rope_cache=self._draft_rope)[1],
                 donate_argnums=2)
@@ -800,6 +818,7 @@ class PagedJaxLLMEngine:
         with self._lock:
             active = sum(1 for r in self._slot_req if r is not None)
             free = self.blocks.num_free()
+            cached = len(self.blocks.free_cached)
             pending = len(self._pending)
         total = self.num_blocks - 1
         row = {
@@ -807,8 +826,10 @@ class PagedJaxLLMEngine:
             "deployment": self._slo_label,
             "slots": {"active": active, "max": self.max_batch,
                       "free": self.max_batch - active},
+            # cached: free blocks that still hold a registered prefix (a
+            # later match revives them; allocation pressure evicts them)
             "kv_blocks": {"total": total, "free": free,
-                          "used": total - free},
+                          "used": total - free, "cached": cached},
             "pending": pending,
             "counters": self.counters(),
         }
@@ -866,7 +887,10 @@ class PagedJaxLLMEngine:
         ``engine.collect`` / ``engine.drain``, and everything else);
         ``loop_idle_s`` (the serving loop found no work); ``compiles`` /
         ``compile_s`` (backend compiles in this process since
-        ``warmup()`` returned: anything above zero ran inside serving).
+        ``warmup()`` returned: anything above zero ran inside serving);
+        and the family's ``decode_counters``, booked by the decode program
+        itself a token-step (the expert family: ``moe_experts_held``,
+        ``moe_experts_hit``, ``moe_pairs_here``, see models/pangu_moe.py).
         """
         out = dict(self._c)
         out["drains"] = dict(self._drains)
@@ -996,7 +1020,8 @@ class PagedJaxLLMEngine:
 
         def one(carry, _):
             tokens, pool, lengths, active, remaining, key = carry
-            logits, pool = llama.decode_step_paged(
+            # a family with decode_counters returns them as a third value
+            logits, pool, *booked = self.family.decode_step(
                 self.cfg, params, tokens, pool, table, lengths,
                 rope_cache=self._rope, use_kernel=self._use_kernel,
                 mesh=self.mesh, kernel_interpret=self._kernel_interpret,
@@ -1011,7 +1036,8 @@ class PagedJaxLLMEngine:
                                    | (lengths + 1 >= self.max_seq))
             active = active * (1 - done.astype(active.dtype))
             tokens = jnp.where(active > 0, ids, tokens)
-            return (tokens, pool, lengths, active, remaining, key), emitted
+            carry = (tokens, pool, lengths, active, remaining, key)
+            return carry, ((emitted, booked[0]) if booked else emitted)
 
         carry = (tokens, pool, lengths, active, remaining, key)
         carry, emitted = jax.lax.scan(one, carry, None, length=n_steps)
@@ -1022,7 +1048,7 @@ class PagedJaxLLMEngine:
                             sample_idx, key, temp, top_k):
         """One chunk; also samples the token at chunk-local position
         ``sample_idx`` (the caller uses it only on the final chunk)."""
-        logits, pool = llama.prefill_chunk_paged(
+        logits, pool = self.family.prefill_chunk(
             self.cfg, params, tokens, pool, table, p0, rope_cache=self._rope,
             tp_plan=self._tp_prefill_plan)
         key, sub = jax.random.split(key)
@@ -1049,7 +1075,7 @@ class PagedJaxLLMEngine:
         def one(carry, j):
             tok, pool, key = carry
             cur = jnp.minimum(lengths + j, self.max_seq - 1)
-            logits, pool = llama.decode_step_paged(
+            logits, pool = self._draft_family.decode_step(
                 self._draft_cfg, params, tok, pool, table, cur,
                 rope_cache=self._draft_rope)
             key, sub = jax.random.split(key)
@@ -1091,7 +1117,7 @@ class PagedJaxLLMEngine:
         k = self._spec_k
         b = tokens.shape[0]
         window = jnp.concatenate([tokens[:, None], drafted.T], axis=1)
-        logits, pool = llama.decode_window_paged(
+        logits, pool = self.family.decode_window(
             self.cfg, params, window, pool, table, lengths,
             rope_cache=self._rope, pos_limit=self.max_seq,
             tp_plan=self._tp_verify_plan)
@@ -1204,9 +1230,9 @@ class PagedJaxLLMEngine:
         from ray_tpu._private import runtime_metrics
 
         with tracing.region("kv.demote", blocks=1):
-            k = np.asarray(self.pool["k"][:, block])
-            v = np.asarray(self.pool["v"][:, block])
-            self._host_cache.put(h, k, v)
+            self._host_cache.put(h, *(
+                np.asarray(self.pool[n][:, block])
+                for n in self.cache_leaves))
         self._c["kv_demotions"] += 1
         runtime_metrics.add_prefix_cache_evictions("hbm")
 
@@ -1239,13 +1265,12 @@ class PagedJaxLLMEngine:
                 fresh = self.blocks.alloc(1)
                 if fresh is None:
                     break  # pool full: revival loses to live requests
-                k, v, tier = got
+                *leaves, tier = got
                 b = fresh[0]
-                kd = self.pool["k"].dtype
                 self.pool = self._upload_block(
                     self.pool, jnp.int32(b),
-                    jnp.asarray(np.asarray(k, dtype=kd)),
-                    jnp.asarray(np.asarray(v, dtype=kd)))
+                    {n: jnp.asarray(np.asarray(x, dtype=self.pool[n].dtype))
+                     for n, x in zip(self.cache_leaves, leaves)})
                 self.blocks.adopt(b, chain[i])
                 shared.append(b)
                 revived.append(tier)
@@ -1490,9 +1515,8 @@ class PagedJaxLLMEngine:
                 self._c["prefill_padded_tokens"] += c - take
                 self._c["prefill_live_pages"] += math.ceil(
                     (p0 + take) / self.bs)
-                tile = llama.PREFILL_KV_TILE
                 self._c["prefill_visited_pages"] += (
-                    math.ceil((p0 + c) / tile) * tile // self.bs)
+                    self.family.prefill_visited_pages(p0, c, self.bs))
                 if self._tp_collectives is not None:
                     self._book_tp_collectives(
                         "prefill",
@@ -1713,10 +1737,17 @@ class PagedJaxLLMEngine:
         exactly for short generations) BEFORE the emit loop, so a
         request finishing mid-collect reports final stats at its
         terminal booking.  Dead slots (zero emissions) book nothing."""
+        booked = None
+        if isinstance(em_dev, tuple):  # the family's decode_counters ride
+            em_dev, booked = em_dev    # beside the emitted tokens
         with tracing.region("engine.collect", slots=len(active)):
             t0 = time.monotonic()
             em = np.asarray(em_dev)  # fences this chunk (a later may run on)
             self._step_wait += time.monotonic() - t0
+        if booked is not None:
+            for name, n in zip(self.family.decode_counters,
+                               np.asarray(booked).sum(0)):
+                self._c[name] += int(n)
         if spec_slots:
             acc = np.asarray(acc_dev)
             proposed = accepted = 0
@@ -2036,8 +2067,10 @@ class PagedJaxLLMEngine:
         engine's prefix cache, so the source keeps serving chain hits
         for the prompt it just handed off.
 
-        Returns {prompt, first_token, k, v, block_size, emitted, gen}:
-        k/v are host arrays [L, nblocks, block_size, kv_dim] covering
+        Returns {prompt, first_token, <the cache's leaves>, block_size,
+        emitted, gen}: each leaf of the family's paged cache under its own
+        name (a Llama handoff: k and v), host arrays [L, nblocks,
+        block_size, width] covering
         exactly the live positions (prompt + generated-so-far), emitted
         is the full output-token history, and gen carries the sampling /
         stop / budget state.  Raises if the request isn't in the
@@ -2075,13 +2108,13 @@ class PagedJaxLLMEngine:
             nb_live = max(1, math.ceil(live / self.bs))
             blocks = list(req.blocks)[:nb_live]
             barr = jnp.asarray(np.asarray(blocks, np.int32))
-            # one gather program + readback; [L, nb, bs, D]
-            k = np.asarray(self.pool["k"][:, barr])
-            v = np.asarray(self.pool["v"][:, barr])
+            # one gather program + readback a leaf; [L, nb, bs, D]
             g = req.gen
             out = {"prompt": list(req.prompt),
                    "first_token": int(req.out_tokens[0]),
-                   "k": k, "v": v, "block_size": self.bs,
+                   **{n: np.asarray(self.pool[n][:, barr])
+                      for n in self.cache_leaves},
+                   "block_size": self.bs,
                    "emitted": [int(t) for t in req.out_tokens],
                    "gen": {"max_new_tokens": g.max_new_tokens,
                            "temperature": g.temperature,
@@ -2093,10 +2126,12 @@ class PagedJaxLLMEngine:
             return out
 
     def import_request(self, prompt: Sequence[int], first_token: int,
-                       k, v, gen: Optional[GenerationConfig] = None,
+                       k, v=None, gen: Optional[GenerationConfig] = None,
                        emitted: Optional[Sequence[int]] = None):
         """Admit a request directly into the decode state from handed-off
-        KV: allocates pool blocks, scatters the KV in, registers the
+        KV (``k`` and ``v`` as ``export_request`` gave them; a family whose
+        cache has other leaves hands the dict of them as ``k`` and leaves
+        ``v`` out): allocates pool blocks, scatters the KV in, registers the
         prompt's chain for prefix sharing, and resumes decode.  Two
         callers: the decode stage of a disaggregated deployment
         (``emitted`` omitted — ``first_token`` is emitted as the
@@ -2137,7 +2172,13 @@ class PagedJaxLLMEngine:
             raise ValueError(
                 f"prompt ({plen}) + max_new_tokens ({gen.max_new_tokens})"
                 f" exceeds max_seq_len {self.max_seq}")
-        nb = int(k.shape[1])
+        leaves = k if isinstance(k, dict) else {"k": k, "v": v}
+        if set(leaves) != set(self.cache_leaves):
+            raise ValueError(
+                f"handoff carries cache leaves {sorted(leaves)}, the "
+                f"{self.family.name} family's pool has "
+                f"{list(self.cache_leaves)}")
+        nb = int(leaves[self.cache_leaves[0]].shape[1])
         if nb != max(1, math.ceil(live / self.bs)):
             raise ValueError(
                 f"handoff covers {nb} blocks but {live} live tokens "
@@ -2152,16 +2193,16 @@ class PagedJaxLLMEngine:
             if blocks is None:
                 return None
             pad = _bucket_pow2(nb)
-            kd = self.pool["k"].dtype
             idx = np.zeros(pad, np.int32)
             idx[:nb] = blocks  # pad rows scatter into sink block 0
-            kp = np.zeros((k.shape[0], pad) + tuple(k.shape[2:]), dtype=kd)
-            vp = np.zeros_like(kp)
-            kp[:, :nb] = np.asarray(k, dtype=kd)
-            vp[:, :nb] = np.asarray(v, dtype=kd)
+            padded = {}
+            for n, x in leaves.items():
+                dt = self.pool[n].dtype
+                xp = np.zeros((x.shape[0], pad) + tuple(x.shape[2:]), dtype=dt)
+                xp[:, :nb] = np.asarray(x, dtype=dt)
+                padded[n] = jnp.asarray(xp)
             self.pool = self._import_blocks(
-                self.pool, jnp.asarray(idx), jnp.asarray(kp),
-                jnp.asarray(vp))
+                self.pool, jnp.asarray(idx), padded)
             self._req_counter += 1
             req = _PagedReq(self._req_counter, list(prompt), gen)
             req.slot = slot
@@ -2318,7 +2359,7 @@ class PagedJaxLLMEngine:
                     self.params, zi(b), self.pool, zi(b, w), zi(b), zi(b),
                     zi(b), stops, key, zf(b), zi(b), steps)
                 self.pool = out[2]
-                np.asarray(out[0])  # force compile + run to completion
+                jax.block_until_ready(out[0])  # compile + run to completion
                 decode_widths.append(w)
                 if w >= w_cap:
                     break
@@ -2372,10 +2413,10 @@ class PagedJaxLLMEngine:
         toks[0, :n] = prompt[:-1]
 
         def run(params, pool, toks, table, last, length):
-            _, pool = llama.prefill_chunk_paged(
+            _, pool = self.family.prefill_chunk(
                 self.cfg, params, toks, pool, table, jnp.int32(0),
                 rope_cache=self._rope, tp_plan=self._tp_prefill_plan)
-            logits, pool = llama.decode_step_paged(
+            logits, pool, *_ = self.family.decode_step(
                 self.cfg, params, last, pool, table, length,
                 rope_cache=self._rope, use_kernel=self._use_kernel,
                 mesh=self.mesh, kernel_interpret=self._kernel_interpret,

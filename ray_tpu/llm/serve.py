@@ -214,19 +214,17 @@ class LLMServer:
     def reference_check(self, prompt: Sequence[int],
                         served: Sequence[int]) -> Dict[str, Any]:
         """Hold greedy tokens this replica served for ``prompt`` against
-        the plain float32 forward of the same weights
-        (``models/llama_reference.py``), teacher-forced over prompt +
+        the plain float32 forward of the same weights (the model family's
+        ``reference_logits``, models/family.py), teacher-forced over prompt +
         served: for each served token, the reference logit it gives up
         against the reference's own argmax at that position (0 where they
         agree).  The caller states the tolerance."""
         import numpy as np
 
-        from ray_tpu.models.llama_reference import reference_logits
-
         eng = self._engine
         seq = list(prompt) + list(served)
-        rows = np.asarray(reference_logits(eng.cfg, eng.params, seq[:-1]))[
-            len(prompt) - 1:]
+        rows = np.asarray(eng.family.reference_logits(
+            eng.cfg, eng.params, seq[:-1], first_row=len(prompt) - 1))
         served = np.asarray(served)
         gaps = rows.max(-1) - rows[np.arange(len(served)), served]
         argmax = rows.argmax(-1)
@@ -693,12 +691,12 @@ class LLMServer:
         model = handoff.get("model")
         emitted = [int(t) for t in handoff["emitted"]]
         res = None
-        if not model and handoff.get("k") is not None:
+        leaves = {n: handoff.get(n) for n in self._engine.cache_leaves}
+        if not model and all(x is not None for x in leaves.values()):
             try:
                 res = self._engine.import_request(
-                    handoff["prompt"], handoff["first_token"],
-                    handoff["k"], handoff["v"], self._handoff_gen(handoff),
-                    emitted=emitted)
+                    handoff["prompt"], handoff["first_token"], leaves,
+                    gen=self._handoff_gen(handoff), emitted=emitted)
             except ValueError:
                 # geometry mismatch (block size / max_seq) — recompute
                 # is the only road

@@ -864,3 +864,43 @@ def flops_per_token(cfg: LlamaConfig, seq_len: int) -> float:
     n = cfg.num_params
     attn = 12 * cfg.n_layers * cfg.dim * seq_len  # 2*2*3 * L * d * s (fwd+bwd, causal half)
     return 6.0 * n + attn
+
+
+# ---------------------------------------------------------------------------
+# The family seam (models/family.py): what the paged engine takes from here.
+# ---------------------------------------------------------------------------
+
+
+def _rope_cache(cfg: LlamaConfig, max_seq: int):
+    cos, sin = rope_frequencies(cfg.head_dim, max_seq, cfg.rope_theta)
+    return jnp.asarray(cos), jnp.asarray(sin)
+
+
+def _prefill_visited_pages(p0: int, chunk: int, block_size: int) -> int:
+    """Pages of the whole KV tiles ``_prefill_attend_tiles`` visits."""
+    tile = PREFILL_KV_TILE
+    return math.ceil((p0 + chunk) / tile) * tile // block_size
+
+
+def _reference_logits(cfg, params, tokens, first_row: int = 0):
+    from ray_tpu.models.llama_reference import reference_logits
+
+    return reference_logits(cfg, params, tokens)[first_row:]
+
+
+def _family():
+    from ray_tpu.models.family import ModelFamily
+
+    return ModelFamily(
+        name="llama", config_type=LlamaConfig, init_params=init_params,
+        init_paged_cache=init_paged_kv_cache, rope_cache=_rope_cache,
+        prefill_chunk=prefill_chunk_paged, decode_step=decode_step_paged,
+        kernel_supported=paged_kernel_supported,
+        prefill_visited_pages=_prefill_visited_pages,
+        reference_logits=_reference_logits,
+        param_specs=inference_param_specs,
+        paged_cache_spec=paged_kv_cache_spec,
+        decode_window=decode_window_paged)
+
+
+FAMILY = _family()

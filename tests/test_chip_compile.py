@@ -217,31 +217,119 @@ def test_decode_chunk_program_transposes_no_weights(one_chip, quantum):
     the program keeps no copy of a stacked weight.  Before PR 30 it
     transposed all of ``wq`` and ``wk`` (0.63 GB of temporaries) at the start
     of every dispatch, which is what a short decode quantum paid for."""
-    import types
-
     from ray_tpu.llm import LLMConfig
-    from ray_tpu.llm.engine import _MAX_STOP_IDS
-    from ray_tpu.llm.paged import PagedJaxLLMEngine
 
     cfg, params, pool = _cell_model(one_chip, one_chip)
+    steps = LLMConfig().decode_chunk if quantum == "default" else quantum
+    compiled = _engine_programs(cfg, params, pool, one_chip, 64, 32, steps,
+                                256, 264)[0].compile()
+    assert "tpu_custom_call" in compiled.as_text()
+    assert compiled.memory_analysis().temp_size_in_bytes < 64 << 20
+
+
+def _engine_programs(cfg, params, pool, sh, b, w, steps, c, prefill_w):
+    """The engine's own two programs, lowered: ``(decode chunk of ``steps``
+    over batch ``b`` and a ``w``-block table, prefill chunk of ``c`` tokens
+    over a ``prefill_w``-block table)``.  ``self`` is a stand-in: nothing
+    can be placed on a described device."""
+    import types
+
+    from ray_tpu.llm.engine import _MAX_STOP_IDS
+    from ray_tpu.llm.paged import PagedJaxLLMEngine
+    from ray_tpu.models.family import family_of
+
     eng = types.SimpleNamespace(
-        cfg=cfg, max_seq=cfg.max_seq_len, mesh=None, _rope=None,
-        _use_kernel=True, _kernel_interpret=False, _tp_plan=None)
+        cfg=cfg, family=family_of(cfg), max_seq=cfg.max_seq_len, mesh=None,
+        _rope=None, _use_kernel=True, _kernel_interpret=False, _tp_plan=None,
+        _tp_prefill_plan=None)
     key = jax.eval_shape(lambda: jax.random.PRNGKey(0))
-    b, w = 64, 32
+    key = _spec(key.shape, key.dtype, sh)
 
     def i32(*shape):
-        return _spec(shape, jnp.int32, one_chip)
+        return _spec(shape, jnp.int32, sh)
 
-    steps = LLMConfig().decode_chunk if quantum == "default" else quantum
-    compiled = jax.jit(
+    decode = jax.jit(
         functools.partial(PagedJaxLLMEngine._decode_chunk_impl, eng),
         donate_argnums=2, static_argnums=11).lower(
             params, i32(b), pool, i32(b, w), i32(b), i32(b), i32(b),
-            i32(b, _MAX_STOP_IDS), _spec(key.shape, key.dtype, one_chip),
-            _spec((b,), jnp.float32, one_chip), i32(b), steps).compile()
-    assert "tpu_custom_call" in compiled.as_text()
-    assert compiled.memory_analysis().temp_size_in_bytes < 64 << 20
+            i32(b, _MAX_STOP_IDS), key, _spec((b,), jnp.float32, sh), i32(b),
+            steps)
+    prefill = jax.jit(
+        functools.partial(PagedJaxLLMEngine._prefill_chunk_impl, eng),
+        donate_argnums=2).lower(
+            params, i32(1, c), pool, i32(1, prefill_w), i32(), i32(), key,
+            _spec((1,), jnp.float32, sh), i32(1))
+    return decode, prefill
+
+
+# what the two programs of the Llama family lowered to on the commit before
+# the family seam (PR 31), at the Mistral cell's shapes: sha256 of the
+# StableHLO text less the line of the Pallas kernel's call (its payload
+# carries the source lines of its callers, which any edit above them moves;
+# the kernel has tests of its own).  The seam moves models/llama.py behind a
+# table of functions
+# and must change nothing the device runs.  A PR that changes what
+# models/llama.py computes replaces these (print the digests below).
+_LLAMA_HLO_BEFORE_THE_SEAM = {
+    "decode": "8c1a995b1c164c2bbee173418c2c250903ee79e25c3e8dc8dd072601e7965155",
+    "prefill": "275cc165814222833ab09c0d4e8204abf45080899e425dc7519458cf6132e3db",
+}
+
+
+def test_llama_programs_lower_to_the_hlo_from_before_the_family_seam(one_chip):
+    import hashlib
+
+    cfg, params, pool = _cell_model(one_chip, one_chip)
+    decode, prefill = _engine_programs(cfg, params, pool, one_chip, 64, 32,
+                                       2, 256, 264)
+    got = {name: hashlib.sha256("\n".join(
+        line for line in lo.as_text().split("\n")
+        if "tpu_custom_call" not in line).encode()).hexdigest()
+        for name, lo in (("decode", decode), ("prefill", prefill))}
+    assert got == _LLAMA_HLO_BEFORE_THE_SEAM
+
+
+# -- the latent-attention expert family at its cell's shapes ---------------------
+# openPangu-Ultra-MoE widths, 1 dense + 4 expert layers of 16 held experts,
+# 33,000 blocks of 16 positions of a 640-wide latent row.
+
+
+def _pangu_cell(sh):
+    from ray_tpu.models import pangu_moe
+
+    cfg = pangu_moe.PanguMoEConfig()
+    shapes = jax.eval_shape(
+        lambda: pangu_moe.init_params(cfg, jax.random.PRNGKey(0)))
+    params = jax.tree.map(lambda x: _spec(x.shape, x.dtype, sh), shapes)
+    pool = {"ckv": _spec((cfg.n_layers, 33000, 16, cfg.cache_width), BF16,
+                         sh)}
+    return cfg, params, pool
+
+
+@pytest.mark.parametrize("w", [64, 1024])
+def test_latent_decode_kernel_compiles_at_cell_shapes(one_chip, w):
+    from ray_tpu.ops.mla_paged_attention import mla_paged_decode_attention
+
+    _assert_kernel(
+        functools.partial(mla_paged_decode_attention, value_width=512,
+                          scale=192 ** -0.5),
+        _spec((64, 128, 640), BF16, one_chip),
+        _spec((5, 33000, 16, 640), BF16, one_chip),
+        _spec((), jnp.int32, one_chip), _spec((64, w), jnp.int32, one_chip),
+        _spec((64,), jnp.int32, one_chip), _spec((64,), jnp.int32, one_chip))
+
+
+def test_latent_family_programs_compile_at_cell_shapes(one_chip):
+    """Decode over the 9,216-position table with the kernel in it, named; a
+    1,024-token prefill chunk whose tile loop copies no pool."""
+    cfg, params, pool = _pangu_cell(one_chip)
+    decode, prefill = _engine_programs(cfg, params, pool, one_chip, 64, 1024,
+                                       2, 1024, 641)
+    text = decode.compile().as_text()
+    assert "tpu_custom_call" in text and "mla_paged_attention" in text
+    compiled = prefill.compile()
+    assert "while" in compiled.as_text()
+    assert compiled.memory_analysis().temp_size_in_bytes < 3 << 29
 
 
 # -- flash attention: the 1.14 B train shape and the 8 B widths ----------------

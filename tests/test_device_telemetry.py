@@ -328,14 +328,16 @@ def test_paged_engine_utilization_matches_internal_books(reset_telemetry):
     with eng._lock:
         active = sum(1 for r in eng._slot_req if r is not None)
         free = eng.blocks.num_free()
+        cached = len(eng.blocks.free_cached)
         pending = len(eng._pending)
     assert u["engine"] == "paged"
     assert u["deployment"] == "tel-paged"
     assert u["slots"] == {"active": active, "max": 2,
                           "free": 2 - active}
     # block 0 is the sink and never allocated: capacity = num_blocks-1
+    # (cached: free blocks that still hold a registered prefix)
     assert u["kv_blocks"] == {"total": 23, "free": free,
-                              "used": 23 - free}
+                              "used": 23 - free, "cached": cached}
     assert u["pending"] == pending
     assert 0.0 <= u["duty_cycle"] <= 1.0
     assert u["rates"]["steps"] == 3

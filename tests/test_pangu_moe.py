@@ -1,0 +1,332 @@
+"""The openPangu-Ultra-MoE family (models/pangu_moe.py) against its plain
+float32 reference, at a small size on the CPU with seeded random weights:
+the latent paged cache through chunked prefill, a prefix hit and decode; the
+absorbed form against the expanded; the shares of the expert layer against
+the whole; the router against a hand-written case; the latent kernel in
+interpret mode; the engine's host tier, handoff and preemption on the latent
+pool; and the family seam's refusals."""
+
+import dataclasses
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from ray_tpu.llm import GenerationConfig, LLMConfig
+from ray_tpu.llm.config import SpeculativeConfig
+from ray_tpu.llm.paged import PagedJaxLLMEngine
+from ray_tpu.models import pangu_moe as pm
+from ray_tpu.models import pangu_moe_reference as ref
+from ray_tpu.models.family import family_of
+
+# float32 program against float32 reference: what differs is the order of
+# the sums (scan, tiles, online softmax, absorbed products).  Logits have a
+# standard deviation of about 0.3 here; 2e-4 is a thousandth of that, and a
+# bf16 cache or weight would miss it by two orders of magnitude.
+TOL = 2e-4
+BS = 8
+
+
+@pytest.fixture(scope="module")
+def model():
+    # 4 of 16 routed experts held: absent experts are left out on both sides
+    cfg = pm.PanguMoEConfig.tiny(experts_held=(4, 8))
+    return cfg, pm.init_params(cfg, jax.random.PRNGKey(7))
+
+
+def _tokens(n, seed=0, vocab=256):
+    return np.random.RandomState(seed).randint(1, vocab, size=n).tolist()
+
+
+def _prefill(cfg, params, pool, toks, table, p0=0, chunk=16):
+    """Chunked prefill of ``toks`` from position ``p0``: last chunk's logits."""
+    rope = pm.make_rope_cache(cfg, cfg.max_seq_len)
+    logits = None
+    for i in range(0, len(toks), chunk):
+        part = toks[i:i + chunk]
+        pad = part + [0] * (chunk - len(part))
+        logits, pool = pm.prefill_chunk_paged(
+            cfg, params, jnp.asarray([pad], jnp.int32), pool, table,
+            jnp.int32(p0 + i), rope_cache=rope, kv_tile=16)
+        logits = logits[0, :len(part)]
+    return logits, pool
+
+
+@pytest.mark.parametrize("prefix_hit", [False, True])
+def test_chunked_prefill_then_decode_agrees_with_reference(model, prefix_hit):
+    """(a) three prefill chunks, then four decode steps through the latent
+    paged cache, logits against the reference's full forward; ``prefix_hit``:
+    the first two chunks' blocks were written by ANOTHER sequence with the
+    same first 32 tokens, and this one prefills only its suffix."""
+    cfg, params = model
+    toks, more = _tokens(44), _tokens(4, seed=9)
+    pool = pm.init_paged_cache(cfg, 32, BS)
+    table = jnp.asarray([[3, 9, 4, 11, 5, 17, 6, 2]], jnp.int32)
+    # one full forward of the reference over prompt + the decoded tokens:
+    # its rows from 32 on are what the last chunk and each decode step give
+    want = np.asarray(ref.reference_logits(cfg, params, toks + more,
+                                           first_row=32))
+    if prefix_hit:
+        other = toks[:32] + _tokens(8, seed=5)
+        _, pool = _prefill(cfg, params, pool, other,
+                           jnp.asarray([[3, 9, 4, 11, 20, 21, 22, 23]]))
+        logits, pool = _prefill(cfg, params, pool, toks[32:], table, p0=32)
+    else:
+        logits, pool = _prefill(cfg, params, pool, toks, table)
+    np.testing.assert_allclose(logits, want[:12], atol=TOL)
+    # decode, teacher-forced with fixed tokens
+    for step, tok in enumerate(more):
+        logits, pool, booked = pm.decode_step_paged(
+            cfg, params, jnp.asarray([tok], jnp.int32), pool, table,
+            jnp.asarray([44 + step], jnp.int32))
+        np.testing.assert_allclose(logits[0], want[12 + step], atol=TOL)
+        assert int(booked[0]) == cfg.n_held * cfg.n_moe_layers
+
+
+def test_absorbed_attention_equals_expanded_on_the_same_cache(model):
+    """(b) a decode step (absorbed) over the cache the expanded prefill
+    wrote gives the expanded prefill's logits of one more token."""
+    cfg, params = model
+    toks = _tokens(40, seed=3)
+    table = jnp.asarray([[1, 2, 3, 4, 5, 6]], jnp.int32)
+    pool = pm.init_paged_cache(cfg, 8, BS)
+    _, pool_39 = _prefill(cfg, params, pool, toks[:32], table)
+    more, _ = _prefill(cfg, params, pool_39, toks[32:], table, p0=32)
+    _, pool_39 = _prefill(cfg, params, pool_39, toks[32:39] + [0], table,
+                          p0=32, chunk=8)
+    dec, _, _ = pm.decode_step_paged(
+        cfg, params, jnp.asarray(toks[39:], jnp.int32), pool_39, table,
+        jnp.asarray([39], jnp.int32))
+    np.testing.assert_allclose(dec[0], more[-1], atol=TOL)
+
+
+def test_shares_of_the_expert_layer_add_up_to_the_whole(model):
+    """(c) 4 chips share the layer's 16 experts, 4 each.  The parts the four
+    shares give (program's ``moe_ffn``), the shared expert counted once, add
+    up to the uncut reference's layer."""
+    cfg, _ = model
+    whole = dataclasses.replace(cfg, experts_held=(0, 16))
+    lp = jax.tree.map(lambda x: x[0],
+                      pm.init_params(whole, jax.random.PRNGKey(3))["moe"])
+    h = jax.random.normal(jax.random.PRNGKey(4), (24, cfg.dim))
+    want = ref.moe_layer(whole, h, lambda n, *i: lp[n][i])
+    shared = ref.moe_layer(dataclasses.replace(whole, experts_held=(0, 0)),
+                           h, lambda n, *i: lp[n][i])
+    total, pairs = 0.0, 0
+    for lo in range(0, 16, 4):
+        share = dataclasses.replace(cfg, experts_held=(lo, lo + 4))
+        f = cfg.moe_ffn_dim
+        held = dict(lp, we_gate=lp["we_gate"][:, lo * f:(lo + 4) * f],
+                    we_up=lp["we_up"][:, lo * f:(lo + 4) * f],
+                    we_down=lp["we_down"][lo * f:(lo + 4) * f])
+        y, g = pm.moe_ffn(share, h, held)
+        # one share's result in the reference too
+        np.testing.assert_allclose(
+            y, ref.moe_layer(share, h, lambda n, *i, w=held: w[n][i]),
+            atol=TOL)
+        total = total + (y - shared)
+        pairs += int((g > 0).sum())
+    np.testing.assert_allclose(total + shared, want, atol=TOL)
+    # every (token, expert) pair lands on exactly one share
+    assert pairs == 24 * cfg.n_experts_per_tok
+
+
+def test_router_against_a_hand_written_case():
+    """(d) sigmoid, 2 of 6, normalised over the chosen, x 2.5, no groups."""
+    cfg = pm.PanguMoEConfig.tiny(dim=2, n_routed_experts=6,
+                                 n_experts_per_tok=2, experts_held=(1, 3))
+    router = jnp.asarray([[1.0, 0.0, -1.0, 2.0, 0.5, -2.0],
+                          [0.0, 1.0, 1.0, -2.0, 0.5, 2.0]])
+    h = jnp.asarray([[1.0, 0.0], [0.0, 1.0], [1.0, 1.0]])
+    gates, idx = pm.route(cfg, h, router)
+    sig = lambda z: 1.0 / (1.0 + np.exp(-z))
+    # token 0: logits (1, 0, -1, 2, .5, -2): experts 3 and 0
+    # token 1: logits (0, 1, 1, -2, .5, 2): expert 5, then 1 (first of a tie)
+    # token 2: logits (1, 1, 0, 0, 1, 0): experts 0 and 1 (first of a tie)
+    assert idx.tolist() == [[3, 0], [5, 1], [0, 1]]
+    for row, zs in zip(np.asarray(gates), ([2.0, 1.0], [2.0, 1.0], [1.0, 1.0])):
+        s = np.array([sig(z) for z in zs])
+        np.testing.assert_allclose(row, 2.5 * s / s.sum(), rtol=1e-6)
+    assert cfg.routed_scaling_factor == 2.5
+    # the held range (experts 1 and 2) sees token 1's and token 2's expert 1
+    g = np.asarray(pm.held_gates(cfg, gates, idx))
+    assert g.shape == (3, 2) and (g[:, 1] == 0).all() and g[0, 0] == 0
+    np.testing.assert_allclose(g[1:, 0], np.asarray(gates)[1:, 1])
+
+
+def test_latent_kernel_in_interpret_mode_against_the_gather_path():
+    """(e) the Pallas kernel over the latent pool, live pages only: rows of
+    different lengths, an idle row, table columns past a row's pages that
+    point at a block of NaN (never read)."""
+    from ray_tpu.ops.mla_paged_attention import mla_paged_decode_attention
+
+    cfg = pm.PanguMoEConfig.tiny(kv_lora_rank=128, qk_rope_head_dim=8)
+    assert cfg.cache_width == 256
+    rng = np.random.RandomState(0)
+    nb, b, wt = 24, 4, 8
+    pool = rng.randn(2, nb, BS, cfg.cache_width).astype(np.float32)
+    pool[..., cfg.latent_width:] = 0
+    pool[:, nb - 1] = np.nan
+    lengths = np.asarray([37, 5, 0, 50], np.int32)
+    active = np.asarray([1, 1, 0, 1], np.int32)
+    table = np.full((b, wt), nb - 1, np.int32)
+    blocks = iter(rng.permutation(np.arange(1, nb - 1)))
+    for r in range(b):
+        for j in range(lengths[r] // BS + 1):
+            table[r, j] = next(blocks)
+    q = rng.randn(b, cfg.n_heads, cfg.cache_width).astype(np.float32)
+    q[..., cfg.latent_width:] = 0
+    got = mla_paged_decode_attention(
+        jnp.asarray(q), jnp.asarray(pool), 1, jnp.asarray(table),
+        jnp.asarray(lengths), jnp.asarray(active),
+        value_width=cfg.kv_lora_rank, scale=1.0 / cfg.qk_head_dim ** 0.5,
+        interpret=True)
+    clean = np.nan_to_num(pool[1])
+    span = clean[table].reshape(b, wt * BS, cfg.cache_width)
+    mask = np.arange(wt * BS)[None, None, :] <= lengths[:, None, None]
+    want = pm._attend_absorbed(cfg, jnp.asarray(q)[:, None],
+                               jnp.asarray(span), jnp.asarray(mask))[:, 0]
+    assert np.isfinite(np.asarray(got)).all()
+    np.testing.assert_allclose(np.asarray(got)[active > 0],
+                               np.asarray(want)[active > 0], atol=1e-4)
+    assert (np.asarray(got)[2] == 0).all()
+
+
+# -- the engine over the latent pool ---------------------------------------------
+
+
+def _engine(cfg, params, **kw):
+    kw.setdefault("max_batch_size", 4)
+    kw.setdefault("block_size", BS)
+    kw.setdefault("prefill_chunk", 16)
+    kw.setdefault("max_seq_len", 96)
+    return PagedJaxLLMEngine(LLMConfig(model_config=cfg, **kw), params=params)
+
+
+def _assert_greedy(cfg, params, prompt, out, n):
+    """``out`` is the reference's greedy continuation of ``prompt``: each
+    token is the float32 reference's argmax at its position, teacher-forced
+    (one forward pass; a token inside TOL of the argmax is a tie)."""
+    assert len(out) == n
+    rows = np.asarray(ref.reference_logits(
+        cfg, params, list(prompt) + list(out[:-1]),
+        first_row=len(prompt) - 1))
+    gaps = rows.max(-1) - rows[np.arange(n), np.asarray(out)]
+    assert gaps.max() <= TOL, gaps
+
+
+def test_engine_serves_the_family_and_books_its_counters(model):
+    cfg, params = model
+    eng = _engine(cfg, params, num_blocks=40)
+    assert eng.family is family_of(cfg) and eng.cache_leaves == ("ckv",)
+    prompt = _tokens(37, seed=11)
+    out = eng.generate([prompt], GenerationConfig(max_new_tokens=6))[0]
+    _assert_greedy(cfg, params, prompt, out, 6)
+    c = eng.counters()
+    assert c["moe_experts_held"] >= 5 * cfg.n_held * cfg.n_moe_layers
+    assert 0 < c["moe_experts_hit"] <= c["moe_experts_held"]
+    assert c["moe_experts_hit"] <= c["moe_pairs_here"]
+    # a second request with the same first 32 tokens is a prefix hit
+    again = eng.generate([prompt[:32] + _tokens(5, seed=12)],
+                         GenerationConfig(max_new_tokens=2))
+    assert len(again[0]) == 2 and eng.counters()["prefix_hit_tokens"] == 32
+    assert eng.utilization()["kv_blocks"]["cached"] > 0
+
+
+def test_engine_kernel_interpret_path_matches_gather(model):
+    cfg = pm.PanguMoEConfig.tiny(kv_lora_rank=128, experts_held=(4, 8))
+    params = pm.init_params(cfg, jax.random.PRNGKey(2))
+    prompt = _tokens(21, seed=13)
+    gen = GenerationConfig(max_new_tokens=5)
+    gather = _engine(cfg, params, paged_attention_kernel=False)
+    kernel = _engine(cfg, params, paged_attention_kernel="interpret")
+    assert kernel._use_kernel and kernel._kernel_interpret
+    assert kernel.generate([prompt], gen) == gather.generate([prompt], gen)
+
+
+def test_host_tier_round_trip_on_the_latent_pool(model):
+    """(f) a pool too small for two prompts' blocks: the first prompt's
+    cached blocks are evicted to the host tier and revived for its second
+    asking, and the answer is the one computed cold."""
+    cfg, params = model
+    gen = GenerationConfig(max_new_tokens=3)
+    first, second = _tokens(40, seed=21), _tokens(40, seed=22)
+    eng = _engine(cfg, params, num_blocks=9, max_batch_size=1)
+    cold = eng.generate([first], gen)[0]
+    eng.generate([second], gen)
+    assert eng.counters()["kv_demotions"] > 0
+    assert len(eng._host_cache) > 0
+    assert eng.generate([first], gen)[0] == cold
+    assert eng.counters()["prefix_hit_tokens"] > 0
+
+
+def test_export_import_round_trip_on_the_latent_pool(model):
+    """(f) a request exported mid-decode and imported into a second engine
+    goes on to the tokens the first would have given."""
+    cfg, params = model
+    prompt = _tokens(29, seed=31)
+    src, dst = _engine(cfg, params), _engine(cfg, params)
+    rid = src.add_request(prompt, GenerationConfig(max_new_tokens=8))
+    got = []
+    while len(got) < 3:
+        got += src.step().get(rid, [])
+    h = src.export_request(rid)
+    assert set(h) >= {"ckv", "emitted", "prompt"} and "k" not in h
+    assert h["ckv"].shape[0] == cfg.n_layers
+    assert h["ckv"].shape[-1] == cfg.cache_width
+    res = dst.import_request(
+        h["prompt"], h["first_token"], {"ckv": h["ckv"]},
+        gen=GenerationConfig(max_new_tokens=8), emitted=h["emitted"])
+    assert res is not None and res["emitted"] == []
+    rest = []
+    while dst.has_work():
+        rest += dst.step().get(res["request_id"], [])
+    dst.flush()
+    _assert_greedy(cfg, params, prompt, h["emitted"] + rest, 8)
+    with pytest.raises(ValueError, match="cache leaves"):
+        dst.import_request(h["prompt"], h["first_token"], h["ckv"], h["ckv"])
+
+
+def test_preemption_by_recompute_on_the_latent_pool(model):
+    """(f) two requests outgrow a pool that holds one: the younger is
+    preempted, recomputed, and both give the reference's tokens."""
+    cfg, params = model
+    prompts = [_tokens(30, seed=41), _tokens(30, seed=42)]
+    eng = _engine(cfg, params, num_blocks=12, max_batch_size=2,
+                  host_kv_cache_bytes=0)
+    outs = eng.generate(prompts, GenerationConfig(max_new_tokens=24))
+    assert eng.counters()["preemptions"] > 0
+    for p, o in zip(prompts, outs):
+        _assert_greedy(cfg, params, p, o, 24)
+
+
+@pytest.mark.parametrize("option, match", [
+    (dict(tensor_parallel_size=2), "pangu_moe family supplies no tensor"),
+    (dict(speculative_config=SpeculativeConfig(
+        draft_model_config=pm.PanguMoEConfig.tiny())),
+     "pangu_moe family supplies no decode window"),
+])
+def test_engine_refuses_what_the_family_does_not_supply(model, option, match):
+    cfg, params = model
+    with pytest.raises(ValueError, match=match):
+        _engine(cfg, params, **option)
+
+
+def test_family_of_an_unknown_config_names_the_families():
+    with pytest.raises(TypeError, match="LlamaConfig.*PanguMoEConfig"):
+        family_of(object())
+
+
+def test_parameter_count_at_the_published_widths():
+    """The benchmark's cut: 1 dense layer, 4 expert layers of 16 held
+    experts, 19,200 rows of vocabulary: 4.92 B parameters."""
+    cfg = pm.PanguMoEConfig()
+    attn = (7680 * 1536 + 1536 * 128 * 192 + 7680 * 576
+            + 2 * 128 * 128 * 512 + 128 * 128 * 7680)
+    norms = 4 * 7680 + 1536 + 512
+    dense = attn + norms + 3 * 7680 * 18432
+    moe = attn + norms + 7680 * 256 + 17 * 3 * 7680 * 2048
+    assert round(attn / 1e6, 1) == 196.6
+    assert cfg.num_params == dense + 4 * moe + 2 * 19200 * 7680 + 7680
+    assert 4.91e9 < cfg.num_params < 4.93e9
