@@ -7,10 +7,14 @@ row stored 640 wide, 16-position pages, bf16).
    checked against the gather path, with each row's roofline share (a live
    position: 128 x (576 + 512) x 2 operations and 1,152 B; the larger of the
    two bounds).
-2. The two forms of a prefill chunk's attention (expanded a KV tile at a
-   time: ``models/pangu_moe.py``, the program's; absorbed: this file's, the
-   form the program does not keep), ms a layer-call for chunks of 256 and
-   1,024 queries against an 8,192-position prefix.
+2. A prefill chunk's attention, ms a layer-call for chunks of 64, 256, 512
+   and 1,024 queries against an 8,192-position prefix, each call depending
+   on the last: the Pallas kernel (``ops/mla_prefill_attention.py``, the
+   program's on a TPU) beside the two ``jax.numpy`` forms (expanded a KV tile
+   at a time: ``models/pangu_moe.py``, the program's elsewhere and what the
+   kernel is held against; absorbed: this file's, the form the program does
+   not keep), with each row's share of the chip's peak by the benchmark's own
+   operation count (``chipbench/model_math_mla_moe.py``).
 
     python benchmarks/mla_kernel_bench.py
 
@@ -157,6 +161,23 @@ def _attend_tiles_absorbed(cfg, q_nope, q_rope, pool, li, row, positions, lp,
     return pm._unabsorb(cfg, (acc / l[..., None]).transpose(1, 0, 2), lp)
 
 
+def _attention_flops(chunk: int, prefix: int) -> float:
+    """Operations one layer-call of a chunk's attention needs, by the
+    benchmark's own count (``chipbench/model_math_mla_moe.prefill_flops`` at
+    one layer, less its matrix multiplies and its head): every (query, key)
+    pair a head in expanded form and the expansion of every position
+    visited."""
+    from chipbench import model_math_mla_moe as mm
+
+    with open(os.path.join(ROOT, "chipbench", "configs",
+                           "openpangu-ultra-moe-ep16.json")) as f:
+        one = {**json.load(f), "num_hidden_layers": 1,
+               "first_k_dense_replace": 1}
+    return (mm.prefill_flops(one, chunk, prefix, chunk)
+            - 2.0 * mm.prefill_matmul_params(one) * chunk
+            - 2.0 * one["hidden_size"] * one["vocab_size"])
+
+
 def prefill_rows(cfg, rehearse: bool):
     import jax
     import jax.numpy as jnp
@@ -165,8 +186,11 @@ def prefill_rows(cfg, rehearse: bool):
     from ray_tpu.models import pangu_moe as pm
 
     prefix = 64 if rehearse else 8192
-    chunks = (16,) if rehearse else (256, 1024)
-    tile = 16 if rehearse else pm.PREFILL_KV_TILE
+    chunks = (16,) if rehearse else (64, 256, 512, 1024)
+    # the jax.numpy forms at the tile PR 31 measured them at
+    tile = 16 if rehearse else 512
+    kernel_tile = 16 if rehearse else pm.PREFILL_KV_TILE
+    calls = 2 if rehearse else 4
     nb = (prefix + max(chunks)) // BS + 8
     key = jax.random.PRNGKey(0)
     pool = jax.jit(lambda k: jnp.pad(
@@ -181,7 +205,15 @@ def prefill_rows(cfg, rehearse: bool):
             k, (cfg.n_heads, cfg.kv_lora_rank, cfg.v_head_dim),
             cfg.compute_dtype) * 0.02})(jax.random.PRNGKey(2))
     row = jnp.arange(1, nb)
-    row = jnp.pad(row, (0, -row.shape[0] % (tile // BS)))
+    row = jnp.pad(row, (0, -row.shape[0] % (kernel_tile // BS)))
+
+    def kernel(cfg, qn, qr, pool, li, row, pos, lp, tile):
+        return pm._attend_kernel(cfg, qn, qr, pool, li, row, pos[0], lp,
+                                 tile, rehearse)
+
+    forms = (("expanded", pm._attend_tiles_expanded, tile),
+             ("absorbed", _attend_tiles_absorbed, tile),
+             ("kernel", kernel, kernel_tile))
     for c in chunks:
         kq = jax.random.split(jax.random.PRNGKey(3))
         q_nope = jax.random.normal(
@@ -189,22 +221,34 @@ def prefill_rows(cfg, rehearse: bool):
         q_rope = jax.random.normal(
             kq[1], (c, cfg.n_heads, cfg.qk_rope_head_dim), cfg.compute_dtype)
         positions = prefix + jnp.arange(c)
+        args = (q_nope, q_rope, pool, row, positions, lp)
+        least = 0.0 if rehearse else _attention_flops(c, prefix) / PEAK_FLOPS
         outs = {}
-        for form, fn in (("expanded", pm._attend_tiles_expanded),
-                         ("absorbed", _attend_tiles_absorbed)):
-            prog = jax.jit(lambda qn, qr, pool, row, pos, lp, fn=fn: fn(
-                cfg, qn, qr, pool, 1, row, pos, lp, tile))
-            args = (q_nope, q_rope, pool, row, positions, lp)
-            outs[form] = np.asarray(prog(*args), np.float32)
+        for form, fn, t in forms:
+            def chain(qn, qr, pool, row, pos, lp, fn=fn, t=t):
+                # each call's queries depend on the last call's result
+                for li in range(calls):
+                    o = fn(cfg, qn, qr, pool, li % LAYERS, row, pos, lp, t)
+                    qn = qn + (o[:, :1, None] * 1e-6).astype(qn.dtype)
+                return qn
+
+            one = jax.jit(lambda *a, fn=fn, t=t: fn(
+                cfg, a[0], a[1], a[2], 1, *a[3:], t))
+            outs[form] = np.asarray(one(*args), np.float32)
             out = {"form": form, "chunk": c, "prefix": prefix}
-            if not rehearse:
-                out["ms_a_layer_call"] = round(
-                    _time(prog, *args, reps=5) * 1e3, 3)
+            if rehearse:
+                jax.block_until_ready(jax.jit(chain)(*args))
+            else:
+                sec = _time(jax.jit(chain), *args, reps=3) / calls
+                out["ms_a_layer_call"] = round(sec * 1e3, 3)
+                if form != "absorbed":  # the count is the expanded form's
+                    out["peak_pct"] = round(100 * least / sec, 1)
             print("MLA_PREFILL " + json.dumps(out), flush=True)
-        err = float(np.abs(outs["expanded"] - outs["absorbed"]).max())
-        print("MLA_PREFILL " + json.dumps(
-            {"chunk": c, "max_abs_diff_between_forms": round(err, 5)}),
-            flush=True)
+        diffs = {f"max_abs_diff_{form}_vs_expanded": round(float(np.abs(
+            outs[form] - outs["expanded"]).max()), 5)
+            for form in ("absorbed", "kernel")}
+        print("MLA_PREFILL " + json.dumps({"chunk": c, **diffs}), flush=True)
+        assert max(diffs.values()) < 0.01, diffs
 
 
 def main() -> int:

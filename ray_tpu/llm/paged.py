@@ -316,13 +316,26 @@ class _PagedReq(_Request):
 
 
 # integer engine counters (cumulative; see PagedJaxLLMEngine.counters)
-_COUNTERS = ("steps", "prefill_chunks", "prefill_tokens",
+_COUNTERS = ("steps", "prefill_chunks", "prefill_kernel_chunks",
+             "prefill_tokens",
              "prefill_padded_tokens", "prefix_hit_tokens",
              "prefill_live_pages", "prefill_visited_pages",
              "decode_dispatches", "decode_dispatches_pipelined",
              "decode_token_steps", "decode_table_pages", "decode_live_pages",
              "tokens_emitted", "preemptions", "kv_demotions")
 _TRACKED_MAX = 4096
+
+
+def _prefill_kernel_args(family, cfg, use_kernel: bool,
+                         interpret: bool) -> Dict[str, bool]:
+    """What the family's ``prefill_chunk`` is told of the engine's kernel
+    choice: nothing where the family has no prefill kernel (its
+    ``prefill_kernel_fits`` is None: the function takes no such arguments)."""
+    fits = family.prefill_kernel_fits
+    if fits is None:
+        return {}
+    return {"use_kernel": bool(use_kernel and fits(cfg)),
+            "kernel_interpret": interpret}
 
 
 def _bucket_pow2(n: int, lo: int = 1) -> int:
@@ -666,6 +679,10 @@ class PagedJaxLLMEngine:
                 "pipeline_parallel_size > 1")
         else:
             self._use_kernel = bool(want)
+        # whether a prefill chunk's attention runs in a kernel of the
+        # family's too (counter prefill_kernel_chunks)
+        self._prefill_kernel = _prefill_kernel_args(
+            fam, cfg, self._use_kernel, False).get("use_kernel", False)
         self._decode = jax.jit(self._decode_chunk_impl, donate_argnums=2,
                                static_argnums=11, out_shardings=decode_out)
         self._prefill_chunk = jax.jit(self._prefill_chunk_impl,
@@ -864,7 +881,9 @@ class PagedJaxLLMEngine:
         ledger's publisher calls it): a read beside a running step may
         be one step behind in some keys.
 
-        ``steps``; ``prefill_chunks``, ``prefill_tokens`` (prompt tokens
+        ``steps``; ``prefill_chunks``, ``prefill_kernel_chunks`` (those of
+        them whose attention ran in the family's prefill kernel),
+        ``prefill_tokens`` (prompt tokens
         run through the model, recompute after a preemption included),
         ``prefill_padded_tokens`` (bucket padding run but not asked
         for), ``prefix_hit_tokens`` (prompt tokens admission found in the
@@ -1050,7 +1069,9 @@ class PagedJaxLLMEngine:
         ``sample_idx`` (the caller uses it only on the final chunk)."""
         logits, pool = self.family.prefill_chunk(
             self.cfg, params, tokens, pool, table, p0, rope_cache=self._rope,
-            tp_plan=self._tp_prefill_plan)
+            tp_plan=self._tp_prefill_plan,
+            **_prefill_kernel_args(self.family, self.cfg, self._use_kernel,
+                                   self._kernel_interpret))
         key, sub = jax.random.split(key)
         ids = _sample(logits[:, sample_idx], sub, temp, top_k)
         return ids, pool, key
@@ -1511,6 +1532,7 @@ class PagedJaxLLMEngine:
                         self._put([req.gen.top_k], np.int32))
                 req.prefill_chunks += 1
                 self._c["prefill_chunks"] += 1
+                self._c["prefill_kernel_chunks"] += self._prefill_kernel
                 self._c["prefill_tokens"] += take
                 self._c["prefill_padded_tokens"] += c - take
                 self._c["prefill_live_pages"] += math.ceil(
@@ -2415,7 +2437,10 @@ class PagedJaxLLMEngine:
         def run(params, pool, toks, table, last, length):
             _, pool = self.family.prefill_chunk(
                 self.cfg, params, toks, pool, table, jnp.int32(0),
-                rope_cache=self._rope, tp_plan=self._tp_prefill_plan)
+                rope_cache=self._rope, tp_plan=self._tp_prefill_plan,
+                **_prefill_kernel_args(self.family, self.cfg,
+                                       self._use_kernel,
+                                       self._kernel_interpret))
             logits, pool, *_ = self.family.decode_step(
                 self.cfg, params, last, pool, table, length,
                 rope_cache=self._rope, use_kernel=self._use_kernel,

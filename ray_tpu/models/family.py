@@ -36,7 +36,8 @@ class ModelFamily:
     # (cfg, max_seq) -> (cos, sin) device arrays the forward functions take
     rope_cache: Callable
     # (cfg, params, tokens [1, C], pool, table [1, W], p0, *, rope_cache,
-    #  tp_plan) -> (logits [1, C, V] f32, pool)
+    #  tp_plan[, use_kernel, kernel_interpret: where prefill_kernel_fits])
+    #  -> (logits [1, C, V] f32, pool)
     prefill_chunk: Callable
     # (cfg, params, tokens [B], pool, table [B, W], lengths [B], *,
     #  rope_cache, use_kernel, mesh, kernel_interpret, tp_plan, active)
@@ -55,6 +56,9 @@ class ModelFamily:
     # the speculative verification window (llama.decode_window_paged's
     # signature); None: no speculative decoding
     decode_window: Optional[Callable] = None
+    # (cfg) -> bool: ``prefill_chunk``'s attention runs in a kernel of its
+    # own where the decode kernel is on and the shapes fit; None: it has none
+    prefill_kernel_fits: Optional[Callable] = None
     # engine counters a decode token-step books: names of the int32 vector
     # ``decode_step`` returns as its third value (summed over the chunk)
     decode_counters: Tuple[str, ...] = ()

@@ -68,8 +68,9 @@ Params = Dict[str, Any]
 # (row, held expert) pairs
 DECODE_COUNTERS = ("moe_experts_held", "moe_experts_hit", "moe_pairs_here")
 
-# KV positions one iteration of a prefill chunk's attention loop attends
-PREFILL_KV_TILE = 512
+# KV positions one step of a prefill chunk's attention attends (a grid step of
+# the kernel, an iteration of the ``jax.numpy`` loop)
+PREFILL_KV_TILE = 1024
 
 
 @dataclasses.dataclass(frozen=True)
@@ -273,6 +274,15 @@ def kernel_supported(cfg: PanguMoEConfig) -> bool:
     return True
 
 
+def prefill_kernel_fits(cfg: PanguMoEConfig) -> bool:
+    """Whether a prefill chunk's attention takes its kernel
+    (``ops/mla_prefill_attention.py``) where the decode kernel is on: on a
+    TPU a head's key and value parts must end on a lane tile (the
+    interpreter takes any width)."""
+    return jax.default_backend() != "tpu" or not (
+        cfg.qk_nope_head_dim % 128 or cfg.v_head_dim % 128)
+
+
 # ---------------------------------------------------------------------------
 # the layer's parts
 # ---------------------------------------------------------------------------
@@ -402,6 +412,27 @@ def _attend_tiles_expanded(cfg: PanguMoEConfig, q_nope, q_rope, pool, li,
     return attn.transpose(1, 0, 2).reshape(c, cfg.n_heads * cfg.v_head_dim)
 
 
+def _attend_kernel(cfg: PanguMoEConfig, q_nope, q_rope, pool, li, row, p0,
+                   lp, tile: int, interpret: bool):
+    """``_attend_tiles_expanded``'s attention through the Pallas kernel: the
+    latent rows of the table's whole width gathered into one buffer (14 MB a
+    layer-call at the cell's 641 blocks; the kernel reads the live tiles of
+    it), the queries laid out a head ``[q_nope | q_rope | 0]`` to meet a key
+    ``[k_nope_i | k_rope | 0]``.  Returns ``[C, H * v]`` in the compute
+    dtype."""
+    from ray_tpu.ops.mla_prefill_attention import mla_prefill_attention
+
+    c = q_nope.shape[0]
+    pad = jnp.zeros((c, cfg.n_heads, cfg.cache_width - cfg.latent_width),
+                    q_nope.dtype)
+    q = jnp.concatenate([q_nope, q_rope, pad], axis=-1).reshape(c, -1)
+    lat = pool[li, row].reshape(-1, cfg.cache_width)
+    return mla_prefill_attention(
+        q, lat, lp["w_uk"], lp["w_uv"], p0,
+        scale=1.0 / math.sqrt(cfg.qk_head_dim), kv_tile=tile,
+        interpret=interpret)
+
+
 def route(cfg: PanguMoEConfig, h, router):
     """The router, over ALL ``n_routed_experts``: ``(gates [T, k] float32,
     experts [T, k])`` of inputs ``h [T, d]``: sigmoid scores in float32, the
@@ -500,6 +531,8 @@ def prefill_chunk_paged(cfg: PanguMoEConfig, params: Params,
                         tokens: jnp.ndarray, pool: Dict[str, jnp.ndarray],
                         table: jnp.ndarray, p0: jnp.ndarray,
                         rope_cache: Optional[tuple] = None, tp_plan=None,
+                        use_kernel: bool = False,
+                        kernel_interpret: bool = False,
                         kv_tile: int = PREFILL_KV_TILE):
     """Prefill ONE chunk of a single sequence into its pool blocks.
 
@@ -507,10 +540,11 @@ def prefill_chunk_paged(cfg: PanguMoEConfig, params: Params,
     multiple of the block size, tail padded), ``p0`` the global position of
     the first (a multiple of the block size), table ``[1, W]`` covering
     ``[0, p0 + C)``.  The chunk's latent rows are written to the pool and
-    attention reads the whole prefix back a tile at a time.  ``kv_tile`` is
-    for tests (a toy prefix spans several tiles only at a small one); every
-    caller in the tree leaves the default.  Returns (logits [1, C, V]
-    float32, pool).
+    attention reads the whole prefix back a tile at a time: ``use_kernel``:
+    inside the Pallas kernel (``_attend_kernel``), else in ``jax.numpy``
+    (``_attend_tiles_expanded``).  ``kv_tile`` is for tests (a toy prefix
+    spans several tiles only at a small one); every caller in the tree
+    leaves the default.  Returns (logits [1, C, V] float32, pool).
     """
     del tp_plan  # the family supplies no tensor-parallel layout
     cos, sin = (rope_cache if rope_cache is not None
@@ -537,9 +571,14 @@ def prefill_chunk_paged(cfg: PanguMoEConfig, params: Params,
                 lat = _latent(cfg, h, lp, cos, sin, positions[None])
                 ckv = ckv.at[li, chunk_blocks].set(
                     lat[0].reshape(c // bs, bs, -1).astype(ckv.dtype))
-                attn = _attend_tiles_expanded(
-                    cfg, q_nope[0], q_rope[0], ckv, li, row, positions, lp,
-                    kv_tile)[None]
+                if use_kernel:
+                    attn = _attend_kernel(
+                        cfg, q_nope[0], q_rope[0], ckv, li, row, p0, lp,
+                        kv_tile, kernel_interpret)[None]
+                else:
+                    attn = _attend_tiles_expanded(
+                        cfg, q_nope[0], q_rope[0], ckv, li, row, positions,
+                        lp, kv_tile)[None]
                 out = attn.astype(cfg.compute_dtype) @ lp["w_o"].astype(
                     cfg.compute_dtype)
                 x = x + rms_norm(out, lp["post_attn_norm"], cfg.rms_norm_eps)
@@ -643,6 +682,7 @@ def _family():
         decode_step=decode_step_paged, kernel_supported=kernel_supported,
         prefill_visited_pages=_prefill_visited_pages,
         reference_logits=_reference_logits,
+        prefill_kernel_fits=prefill_kernel_fits,
         decode_counters=DECODE_COUNTERS)
 
 

@@ -319,17 +319,33 @@ def test_latent_decode_kernel_compiles_at_cell_shapes(one_chip, w):
         _spec((64,), jnp.int32, one_chip), _spec((64,), jnp.int32, one_chip))
 
 
+@pytest.mark.parametrize("c", [64, 512, 1024])
+def test_latent_prefill_kernel_compiles_at_cell_shapes(one_chip, c):
+    """A chunk of ``c`` queries, 128 heads ``[q_nope 128 | q_rope 64 | 0]``,
+    over the 641-block table's latent rows in whole tiles of 1,024."""
+    from ray_tpu.ops.mla_prefill_attention import mla_prefill_attention
+
+    _assert_kernel(
+        functools.partial(mla_prefill_attention, scale=192 ** -0.5),
+        _spec((c, 128 * 256), BF16, one_chip),
+        _spec((11 * 1024, 640), BF16, one_chip),
+        _spec((128, 128, 512), BF16, one_chip),
+        _spec((128, 512, 128), BF16, one_chip),
+        _spec((), jnp.int32, one_chip))
+
+
 def test_latent_family_programs_compile_at_cell_shapes(one_chip):
     """Decode over the 9,216-position table with the kernel in it, named; a
-    1,024-token prefill chunk whose tile loop copies no pool."""
+    1,024-token prefill chunk with its attention kernel in it, named, and no
+    score tile among its temporaries."""
     cfg, params, pool = _pangu_cell(one_chip)
     decode, prefill = _engine_programs(cfg, params, pool, one_chip, 64, 1024,
                                        2, 1024, 641)
     text = decode.compile().as_text()
     assert "tpu_custom_call" in text and "mla_paged_attention" in text
     compiled = prefill.compile()
-    assert "while" in compiled.as_text()
-    assert compiled.memory_analysis().temp_size_in_bytes < 3 << 29
+    assert "mla_prefill_attention" in _custom_call_names(compiled.as_text())
+    assert compiled.memory_analysis().temp_size_in_bytes < 1 << 28
 
 
 # -- flash attention: the 1.14 B train shape and the 8 B widths ----------------
@@ -366,13 +382,19 @@ def test_flash_attention_forward_backward_compiles(one_chip, name):
 # -- the kernels' names, as a profiler trace of the chip will show them --------
 
 
-def _kernel_instructions(fn, *args):
-    """Names of the compiled program's ``tpu_custom_call`` instructions."""
+def _custom_call_names(text):
+    """Names of a compiled program's ``tpu_custom_call`` instructions, as
+    one string."""
     import re
 
-    text = jax.jit(fn).lower(*args).compile().as_text()
-    return [m.group(1) for m in re.finditer(
-        r"%([\w.\-]+) = [^\n]*custom_call_target=\"tpu_custom_call\"", text)]
+    return " ".join(m.group(1) for m in re.finditer(
+        r"%([\w.\-]+) = [^\n]*custom_call_target=\"tpu_custom_call\"", text))
+
+
+def _kernel_instructions(fn, *args):
+    """Names of the compiled program's ``tpu_custom_call`` instructions."""
+    return _custom_call_names(
+        jax.jit(fn).lower(*args).compile().as_text()).split()
 
 
 @pytest.mark.parametrize("kernel", ["paged_attention", "flash_attention_fwd",
