@@ -322,6 +322,7 @@ _COUNTERS = ("steps", "prefill_chunks", "prefill_kernel_chunks",
              "prefill_live_pages", "prefill_visited_pages",
              "decode_dispatches", "decode_dispatches_pipelined",
              "decode_token_steps", "decode_table_pages", "decode_live_pages",
+             "decode_rows", "decode_live_rows",
              "tokens_emitted", "preemptions", "kv_demotions")
 _TRACKED_MAX = 4096
 
@@ -508,11 +509,16 @@ class PagedJaxLLMEngine:
         # full prompt blocks to host RAM (and optionally plasma); a later
         # prefix match revives them by pool upload instead of recompute
         self._host_cache: Optional[HostBlockCache] = None
-        if config.enable_prefix_caching and config.host_kv_cache_bytes > 0:
+        # a family with a slot state (models/family.py) gets no prefix hit,
+        # whatever the option says: a block's keys and values can be shared,
+        # but the recurrent state after that block was never kept
+        prefix_matching = (config.enable_prefix_caching
+                           and fam.init_slot_state is None)
+        if prefix_matching and config.host_kv_cache_bytes > 0:
             self._host_cache = HostBlockCache(
                 config.host_kv_cache_bytes, config.plasma_kv_cache_blocks)
         self.blocks = BlockManager(
-            nb, self.bs, config.enable_prefix_caching,
+            nb, self.bs, prefix_matching,
             on_evict=(self._demote_block if self._host_cache is not None
                       else None))
 
@@ -528,6 +534,11 @@ class PagedJaxLLMEngine:
             self.params = (params if params is not None
                            else fam.init_params(cfg, pkey))
             self.pool = fam.init_paged_cache(cfg, nb, self.bs)
+            # the state a SLOT holds, {leaf: [layers, max_batch, ...]}
+            # (None: the family's only state is the pool); one device only,
+            # as the refusal above has it
+            self.slot_state = (None if fam.init_slot_state is None
+                               else fam.init_slot_state(cfg, self.max_batch))
         else:
             from jax.sharding import NamedSharding, PartitionSpec
 
@@ -565,6 +576,7 @@ class PagedJaxLLMEngine:
             # uploaded array and a fed-back one keyed different ones, so
             # warmup() compiled programs serving never ran and serving
             # compiled its own inside the request path.
+            self.slot_state = None  # such a family has no param_specs
             self._rep = NamedSharding(self.mesh, PartitionSpec())
             rep = self._rep
             decode_out = (rep, rep, pool_sh, rep, rep, rep, rep)
@@ -683,10 +695,12 @@ class PagedJaxLLMEngine:
         # family's too (counter prefill_kernel_chunks)
         self._prefill_kernel = _prefill_kernel_args(
             fam, cfg, self._use_kernel, False).get("use_kernel", False)
-        self._decode = jax.jit(self._decode_chunk_impl, donate_argnums=2,
+        stateful = self.slot_state is not None
+        self._decode = jax.jit(self._decode_chunk_impl,
+                               donate_argnums=(2, 12) if stateful else 2,
                                static_argnums=11, out_shardings=decode_out)
         self._prefill_chunk = jax.jit(self._prefill_chunk_impl,
-                                      donate_argnums=2,
+                                      donate_argnums=(2, 9) if stateful else 2,
                                       out_shardings=prefill_out)
         # the pool's leaves, in the order the host tier and the handoff
         # carry them (a Llama pool: k, v)
@@ -694,6 +708,10 @@ class PagedJaxLLMEngine:
 
         def scatter_blocks(pool, idx, blocks):
             return {n: pool[n].at[:, idx].set(blocks[n]) for n in pool}
+
+        # a handed-off sequence's slot state into its new slot (one index
+        # along the leaves' second axis, as a block is one along the pool's)
+        self._import_slot = jax.jit(scatter_blocks, donate_argnums=0)
 
         # tier revival: scatter one host-cached block back into the pool
         # (fixed shapes -> exactly one compile)
@@ -803,6 +821,9 @@ class PagedJaxLLMEngine:
         # engine-owned HBM by N× on sharded replicas, making chip
         # telemetry and the disagg router's free-HBM digests lie.
         kv_bytes = device_telemetry.tree_nbytes_per_device(self.pool)
+        if self.slot_state is not None:  # engine-owned state, like the pool
+            kv_bytes += device_telemetry.tree_nbytes_per_device(
+                self.slot_state)
         if self._spec is not None:
             kv_bytes += device_telemetry.tree_nbytes_per_device(
                 self._draft_pool)
@@ -850,6 +871,14 @@ class PagedJaxLLMEngine:
             "pending": pending,
             "counters": self.counters(),
         }
+        if self.slot_state is not None:
+            # what does not page: one fixed-size state a slot, and no prefix
+            # hit for its family whatever enable_prefix_caching says
+            row["slot_state"] = {
+                "slots": self.max_batch,
+                "bytes": device_telemetry.tree_nbytes_per_device(
+                    self.slot_state),
+                "prefix_matching": self.blocks.prefix_caching}
         tel = self._telemetry
         if tel is not None:
             rates = tel.rates()
@@ -898,7 +927,10 @@ class PagedJaxLLMEngine:
         padded block table handed to the decode program, ``max_batch`` x
         its bucketed width, and the blocks the decoding slots really hold:
         the share of that table a kernel that follows the live pages
-        touches);
+        touches); ``decode_rows`` / ``decode_live_rows`` (per dispatch, times
+        its token-steps: ``max_batch`` rows the decode program runs over, and
+        the rows that decode: the share a kernel that follows the decoding
+        rows, as the state-space update does, moves bytes for);
         ``tokens_emitted``; ``drains`` by cause (an in-flight chunk
         collected before the next dispatch could be queued behind it);
         ``preemptions``, ``kv_demotions``; ``host_s`` / ``device_wait_s``
@@ -1032,19 +1064,26 @@ class PagedJaxLLMEngine:
     # -- jitted programs ------------------------------------------------
 
     def _decode_chunk_impl(self, params, tokens, pool, table, lengths, active,
-                           remaining, stops, key, temps, top_ks, n_steps):
+                           remaining, stops, key, temps, top_ks, n_steps,
+                           state=None):
         """Multi-step paged decode (mirrors the static engine's program; the
         host guarantees every active slot's table covers lengths + n_steps
-        tokens of appends)."""
+        tokens of appends).  ``state``: the family's slot state (None: it
+        has none), carried through the token-steps beside the pool and
+        returned last."""
 
         def one(carry, _):
-            tokens, pool, lengths, active, remaining, key = carry
-            # a family with decode_counters returns them as a third value
+            tokens, pool, lengths, active, remaining, key, *slot = carry
+            # a family with a slot state returns it third; one with
+            # decode_counters returns them after
             logits, pool, *booked = self.family.decode_step(
                 self.cfg, params, tokens, pool, table, lengths,
                 rope_cache=self._rope, use_kernel=self._use_kernel,
                 mesh=self.mesh, kernel_interpret=self._kernel_interpret,
-                tp_plan=self._tp_plan, active=active)
+                tp_plan=self._tp_plan, active=active,
+                **({"slot_state": slot[0]} if slot else {}))
+            if slot:
+                slot, booked = booked[:1], booked[1:]
             key, sub = jax.random.split(key)
             ids = _sample(logits, sub, temps, top_ks)
             emitted = jnp.where(active > 0, ids, -1)
@@ -1055,26 +1094,34 @@ class PagedJaxLLMEngine:
                                    | (lengths + 1 >= self.max_seq))
             active = active * (1 - done.astype(active.dtype))
             tokens = jnp.where(active > 0, ids, tokens)
-            carry = (tokens, pool, lengths, active, remaining, key)
+            carry = (tokens, pool, lengths, active, remaining, key, *slot)
             return carry, ((emitted, booked[0]) if booked else emitted)
 
         carry = (tokens, pool, lengths, active, remaining, key)
+        if state is not None:
+            carry += (state,)
         carry, emitted = jax.lax.scan(one, carry, None, length=n_steps)
-        tokens, pool, lengths, active, remaining, key = carry
-        return emitted, tokens, pool, lengths, active, remaining, key
+        tokens, pool, lengths, active, remaining, key, *slot = carry
+        return (emitted, tokens, pool, lengths, active, remaining, key, *slot)
 
     def _prefill_chunk_impl(self, params, tokens, pool, table, p0,
-                            sample_idx, key, temp, top_k):
+                            sample_idx, key, temp, top_k, state=None,
+                            where=None):
         """One chunk; also samples the token at chunk-local position
-        ``sample_idx`` (the caller uses it only on the final chunk)."""
-        logits, pool = self.family.prefill_chunk(
+        ``sample_idx`` (the caller uses it only on the final chunk).
+        ``state`` / ``where``: the family's slot state and the chunk's
+        ``(slot, real tokens)`` (None: the family has none); the state is
+        returned last."""
+        logits, pool, *slot = self.family.prefill_chunk(
             self.cfg, params, tokens, pool, table, p0, rope_cache=self._rope,
             tp_plan=self._tp_prefill_plan,
             **_prefill_kernel_args(self.family, self.cfg, self._use_kernel,
-                                   self._kernel_interpret))
+                                   self._kernel_interpret),
+            **({} if state is None else
+               {"slot_state": state, "slot": where[0], "take": where[1]}))
         key, sub = jax.random.split(key)
         ids = _sample(logits[:, sample_idx], sub, temp, top_k)
-        return ids, pool, key
+        return (ids, pool, key, *slot)
 
     def _draft_propose_impl(self, params, tokens, pool, table, lengths,
                             key, temps, top_ks):
@@ -1523,13 +1570,16 @@ class PagedJaxLLMEngine:
                 is_last = p0 + take >= plen
                 sample_idx = (plen - 1 - p0) if is_last else 0
                 with tracing.region("engine.prefill_chunk", tokens=take,
-                                    bucket=c, is_last=int(is_last)):
-                    ids, self.pool, self._d_key = self._prefill_chunk(
+                                    bucket=c, is_last=int(is_last), p0=p0):
+                    ids, self.pool, self._d_key, *state = self._prefill_chunk(
                         self.params, self._put(tokens), self.pool,
                         self._put(table), self._put(p0, np.int32),
                         self._put(sample_idx, np.int32), self._d_key,
                         self._put([req.gen.temperature], np.float32),
-                        self._put([req.gen.top_k], np.int32))
+                        self._put([req.gen.top_k], np.int32),
+                        *self._slot_args(slot, take))
+                    if state:
+                        self.slot_state = state[0]
                 req.prefill_chunks += 1
                 self._c["prefill_chunks"] += 1
                 self._c["prefill_kernel_chunks"] += self._prefill_kernel
@@ -1573,6 +1623,17 @@ class PagedJaxLLMEngine:
                     self._mark_dirty("final_prefill")
                 budget -= take
                 self._tel_prefill_spent += take
+
+    def _slot_args(self, slot: Optional[int] = None, take: int = 0) -> tuple:
+        """What a dispatch hands its program beyond the pool: the family's
+        slot state and, for a prefill chunk (``slot`` given), the chunk's
+        ``(slot, real tokens)``; nothing for a family without one."""
+        if self.slot_state is None:
+            return ()
+        if slot is None:
+            return (self.slot_state,)
+        return (self.slot_state,
+                (self._put(slot, np.int32), self._put(take, np.int32)))
 
     def _mark_dirty(self, cause: str):
         """The device mirrors are stale; ``cause`` (the first since the
@@ -1946,8 +2007,9 @@ class PagedJaxLLMEngine:
         speculative cycle) for ``active``; it becomes the chunk in flight.
         Returns the chunk that was in flight before, still to collect."""
         w = _bucket_pow2(max(len(self._slot_req[s].blocks) for s in active))
+        pages = sum(len(self._slot_req[s].blocks) for s in active)
         with tracing.region("engine.decode_dispatch", slots=len(active),
-                            w=w, chunk=chunk):
+                            w=w, chunk=chunk, pages=pages):
             table = np.zeros((self.max_batch, w), np.int32)
             for s in active:
                 blks = self._slot_req[s].blocks
@@ -1958,13 +2020,16 @@ class PagedJaxLLMEngine:
                 steps = self._spec_k + 1
             else:
                 (em_dev, self._d_next, self.pool, self._d_lengths,
-                 self._d_active, self._d_remaining, self._d_key) = \
+                 self._d_active, self._d_remaining, self._d_key, *slot) = \
                     self._decode(
                         self.params, self._d_next, self.pool,
                         self._put(table), self._d_lengths,
                         self._d_active, self._d_remaining,
                         self._d_stops, self._d_key,
-                        self._d_temp, self._d_topk, chunk)
+                        self._d_temp, self._d_topk, chunk,
+                        *self._slot_args())
+                if slot:
+                    self.slot_state = slot[0]
                 self._book_tp_collectives("decode", chunk)
                 acc_dev, spec_slots, steps = None, (), chunk
         prev, self._inflight = (self._inflight,
@@ -1973,8 +2038,9 @@ class PagedJaxLLMEngine:
         c["decode_dispatches"] += 1
         c["decode_token_steps"] += steps
         c["decode_table_pages"] += self.max_batch * w
-        c["decode_live_pages"] += sum(
-            len(self._slot_req[s].blocks) for s in active)
+        c["decode_live_pages"] += pages
+        c["decode_rows"] += self.max_batch * steps
+        c["decode_live_rows"] += len(active) * steps
         if prev is not None:
             c["decode_dispatches_pipelined"] += 1
         return prev
@@ -2137,6 +2203,11 @@ class PagedJaxLLMEngine:
                    **{n: np.asarray(self.pool[n][:, barr])
                       for n in self.cache_leaves},
                    "block_size": self.bs,
+                   # what the sequence holds that does not page: the slot's
+                   # own index of every leaf, [layers, ...]
+                   **({} if self.slot_state is None else {"slot_state": {
+                       n: np.asarray(x[:, req.slot])
+                       for n, x in self.slot_state.items()}}),
                    "emitted": [int(t) for t in req.out_tokens],
                    "gen": {"max_new_tokens": g.max_new_tokens,
                            "temperature": g.temperature,
@@ -2149,7 +2220,8 @@ class PagedJaxLLMEngine:
 
     def import_request(self, prompt: Sequence[int], first_token: int,
                        k, v=None, gen: Optional[GenerationConfig] = None,
-                       emitted: Optional[Sequence[int]] = None):
+                       emitted: Optional[Sequence[int]] = None,
+                       slot_state: Optional[Dict] = None):
         """Admit a request directly into the decode state from handed-off
         KV (``k`` and ``v`` as ``export_request`` gave them; a family whose
         cache has other leaves hands the dict of them as ``k`` and leaves
@@ -2160,7 +2232,10 @@ class PagedJaxLLMEngine:
         request's first output token) and live KV migration (``emitted``
         is the source's full output history — decode resumes at position
         prompt+len(emitted)-1 and the history is NOT re-emitted, the
-        source already streamed it).
+        source already streamed it).  ``slot_state``: the handoff's leaves
+        of the same name, for a family whose sequences hold a state that does
+        not page; a handoff without them is refused (ValueError: the caller
+        recomputes, as for any handoff this engine cannot use).
 
         Returns {request_id, emitted, done} or None when no slot/blocks
         are free right now — the caller falls back to a plain
@@ -2200,6 +2275,16 @@ class PagedJaxLLMEngine:
                 f"handoff carries cache leaves {sorted(leaves)}, the "
                 f"{self.family.name} family's pool has "
                 f"{list(self.cache_leaves)}")
+        if self.slot_state is not None and (
+                slot_state is None
+                or set(slot_state) != set(self.slot_state)
+                or any(tuple(np.shape(slot_state[n]))
+                       != x.shape[:1] + x.shape[2:]
+                       for n, x in self.slot_state.items())):
+            raise ValueError(
+                f"the {self.family.name} family resumes a sequence from its "
+                f"slot state {sorted(self.slot_state)}, which this handoff "
+                "does not carry at this engine's shapes")
         nb = int(leaves[self.cache_leaves[0]].shape[1])
         if nb != max(1, math.ceil(live / self.bs)):
             raise ValueError(
@@ -2225,6 +2310,11 @@ class PagedJaxLLMEngine:
                 padded[n] = jnp.asarray(xp)
             self.pool = self._import_blocks(
                 self.pool, jnp.asarray(idx), padded)
+            if self.slot_state is not None:
+                self.slot_state = self._import_slot(
+                    self.slot_state, jnp.int32(slot),
+                    {n: jnp.asarray(np.asarray(slot_state[n], dtype=x.dtype))
+                     for n, x in self.slot_state.items()})
             self._req_counter += 1
             req = _PagedReq(self._req_counter, list(prompt), gen)
             req.slot = slot
@@ -2379,8 +2469,11 @@ class PagedJaxLLMEngine:
                     steps = chunk
                 out = self._decode(
                     self.params, zi(b), self.pool, zi(b, w), zi(b), zi(b),
-                    zi(b), stops, key, zf(b), zi(b), steps)
+                    zi(b), stops, key, zf(b), zi(b), steps,
+                    *self._slot_args())
                 self.pool = out[2]
+                if self.slot_state is not None:  # active=0: as it was
+                    self.slot_state = out[7]
                 jax.block_until_ready(out[0])  # compile + run to completion
                 decode_widths.append(w)
                 if w >= w_cap:
@@ -2397,9 +2490,12 @@ class PagedJaxLLMEngine:
             c = self.bs
             while True:
                 c = min(c, c_cap)
-                ids, self.pool, _ = self._prefill_chunk(
+                # no real token: a slot state stays as it was
+                ids, self.pool, _, *slot = self._prefill_chunk(
                     self.params, zi(1, c), self.pool, zi(1, self._prefill_w),
-                    zi(), zi(), key, zf(1), zi(1))
+                    zi(), zi(), key, zf(1), zi(1), *self._slot_args(0, 0))
+                if slot:
+                    self.slot_state = slot[0]
                 np.asarray(ids)
                 if self._spec is not None:
                     self._draft_pool = self._draft_prefill(
@@ -2435,17 +2531,22 @@ class PagedJaxLLMEngine:
         toks[0, :n] = prompt[:-1]
 
         def run(params, pool, toks, table, last, length):
-            _, pool = self.family.prefill_chunk(
+            # a family with a slot state runs on one slot of its own here
+            own = ({} if self.slot_state is None else dict(
+                slot_state=self.family.init_slot_state(self.cfg, 1),
+                slot=jnp.int32(0), take=jnp.int32(n)))
+            _, pool, *state = self.family.prefill_chunk(
                 self.cfg, params, toks, pool, table, jnp.int32(0),
                 rope_cache=self._rope, tp_plan=self._tp_prefill_plan,
                 **_prefill_kernel_args(self.family, self.cfg,
                                        self._use_kernel,
-                                       self._kernel_interpret))
+                                       self._kernel_interpret), **own)
             logits, pool, *_ = self.family.decode_step(
                 self.cfg, params, last, pool, table, length,
                 rope_cache=self._rope, use_kernel=self._use_kernel,
                 mesh=self.mesh, kernel_interpret=self._kernel_interpret,
-                tp_plan=self._tp_plan)
+                tp_plan=self._tp_plan,
+                **({"slot_state": state[0]} if state else {}))
             return logits[0], pool
 
         with self._lock:

@@ -238,6 +238,55 @@ class LLMServer:
             "logit_std": float(rows.std()),
         }
 
+    def reference_state_check(self, prompt: Sequence[int],
+                              tokens: int) -> Dict[str, Any]:
+        """Hold the SLOT STATE this replica keeps for a sequence against the
+        plain float32 recurrence over the same positions (the family's
+        ``reference_slot_state``, models/family.py).  ``prompt`` is served
+        greedily like any request; once it has emitted ``tokens`` tokens it
+        is taken out of the engine mid-decode (``export_stream``: the handoff
+        carries the slot's leaves and the token history) and its stream is
+        ended there.  For each leaf the reference defines: the relative error
+        (the norm of the difference over the reference's norm) of the whole
+        leaf and of each layer.  The caller states the tolerance."""
+        import numpy as np
+
+        eng = self._engine
+        if eng.family.reference_slot_state is None:
+            raise ValueError(f"family {eng.family.name!r} keeps no slot "
+                             "state with a reference")
+        # the export waits for the step lock, which the loop takes again at
+        # once: the request decodes on meanwhile (5 to over 64 tokens, on
+        # the chip), so its budget leaves that room and the reply says where
+        # it was taken
+        gen = GenerationConfig(temperature=0.0, max_new_tokens=min(
+            tokens + 1024, self._config.max_seq_len - len(prompt)))
+        wkey = self._submit(None, list(prompt), gen)
+        handoff, got = None, 0
+        for chunk in self._iter_tokens(wkey):
+            got += len(chunk)
+            if handoff is None and got >= tokens:
+                handoff = self.export_stream(wkey[2])
+                self._finish_migrated(wkey[2])
+        emitted = handoff["emitted"]
+        # the state has taken in every token but the last emitted, which the
+        # next token-step would have fed
+        taken = list(prompt) + emitted[:-1]
+        out = {"positions": len(taken), "emitted": len(emitted)}
+        for name, (have, want) in eng.family.reference_slot_state(
+                eng.cfg, eng.params, taken, handoff["slot_state"]).items():
+            have, want = (np.asarray(a, np.float32).reshape(len(a), -1)
+                          for a in (have, want))
+
+            def rel(axis):
+                return (np.sqrt(((have - want) ** 2).sum(axis))
+                        / np.maximum(np.sqrt((want ** 2).sum(axis)), 1e-30))
+
+            out[name] = {"finite": bool(np.isfinite(have).all()),
+                         "rel_err": float(rel(None)),
+                         "layer_rel_err": [float(v) for v in rel(1)]}
+        return out
+
     def first_decode_logits(self, prompt: Sequence[int]):
         """The base engine's ``first_decode_logits``."""
         return self._engine.first_decode_logits(prompt)
@@ -696,7 +745,8 @@ class LLMServer:
             try:
                 res = self._engine.import_request(
                     handoff["prompt"], handoff["first_token"], leaves,
-                    gen=self._handoff_gen(handoff), emitted=emitted)
+                    gen=self._handoff_gen(handoff), emitted=emitted,
+                    slot_state=handoff.get("slot_state"))
             except ValueError:
                 # geometry mismatch (block size / max_seq) — recompute
                 # is the only road

@@ -464,16 +464,20 @@ def _tp_out_proj(a, w, tp_plan: Optional["TPPlan"], token):
     return out, token
 
 
-def _paged_attend(cfg: LlamaConfig, q, ck, cv, span_mask):
+def _paged_attend(cfg: LlamaConfig, q, ck, cv, span_mask, scale=None):
     """GQA attention of q [B, T, nh, hd] against gathered spans ck/cv
-    [B, S, kv, hd]; span_mask [B, T, S] True = visible."""
+    [B, S, kv, hd]; span_mask [B, T, S] True = visible.  ``scale``: what
+    the scores are multiplied by (None: ``1 / sqrt(head_dim)``)."""
     b, t = q.shape[:2]
     group = cfg.n_heads // cfg.n_kv_heads
     qg = q.reshape(b, t, cfg.n_kv_heads, group, cfg.head_dim)
     # bf16 operands, fp32 accumulate: no full-span fp32 cache copies
     scores = jnp.einsum("btkgd,bskd->bkgts", qg, ck,
                         preferred_element_type=jnp.float32)
-    scores = scores / math.sqrt(cfg.head_dim)
+    if scale is None:
+        scores = scores / math.sqrt(cfg.head_dim)
+    else:
+        scores = scores * scale
     scores = jnp.where(span_mask[:, None, None], scores, -1e30)
     probs = jax.nn.softmax(scores, axis=-1)
     attn = jnp.einsum("bkgts,bskd->btkgd", probs.astype(ck.dtype), cv,
@@ -488,7 +492,7 @@ PREFILL_KV_TILE = 512
 
 
 def _prefill_attend_tiles(cfg: LlamaConfig, q, pk_all, pv_all, li, row,
-                          positions, tile: int):
+                          positions, tile: int, scale=None):
     """Causal GQA attention of one chunk's queries q [C, nh, hd], at global
     ``positions`` [C] (rising), over the sequence's KV in the pool.
 
@@ -500,7 +504,10 @@ def _prefill_attend_tiles(cfg: LlamaConfig, q, pk_all, pv_all, li, row,
     and table entries in tiles past it are never read.  Inside a visited
     tile, positions past a query's own are masked to exactly zero weight;
     position 0 is visible to every query, so after the first tile every
-    running max is a real score.  Returns [C, nh * hd] float32.
+    running max is a real score.  ``scale`` multiplies the scores (None:
+    ``1 / sqrt(head_dim)``; ``cfg`` is read for its head counts and widths
+    alone, so another family's config with the same names does).  Returns
+    [C, nh * hd] float32.
     """
     c = q.shape[0]
     bs = pk_all.shape[2]
@@ -508,7 +515,7 @@ def _prefill_attend_tiles(cfg: LlamaConfig, q, pk_all, pv_all, li, row,
     group = cfg.n_heads // kv
     pages = tile // bs
     qg = q.reshape(c, kv, group, hd)
-    scale = 1.0 / math.sqrt(hd)
+    scale = 1.0 / math.sqrt(hd) if scale is None else scale
     offs = jnp.arange(tile)
 
     def fold(i, state):

@@ -28,7 +28,9 @@ what a call cost: 1.85 us a chunk, 512 chunks.
 Pool layout (canonical, see `models/llama.py init_paged_kv_cache`):
 [L, NB, bs, kv*hd] — one page is a contiguous [bs, kv*hd] slab whose
 (sublane, lane) tiling is exact for bs % 8 == 0 and hd % 128 == 0, and a
-kv head is a lane-aligned column slice.
+kv head is a lane-aligned column slice.  Heads of 64 (a cached position
+half as wide) are read two a tile, the kernel's body unchanged
+(``_pair_heads``).
 
 Reference capability boundary: the paged-attention kernel Ray LLM inherits
 from vLLM (llm/_internal/serve/deployments/llm/vllm/vllm_models.py:177-186);
@@ -204,8 +206,38 @@ def _kernel(li_ref, tbl_ref, len_ref, act_ref, q_ref, k_hbm, v_hbm, o_ref,
             o_ref[0, h * group:(h + 1) * group, :] = acc[h] / l[h]
 
 
+def _odd_kv_head(nh: int, kv: int):
+    """[nh] bool: the query head reads an odd kv head."""
+    return (jnp.arange(nh) // (nh // kv)) % 2 == 1
+
+
+def _pair_heads(q, kv: int):
+    """Heads of 64 for a kernel that reads 128-lane heads: ``q [B, nh, 64]``
+    -> ``[B, nh, 128]``, a query of an EVEN kv head in lanes 0..63 and one of
+    an ODD kv head in lanes 64..127, zeros in the other half.  Two kv heads of
+    64 lie side by side in the pool's ``kv * 64`` lanes, so the kernel, told
+    the heads are 128 wide, sees ``kv / 2`` pair heads of twice the group: the
+    zeros cancel the neighbour's keys in the scores, and of the 128 output
+    lanes a query's own half is its answer (:func:`_unpair_heads`).  The pool
+    stays 64 lanes a head (no padded byte at rest or in a handoff), the
+    kernel's body is untouched and every tile it loads is whole; the price is
+    score and value products twice as wide, on an MXU that decode leaves
+    idle."""
+    zeros = jnp.zeros_like(q)
+    odd = _odd_kv_head(q.shape[1], kv)[None, :, None]
+    return jnp.where(odd, jnp.concatenate([zeros, q], -1),
+                     jnp.concatenate([q, zeros], -1))
+
+
+def _unpair_heads(out, kv: int):
+    """``[B, nh, 128]`` -> ``[B, nh, 64]``: each query's own half."""
+    hd = out.shape[-1] // 2
+    odd = _odd_kv_head(out.shape[1], kv)[None, :, None]
+    return jnp.where(odd, out[..., hd:], out[..., :hd])
+
+
 def paged_decode_attention(q, pk_all, pv_all, li, table, lengths,
-                           active=None, interpret=False):
+                           active=None, interpret=False, scale=None):
     """GQA paged decode attention.
 
     q [B, nh, hd] (unscaled); pk/pv [L, NB, bs, kv*hd]; li scalar layer id;
@@ -216,9 +248,23 @@ def paged_decode_attention(q, pk_all, pv_all, li, table, lengths,
     and table row hold.
     kv-head count is derived from the pool's folded last dim, so per-shard
     calls under shard_map (kv heads sharded over "tensor") need no extra
-    plumbing.  Returns [B, nh*hd] fp32, numerically matching
+    plumbing.  ``scale`` multiplies the scores (None: ``1 / sqrt(hd)``).
+    Heads of 64 are read two a 128-lane tile (:func:`_pair_heads`).
+    Returns [B, nh*hd] fp32, numerically matching
     `_paged_attend` on the active rows.
     """
+    if scale is None:
+        scale = 1.0 / math.sqrt(q.shape[2])
+    if q.shape[2] == 64:
+        kv64 = pk_all.shape[3] // 64
+        if kv64 % 2:
+            raise ValueError("heads of 64 are read in pairs: an even number "
+                             f"of kv heads, not {kv64}")
+        out = paged_decode_attention(
+            _pair_heads(q, kv64), pk_all, pv_all, li, table, lengths, active,
+            interpret, scale)
+        b, nh = q.shape[:2]
+        return _unpair_heads(out.reshape(b, nh, 128), kv64).reshape(b, nh * 64)
     b, nh, hd = q.shape
     kv = pk_all.shape[3] // hd  # per-shard kv heads under shard_map
     bs = pk_all.shape[2]
@@ -245,7 +291,7 @@ def paged_decode_attention(q, pk_all, pv_all, li, table, lengths,
         ],
     )
     kern = functools.partial(
-        _kernel, kv=kv, hd=hd, bs=bs, cw=cw, scale=1.0 / math.sqrt(hd))
+        _kernel, kv=kv, hd=hd, bs=bs, cw=cw, scale=scale)
     if active is None:
         active = jnp.ones_like(lengths)
     out = pl.pallas_call(

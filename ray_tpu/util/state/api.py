@@ -847,11 +847,17 @@ class StateApiClient:
                     logdir: Optional[str] = None) -> dict:
         """Capture a JAX profiler (XPlane) trace on one worker; open the
         returned logdir with TensorBoard/xprof (SURVEY §5: the TPU analog of
-        the reference's GPU profiler plugins)."""
+        the reference's GPU profiler plugins).
+
+        The reply comes when the worker has WRITTEN the trace, and
+        ``stop_trace`` converts every event it captured: a deep program's
+        worker takes 14 to 16 s a traced second (40 layers, 45 token-steps a
+        second: PERF.md, PR 36), so the wait allows 30 s a traced second on
+        top of the minute."""
         return self._agent_call_by_pid(
             "AgentJaxProfile",
             {"pid": pid, "duration_s": duration_s, "logdir": logdir},
-            pid=pid, node_id=node_id, timeout=duration_s + 60)
+            pid=pid, node_id=node_id, timeout=60 + 31 * duration_s)
 
     # -- summaries ------------------------------------------------------
 

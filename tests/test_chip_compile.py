@@ -348,6 +348,96 @@ def test_latent_family_programs_compile_at_cell_shapes(one_chip):
     assert compiled.memory_analysis().temp_size_in_bytes < 1 << 28
 
 
+# -- the hybrid state-space family at its cell's shapes ---------------------------
+# granite-4.0-h-micro whole: 36 Mamba-2 layers whose state is 64 slots of
+# [32, 128, 128] float32 a layer, 4 attention layers with heads of 64 over
+# 16,384 blocks of 16 positions.
+
+
+def _granite_cell(sh):
+    from ray_tpu.models import granite_hybrid as gh
+
+    cfg = gh.GraniteHybridConfig()
+
+    def specs(fn):
+        return jax.tree.map(lambda x: _spec(x.shape, x.dtype, sh),
+                            jax.eval_shape(fn))
+
+    return (cfg,
+            specs(lambda: gh.init_params(cfg, jax.random.PRNGKey(0))),
+            specs(lambda: gh.init_paged_cache(cfg, 16384, 16)),
+            specs(lambda: gh.init_slot_state(cfg, 64)))
+
+
+@pytest.mark.parametrize("slots", [64, 16])
+def test_ssm_state_update_kernel_compiles_at_cell_shapes(one_chip, slots):
+    """The cell's 64 slots, and a quarter of them (the leaf is taken whole
+    and the loop runs over the live rows: nothing is sized by the slots)."""
+    from ray_tpu.ops.ssm_state_update import ssm_state_update
+
+    f32 = jnp.float32
+    _assert_kernel(
+        ssm_state_update, _spec((36, slots, 32, 128, 128), f32, one_chip),
+        _spec((), jnp.int32, one_chip), _spec((slots, 4096), f32, one_chip),
+        _spec((slots, 4096), f32, one_chip), _spec((slots, 128), f32, one_chip),
+        _spec((slots, 128), f32, one_chip),
+        _spec((slots,), jnp.int32, one_chip))
+
+
+def test_paged_decode_attention_compiles_at_heads_of_64(one_chip):
+    from ray_tpu.ops.paged_attention import paged_decode_attention
+
+    _assert_kernel(
+        functools.partial(paged_decode_attention, scale=0.015625),
+        _spec((64, 32, 64), BF16, one_chip),
+        _spec((4, 16384, 16, 512), BF16, one_chip),
+        _spec((4, 16384, 16, 512), BF16, one_chip),
+        _spec((), jnp.int32, one_chip), _spec((64, 256), jnp.int32, one_chip),
+        _spec((64,), jnp.int32, one_chip), _spec((64,), jnp.int32, one_chip))
+
+
+def test_hybrid_family_programs_compile_at_cell_shapes(one_chip):
+    """The engine's two programs with the slot state beside the pool: both
+    kernels in the decode program, by name; neither program keeps a copy of
+    a stacked weight or of the 4.8 GB state (before the layers indexed their
+    weights out of the whole stacks and the in-projection was split at a lane
+    tile, the temporaries were 2.7 and 7.7 GB)."""
+    import types
+
+    from ray_tpu.llm.engine import _MAX_STOP_IDS
+    from ray_tpu.llm.paged import PagedJaxLLMEngine
+    from ray_tpu.models.family import family_of
+
+    cfg, params, pool, state = _granite_cell(one_chip)
+    eng = types.SimpleNamespace(
+        cfg=cfg, family=family_of(cfg), max_seq=cfg.max_seq_len, mesh=None,
+        _rope=None, _use_kernel=True, _kernel_interpret=False, _tp_plan=None,
+        _tp_prefill_plan=None)
+    key = jax.eval_shape(lambda: jax.random.PRNGKey(0))
+    key = _spec(key.shape, key.dtype, one_chip)
+
+    def i32(*shape):
+        return _spec(shape, jnp.int32, one_chip)
+
+    b, w = 64, 32
+    decode = jax.jit(
+        functools.partial(PagedJaxLLMEngine._decode_chunk_impl, eng),
+        donate_argnums=(2, 12), static_argnums=11).lower(
+            params, i32(b), pool, i32(b, w), i32(b), i32(b), i32(b),
+            i32(b, _MAX_STOP_IDS), key, _spec((b,), jnp.float32, one_chip),
+            i32(b), 2, state).compile()
+    names = _custom_call_names(decode.as_text())
+    assert "ssm_state_update" in names and "paged_attention" in names
+    assert decode.memory_analysis().temp_size_in_bytes < 256 << 20
+    prefill = jax.jit(
+        functools.partial(PagedJaxLLMEngine._prefill_chunk_impl, eng),
+        donate_argnums=(2, 9)).lower(
+            params, i32(1, 256), pool, i32(1, 264), i32(), i32(), key,
+            _spec((1,), jnp.float32, one_chip), i32(1), state,
+            (i32(), i32())).compile()
+    assert prefill.memory_analysis().temp_size_in_bytes < 512 << 20
+
+
 # -- flash attention: the 1.14 B train shape and the 8 B widths ----------------
 
 _FLASH = {"train_1b": (8, 2048, 16, 8), "llama3_8b": (1, 2048, 32, 8)}
@@ -398,7 +488,8 @@ def _kernel_instructions(fn, *args):
 
 
 @pytest.mark.parametrize("kernel", ["paged_attention", "flash_attention_fwd",
-                                    "flash_attention_bwd"])
+                                    "flash_attention_bwd",
+                                    "ssm_state_update"])
 def test_kernel_name_reaches_the_compiled_instruction(one_chip, kernel):
     """``pl.pallas_call(name=)`` names the HLO instruction, and an ``XLA
     Ops`` event of a trace is named by its instruction: the benchmark's
@@ -420,6 +511,24 @@ def test_kernel_name_reaches_the_compiled_instruction(one_chip, kernel):
                 length=2)[0]
 
         args = _paged_args(16, 8, one_chip, one_chip, one_chip)
+    elif kernel == "ssm_state_update":
+        from ray_tpu.ops.ssm_state_update import ssm_state_update
+
+        def fn(state, decay, xdt, b, c, active):
+            def body(state, li):
+                with jax.named_scope("ssm"):
+                    y, state = ssm_state_update(state, li, decay, xdt, b, c,
+                                                active)
+                return state, y
+
+            return jax.lax.scan(body, state, jnp.arange(2))
+
+        f32 = jnp.float32
+        args = (_spec((2, 8, 32, 128, 128), f32, one_chip),
+                _spec((8, 4096), f32, one_chip),
+                _spec((8, 4096), f32, one_chip),
+                _spec((8, 128), f32, one_chip), _spec((8, 128), f32, one_chip),
+                _spec((8,), jnp.int32, one_chip))
     else:
         from ray_tpu.ops.flash_attention import flash_attention
 
