@@ -322,9 +322,12 @@ _COUNTERS = ("steps", "prefill_chunks", "prefill_kernel_chunks",
              "prefill_live_pages", "prefill_visited_pages",
              "decode_dispatches", "decode_dispatches_pipelined",
              "decode_token_steps", "decode_table_pages", "decode_live_pages",
-             "decode_rows", "decode_live_rows",
+             "decode_rows", "decode_live_rows", "decode_joins",
              "tokens_emitted", "preemptions", "kv_demotions")
 _TRACKED_MAX = 4096
+# what the host hands ``_join_impl`` of one row, as one int32 vector:
+# [slot, length, remaining, top_k, spec, stop ids...]
+_JOIN_ROW = 5 + _MAX_STOP_IDS
 
 
 def _prefill_kernel_args(family, cfg, use_kernel: bool,
@@ -603,7 +606,13 @@ class PagedJaxLLMEngine:
         self._next_tok = np.zeros(self.max_batch, np.int32)
         self._slot_temp = np.zeros(self.max_batch, np.float32)
         self._slot_topk = np.zeros(self.max_batch, np.int32)
-        self._dirty = True
+        # the decode program's row state lives on the device (the mirrors
+        # below, uploaded whole at the end of __init__).  A row enters
+        # them, and a finished one is cleared, by a program queued in order
+        # (_join_locked); only the rare events that change a row behind
+        # the device's back (preempt, cancel, import, export) mark them
+        # dirty, which drains the chunk in flight and uploads them anew
+        self._dirty = False
         self._d_next = self._d_lengths = self._d_active = None
         self._d_temp = self._d_topk = None
         self._d_remaining = self._d_stops = None
@@ -652,11 +661,13 @@ class PagedJaxLLMEngine:
         # (LLMServer reads t_first_emit at the first yield and pops the
         # row at the end); only filled under an slo_label, bounded
         self._tracked: Dict[int, _PagedReq] = {}
-        # a finished prefill's sampled first token stays a DEVICE future
-        # until the next drain point: a synchronous int(ids[0]) per request
-        # serialized a ~100 ms readback behind every queued program
-        # (measured: engine prefill 1,493 tok/s vs 13,000 tok/s for the
-        # chunk program itself).  (slot, req, ids_future) tuples.
+        # a final prompt chunk's sampled first token stays a DEVICE future
+        # until the end of the step that dispatched the chunk: the join
+        # hands it to the decode mirrors on the device, the step's decode
+        # chunk is queued behind it, and only then does the host read it
+        # (_resolve_first_tokens_locked).  What a synchronous int(ids[0])
+        # at the dispatch would cost: not measured.
+        # (slot, req, ids_future) tuples.
         self._first_pending: List[Tuple[int, _PagedReq, jnp.ndarray]] = []
 
         # fused pallas paged-attention kernel (ray_tpu/ops/paged_attention):
@@ -702,6 +713,11 @@ class PagedJaxLLMEngine:
         self._prefill_chunk = jax.jit(self._prefill_chunk_impl,
                                       donate_argnums=(2, 9) if stateful else 2,
                                       out_shardings=prefill_out)
+        # one row into (or out of) the decode mirrors; every output placed
+        # as the decode program's are, so each feeds the other's executable
+        self._join = jax.jit(self._join_impl, out_shardings=self._rep)
+        # what a row that leaves is given for a first token
+        self._no_ids = self._put(np.zeros(1, np.int32))
         # the pool's leaves, in the order the host tier and the handoff
         # carry them (a Llama pool: k, v)
         self.cache_leaves: Tuple[str, ...] = tuple(sorted(self.pool))
@@ -794,6 +810,7 @@ class PagedJaxLLMEngine:
             # layer's per-request acceptance rows (bounded)
             self._spec_finished: "collections.OrderedDict[int, Tuple[int, int]]" = (
                 collections.OrderedDict())
+        self._upload_mirrors_locked()  # all rows empty: there is no other thread yet
 
     def _put(self, x, dtype=None):
         """Host value -> device array for a program argument; under a mesh
@@ -931,8 +948,12 @@ class PagedJaxLLMEngine:
         its token-steps: ``max_batch`` rows the decode program runs over, and
         the rows that decode: the share a kernel that follows the decoding
         rows, as the state-space update does, moves bytes for);
+        ``decode_joins`` (rows that entered the decode batch through the
+        device join, ``_join_locked``: no drain, no upload);
         ``tokens_emitted``; ``drains`` by cause (an in-flight chunk
-        collected before the next dispatch could be queued behind it);
+        collected before the next dispatch could be queued behind it:
+        ``preempt``, ``cancel``, ``import``, ``export``, ``flush``,
+        ``idle``; a final prompt chunk and a finish cause none);
         ``preemptions``, ``kv_demotions``; ``host_s`` / ``device_wait_s``
         (each step's wall time: blocked in the device reads of
         ``engine.collect`` / ``engine.drain``, and everything else);
@@ -1122,6 +1143,26 @@ class PagedJaxLLMEngine:
         key, sub = jax.random.split(key)
         ids = _sample(logits[:, sample_idx], sub, temp, top_k)
         return (ids, pool, key, *slot)
+
+    def _join_impl(self, mirrors, ids, row, temp):
+        """One row of the decode mirrors, set on the device: ``mirrors`` as
+        ``_mirrors()`` lists them, ``ids`` [1] the final prompt chunk's
+        sampled token (still a future on the host), ``row`` the host's
+        int32 ``_JOIN_ROW`` vector, ``temp`` [1].  The row decodes unless
+        it ends at this first token, by the predicate ``_emit_locked``
+        applies to the same token on the host.  A row that LEAVES is the
+        same scatter with length and remaining 0: inactive, and its length
+        no index into a table row that is zeros from now on."""
+        nxt, lengths, active, remaining, stops, temps, top_ks, *spec = mirrors
+        slot, length, rem, top_k, spec_on = row[:5]
+        first, stop_ids = ids[0], row[5:]
+        ends = ((stop_ids == first).any() | (rem <= 0)
+                | (length + 1 >= self.max_seq))
+        return (nxt.at[slot].set(first), lengths.at[slot].set(length),
+                active.at[slot].set((~ends).astype(active.dtype)),
+                remaining.at[slot].set(rem), stops.at[slot].set(stop_ids),
+                temps.at[slot].set(temp[0]), top_ks.at[slot].set(top_k),
+                *(m.at[slot].set(spec_on) for m in spec))
 
     def _draft_propose_impl(self, params, tokens, pool, table, lengths,
                             key, temps, top_ks):
@@ -1510,7 +1551,6 @@ class PagedJaxLLMEngine:
             if len(req.draft_blocks) > keep:
                 self.draft_blocks.release(req.draft_blocks[keep:])
                 del req.draft_blocks[keep:]
-            self._dirty = True
 
     def _prefill_step_locked(self):
         """Advance mid-prefill slots, one chunk per slot, until the step's
@@ -1519,8 +1559,9 @@ class PagedJaxLLMEngine:
         decode at a bounded per-step cost (the vLLM
         max_num_batched_tokens analog) so a long prompt can never starve
         decode ITL, while a burst of arrivals still ramps many slots per
-        step.  Prefill dispatches are pipelined: only a FINAL chunk's
-        sampled token syncs the host.  Blocks were reserved at admission
+        step.  Prefill dispatches are pipelined: a FINAL chunk's
+        sampled token joins the decode mirrors on the device and is read
+        by the host at the end of the step.  Blocks were reserved at admission
         — no allocation can fail here.
 
         With speculation, the draft model prefills the same prompt into
@@ -1552,6 +1593,7 @@ class PagedJaxLLMEngine:
                     # the frontier loop below keeps them in lockstep)
                     while req.draft_prefill_pos < plen:
                         self._draft_prefill_chunk_locked(req)
+                    self._mark_dirty("draft_prefill")  # no join ran for it
                     continue
                 remaining = plen - req.prefill_pos
                 c = min(self.config.prefill_chunk,
@@ -1620,7 +1662,7 @@ class PagedJaxLLMEngine:
                     self._slot_temp[slot] = req.gen.temperature
                     self._slot_topk[slot] = req.gen.top_k
                     self._first_pending.append((slot, req, ids))
-                    self._mark_dirty("final_prefill")
+                    self._join_locked(slot, req, ids)
                 budget -= take
                 self._tel_prefill_spent += take
 
@@ -1641,6 +1683,42 @@ class PagedJaxLLMEngine:
         self._dirty = True
         if self._dirty_cause is None:
             self._dirty_cause = cause
+
+    def _mirrors(self) -> tuple:
+        return (self._d_next, self._d_lengths, self._d_active,
+                self._d_remaining, self._d_stops, self._d_temp, self._d_topk,
+                *(() if self._spec is None else (self._d_spec,)))
+
+    def _join_locked(self, slot: int, req: Optional[_PagedReq] = None,
+                     ids=None):
+        """Queue ``_join_impl`` for ``slot``: ``req`` enters the decode
+        batch with the first token its final prompt chunk sampled
+        (``ids``), or, ``req`` None, the row a finished request held is
+        cleared.  Either way the device is not waited for: the program
+        runs after the prompt chunk and before the next decode chunk, in
+        the order dispatched."""
+        if self._dirty:
+            return  # an upload of every row is due before the next dispatch
+        row = np.full(_JOIN_ROW, -1, np.int32)
+        row[:5] = slot, 0, 0, 0, 0
+        temp = 0.0
+        if req is not None:
+            g = req.gen
+            row[1:5] = (len(req.prompt),
+                        g.max_new_tokens - len(req.out_tokens) - 1, g.top_k,
+                        req.spec_enabled)
+            row[5:5 + len(g.stop_token_ids)] = g.stop_token_ids
+            temp = g.temperature
+            self._c["decode_joins"] += 1
+        with tracing.region("engine.join", rows=int(req is not None),
+                            slot=slot):
+            mirrors = self._join(
+                self._mirrors(), self._no_ids if ids is None else ids,
+                self._put(row), self._put([temp], np.float32))
+        (self._d_next, self._d_lengths, self._d_active, self._d_remaining,
+         self._d_stops, self._d_temp, self._d_topk, *spec) = mirrors
+        if spec:
+            self._d_spec = spec[0]
 
     def _emit_locked(self, req: _PagedReq, token: int):
         req.out_tokens.append(token)
@@ -1680,8 +1758,13 @@ class PagedJaxLLMEngine:
             req.draft_blocks = []
         self._slot_req[req.slot] = None
         self._lengths[req.slot] = 0
+        # a finish dirties nothing: the decode program cleared the row's
+        # `active` itself, and this zeroes its length (a stale one would
+        # index past a narrower table on the gather path).  The callers
+        # that free a row the device still decodes mark the mirrors dirty
+        # first, and an upload follows.
+        self._join_locked(req.slot)
         req.slot = -1
-        self._mark_dirty("finish")
 
     def _preempt_locked(self, exclude_slot: int = -1) -> bool:
         """Evict the youngest decode-active request by recompute: free its
@@ -1880,8 +1963,11 @@ class PagedJaxLLMEngine:
             slo.note_specdec(self.slo_label, proposed, accepted)
 
     def _resolve_first_tokens_locked(self):
-        """Book pending first-token futures (one sync covers them all —
-        their programs finished long before the drain that calls this)."""
+        """Book pending first-token futures: each read waits for its
+        prompt chunk alone, never for a decode chunk queued after it, and
+        a request's first token is booked before any token such a chunk
+        decodes for it (``step()`` calls this before it returns; a drain
+        and an upload call it too)."""
         pending, self._first_pending = self._first_pending, []
         for slot, req, ids in pending:
             if self._slot_req[slot] is not req:
@@ -1910,15 +1996,20 @@ class PagedJaxLLMEngine:
             self._resolve_first_tokens_locked()
 
     def step(self, decode: bool = True) -> Dict[int, List[int]]:
-        """One engine step: admit, one prefill chunk, one decode chunk.
+        """One engine step: admit, prompt chunks up to the budget, one
+        decode chunk.
 
-        Steady-state full-batch decode PIPELINES: the chunk dispatched here
-        is collected on the NEXT step, so its device compute overlaps this
-        step's host bookkeeping and readback latency.  Any non-steady event
-        (admission, prefill, a finished request, preemption pressure)
-        drains the in-flight chunk first — correctness never depends on
-        the lagged view.  ``decode=False`` runs admission/prefill only
-        (ramp control)."""
+        Decode PIPELINES: the chunk dispatched here is collected on the
+        NEXT step, so its device compute overlaps this step's host
+        bookkeeping and readback latency.  Admission, prompt chunks, a row
+        that joins the batch after its final chunk and a finished request
+        all keep the pipeline: the row state the decode program reads is
+        updated on the device, in the order dispatched (``_join_locked``).
+        A first token is returned by the step that dispatched its final
+        prompt chunk.  What drains the chunk in flight first: preemption
+        pressure, a cancel, an import or export, and a step with no row to
+        decode.  ``decode=False`` runs admission/prefill only (ramp
+        control)."""
         now = time.monotonic()
         # device telemetry: one attribute read + None check when disabled
         tel = self._telemetry
@@ -1936,8 +2027,8 @@ class PagedJaxLLMEngine:
                 # decode chunk: a new slot's fresh blocks are disjoint from
                 # every in-flight table row (its own row was zeros → sink),
                 # and prefill dispatches chain after the decode on the pool
-                # dataflow.  Only a final prefill chunk (_dirty → refresh)
-                # forces a drain, below.
+                # dataflow.  A final chunk's row joins the mirrors on the
+                # device and decodes in this step's dispatch, below.
                 self._admit_locked()
                 self._prefill_step_locked()
             chunk = self.config.decode_chunk
@@ -1978,6 +2069,9 @@ class PagedJaxLLMEngine:
                     self._collect_locked(prev[0], prev[1], margin=app,
                                          spec_slots=prev[2],
                                          acc_dev=prev[3])
+                # this step's final prompt chunks: the device already runs
+                # the decode chunk queued behind them
+                self._resolve_first_tokens_locked()
             else:
                 self._drain_locked("idle")
             emitted = self._gather_emitted_locked(before)
@@ -2214,6 +2308,7 @@ class PagedJaxLLMEngine:
                            "top_k": g.top_k, "seed": g.seed,
                            "stop_token_ids": list(g.stop_token_ids)}}
             req.done = True
+            self._mark_dirty("export")
             self._free_slot_locked(req)
             del self._requests[request_id]
             return out
@@ -2410,7 +2505,8 @@ class PagedJaxLLMEngine:
     # -- warmup ---------------------------------------------------------
 
     def warmup(self, max_len: Optional[int] = None):
-        """Compile the decode program for every (B, W) table bucket.
+        """Compile the decode program for every (B, W) table bucket, the
+        prefill program for every chunk width, and the join.
 
         W buckets are powers of two up to the per-sequence block cap (or
         the blocks covering ``max_len`` + pipelining margin, if given); a
@@ -2505,6 +2601,11 @@ class PagedJaxLLMEngine:
                 if c >= c_cap:
                     break
                 c *= 2
+            # the join: one shape, over mirrors of its own
+            jax.block_until_ready(self._join(
+                (zi(b), zi(b), zi(b), zi(b), stops, zf(b), zi(b),
+                 *(() if self._spec is None else (zi(b),))),
+                zi(1), zi(_JOIN_ROW), zf(1)))
         # what ran, for whoever has to show that it did (device_report)
         # compiles from here on ran inside serving
         self._compile_base = device_telemetry.compile_totals()

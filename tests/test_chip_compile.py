@@ -262,6 +262,31 @@ def _engine_programs(cfg, params, pool, sh, b, w, steps, c, prefill_w):
     return decode, prefill
 
 
+def test_join_program_compiles_at_cell_shapes(one_chip):
+    """``PagedJaxLLMEngine._join_impl`` over the serving cells' 64 rows: a
+    handful of scatters into the decode mirrors, no kernel, no temporary
+    worth the name (it runs between a prompt chunk and a decode chunk, a
+    request at a time)."""
+    import types
+
+    from ray_tpu.llm.engine import _MAX_STOP_IDS
+    from ray_tpu.llm.paged import _JOIN_ROW, PagedJaxLLMEngine
+
+    def i32(*shape):
+        return _spec(shape, jnp.int32, one_chip)
+
+    def f32(*shape):
+        return _spec(shape, jnp.float32, one_chip)
+
+    b = 64
+    compiled = jax.jit(functools.partial(
+        PagedJaxLLMEngine._join_impl, types.SimpleNamespace(max_seq=4096))
+    ).lower((i32(b), i32(b), i32(b), i32(b), i32(b, _MAX_STOP_IDS), f32(b),
+             i32(b)), i32(1), i32(_JOIN_ROW), f32(1)).compile()
+    assert "tpu_custom_call" not in compiled.as_text()
+    assert compiled.memory_analysis().temp_size_in_bytes < 1 << 20
+
+
 # what the two programs of the Llama family lowered to on the commit before
 # the family seam (PR 31), at the Mistral cell's shapes: sha256 of the
 # StableHLO text less the line of the Pallas kernel's call (its payload

@@ -111,8 +111,9 @@ def test_first_token_waits_a_bounded_number_of_token_steps(
 def test_warmup_compiles_the_same_programs_by_count(tiny_cfg, tiny_params):
     """The serving cell's geometry (4,096 positions in 16-token blocks,
     256-token chunks) at the default quantum: nine decode table widths and
-    five prefill chunks, as at every quantum before, and a served batch that
-    joins, grows across table widths and finishes compiles nothing more."""
+    five prefill chunks, as at every quantum before, and the one join; a
+    served batch that joins, grows across table widths and finishes
+    compiles nothing more."""
     eng = PagedJaxLLMEngine(
         LLMConfig(model_config=tiny_cfg, max_batch_size=4, max_seq_len=4096,
                   num_blocks=600), params=tiny_params)
@@ -122,8 +123,11 @@ def test_warmup_compiles_the_same_programs_by_count(tiny_cfg, tiny_params):
     assert rep["prefill_chunks"] == [16, 32, 64, 128, 256]
     assert eng._decode._cache_size() == 9
     assert eng._prefill_chunk._cache_size() == 5
+    assert eng._join._cache_size() == 1
     out = eng.generate([_prompt(0, 20), _prompt(1, 300), _prompt(2, 70)],
                        GenerationConfig(max_new_tokens=40))
     assert [len(o) for o in out] == [40, 40, 40]
     assert eng.counters()["compiles"] == 0
+    assert eng.counters()["decode_joins"] == 3
     assert eng._decode._cache_size() == 9
+    assert eng._join._cache_size() == 1  # rows entered and left through it

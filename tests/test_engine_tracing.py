@@ -42,17 +42,24 @@ def _prompts(n, size):
 @pytest.fixture(scope="module")
 def host_events(tiny, tmp_path_factory):
     """``{line name: [(name, start_ns, end_ns, stats)]}`` of the region
-    events of a capture around a few engine steps."""
+    events of a capture around an engine's steps: three requests join and
+    decode in a pool that holds two, so a step preempts one (a drain and an
+    upload of the mirrors, ``engine.refresh``: a join or a finish causes
+    neither), and the last step finds the engine idle (a drain again)."""
     from jax.profiler import ProfileData
 
-    eng = _engine(tiny)
+    eng = _engine(tiny, num_blocks=14, enable_prefix_caching=False)
     eng.generate(_prompts(1, 12), GenerationConfig(max_new_tokens=4))  # compile
     logdir = str(tmp_path_factory.mktemp("xplane"))
     jax.profiler.start_trace(logdir)
     try:
-        eng.generate(_prompts(2, 20), GenerationConfig(max_new_tokens=12))
+        for prompt in _prompts(3, 16):
+            eng.add_request(prompt, GenerationConfig(max_new_tokens=40))
+        while eng.has_work():
+            eng.step()
     finally:
         jax.profiler.stop_trace()
+    assert eng.counters()["preemptions"] >= 1
     (path,) = glob.glob(f"{logdir}/**/*.xplane.pb", recursive=True)
     lines = {}
     for plane in ProfileData.from_file(path).planes:
@@ -68,7 +75,7 @@ def host_events(tiny, tmp_path_factory):
 
 
 @pytest.mark.parametrize("name", ["engine.step", "engine.admit",
-                                  "engine.prefill_chunk",
+                                  "engine.prefill_chunk", "engine.join",
                                   "engine.ensure_blocks", "engine.refresh",
                                   "engine.decode_dispatch", "engine.collect",
                                   "engine.drain"])
@@ -170,7 +177,7 @@ def test_first_token_stages_partition_enqueue_to_first_yield(staged, what):
 @pytest.fixture(scope="module")
 def counted(tiny):
     """Counter reads after each phase of one engine's life: a batch with a
-    shared prefix, a drain forced by a late arrival, a cancel."""
+    shared prefix, a late arrival that joins a pipelined row, a cancel."""
     eng = _engine(tiny)
     reads = [eng.counters()]
     shared = list(range(1, 25))  # three full blocks
@@ -180,7 +187,8 @@ def counted(tiny):
     outs = eng.generate(prompts[:1], gen) + eng.generate(prompts[1:], gen)
     reads.append(eng.counters())
     # a request decoding alone pipelines; a late arrival's final prefill
-    # chunk then forces exactly one drain of the chunk in flight
+    # chunk joins it on the device and drains nothing; the cancel that
+    # follows drains the chunk in flight, once
     eng.add_request([7] * 12, GenerationConfig(max_new_tokens=40))
     while eng.counters()["decode_dispatches_pipelined"] \
             == reads[-1]["decode_dispatches_pipelined"]:
@@ -267,10 +275,15 @@ def test_engine_counters(counted, preempted, staged, case):
         assert first["steps"] > 0 and first["host_s"] > 0
         assert first["device_wait_s"] > 0
     elif case == "forced_drain":
-        before, after = counted["mid"]["drains"], reads[2]["drains"]
-        assert after.get("final_prefill", 0) \
-            == before.get("final_prefill", 0) + 1
-        assert sum(after.values()) == sum(before.values()) + 1
+        mid, joined, end = counted["mid"], reads[2], reads[3]
+        assert joined["drains"] == mid["drains"]
+        assert joined["decode_joins"] == mid["decode_joins"] + 1
+        assert (joined["decode_dispatches_pipelined"]
+                == mid["decode_dispatches_pipelined"] + 1)
+        # the cancel, and the step that found the engine idle at the end
+        assert {k: n - mid["drains"].get(k, 0)
+                for k, n in end["drains"].items()} \
+            == {"flush": 0, "cancel": 1, "idle": 1}
     elif case == "forced_preemption":
         got, outs = preempted
         assert all(len(o) == 40 for o in outs)
