@@ -309,6 +309,9 @@ def _err_frame(exc: BaseException, tb: str) -> bytes:
             protocol=5)
 
 
+_region = None  # tracing.region, resolved at the first frame
+
+
 class RpcServer:
     """Serves registered handlers; one handler thread pool per server.
 
@@ -411,11 +414,24 @@ class RpcServer:
                 self.register(prefix + name[len("Handle"):], getattr(obj, name))
 
     def _dispatch(self, sock, send_lock, msg_id, body):
+        """One frame, decode to reply, as a ``serve.rpc`` region on the
+        profiler's timeline (``method``): in a process that serves a model
+        the handler threads hold the interpreter beside the engine loop."""
+        global _region
+        if _region is None:
+            # not at import: ray_tpu.util imports the core, which imports this
+            from ray_tpu.util.tracing import region as _region
+        with _region("serve.rpc") as span:
+            self._handle_frame(sock, send_lock, msg_id, body, span)
+
+    def _handle_frame(self, sock, send_lock, msg_id, body, span):
         try:
             method, payload = decode_body(body)
         except Exception:
             logger.exception("rpc: undecodable frame")
             return
+        if span is not None:
+            span.set_metadata(method=method)
         chaos = _get_chaos().check(method)
         if chaos == "drop_request":
             return  # server never saw it

@@ -1117,8 +1117,10 @@ class CoreWorker:
         """Capture a JAX profiler trace (XPlane) for ``duration_s``
         (reference: the GPU profilers shipped as runtime-env plugins,
         _private/runtime_env/nsight.py; the TPU-native analog is the jax
-        profiler — SURVEY §5 tracing). Returns the trace directory + files;
-        open with TensorBoard or xprof."""
+        profiler — SURVEY §5 tracing).  One mode, ``tracing.capture``: no
+        Python tracer, and the ``.xplane.pb`` alone is written.  Returns the
+        trace directory, that file, and what the capture cost (``traced_s``,
+        ``write_s``, ``bytes``); open with TensorBoard or xprof."""
         duration = min(float(req.get("duration_s", 3.0)), 60.0)
         logdir = req.get("logdir") or os.path.join(
             tempfile.gettempdir(), f"ray-tpu-jaxprof-{os.getpid()}-{int(time.time())}")
@@ -1127,19 +1129,12 @@ class CoreWorker:
         def run():
             name_os_thread()
             try:
-                import jax
-
-                os.makedirs(logdir, exist_ok=True)
-                jax.profiler.start_trace(logdir)
-                time.sleep(duration)
-                jax.profiler.stop_trace()
-                files = []
-                for dp, _, fs in os.walk(logdir):
-                    files.extend(os.path.join(dp, f) for f in fs)
+                # the capture stops the tracer in a ``finally``: a failed
+                # one cannot leave it on in a serving process
+                with tracing.capture(logdir) as got:
+                    time.sleep(duration)
                 server.send_reply(reply_token, {
-                    "pid": os.getpid(), "logdir": logdir,
-                    "files": sorted(files),
-                })
+                    "pid": os.getpid(), "logdir": logdir, **got})
             except Exception as e:  # noqa: BLE001 — the caller must hear back
                 try:
                     server.send_error_reply(reply_token, e)
@@ -1855,14 +1850,16 @@ class CoreWorker:
         count = 0
         for item in result:
             count += 1
-            entry = self._pack_one_return(
-                ObjectID.from_task(spec.task_id, count), item, spec)
-            # RELIABLE send: the anchor count rides the (retried) task reply,
-            # so a silently-dropped item would strand the consumer at that
-            # index forever — deliver each item with the same guarantees
-            self.pool.get(tuple(spec.owner_addr)).call(
-                "StreamingItem", {"item": entry, "task_id": spec.task_id},
-                timeout=global_config().gcs_rpc_timeout_s)
+            with tracing.region("serve.task", name=spec.name, item=count):
+                entry = self._pack_one_return(
+                    ObjectID.from_task(spec.task_id, count), item, spec)
+                # RELIABLE send: the anchor count rides the (retried) task
+                # reply, so a silently-dropped item would strand the
+                # consumer at that index forever — deliver each item with
+                # the same guarantees
+                self.pool.get(tuple(spec.owner_addr)).call(
+                    "StreamingItem", {"item": entry, "task_id": spec.task_id},
+                    timeout=global_config().gcs_rpc_timeout_s)
         anchor = ObjectID.from_task(spec.task_id, 0)
         return [self._pack_one_return(anchor, count, spec)]
 
@@ -2109,28 +2106,39 @@ class CoreWorker:
         spec: TaskSpec = req["spec"]
         flight_recorder.record("actor_task", spec.name or spec.actor_method,
                                f"start:a{spec.attempt}")
+        streams = spec.num_returns == "streaming"
         try:
             self._record_exec_event(spec)
             with tracing.activate_from_spec(spec):
-                args = [self._unpack_arg(a) for a in spec.args]
-                kwargs = {k: self._unpack_arg((kind, p)) for k, kind, p in spec.kwargs}
-                exec_t0 = time.perf_counter()
-                if spec.actor_method == "__ray_tpu_call__":
-                    # Hidden protocol: run fn(instance, *args, **kwargs) on
-                    # the actor (used by collectives/train to inject gang
-                    # setup).
-                    fn, args = args[0], args[1:]
-                    result = fn(self._actor_instance, *args, **kwargs)
-                else:
-                    method = getattr(self._actor_instance, spec.actor_method)
-                    result = method(*args, **kwargs)
-                runtime_metrics.observe_task_execution(
-                    time.perf_counter() - exec_t0, kind="actor")
-                if hasattr(result, "__await__"):
-                    import asyncio
+                # on the profiler's timeline: a replica's task threads hold
+                # the interpreter beside its engine loop
+                with tracing.region("serve.task",
+                                    name=spec.name or spec.actor_method):
+                    args = [self._unpack_arg(a) for a in spec.args]
+                    kwargs = {k: self._unpack_arg((kind, p)) for k, kind, p in spec.kwargs}
+                    exec_t0 = time.perf_counter()
+                    if spec.actor_method == "__ray_tpu_call__":
+                        # Hidden protocol: run fn(instance, *args, **kwargs)
+                        # on the actor (used by collectives/train to inject
+                        # gang setup).
+                        fn, args = args[0], args[1:]
+                        result = fn(self._actor_instance, *args, **kwargs)
+                    else:
+                        method = getattr(self._actor_instance, spec.actor_method)
+                        result = method(*args, **kwargs)
+                    runtime_metrics.observe_task_execution(
+                        time.perf_counter() - exec_t0, kind="actor")
+                    if hasattr(result, "__await__"):
+                        import asyncio
 
-                    result = asyncio.run(_await(result))
-                returns = self._pack_returns(spec, result)
+                        result = asyncio.run(_await(result))
+                    if not streams:
+                        returns = self._pack_returns(spec, result)
+                if streams:
+                    # outside the task's region, which would span every
+                    # wait for the stream's next item: each item handed
+                    # out is a region of its own there
+                    returns = self._stream_returns(spec, result)
             self.server.send_reply(reply_token, {"status": "ok", "returns": returns})
         except Exception as e:  # noqa: BLE001
             self.server.send_reply(

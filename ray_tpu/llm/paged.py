@@ -646,7 +646,14 @@ class PagedJaxLLMEngine:
         # alone), read without it — a reader subtracts two reads
         self._c: Dict[str, float] = dict.fromkeys(
             _COUNTERS + fam.decode_counters, 0)
-        self._c.update(host_s=0.0, device_wait_s=0.0, loop_idle_s=0.0)
+        self._c.update(host_s=0.0, device_wait_s=0.0, loop_idle_s=0.0,
+                       device_empty_s=0.0)
+        # device_empty_s: when the host learned that nothing is queued on
+        # the device (None: something is, or may be), and how many decode
+        # chunks had been dispatched when the last prompt chunk was (a
+        # drain proves that chunk done only by reading a later one)
+        self._empty_since: Optional[float] = time.monotonic()
+        self._chunk_mark = -1
         self._drains: Dict[str, int] = {}
         # why the device mirrors went stale (first cause since the last
         # refresh): names the drain that the refresh forces
@@ -870,7 +877,9 @@ class PagedJaxLLMEngine:
         not a device figure: for the device read ``counters``'
         ``device_wait_s`` against ``host_s``, or a ``state.jax_profile``
         trace."""
-        with self._lock:
+        # a step holds the lock for its whole body: a publisher or a
+        # dashboard waits here, on a thread of its own
+        with tracing.region("serve.utilization"), self._lock:
             active = sum(1 for r in self._slot_req if r is not None)
             free = self.blocks.num_free()
             cached = len(self.blocks.free_cached)
@@ -957,7 +966,15 @@ class PagedJaxLLMEngine:
         ``preemptions``, ``kv_demotions``; ``host_s`` / ``device_wait_s``
         (each step's wall time: blocked in the device reads of
         ``engine.collect`` / ``engine.drain``, and everything else);
-        ``loop_idle_s`` (the serving loop found no work); ``compiles`` /
+        ``loop_idle_s`` (the serving loop found no work);
+        ``device_empty_s`` (the idle share by the engine's own account, with
+        no capture: seconds from the moment the host LEARNED that nothing is
+        queued on the device, a drain whose blocking reads left no chunk in
+        flight and no prompt chunk behind them, to the next dispatch of a
+        program, a decode chunk that finds none in flight or a prompt chunk,
+        counted only while a request is live; a steady pipelined step books
+        nothing, and a device that idles behind a chunk the host has not
+        read yet is not seen: a trace sees that); ``compiles`` /
         ``compile_s`` (backend compiles in this process since
         ``warmup()`` returned: anything above zero ran inside serving);
         and the family's ``decode_counters``, booked by the decode program
@@ -1304,6 +1321,7 @@ class PagedJaxLLMEngine:
         # most of a step here before its request is even queued
         t_submit = time.monotonic() if self.slo_label is not None else 0.0
         with self._lock:
+            self._note_arrival_locked()
             self._req_counter += 1
             req = _PagedReq(self._req_counter, list(prompt), gen)
             req.spec_enabled = self._spec is not None
@@ -1322,6 +1340,22 @@ class PagedJaxLLMEngine:
             slo.record_stage(self.slo_label, "enqueue_wait",
                              req.t_enqueue - t_submit)
         return req.request_id
+
+    def _note_arrival_locked(self):
+        """A request is about to become live.  ``device_empty_s`` counts
+        only while one is: where the device is known to be empty and no
+        request is live, its clock starts at this arrival, not at the drain
+        that emptied the device."""
+        if (self._empty_since is not None and not self._pending
+                and self._slot_req.count(None) == self.max_batch):
+            self._empty_since = time.monotonic()
+
+    def _book_empty_locked(self):
+        """A program has just been queued on a device the host knew to be
+        empty (the caller tested ``_empty_since``): its host-side build and
+        dispatch are part of the empty time."""
+        self._c["device_empty_s"] += time.monotonic() - self._empty_since
+        self._empty_since = None
 
     def has_work(self) -> bool:
         with self._lock:
@@ -1438,77 +1472,80 @@ class PagedJaxLLMEngine:
         — proportional to ACTUAL prompt length, never max_seq.  Reserving
         the prompt up front (instead of chunk-by-chunk) makes the system
         livelock-free: a mid-prefill request can never stall on allocation,
-        so every admitted request reaches the preemptible decode state."""
-        if not self._pending:
-            return
-        with tracing.region("engine.admit") as span:
-            admitted, hit = self._admit_pending_locked()
-            if span is not None:
-                span.set_metadata(admitted=admitted, prefix_hit_tokens=hit)
-
-    def _admit_pending_locked(self) -> Tuple[int, int]:
-        """``(requests admitted, prefix-hit tokens)``."""
-        admitted = hit = 0
+        so every admitted request reaches the preemptible decode state.
+        One ``engine.admit`` region a request tried (the hash and match of
+        ITS prompt's prefix lie inside it)."""
         for slot in range(self.max_batch):
-            if not self._pending or self._slot_req[slot] is not None:
+            if not self._pending:
+                return
+            if self._slot_req[slot] is not None:
                 continue
             req = self._pending[0]
-            shared, matched, hit_miss = self._match_prefix_tiered(req.prompt)
-            # reserve every block any (pow2-bucketed) prefill chunk's table
-            # must cover — chunk padding may reach past the prompt's own
-            # blocks (trimmed at prefill end); +1 is the first decode
-            # write's spare
-            cover = _prefill_plan(len(req.prompt), matched,
-                                  self.config.prefill_chunk, self.bs)
-            need = cover - len(shared) + 1
-            fresh = self.blocks.alloc(need)
-            if fresh is None:
-                self.blocks.release(shared)
-                break  # pool full: keep FIFO order, retry next step
-            if req.spec_enabled:
-                # the draft prefills the WHOLE prompt (no prefix cache in
-                # the draft pool), so it needs the full chunk-padded cover
-                dcover = _prefill_plan(len(req.prompt), 0,
-                                       self.config.prefill_chunk, self.bs)
-                dfresh = self.draft_blocks.alloc(dcover + 1)
-                if dfresh is None:
-                    # draft-pool exhaustion degrades THIS request to
-                    # plain decode — never blocks admission (zero drops)
-                    req.spec_enabled = False
-                else:
-                    req.draft_blocks = dfresh
-                    req.draft_prefill_pos = 0
-            if self.blocks.prefix_caching:
-                from ray_tpu._private import runtime_metrics
+            with tracing.region("engine.admit", rid=req.request_id) as span:
+                matched = self._admit_one_locked(req, slot)
+                if span is not None:
+                    span.set_metadata(admitted=int(matched is not None),
+                                      prefix_hit_tokens=matched or 0)
+            if matched is None:
+                return  # pool full: keep FIFO order, retry next step
 
-                hbm_hits, misses, revived = hit_miss
-                runtime_metrics.add_prefix_cache_hits("hbm", hbm_hits)
-                for tier in revived:
-                    runtime_metrics.add_prefix_cache_hits(tier)
-                runtime_metrics.add_prefix_cache_misses(misses)
-            self._pending.popleft()
-            req.slot = slot
-            req.blocks = shared + fresh
-            req.prefill_pos = matched
-            self._admit_counter += 1
-            req.admitted_order = self._admit_counter
-            self._slot_req[slot] = req
-            admitted += 1
-            hit += matched
-            req.prefix_hit_tokens += matched
-            self._c["prefix_hit_tokens"] += matched
-            if (self.slo_label is not None and req.t_enqueue
-                    and not req.t_admit):
-                # first admission only: a preempted request re-queues
-                # with t_admit set, and its stages were booked once (its
-                # recompute shows in first_emit or decode, where the
-                # client waits for it)
-                from ray_tpu.serve._private import slo
+    def _admit_one_locked(self, req: _PagedReq, slot: int) -> Optional[int]:
+        """Admit the queue's head into ``slot``: its prefix-hit tokens, or
+        None where the pool has no blocks for it."""
+        shared, matched, hit_miss = self._match_prefix_tiered(req.prompt)
+        # reserve every block any (pow2-bucketed) prefill chunk's table
+        # must cover — chunk padding may reach past the prompt's own
+        # blocks (trimmed at prefill end); +1 is the first decode
+        # write's spare
+        cover = _prefill_plan(len(req.prompt), matched,
+                              self.config.prefill_chunk, self.bs)
+        need = cover - len(shared) + 1
+        fresh = self.blocks.alloc(need)
+        if fresh is None:
+            self.blocks.release(shared)
+            return None
+        if req.spec_enabled:
+            # the draft prefills the WHOLE prompt (no prefix cache in
+            # the draft pool), so it needs the full chunk-padded cover
+            dcover = _prefill_plan(len(req.prompt), 0,
+                                   self.config.prefill_chunk, self.bs)
+            dfresh = self.draft_blocks.alloc(dcover + 1)
+            if dfresh is None:
+                # draft-pool exhaustion degrades THIS request to
+                # plain decode — never blocks admission (zero drops)
+                req.spec_enabled = False
+            else:
+                req.draft_blocks = dfresh
+                req.draft_prefill_pos = 0
+        if self.blocks.prefix_caching:
+            from ray_tpu._private import runtime_metrics
 
-                req.t_admit = time.monotonic()
-                slo.record_stage(self.slo_label, "queue_wait",
-                                 req.t_admit - req.t_enqueue)
-        return admitted, hit
+            hbm_hits, misses, revived = hit_miss
+            runtime_metrics.add_prefix_cache_hits("hbm", hbm_hits)
+            for tier in revived:
+                runtime_metrics.add_prefix_cache_hits(tier)
+            runtime_metrics.add_prefix_cache_misses(misses)
+        self._pending.popleft()
+        req.slot = slot
+        req.blocks = shared + fresh
+        req.prefill_pos = matched
+        self._admit_counter += 1
+        req.admitted_order = self._admit_counter
+        self._slot_req[slot] = req
+        req.prefix_hit_tokens += matched
+        self._c["prefix_hit_tokens"] += matched
+        if (self.slo_label is not None and req.t_enqueue
+                and not req.t_admit):
+            # first admission only: a preempted request re-queues
+            # with t_admit set, and its stages were booked once (its
+            # recompute shows in first_emit or decode, where the
+            # client waits for it)
+            from ray_tpu.serve._private import slo
+
+            req.t_admit = time.monotonic()
+            slo.record_stage(self.slo_label, "queue_wait",
+                             req.t_admit - req.t_enqueue)
+        return matched
 
     def _decode_ready(self, req: _PagedReq) -> bool:
         """A slot joins the decode batch only when its target prefill —
@@ -1612,7 +1649,8 @@ class PagedJaxLLMEngine:
                 is_last = p0 + take >= plen
                 sample_idx = (plen - 1 - p0) if is_last else 0
                 with tracing.region("engine.prefill_chunk", tokens=take,
-                                    bucket=c, is_last=int(is_last), p0=p0):
+                                    bucket=c, is_last=int(is_last), p0=p0,
+                                    rid=req.request_id):
                     ids, self.pool, self._d_key, *state = self._prefill_chunk(
                         self.params, self._put(tokens), self.pool,
                         self._put(table), self._put(p0, np.int32),
@@ -1622,6 +1660,9 @@ class PagedJaxLLMEngine:
                         *self._slot_args(slot, take))
                     if state:
                         self.slot_state = state[0]
+                if self._empty_since is not None:
+                    self._book_empty_locked()
+                self._chunk_mark = self._c["decode_dispatches"]
                 req.prefill_chunks += 1
                 self._c["prefill_chunks"] += 1
                 self._c["prefill_kernel_chunks"] += self._prefill_kernel
@@ -1711,7 +1752,8 @@ class PagedJaxLLMEngine:
             temp = g.temperature
             self._c["decode_joins"] += 1
         with tracing.region("engine.join", rows=int(req is not None),
-                            slot=slot):
+                            slot=slot,
+                            rid=-1 if req is None else req.request_id):
             mirrors = self._join(
                 self._mirrors(), self._no_ids if ids is None else ids,
                 self._put(row), self._put([temp], np.float32))
@@ -1994,6 +2036,11 @@ class PagedJaxLLMEngine:
                 self._collect_locked(em_dev, active, margin=0,
                                      spec_slots=spec_slots, acc_dev=acc_dev)
             self._resolve_first_tokens_locked()
+            if self._c["decode_dispatches"] > self._chunk_mark:
+                # every decode chunk is read, and the last prompt chunk
+                # ran before one of them: nothing is queued (a join's
+                # microseconds aside) until the next dispatch books this
+                self._empty_since = time.monotonic()
 
     def step(self, decode: bool = True) -> Dict[int, List[int]]:
         """One engine step: admit, prompt chunks up to the budget, one
@@ -2137,6 +2184,8 @@ class PagedJaxLLMEngine:
         c["decode_live_rows"] += len(active) * steps
         if prev is not None:
             c["decode_dispatches_pipelined"] += 1
+        elif self._empty_since is not None:
+            self._book_empty_locked()
         return prev
 
     def _spec_step_locked(self, table, active: List[int]):
@@ -2394,6 +2443,7 @@ class PagedJaxLLMEngine:
             blocks = self.blocks.alloc(nb)
             if blocks is None:
                 return None
+            self._note_arrival_locked()
             pad = _bucket_pow2(nb)
             idx = np.zeros(pad, np.int32)
             idx[:nb] = blocks  # pad rows scatter into sink block 0
@@ -2405,6 +2455,8 @@ class PagedJaxLLMEngine:
                 padded[n] = jnp.asarray(xp)
             self.pool = self._import_blocks(
                 self.pool, jnp.asarray(idx), padded)
+            if self._empty_since is not None:
+                self._book_empty_locked()  # the scatter is a dispatch
             if self.slot_state is not None:
                 self.slot_state = self._import_slot(
                     self.slot_state, jnp.int32(slot),

@@ -209,6 +209,8 @@ class LLMServer:
             "compile_cache_dir": jax.config.jax_compilation_cache_dir,
             "native": _native.status(),
             "utilization": self.utilization(),
+            # what this process's last state.jax_profile capture cost
+            "last_capture": tracing.last_capture(),
         }
 
     def reference_check(self, prompt: Sequence[int],
@@ -349,6 +351,12 @@ class LLMServer:
 
             slo.note_specdec_request(stats[0], stats[1])
 
+    def _buffer_locked(self, wkey):
+        """``(done, tokens so far)`` of ``wkey``'s stream, under ``_cv``."""
+        done = wkey in self._done
+        return done, (self._done[wkey] if done
+                      else self._waiters.get(wkey, []))
+
     def _iter_tokens(self, wkey):
         """Yield ``wkey``'s token chunks as they decode (generate_stream's
         engine-side loop, shared with the disaggregated decode stage).
@@ -369,19 +377,25 @@ class LLMServer:
                                 "LLM engine loop failed") from self._error
                         if self._stop:
                             raise RuntimeError("LLM server shut down")
-                        done = wkey in self._done
-                        buf = (self._done[wkey] if done
-                               else self._waiters.get(wkey, []))
+                        done, buf = self._buffer_locked(wkey)
                         if len(buf) > sent or done:
                             break
                         self._cv.wait(timeout=0.1)
-                    chunk = list(buf[sent:])
-                    sent += len(chunk)
-                    if done:
-                        self._done.pop(wkey, None)
-                if chunk:
-                    if sent == len(chunk):  # the request's first chunk
+                # the pass that hands tokens out, without the wait above:
+                # this thread now holds the interpreter beside the loop's
+                with tracing.region("serve.iter_tokens",
+                                    rid=wkey[2]) as span:
+                    with self._cv:  # read again: an export swaps buffers
+                        done, buf = self._buffer_locked(wkey)
+                        chunk = list(buf[sent:])
+                        sent += len(chunk)
+                        if done:
+                            self._done.pop(wkey, None)
+                    if span is not None:
+                        span.set_metadata(tokens=len(chunk))
+                    if chunk and sent == len(chunk):  # the first chunk
                         stream_out_s = self._note_first_yield(wkey)
+                if chunk:
                     yield chunk
                 if done:
                     completed = True
@@ -435,6 +449,17 @@ class LLMServer:
     _MAX_ADAPTER_ENGINES = 4
 
     def _submit(self, model: Optional[str], prompt, gen):
+        """``_enqueue``, entry to return, as one region on the profiler's
+        timeline; ``rid`` is the engine's request id, which the engine
+        loop's ``engine.admit`` / ``.prefill_chunk`` / ``.join`` and the
+        stream's ``serve.iter_tokens`` carry too."""
+        with tracing.region("serve.add_request") as span:
+            wkey = self._enqueue(model, prompt, gen)
+            if span is not None:
+                span.set_metadata(rid=wkey[2])
+            return wkey
+
+    def _enqueue(self, model: Optional[str], prompt, gen):
         """Resolve the engine for ``model`` and enqueue the request under
         ONE _engines_lock critical section, returning the waiter key.
 

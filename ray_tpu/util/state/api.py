@@ -847,12 +847,17 @@ class StateApiClient:
                     logdir: Optional[str] = None) -> dict:
         """Capture a JAX profiler (XPlane) trace on one worker; open the
         returned logdir with TensorBoard/xprof (SURVEY §5: the TPU analog of
-        the reference's GPU profiler plugins).
+        the reference's GPU profiler plugins).  One mode
+        (``tracing.capture``): no Python tracer (``cpu_profile`` gives Python
+        stacks), and the worker writes the ``.xplane.pb`` alone.
 
-        The reply comes when the worker has WRITTEN the trace, and
-        ``stop_trace`` converts every event it captured: a deep program's
-        worker takes 14 to 16 s a traced second (40 layers, 45 token-steps a
-        second: PERF.md, PR 36), so the wait allows 30 s a traced second on
+        The reply (``pid``, ``logdir``, ``files``, ``traced_s``, ``write_s``,
+        ``bytes``) comes when the worker has WRITTEN the trace: ``write_s``
+        is the time from the end of the traced seconds to the file on disk,
+        which the worker spends collecting the device's events (a deep
+        program's took 14 to 16 s a traced second while the export was part
+        of it: 40 layers, 45 token-steps a second, PERF.md, PR 36; PR 39
+        has what is left).  The wait still allows 30 s a traced second on
         top of the minute."""
         return self._agent_call_by_pid(
             "AgentJaxProfile",

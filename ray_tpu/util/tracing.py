@@ -34,6 +34,7 @@ from __future__ import annotations
 
 import contextlib
 import os
+import sys
 import threading
 import time
 import uuid
@@ -281,9 +282,10 @@ def activate_span(ctx3: Optional[Tuple[str, str, Optional[str]]], name: str,
 # -- profiler regions (XPlane host plane; no GCS) ---------------------------
 
 _annotation = None
+_NO_REGION = contextlib.nullcontext()
 
 
-def region(name: str, **attrs):
+def region(name: str, /, **attrs):
     """A named region on the JAX profiler's host plane.
 
         with tracing.region("engine.collect", slots=len(active)):
@@ -291,18 +293,85 @@ def region(name: str, **attrs):
 
     ``attrs`` (plain ints / strings) become the event's stats in xprof.
     Names are a fixed vocabulary (README, Observability): the benchmark's
-    ``idle_attributed_pct`` and PERF.md's idle-by-span table read them."""
+    ``idle_attributed_pct`` and PERF.md's idle-by-region tables read them.
+    A process that has not imported JAX has no profiler to capture it, and
+    a region never imports anything for it: the RPC and task paths mark
+    regions in every process (the GCS and the raylets among them), on
+    handler threads that may run while another thread is still inside
+    ``import jax``, where an import of their own would find the package
+    half made."""
     global _annotation
     if _annotation is None:
-        try:
-            from jax.profiler import TraceAnnotation as _annotation
-        except ImportError:  # no jax in this process: nothing to capture
-            _annotation = _null_region
+        found = getattr(sys.modules.get("jax.profiler"), "TraceAnnotation",
+                        None)
+        if found is None:
+            return _NO_REGION
+        _annotation = found
     return _annotation(name, **attrs)
 
 
-def _null_region(name, **attrs):
-    return contextlib.nullcontext()
+# -- the capture (``state.jax_profile`` -> the worker's HandleJaxProfile) ----
+
+_last_capture: Optional[Dict[str, Any]] = None
+
+
+def last_capture() -> Optional[Dict[str, Any]]:
+    """What this process's last ``capture`` cost (``traced_s``, ``write_s``,
+    ``bytes``), or None: a replica's ``device_report()`` carries it."""
+    return _last_capture
+
+
+@contextlib.contextmanager
+def capture(logdir: str) -> Iterator[Dict[str, Any]]:
+    """One XPlane capture of this process, in the one mode there is: the
+    device tracer, the host tracer with ``region``'s events and JAX's own
+    (``PjitFunction(..)``), and NO Python tracer (it costs a serving loop a
+    tenth of its pace; Python stacks come from ``state.cpu_profile``).  The
+    yielded dict is filled when the block ends: ``files`` (the one
+    ``.xplane.pb``, where ``jax.profiler`` would have put it, so TensorBoard
+    and xprof open ``logdir`` as before), ``traced_s``, ``write_s`` (from the
+    end of the traced seconds to the file on disk) and ``bytes``.
+
+    ``jax.profiler.stop_trace`` is ``stop_and_export``: it also converts
+    every event to a ``trace.json.gz`` that nothing here reads, which
+    doubled the write.  JAX 0.9.0 has no public stop without the export, so
+    the session is taken from the PRIVATE ``jax._src.profiler._profile_state``
+    (``lock``, ``profile_session``, ``reset()``) and its ``stop()`` returns
+    the serialized XSpace; ``tests/test_engine_tracing.py`` pins those names
+    and fails loudly when an upgrade moves them."""
+    global _last_capture
+    import socket
+
+    import jax
+    from jax._src import profiler as _private
+
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level = 0
+    opts.host_tracer_level = 2  # TraceAnnotation events and JAX's own
+    os.makedirs(logdir, exist_ok=True)
+    got: Dict[str, Any] = {}
+    jax.profiler.start_trace(logdir, profiler_options=opts)
+    t0 = time.monotonic()
+    try:
+        yield got
+    finally:
+        t1 = time.monotonic()
+        state = _private._profile_state
+        with state.lock:
+            try:
+                xspace = state.profile_session.stop()
+            finally:
+                state.reset()  # whatever stop() did, no session stays on
+        run_dir = os.path.join(logdir, "plugins", "profile",
+                               time.strftime("%Y_%m_%d_%H_%M_%S"))
+        os.makedirs(run_dir, exist_ok=True)
+        path = os.path.join(run_dir, socket.gethostname() + ".xplane.pb")
+        with open(path, "wb") as f:
+            f.write(xspace)
+        _last_capture = {"traced_s": round(t1 - t0, 6),
+                         "write_s": round(time.monotonic() - t1, 6),
+                         "bytes": len(xspace)}
+        got.update(files=[path], **_last_capture)
 
 
 def trace_function(fn=None, *, name: Optional[str] = None):

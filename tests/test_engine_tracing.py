@@ -305,3 +305,262 @@ def test_engine_counters(counted, preempted, staged, case):
             len(v) for v in staged["got"].values())
         assert pub["prefill_tokens"] + pub["prefix_hit_tokens"] == 6 * 40
         assert live["loop_idle_s"] >= 0.0
+
+
+# -- (d) the capture: one mode, no Python tracer, the .xplane.pb alone ------------
+
+RID_CHAIN = ("serve.add_request", "engine.admit", "engine.prefill_chunk",
+             "engine.join", "serve.iter_tokens")
+
+
+def _events(path):
+    """``[(line name, event name, start_ns, stats)]`` of a trace file's host
+    planes."""
+    from jax.profiler import ProfileData
+
+    return [(line.name, e.name, e.start_ns, dict(e.stats))
+            for plane in ProfileData.from_file(path).planes
+            if plane.name.startswith("/host:")
+            for line in plane.lines for e in line.events]
+
+
+@pytest.fixture(scope="module")
+def served_capture(tiny, tmp_path_factory):
+    """``tracing.capture`` (what ``HandleJaxProfile`` runs) around a server
+    that streams three requests to three client threads, answers a
+    ``utilization()`` and handles one RPC frame: the reply's dict, the files
+    under the log directory, the trace's host events and the engine's id of
+    each request."""
+    import os
+
+    from ray_tpu._private.rpc import RpcClient, RpcServer
+    from ray_tpu.llm.serve import LLMServer
+    from ray_tpu.util import tracing
+
+    mcfg, params = tiny
+    server = LLMServer(
+        LLMConfig(model_config=mcfg, max_batch_size=4, decode_chunk=4,
+                  block_size=8, prefill_chunk=16, max_seq_len=128,
+                  num_blocks=40), params)
+    rpc = RpcServer()
+    rpc.register("Echo", lambda req: req)
+    logdir = str(tmp_path_factory.mktemp("capture"))
+    got_tokens = {}
+
+    def client(i, prompt):
+        got_tokens[i] = [t for chunk in server.generate_stream(
+            prompt, max_new_tokens=10) for t in chunk]
+
+    try:
+        server.generate(_prompts(1, 24)[0], max_new_tokens=6)  # compile
+        rid0 = server._engine._req_counter
+        with tracing.capture(logdir) as got:
+            threads = [threading.Thread(target=client, args=(i, p))
+                       for i, p in enumerate(_prompts(3, 24))]
+            for t in threads:
+                t.start()
+            server.utilization()
+            cli = RpcClient(rpc.address)
+            assert cli.call("Echo", {"x": 1}, timeout=30) == {"x": 1}
+            cli.close()
+            for t in threads:
+                t.join(timeout=120)
+        files = sorted(os.path.join(dp, f) for dp, _, fs in os.walk(logdir)
+                       for f in fs)
+        assert sorted(len(v) for v in got_tokens.values()) == [10, 10, 10]
+        return {"got": got, "files": files, "events": _events(got["files"][0]),
+                "rids": list(range(rid0 + 1, rid0 + 4)),
+                "last": tracing.last_capture()}
+    finally:
+        rpc.shutdown()
+        server.shutdown()
+
+
+@pytest.mark.parametrize("case", [
+    "private_names", "reply", "one_file", "no_python_frames", "engine.step",
+    "serve.add_request", "serve.iter_tokens", "serve.rpc",
+    "serve.utilization", "rid_chain", "tracer_off_after_a_failure"])
+def test_capture(served_capture, tmp_path, case):
+    got, events = served_capture["got"], served_capture["events"]
+    names = {e[1] for e in events}
+    if case == "private_names":
+        # ``tracing.capture`` stops the session without the export through
+        # PRIVATE names of jax 0.9.0; if an upgrade moves one, this fails
+        # here and not inside a serving process
+        import jaxlib._profiler as lib
+        from jax._src import profiler as private
+
+        state = private._profile_state
+        assert hasattr(state, "lock") and callable(state.reset), state
+        assert state.profile_session is None  # none was left on
+        assert callable(lib.ProfilerSession.stop)
+        opts = jax.profiler.ProfileOptions()
+        assert {"python_tracer_level", "host_tracer_level"} <= set(dir(opts))
+    elif case == "reply":
+        assert {"files", "traced_s", "write_s", "bytes"} <= set(got)
+        assert got["traced_s"] > 0 and got["write_s"] > 0
+        assert served_capture["last"] == {
+            k: got[k] for k in ("traced_s", "write_s", "bytes")}
+    elif case == "one_file":
+        import os
+
+        from jax.profiler import ProfileData
+
+        assert served_capture["files"] == got["files"]  # no trace.json.gz
+        (path,) = got["files"]
+        assert path.endswith(".xplane.pb") and "/plugins/profile/" in path
+        assert os.path.getsize(path) == got["bytes"]
+        assert any(p.lines for p in ProfileData.from_file(path).planes)
+    elif case == "no_python_frames":
+        # the Python tracer names its events ``$file.py:line function``
+        assert not [n for n in names if n.startswith("$")]
+        assert any(n.startswith("PjitFunction(") for n in names), names
+    elif case == "rid_chain":
+        # one request's regions share its id from the server's add_request
+        # to its first tokens handed out
+        for rid in served_capture["rids"]:
+            mine = [e for e in events if e[3].get("rid") == rid]
+            assert set(RID_CHAIN) <= {e[1] for e in mine}, (rid, mine)
+            first = {n: min(e[2] for e in mine if e[1] == n)
+                     for n in RID_CHAIN}
+            assert [first[n] for n in RID_CHAIN] == sorted(first.values())
+        tokens = [e[3].get("tokens") for e in events
+                  if e[1] == "serve.iter_tokens"]
+        assert sum(tokens) == 30, tokens
+    elif case == "tracer_off_after_a_failure":
+        from jax._src import profiler as private
+
+        from ray_tpu.util import tracing
+
+        with pytest.raises(ZeroDivisionError):
+            with tracing.capture(str(tmp_path)):
+                1 / 0
+        assert private._profile_state.profile_session is None
+        with tracing.capture(str(tmp_path / "again")) as again:
+            pass
+        assert again["bytes"] > 0
+    else:
+        assert case in names, sorted(n for n in names if "." in n)
+        if case == "serve.rpc":
+            assert any(e[3].get("method") == "Echo" for e in events
+                       if e[1] == case)
+
+
+# -- (e) a worker's capture through state.jax_profile -----------------------------
+
+
+@pytest.fixture(scope="module")
+def worker_capture():
+    """The whole path (``state.jax_profile`` -> raylet -> the worker's
+    ``HandleJaxProfile``) on an actor that streams items and answers a call
+    meanwhile: the reply and the trace's host events."""
+    import ray_tpu
+    from ray_tpu.util import state
+
+    @ray_tpu.remote(max_concurrency=2)
+    class Streamer:
+        def pid(self):
+            import os
+
+            return os.getpid()
+
+        def stream(self, seconds):
+            import jax.numpy as jnp
+
+            f = jax.jit(lambda x: (x @ x).sum())
+            x = jnp.ones((64, 64))
+            end = time.monotonic() + seconds
+            n = 0
+            while time.monotonic() < end:
+                f(x).block_until_ready()
+                time.sleep(0.05)
+                n += 1
+                yield n
+
+    ray_tpu.init(num_cpus=2)
+    try:
+        a = Streamer.remote()
+        pid = ray_tpu.get(a.pid.remote(), timeout=60)
+        gen = a.stream.options(num_returns="streaming").remote(6.0)
+        first = ray_tpu.get(next(gen), timeout=60)
+        out = {}
+
+        def profile():
+            out["reply"] = state.jax_profile(pid, duration_s=1.5)
+
+        t = threading.Thread(target=profile)
+        t.start()
+        while t.is_alive():  # calls inside the traced seconds
+            ray_tpu.get(a.pid.remote(), timeout=60)
+            time.sleep(0.2)
+        t.join(timeout=120)
+        items = [first] + [ray_tpu.get(r, timeout=60) for r in gen]
+        assert items == list(range(1, len(items) + 1))
+        return out["reply"], _events(out["reply"]["files"][0])
+    finally:
+        ray_tpu.shutdown()
+
+
+@pytest.mark.timeout(240)
+@pytest.mark.parametrize("case", ["reply", "serve.task", "serve.rpc",
+                                  "no_python_frames"])
+def test_worker_capture(worker_capture, case):
+    reply, events = worker_capture
+    if case == "reply":
+        assert {"pid", "logdir", "files", "traced_s", "write_s",
+                "bytes"} <= set(reply)
+        assert 1.4 < reply["traced_s"] < 3.0
+        assert len(reply["files"]) == 1
+    elif case == "no_python_frames":
+        assert events and not [e for e in events if e[1].startswith("$")]
+    elif case == "serve.task":
+        mine = [e[3] for e in events if e[1] == case]
+        assert any(s.get("item", 0) > 0 for s in mine), mine  # a stream's
+        assert any("pid" in str(s.get("name")) for s in mine), mine
+    else:
+        methods = {e[3].get("method") for e in events if e[1] == case}
+        assert "PushActorTask" in methods or any(
+            "Task" in str(m) for m in methods), methods
+
+
+# -- (f) device_empty_s: the idle share by the engine's own clocks ----------------
+
+
+@pytest.mark.parametrize("case", ["late_arrival", "pipelined_steps",
+                                  "adds_up"])
+def test_device_empty_clock(tiny, case):
+    eng = _engine(tiny, decode_chunk=1)
+    gen = GenerationConfig(max_new_tokens=100)
+    warm, late = _prompts(2, 12)  # nothing shared: the same programs run
+    eng.generate([warm], GenerationConfig(max_new_tokens=4))
+    if case == "late_arrival":
+        # the last step drained an idle engine: the device is known to be
+        # empty, and no request is live, so the wait for one books nothing;
+        # the pause between an arrival and the step that serves it does
+        time.sleep(0.3)
+        before = eng.counters()["device_empty_s"]
+        eng.add_request(late, gen)
+        time.sleep(0.2)
+        eng.step()
+        grew = eng.counters()["device_empty_s"] - before
+        assert 0.2 <= grew < 0.45, grew
+        return
+    eng.add_request(late, gen)
+    warm_up = eng.counters()["decode_dispatches_pipelined"]
+    while eng.counters()["decode_dispatches_pipelined"] < warm_up + 2:
+        eng.step()
+    before = eng.counters()
+    t0 = time.monotonic()
+    for _ in range(50):
+        eng.step()
+    wall = time.monotonic() - t0
+    after = eng.counters()
+    if case == "pipelined_steps":
+        assert (after["decode_dispatches_pipelined"]
+                - before["decode_dispatches_pipelined"]) == 50
+        assert after["device_empty_s"] == before["device_empty_s"]
+    else:
+        booked = (after["host_s"] + after["device_wait_s"]
+                  - before["host_s"] - before["device_wait_s"])
+        assert after["steps"] - before["steps"] == 50
+        assert 0.9 * wall <= booked <= wall, (booked, wall)
