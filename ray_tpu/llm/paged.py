@@ -317,7 +317,7 @@ class _PagedReq(_Request):
 
 # integer engine counters (cumulative; see PagedJaxLLMEngine.counters)
 _COUNTERS = ("steps", "prefill_chunks", "prefill_kernel_chunks",
-             "prefill_tokens",
+             "prefill_grouped_chunks", "prefill_tokens",
              "prefill_padded_tokens", "prefix_hit_tokens",
              "prefill_live_pages", "prefill_visited_pages",
              "decode_dispatches", "decode_dispatches_pipelined",
@@ -713,6 +713,11 @@ class PagedJaxLLMEngine:
         # family's too (counter prefill_kernel_chunks)
         self._prefill_kernel = _prefill_kernel_args(
             fam, cfg, self._use_kernel, False).get("use_kernel", False)
+        # the chunk width from which on the family's expert layers run as a
+        # grouped product (counter prefill_grouped_chunks); None: never
+        self._prefill_grouped_from = (
+            fam.prefill_grouped_from(cfg, self._kernel_interpret)
+            if fam.prefill_grouped_from else None)
         stateful = self.slot_state is not None
         self._decode = jax.jit(self._decode_chunk_impl,
                                donate_argnums=(2, 12) if stateful else 2,
@@ -938,6 +943,9 @@ class PagedJaxLLMEngine:
 
         ``steps``; ``prefill_chunks``, ``prefill_kernel_chunks`` (those of
         them whose attention ran in the family's prefill kernel),
+        ``prefill_grouped_chunks`` (those wide enough that the family's
+        expert layers multiplied a token by the experts it chose alone; 0
+        for a family without expert layers),
         ``prefill_tokens`` (prompt tokens
         run through the model, recompute after a preemption included),
         ``prefill_padded_tokens`` (bucket padding run but not asked
@@ -1648,9 +1656,11 @@ class PagedJaxLLMEngine:
                 table[0, :len(req.blocks)] = req.blocks
                 is_last = p0 + take >= plen
                 sample_idx = (plen - 1 - p0) if is_last else 0
+                grouped = int(self._prefill_grouped_from is not None
+                              and c >= self._prefill_grouped_from)
                 with tracing.region("engine.prefill_chunk", tokens=take,
                                     bucket=c, is_last=int(is_last), p0=p0,
-                                    rid=req.request_id):
+                                    grouped=grouped, rid=req.request_id):
                     ids, self.pool, self._d_key, *state = self._prefill_chunk(
                         self.params, self._put(tokens), self.pool,
                         self._put(table), self._put(p0, np.int32),
@@ -1666,6 +1676,7 @@ class PagedJaxLLMEngine:
                 req.prefill_chunks += 1
                 self._c["prefill_chunks"] += 1
                 self._c["prefill_kernel_chunks"] += self._prefill_kernel
+                self._c["prefill_grouped_chunks"] += grouped
                 self._c["prefill_tokens"] += take
                 self._c["prefill_padded_tokens"] += c - take
                 self._c["prefill_live_pages"] += math.ceil(

@@ -82,6 +82,11 @@ class ModelFamily:
     # (cfg) -> bool: ``prefill_chunk``'s attention runs in a kernel of its
     # own where the decode kernel is on and the shapes fit; None: it has none
     prefill_kernel_fits: Optional[Callable] = None
+    # (cfg, interpret) -> the chunk width from which on ``prefill_chunk``'s
+    # expert layers multiply a token by the experts it chose alone (a grouped
+    # product; ``interpret``: the engine runs kernels in the interpreter), or
+    # None where they never do; None: the family has no expert layers
+    prefill_grouped_from: Optional[Callable] = None
     # engine counters a decode token-step books: names of the int32 vector
     # ``decode_step`` returns as its third value (summed over the chunk)
     decode_counters: Tuple[str, ...] = ()

@@ -33,12 +33,19 @@ weights live here.  The router keeps its full width and its experts per token;
 this chip computes the shared expert and its own experts' part of the sum, and
 what absent experts would add is left out (in the reference alike): that
 partial result goes on to the next layer.  Nothing here stands in for the
-other chips or their exchange.  The held experts' part is computed as ONE
-gated feed-forward of width ``held x moe_ffn_dim`` whose hidden units are
-scaled by their expert's gate (zero where the token did not choose it): the
-same sum, exact at any number of rows an expert, at the price of reading every
-held expert's weights whether or not a token chose it (``moe_experts_hit``
-over ``moe_experts_held`` says how many were wanted).  ``vocab_slice`` is the
+other chips or their exchange.  The held experts' part has two forms of one
+sum, and ``moe_ffn`` chooses by its static row count.  Below
+``GROUPED_MIN_ROWS`` (decode's batch, a narrow prompt chunk): ONE gated
+feed-forward of width ``held x moe_ffn_dim`` whose hidden units are scaled by
+their expert's gate (zero where the token did not choose it): exact at any
+number of rows an expert, at the price of reading every held expert's weights
+whether or not a token chose it (``moe_experts_hit`` over
+``moe_experts_held`` says how many were wanted) and of every token times
+every held expert, which is free while the weights' read bounds the product.
+From ``GROUPED_MIN_ROWS`` rows on that product costs more than the read, and
+the chosen (token, expert) pairs are sorted by expert and multiplied a group
+at a time against their expert's block of the same weights
+(``ops/moe_grouped_ffn.py``).  ``vocab_slice`` is the
 range of the published vocabulary's rows held: a sliced vocabulary is a
 smaller vocabulary, ids and logits are over the slice.
 
@@ -71,6 +78,16 @@ DECODE_COUNTERS = ("moe_experts_held", "moe_experts_hit", "moe_pairs_here")
 # KV positions one step of a prefill chunk's attention attends (a grid step of
 # the kernel, an iteration of the ``jax.numpy`` loop)
 PREFILL_KV_TILE = 1024
+
+# rows from which on an expert layer's routed part runs as a grouped product
+# over the chosen pairs (``moe_ffn``), and the row tile of its kernel.  ms a
+# layer-call on a v5e, dense / grouped (benchmarks/moe_prefill_bench.py;
+# PERF.md section 6, PR 40): 128 rows 2.32 / 2.33, 256 rows 2.51 / 2.42, 384
+# rows 3.43 / 2.52, 512 rows 4.46 / 2.60, 1,024 rows 8.86 / 3.23
+GROUPED_MIN_ROWS = 256
+GROUPED_ROW_TILE = 128
+# the held experts' weights, in the order of their three products
+HELD_EXPERT_LEAVES = ("we_gate", "we_up", "we_down")
 
 
 @dataclasses.dataclass(frozen=True)
@@ -454,12 +471,122 @@ def held_gates(cfg: PanguMoEConfig, gates, idx):
                   gates[:, :, None], 0.0), axis=1)
 
 
-def moe_ffn(cfg: PanguMoEConfig, h, lp):
-    """The expert layer's feed-forward of ``h [T, d]``: the shared expert
-    plus this chip's experts' part of the routed sum.  Returns ``(y [T, d]
-    float32, g [T, n_held])``."""
+def grouped_ffn_from(cfg: PanguMoEConfig,
+                     interpret: bool = False) -> Optional[int]:
+    """The row count from which on ``moe_ffn`` computes the held experts'
+    part as a grouped product over the chosen (token, expert) pairs; None:
+    never (no expert layer, or no kernel for this backend and these widths:
+    on a TPU an expert's block must be whole lane tiles; the interpreter
+    takes any width)."""
+    if not cfg.n_moe_layers:
+        return None
+    if not interpret and (jax.default_backend() != "tpu"
+                          or cfg.dim % 128 or cfg.moe_ffn_dim % 128):
+        return None
+    return GROUPED_MIN_ROWS
+
+
+def _routed_dense(cfg: PanguMoEConfig, h, g, lp, layer=None):
+    """The held experts' part as ONE feed-forward of width ``held x f`` whose
+    hidden units are scaled by their expert's gate: every token times every
+    held expert.  ``layer``: ``moe_ffn``'s.  Returns ``[T, d]`` float32."""
     cdt = cfg.compute_dtype
     t = h.shape[0]
+    e, f = cfg.n_held, cfg.moe_ffn_dim
+    w_gate, w_up, w_down = (
+        (lp[k] if layer is None else lp[k][layer]).astype(cdt)
+        for k in HELD_EXPERT_LEAVES)
+    act = (jax.nn.silu(h @ w_gate) * (h @ w_up)).reshape(t, e, f)
+    act = (act * g[:, :, None].astype(cdt)).reshape(t, e * f)
+    return jnp.dot(act, w_down, preferred_element_type=jnp.float32)
+
+
+def sort_pairs(g, m: int):
+    """The (token, held expert) pairs of gates ``g [T, e]`` (non-zero =
+    chosen), sorted by expert and within an expert by token, by counting (a
+    cumulative sum of the choices; no sort runs).  Returns ``(tokens [m],
+    gates [m], group_sizes [e], pairs)``: slot ``r < pairs`` holds a pair's
+    token and gate, the slots past them token 0 and gate 0."""
+    t, e = g.shape
+    chosen = (g > 0).T
+    within = jnp.cumsum(chosen, axis=1, dtype=jnp.int32)
+    sizes = within[:, -1]
+    ends = jnp.cumsum(sizes)
+    slot = jnp.arange(m, dtype=jnp.int32)
+    grp = jnp.minimum(jnp.sum(slot[:, None] >= ends[None, :], axis=1), e - 1)
+    rank = slot - (ends - sizes)[grp]
+    # the token whose choice of expert ``grp`` is that expert's rank-th
+    tok = jnp.sum(within[grp] <= rank[:, None], axis=1)
+    live = slot < ends[-1]
+    tok = jnp.where(live, tok, 0)
+    return tok, jnp.where(live, g[tok, grp], 0.0), sizes, ends[-1]
+
+
+def _add_to_tokens(rows, tok, live, t: int):
+    """``y[tok[r]] += rows[r]`` over the ``live`` rows of ``rows [m, d]``
+    float32 (the others were never written), as float32 sums: ``[t, d]``.
+    A one-hot ``[t, m]`` product over the rows split into three bfloat16
+    parts (8 + 8 + 8 bits of a float32's 24: each part's product with a 0 /
+    1 matrix is exact, the sums are float32), because XLA's scatter-add
+    takes 2.8 us a row on a v5e (benchmarks/moe_prefill_bench.py: 1.4 ms for
+    512 rows of 7,680, as long as the products themselves).  The parts are
+    cut by ``reduce_precision``: a float32 -> bfloat16 -> float32 round trip
+    the compiler may drop, and the second and third part with it."""
+    rows = jnp.where(live[:, None], rows, 0.0)
+    onehot = ((tok[None, :] == jnp.arange(t)[:, None])
+              & live[None, :]).astype(jnp.bfloat16)
+    y = jnp.zeros((t, rows.shape[1]), jnp.float32)
+    for _ in range(3):
+        part = lax.reduce_precision(rows, exponent_bits=8, mantissa_bits=7)
+        y = y + jnp.dot(onehot, part.astype(jnp.bfloat16),
+                        preferred_element_type=jnp.float32)
+        rows = rows - part
+    return y
+
+
+def _routed_grouped(cfg: PanguMoEConfig, h, g, lp, layer, interpret: bool):
+    """``_routed_dense``'s sum over the pairs the router chose alone: the
+    pairs sorted by expert, their tokens' rows gathered, the three products
+    a group at a time against that expert's block of the weights as they lie
+    (``ops/moe_grouped_ffn.py``), each pair's row added to its token in
+    float32 (``_add_to_tokens``).  The buffers hold ``T`` pairs, twice what
+    uniform routing sends to a sixteenth of the experts; a chunk with more
+    takes the dense product: no pair is ever dropped."""
+    from ray_tpu.ops.moe_grouped_ffn import moe_grouped_ffn
+
+    t, d = h.shape
+    m = -(-t // GROUPED_ROW_TILE) * GROUPED_ROW_TILE
+    tok, gates, sizes, pairs = sort_pairs(g, m)
+
+    if layer is None:
+        lp = {k: lp[k][None] for k in HELD_EXPERT_LEAVES}
+        layer = 0
+
+    def grouped(h, g):
+        del g
+        out = moe_grouped_ffn(
+            jnp.take(h, tok, axis=0), *(lp[k] for k in HELD_EXPERT_LEAVES),
+            layer, sizes, gates, tm=GROUPED_ROW_TILE, interpret=interpret)
+        return _add_to_tokens(out, tok, jnp.arange(m) < pairs, t)
+
+    return lax.cond(pairs <= m, grouped,
+                    lambda h, g: _routed_dense(cfg, h, g, lp, layer), h, g)
+
+
+def moe_ffn(cfg: PanguMoEConfig, h, lp, interpret: bool = False, layer=None):
+    """The expert layer's feed-forward of ``h [T, d]``: the shared expert
+    plus this chip's experts' part of the routed sum.  Returns ``(y [T, d]
+    float32, g [T, n_held])``.  ``layer``: ``lp``'s ``HELD_EXPERT_LEAVES``
+    are whole STACKS of layers and this scalar picks the one meant (a kernel
+    cannot read a layer sliced out of its stack without a copy of it); None:
+    they are one layer's, as every other leaf is.
+
+    The routed part has two forms of one sum, chosen by the static ``T``:
+    from ``grouped_ffn_from`` rows on, where every-token-times-every-held-
+    expert costs more than reading the experts' weights, a grouped product
+    over the chosen pairs (``interpret``: its kernel in the interpreter);
+    below, and wherever that kernel does not apply, the dense product."""
+    cdt = cfg.compute_dtype
     gates, idx = route(cfg, h, lp["router"])
     g = held_gates(cfg, gates, idx)
     # the two down-projections leave the matrix unit in float32 and are
@@ -469,12 +596,11 @@ def moe_ffn(cfg: PanguMoEConfig, h, lp):
                      * (h @ lp["ws_up"].astype(cdt)),
                      lp["ws_down"].astype(cdt),
                      preferred_element_type=jnp.float32)
-    e, f = cfg.n_held, cfg.moe_ffn_dim
-    act = (jax.nn.silu(h @ lp["we_gate"].astype(cdt))
-           * (h @ lp["we_up"].astype(cdt))).reshape(t, e, f)
-    act = (act * g[:, :, None].astype(cdt)).reshape(t, e * f)
-    routed = jnp.dot(act, lp["we_down"].astype(cdt),
-                     preferred_element_type=jnp.float32)
+    first = grouped_ffn_from(cfg, interpret)
+    if first is not None and h.shape[0] >= first:
+        routed = _routed_grouped(cfg, h, g, lp, layer, interpret)
+    else:
+        routed = _routed_dense(cfg, h, g, lp, layer)
     return shared + routed, g
 
 
@@ -484,15 +610,17 @@ def _dense_ffn(cfg: PanguMoEConfig, h, lp):
             * (h @ lp["w_up"].astype(cdt))) @ lp["w_down"].astype(cdt)
 
 
-def _ffn_sublayer(cfg: PanguMoEConfig, x, lp, is_moe: bool, live=None):
+def _ffn_sublayer(cfg: PanguMoEConfig, x, lp, is_moe: bool, live=None,
+                  interpret: bool = False, layer=None):
     """``x + N_post(FFN(N_pre(x)))`` of ``x [B, T, d]``; for an expert layer
-    also the decode counters of rows ``live [B * T]`` (None: not booked)."""
+    also the decode counters of rows ``live [B * T]`` (None: not booked).
+    ``interpret``, ``layer``: ``moe_ffn``'s."""
     b, t, d = x.shape
     h = rms_norm(x, lp["mlp_norm"], cfg.rms_norm_eps).reshape(b * t, d)
     booked = None
     if is_moe:
         with jax.named_scope("moe"):
-            y, g = moe_ffn(cfg, h, lp)
+            y, g = moe_ffn(cfg, h, lp, interpret, layer)
         if live is not None:
             chose = (g > 0) & (live[:, None] > 0)
             booked = jnp.stack([
@@ -510,6 +638,19 @@ def _head(cfg: PanguMoEConfig, params, x):
         x = rms_norm(x, params["final_norm"], cfg.rms_norm_eps)
         return (x @ params["lm_head"].astype(cfg.compute_dtype)).astype(
             jnp.float32)
+
+
+def _held_whole(cfg: PanguMoEConfig, stack, is_moe: bool, rows: int,
+                interpret: bool):
+    """``(the stack's leaves a layer scan slices, the held experts' leaves it
+    must leave whole)``: where ``rows`` rows take the grouped form, its
+    kernel reads a layer's experts out of their stack in place (``moe_ffn``'s
+    ``layer``); else everything is scanned and the second is empty."""
+    first = grouped_ffn_from(cfg, interpret)
+    if not is_moe or first is None or rows < first:
+        return stack, {}
+    return ({k: v for k, v in stack.items() if k not in HELD_EXPERT_LEAVES},
+            {k: stack[k] for k in HELD_EXPERT_LEAVES})
 
 
 def _stacks(cfg: PanguMoEConfig, params):
@@ -561,7 +702,9 @@ def prefill_chunk_paged(cfg: PanguMoEConfig, params: Params,
     x = jnp.take(params["embed"], tokens, axis=0).astype(cfg.compute_dtype)
 
     for stack, first, is_moe in _stacks(cfg, params):
-        def body(carry, inp, is_moe=is_moe):
+        stack, whole = _held_whole(cfg, stack, is_moe, c, kernel_interpret)
+
+        def body(carry, inp, is_moe=is_moe, whole=whole, first=first):
             x, ckv = carry
             lp, li = inp
             with jax.named_scope("attention"):
@@ -583,7 +726,9 @@ def prefill_chunk_paged(cfg: PanguMoEConfig, params: Params,
                     cfg.compute_dtype)
                 x = x + rms_norm(out, lp["post_attn_norm"], cfg.rms_norm_eps)
             with jax.named_scope("ffn"):
-                x, _ = _ffn_sublayer(cfg, x, lp, is_moe)
+                x, _ = _ffn_sublayer(cfg, x, {**lp, **whole}, is_moe,
+                                     interpret=kernel_interpret,
+                                     layer=li - first if whole else None)
             return (x, ckv), None
 
         n = jax.tree.leaves(stack)[0].shape[0]
@@ -624,7 +769,9 @@ def decode_step_paged(cfg: PanguMoEConfig, params: Params,
     booked = jnp.zeros((len(DECODE_COUNTERS),), jnp.int32)
 
     for stack, first, is_moe in _stacks(cfg, params):
-        def body(carry, inp, is_moe=is_moe):
+        stack, whole = _held_whole(cfg, stack, is_moe, b, kernel_interpret)
+
+        def body(carry, inp, is_moe=is_moe, whole=whole, first=first):
             x, ckv, booked = carry
             lp, li = inp
             with jax.named_scope("attention"):
@@ -652,7 +799,9 @@ def decode_step_paged(cfg: PanguMoEConfig, params: Params,
                     cfg.compute_dtype)
                 x = x + rms_norm(out, lp["post_attn_norm"], cfg.rms_norm_eps)
             with jax.named_scope("ffn"):
-                x, got = _ffn_sublayer(cfg, x, lp, is_moe, live=live)
+                x, got = _ffn_sublayer(cfg, x, {**lp, **whole}, is_moe,
+                                       live=live, interpret=kernel_interpret,
+                                       layer=li - first if whole else None)
             return (x, ckv, booked if got is None else booked + got), None
 
         n = jax.tree.leaves(stack)[0].shape[0]
@@ -683,6 +832,7 @@ def _family():
         prefill_visited_pages=_prefill_visited_pages,
         reference_logits=_reference_logits,
         prefill_kernel_fits=prefill_kernel_fits,
+        prefill_grouped_from=grouped_ffn_from,
         decode_counters=DECODE_COUNTERS)
 
 

@@ -359,17 +359,43 @@ def test_latent_prefill_kernel_compiles_at_cell_shapes(one_chip, c):
         _spec((), jnp.int32, one_chip))
 
 
-def test_latent_family_programs_compile_at_cell_shapes(one_chip):
+@pytest.mark.parametrize("rows", [256, 512, 1024])
+def test_grouped_expert_kernel_compiles_at_cell_shapes(one_chip, rows):
+    """The grouped product of a chunk's (token, expert) pairs, ``rows`` of
+    them at most, against the 16 held experts as they lie in the four expert
+    layers' stacks: gate and up ``[4, 7680, 16 x 2048]`` (k 7680, n 2048, an
+    expert its columns), down ``[4, 16 x 2048, 7680]`` (k 2048, n 7680, an
+    expert its rows)."""
+    from ray_tpu.ops.moe_grouped_ffn import moe_grouped_ffn
+
+    names = _kernel_instructions(
+        moe_grouped_ffn, _spec((rows, 7680), BF16, one_chip),
+        _spec((4, 7680, 16 * 2048), BF16, one_chip),
+        _spec((4, 7680, 16 * 2048), BF16, one_chip),
+        _spec((4, 16 * 2048, 7680), BF16, one_chip),
+        _spec((), jnp.int32, one_chip), _spec((16,), jnp.int32, one_chip),
+        _spec((rows,), jnp.float32, one_chip))
+    assert any("moe_grouped_ffn_up" in n for n in names), names
+    assert any("moe_grouped_ffn_down" in n for n in names), names
+
+
+def test_latent_family_programs_compile_at_cell_shapes(one_chip, monkeypatch):
     """Decode over the 9,216-position table with the kernel in it, named; a
     1,024-token prefill chunk with its attention kernel in it, named, and no
-    score tile among its temporaries."""
+    score tile among its temporaries.  The expert layers' grouped product
+    (named) is in the prefill chunk and not in decode's 64 rows: the model
+    asks the backend, which is the CPU here, so the test answers for it."""
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
     cfg, params, pool = _pangu_cell(one_chip)
     decode, prefill = _engine_programs(cfg, params, pool, one_chip, 64, 1024,
                                        2, 1024, 641)
     text = decode.compile().as_text()
     assert "tpu_custom_call" in text and "mla_paged_attention" in text
+    assert "moe_grouped_ffn" not in text
     compiled = prefill.compile()
-    assert "mla_prefill_attention" in _custom_call_names(compiled.as_text())
+    names = _custom_call_names(compiled.as_text())
+    assert "mla_prefill_attention" in names
+    assert "moe_grouped_ffn_up" in names and "moe_grouped_ffn_down" in names
     assert compiled.memory_analysis().temp_size_in_bytes < 1 << 28
 
 

@@ -92,6 +92,65 @@ def test_region_lands_in_the_xplane_inside_a_step(host_events, name):
                    if n == "engine.step"), (name, t0, t1)
 
 
+@pytest.fixture(scope="module")
+def grouped_chunks():
+    """``(counters, [attributes of every engine.prefill_chunk region])`` of a
+    latent-family engine (kernels in the interpreter, as a TPU runs them)
+    that prefilled one prompt in a chunk of ``GROUPED_MIN_ROWS`` tokens and
+    one of 64 (44 more, in their power-of-two bucket).  The regions are
+    heard at ``tracing.region`` itself: a capture of a kernel under the
+    interpreter is all interpreter."""
+    from ray_tpu.llm import paged
+    from ray_tpu.models import pangu_moe as pm
+
+    wide = pm.GROUPED_MIN_ROWS
+    mcfg = pm.PanguMoEConfig.tiny(n_routed_experts=128, kv_lora_rank=128,
+                                  experts_held=(16, 32), max_seq_len=2 * wide)
+    eng = PagedJaxLLMEngine(LLMConfig(
+        model_config=mcfg, max_batch_size=2, max_seq_len=2 * wide,
+        block_size=8, num_blocks=wide // 4, prefill_chunk=wide,
+        paged_attention_kernel="interpret"),
+        params=pm.init_params(mcfg, jax.random.PRNGKey(1)))
+    heard, region = [], paged.tracing.region
+
+    def spy(name, /, **attrs):
+        if name == "engine.prefill_chunk":
+            heard.append(attrs)
+        return region(name, **attrs)
+
+    with pytest.MonkeyPatch.context() as mp:
+        mp.setattr(paged.tracing, "region", spy)
+        eng.generate(_prompts(1, wide + 44),
+                     GenerationConfig(max_new_tokens=2))
+    return eng.counters(), heard
+
+
+@pytest.mark.parametrize("case", ["latent_family", "llama"])
+def test_prefill_grouped_chunks(grouped_chunks, host_events, tiny, case):
+    """``prefill_grouped_chunks`` beside ``prefill_chunks``: the chunks wide
+    enough that the family's expert layers ran as a grouped product
+    (``ModelFamily.prefill_grouped_from``); the dispatch region says which
+    (``grouped``).  A family without expert layers books none."""
+    from ray_tpu.models import pangu_moe as pm
+    from ray_tpu.models.family import family_of
+
+    if case == "latent_family":
+        counters, stats = grouped_chunks
+        assert counters["prefill_chunks"] == 2
+        assert counters["prefill_grouped_chunks"] == 1
+        assert sorted((int(s["bucket"]), int(s["grouped"])) for s in stats) \
+            == [(64, 0), (pm.GROUPED_MIN_ROWS, 1)]
+        return
+    assert family_of(tiny[0]).prefill_grouped_from is None
+    eng = _engine(tiny, prefill_chunk=64, max_seq_len=512, num_blocks=80)
+    eng.generate(_prompts(1, 300), GenerationConfig(max_new_tokens=2))
+    c = eng.counters()
+    assert c["prefill_chunks"] == 5 and c["prefill_grouped_chunks"] == 0
+    chunks = [ev[3] for evs in host_events.values() for ev in evs
+              if ev[0] == "engine.prefill_chunk"]
+    assert chunks and all(int(s["grouped"]) == 0 for s in chunks)
+
+
 # -- (b) first-token stages ----------------------------------------------------
 
 

@@ -3,8 +3,9 @@ float32 reference, at a small size on the CPU with seeded random weights:
 the latent paged cache through chunked prefill, a prefix hit and decode; the
 absorbed form against the expanded; the shares of the expert layer against
 the whole; the router against a hand-written case; the latent kernel in
-interpret mode; the engine's host tier, handoff and preemption on the latent
-pool; and the family seam's refusals."""
+interpret mode; the grouped form of the expert layer against the dense one;
+the engine's host tier, handoff and preemption on the latent pool; and the
+family seam's refusals."""
 
 import dataclasses
 
@@ -130,6 +131,110 @@ def test_shares_of_the_expert_layer_add_up_to_the_whole(model):
     np.testing.assert_allclose(total + shared, want, atol=TOL)
     # every (token, expert) pair lands on exactly one share
     assert pairs == 24 * cfg.n_experts_per_tok
+
+
+# -- the grouped form of the held experts' part --------------------------------
+# 16 of 128 experts held, 4 a token: a token lands on half a held expert, as
+# at the published widths (16 of 256, 8 a token).
+
+
+@pytest.fixture(scope="module")
+def sparse():
+    cfg = pm.PanguMoEConfig.tiny(n_routed_experts=128, experts_held=(16, 32),
+                                 max_seq_len=512)
+    return cfg, pm.init_params(cfg, jax.random.PRNGKey(5))
+
+
+def _routing(cfg, h, router, name):
+    """Gates ``[T, held]`` of one of four routings."""
+    own = pm.held_gates(cfg, *pm.route(cfg, h, router))
+    zero = jnp.zeros_like(own)
+    return {"the_routers_own": own,
+            "every_token_on_one_held_expert": zero.at[:, 3].set(1.3),
+            # more pairs than the buffers hold: the dense product answers
+            "every_token_on_8_held_experts": zero.at[:, 4:12].set(0.31),
+            "no_token_on_a_held_expert": zero}[name]
+
+
+@pytest.mark.parametrize("rows", [pm.GROUPED_MIN_ROWS,
+                                  pm.GROUPED_MIN_ROWS + 136])
+@pytest.mark.parametrize("routing", [
+    "the_routers_own", "every_token_on_one_held_expert",
+    "every_token_on_8_held_experts", "no_token_on_a_held_expert"])
+def test_grouped_form_equals_dense_form(sparse, routing, rows):
+    """The same sum over the chosen pairs alone, whatever the routing: groups
+    that span row tiles, a chunk with more pairs than the buffers hold (none
+    is dropped), a chunk with none (exactly zero).  Through a layer of the
+    stack, as the prefill program reads it, and through one layer's own
+    leaves."""
+    cfg, params = sparse
+    stack = params["moe"]
+    h = jax.random.normal(jax.random.PRNGKey(rows), (rows, cfg.dim))
+    g = _routing(cfg, h, stack["router"][1], routing)
+    lp = {k: stack[k][1] for k in pm.HELD_EXPERT_LEAVES}
+    want = pm._routed_dense(cfg, h, g, lp)
+    got = jax.jit(lambda h, g: pm._routed_grouped(
+        cfg, h, g, stack, 1, True))(h, g)
+    pairs = int((g > 0).sum())
+    assert (pairs > rows) == (routing == "every_token_on_8_held_experts")
+    if pairs == 0:
+        assert not np.asarray(got).any() and not np.asarray(want).any()
+    np.testing.assert_allclose(got, want, atol=1e-6)
+    assert float(jnp.abs(want).max()) > 1e-3 or pairs == 0
+    np.testing.assert_allclose(
+        pm._routed_grouped(cfg, h, g, lp, None, True), want, atol=1e-6)
+
+
+def test_sort_pairs_by_hand():
+    """Tokens 0..3 over 3 held experts: expert 0 chosen by tokens 1 and 3,
+    expert 1 by none, expert 2 by tokens 0, 1 and 2."""
+    g = jnp.asarray([[0, 0, .5], [.25, 0, .75], [0, 0, 1.], [2., 0, 0]])
+    tok, gates, sizes, pairs = pm.sort_pairs(g, 8)
+    assert int(pairs) == 5 and sizes.tolist() == [2, 0, 3]
+    assert tok.tolist() == [1, 3, 0, 1, 2, 0, 0, 0]
+    assert gates.tolist() == [.25, 2., .5, .75, 1., 0, 0, 0]
+
+
+@pytest.mark.parametrize("rows, grouped", [
+    (pm.GROUPED_MIN_ROWS - 128, False), (pm.GROUPED_MIN_ROWS, True)])
+def test_expert_layer_takes_the_grouped_form_by_its_rows_alone(sparse, rows,
+                                                               grouped):
+    """Below the threshold (decode's 64 rows, a narrow chunk) ``moe_ffn`` is
+    the dense product and lowers to no kernel; from it on, where the kernel
+    applies (here: the interpreter), the grouped product, with the same
+    result.  On this backend without the interpreter: dense at any width."""
+    cfg, params = sparse
+    lp = jax.tree.map(lambda x: x[0], params["moe"])
+    h = jax.random.normal(jax.random.PRNGKey(9), (rows, cfg.dim))
+    assert pm.grouped_ffn_from(cfg) is None
+    assert pm.grouped_ffn_from(cfg, interpret=True) == pm.GROUPED_MIN_ROWS
+    plain = jax.jit(lambda h: pm.moe_ffn(cfg, h, lp))
+    text = plain.lower(h).as_text()
+    assert "custom_call" not in text and "pallas" not in text
+    jaxpr = str(jax.make_jaxpr(lambda h: pm.moe_ffn(cfg, h, lp, True))(h))
+    assert ("pallas_call" in jaxpr) == grouped
+    y, g = pm.moe_ffn(cfg, h, lp, True)
+    want, g_want = plain(h)
+    np.testing.assert_allclose(y, want, atol=1e-6)
+    np.testing.assert_array_equal(g, g_want)
+
+
+def test_prefill_chunk_with_grouped_expert_layers_equals_dense(sparse):
+    """A chunk at the threshold through the whole prefill program, its expert layers
+    grouped (their weights read out of the layer stack by the kernel) and
+    dense: the same logits and the same cache rows."""
+    cfg, params = sparse
+    c = pm.GROUPED_MIN_ROWS
+    pool = pm.init_paged_cache(cfg, c // BS + 2, BS, dtype=jnp.float32)
+    table = jnp.arange(1, c // BS + 1, dtype=jnp.int32)[None]
+    toks = jnp.asarray([_tokens(c, seed=21)], jnp.int32)
+    rope = pm.make_rope_cache(cfg, cfg.max_seq_len)
+    run = lambda interpret: pm.prefill_chunk_paged(  # noqa: E731
+        cfg, params, toks, pool, table, jnp.int32(0), rope_cache=rope,
+        kernel_interpret=interpret)
+    (want, pool_want), (got, pool_got) = run(False), run(True)
+    np.testing.assert_allclose(got, want, atol=TOL)
+    np.testing.assert_allclose(pool_got["ckv"], pool_want["ckv"], atol=TOL)
 
 
 def test_router_against_a_hand_written_case():
