@@ -25,7 +25,8 @@ attention layers ONLY).  A Mamba layer's state does not page: it is one
 fixed-size value a sequence, so the engine keeps it a SLOT (``init_slot_state``:
 ``ssm`` ``[mamba layers, max_batch, tiles, N, 128]`` float32 in the layout
 ``ops/ssm_state_update.py`` explains, and ``conv`` ``[mamba layers, max_batch,
-(K - 1) x (I + 2N)]``, the convolution's last inputs).
+K - 1, tiles, 128]``, the convolution's last inputs, ``I + 2N`` channels a tap
+as rows of 128).
 
 **Two forms that must agree.**  A prompt chunk runs the chunked
 (matrix-product) form at ``mamba_chunk_size``: inside a chunk a masked
@@ -34,9 +35,10 @@ slot's state in (zeros where ``p0 == 0``: a re-used slot needs no clearing)
 and writes the state after the chunk's last REAL token: positions past
 ``take`` (the engine pads every chunk to a power of two) get ``delta = 0``,
 which neither decays the state nor adds to it, and the convolution's window
-is cut at ``take``.  A decode token-step runs the one-step recurrence: the
-Pallas kernel ``ssm_state_update`` for the rows with ``active != 0``, nothing
-for the others (for keys and values a masked row is harmless; for a
+is cut at ``take``.  A decode token-step runs the one-step recurrence: between
+a layer's two projections ONE call of the Pallas kernel ``ssm_state_update``
+(``ops/ssm_state_update.py ssm_layer_step``) for the rows with ``active !=
+0``, nothing for the others (for keys and values a masked row is harmless; for a
 recurrent state it would be a wrong answer).
 
 Layers are stacked by kind and scanned by PERIOD of ``layer_types`` (the
@@ -303,24 +305,31 @@ def init_slot_state(cfg: GraniteHybridConfig,
             # float32 at rest: the published config says only ``bfloat16``
             # for the model, and this is the program's choice (``assumed``)
             cfg.mamba_d_state), jnp.float32),
-        # the last K - 1 inputs, oldest first, side by side in the lanes: a
-        # [K - 1, width] tail would be padded to whole sublane tiles, 5 x
-        "conv": jnp.zeros((nm, max_batch,
-                           (cfg.mamba_d_conv - 1) * cfg.conv_width),
-                          cfg.compute_dtype),
+        # the last K - 1 inputs, oldest first, each tap's channels 128 a
+        # sublane row: a slot's window is whole memory tiles, which the
+        # decode kernel copies in and out a row at a time
+        "conv": jnp.zeros(ssm_ops.window_shape(
+            nm, max_batch, cfg.mamba_d_conv, cfg.conv_width),
+            cfg.compute_dtype),
     }
 
 
 # -- the pieces both programs share ---------------------------------------------
 
 
-def _project_in(cfg, lp, u):
-    """``u W_in`` -> ``(z, xBC, dt)``.  The barrier keeps the wide projection
-    ONE product whose slices are cut from its result, not from the weight."""
+def _project(cfg, lp, u):
+    """``u W_in`` -> ``([z | xBC], dt)``.  The barrier keeps the wide
+    projection ONE product in its own layout: what is cut or laid out anew is
+    its result, not the weight (a layer's 35 MB, copied a layer-call)."""
     cdt = cfg.compute_dtype
-    proj = lax.optimization_barrier(u @ lp["w_in"].astype(cdt))
-    return (proj[..., :cfg.d_inner], proj[..., cfg.d_inner:],
+    return (lax.optimization_barrier(u @ lp["w_in"].astype(cdt)),
             u @ lp["w_dt"].astype(cdt))
+
+
+def _project_in(cfg, lp, u):
+    """``u W_in`` -> ``(z, xBC, dt)``."""
+    proj, dt = _project(cfg, lp, u)
+    return proj[..., :cfg.d_inner], proj[..., cfg.d_inner:], dt
 
 
 def _split_xbc(cfg, xbc):
@@ -334,12 +343,10 @@ def _delta(lp, dt):
                            + lp["dt_bias"].astype(jnp.float32))
 
 
-def _gated_out(cfg, lp, y, z):
-    """``RMSNorm(y * silu(z)) W_out``."""
-    cdt = cfg.compute_dtype
+def _gated(cfg, lp, y, z):
+    """``RMSNorm(y * silu(z))`` in the compute dtype."""
     g = y.astype(jnp.float32) * jax.nn.silu(z.astype(jnp.float32))
-    g = rms_norm(g, lp["norm"], cfg.rms_norm_eps).astype(cdt)
-    return g @ lp["w_out"].astype(cdt)
+    return rms_norm(g, lp["norm"], cfg.rms_norm_eps).astype(cfg.compute_dtype)
 
 
 def _ffn(cfg, x, norm_w, fp):
@@ -521,16 +528,17 @@ def prefill_chunk_paged(cfg: GraniteHybridConfig, params: Params,
             u = rms_norm(x, lp["in_norm"], cfg.rms_norm_eps)
             z, xbc, dt = _project_in(cfg, lp, u)
             win_in = jnp.where(fresh, jnp.zeros_like(win_old), win_old)
-            seq = jnp.concatenate([win_in.reshape(kw, cfg.conv_width),
-                                   xbc.astype(win_old.dtype)], axis=0)
+            seq = jnp.concatenate(
+                [ssm_ops.unpack_window(win_in, cfg.conv_width),
+                 xbc.astype(win_old.dtype)], axis=0)
             w = lp["conv_w"].astype(f32)
             acc = lp["conv_b"].astype(f32)[None, :]
             for k in range(cfg.mamba_d_conv):
                 acc = acc + w[k][None, :] * seq[k:k + c].astype(f32)
             xbc = jax.nn.silu(acc).astype(cdt)
             # the window after the last REAL token
-            win_new = lax.dynamic_slice(
-                seq, (take, 0), (kw, cfg.conv_width)).reshape(-1)
+            win_new = ssm_ops.pack_window(lax.dynamic_slice(
+                seq, (take, 0), (kw, cfg.conv_width)))
             xm, bm, cm = _split_xbc(cfg, xbc)
             delta = jnp.where(real[:, None], _delta(lp, dt), 0.0)
             a = -jnp.exp(lp["a_log"].astype(f32))
@@ -539,7 +547,8 @@ def prefill_chunk_paged(cfg: GraniteHybridConfig, params: Params,
             xh = xm.reshape(c, h, p)
             y, s_new = ssd_chunked(cfg, xh, delta, a, bm, cm, s0)
             y = y + lp["d"].astype(f32)[None, :, None] * xh.astype(f32)
-            out = _gated_out(cfg, lp, y.reshape(c, h * p), z)
+            out = _gated(cfg, lp, y.reshape(c, h * p), z) @ lp[
+                "w_out"].astype(cdt)
             x = x + (cfg.residual_multiplier * out).astype(x.dtype)
             keep = take > 0
             s_new = jnp.where(keep, ssm_ops.pack_state(s_new).astype(
@@ -594,13 +603,48 @@ def kernel_supported(cfg: GraniteHybridConfig) -> bool:
     hd = cfg.head_dim
     if not (hd % 128 == 0 or (hd == 64 and cfg.n_kv_heads % 2 == 0)):
         return False
-    if (cfg.mamba_n_heads * cfg.mamba_d_head) % 128 or cfg.mamba_d_state % 8:
+    if cfg.mamba_d_state % 8 or ssm_ops.layer_step_unsupported(
+            cfg.mamba_n_heads, cfg.mamba_d_head, cfg.mamba_d_state,
+            cfg.mamba_n_groups):
         return False
     from ray_tpu.ops.paged_attention import (  # noqa: F401
         paged_decode_attention,
     )
 
     return True
+
+
+def recurrent_step_jnp(cfg: GraniteHybridConfig, lp, z, xbc, dt, ssm, win,
+                       mi, active):
+    """A Mamba layer's decode step between its two projections, in
+    ``jax.numpy`` over every slot (the CPU's form and the tests'; what
+    ``ops/ssm_state_update.py ssm_layer_step`` computes for the rows that
+    decode).  z, xbc, dt: the in-projection's, ``[B, ...]``; ssm: the state
+    leaf whole; win ``[B, K - 1, tiles, 128]``: this layer's windows.
+    Returns ``(RMSNorm(y * silu(z)) [B, I], ssm, win)``."""
+    f32 = jnp.float32
+    b = z.shape[0]
+    h, p = cfg.mamba_n_heads, cfg.mamba_d_head
+    # the last inputs, oldest first, then this token's
+    held = ssm_ops.unpack_window(win, cfg.conv_width)
+    seq = jnp.concatenate([held, xbc.astype(win.dtype)[:, None]], axis=1)
+    w = lp["conv_w"].astype(f32)
+    acc = lp["conv_b"].astype(f32)[None, :]
+    for k in range(cfg.mamba_d_conv):
+        acc = acc + w[k][None, :] * seq[:, k].astype(f32)
+    win = jnp.where((active != 0)[:, None, None, None],
+                    ssm_ops.pack_window(seq[:, 1:]), win)
+    xm, bm, cm = _split_xbc(
+        cfg, jax.nn.silu(acc).astype(cfg.compute_dtype))
+    delta = _delta(lp, dt)                                  # [B, H]
+    a = -jnp.exp(lp["a_log"].astype(f32))
+    xh = xm.astype(f32).reshape(b, h, p)
+    decay = jnp.broadcast_to(jnp.exp(delta * a)[..., None],
+                             (b, h, p)).reshape(b, h * p)
+    xdt = (delta[..., None] * xh).reshape(b, h * p)
+    y, ssm = ssm_ops.ssm_state_update_jnp(ssm, mi, decay, xdt, bm, cm, active)
+    y = y + (lp["d"].astype(f32)[None, :, None] * xh).reshape(b, h * p)
+    return _gated(cfg, lp, y, z), ssm, win
 
 
 def decode_step_paged(cfg: GraniteHybridConfig, params: Params,
@@ -620,11 +664,13 @@ def decode_step_paged(cfg: GraniteHybridConfig, params: Params,
     bs = pool["k"].shape[2]
     w = table.shape[1]
     cdt = cfg.compute_dtype
-    f32 = jnp.float32
-    h, p, cw = cfg.mamba_n_heads, cfg.mamba_d_head, cfg.conv_width
     active = jnp.ones_like(lengths) if active is None else active
-    live = active != 0
-    live_list = ssm_ops.live_rows(active) if use_kernel else None
+    if use_kernel:
+        live_list = ssm_ops.live_rows(active)
+        mp = params["mamba"]
+        small = ssm_ops.prepare_layer_params(
+            mp["conv_w"], mp["conv_b"], mp["dt_bias"], mp["a_log"], mp["d"],
+            mp["norm"], cfg.mamba_d_head)
     bidx = jnp.arange(b)
     cur_blk = table[bidx, lengths // bs]
     cur_off = lengths % bs
@@ -634,40 +680,23 @@ def decode_step_paged(cfg: GraniteHybridConfig, params: Params,
     x = _embed(cfg, params, tokens)
 
     def mamba_layer(carry, lp, mi, win):
-        x, pk, pv, ssm = carry
+        x, pk, pv, ssm, conv = carry
         with jax.named_scope("ssm"):
             u = rms_norm(x, lp["in_norm"], cfg.rms_norm_eps)
-            z, xbc, dt = _project_in(cfg, lp, u)
-            # win [B, (K-1) x W]: the last inputs, oldest first
-            seq = jnp.concatenate([win, xbc.astype(win.dtype)], axis=1)
-            w = lp["conv_w"].astype(f32)
-            acc = lp["conv_b"].astype(f32)[None, :]
-            for k in range(cfg.mamba_d_conv):
-                acc = acc + w[k][None, :] * seq[
-                    :, k * cw:(k + 1) * cw].astype(f32)
-            win = jnp.where(live[:, None], seq[:, cw:], win)
-            xm, bm, cm = _split_xbc(cfg, jax.nn.silu(acc).astype(cdt))
-            delta = _delta(lp, dt)                      # [B, H]
-            a = -jnp.exp(lp["a_log"].astype(f32))
-            xh = xm.astype(f32).reshape(b, h, p)
-            decay = jnp.broadcast_to(jnp.exp(delta * a)[..., None],
-                                     (b, h, p)).reshape(b, h * p)
-            xdt = (delta[..., None] * xh).reshape(b, h * p)
-            if use_kernel:
-                y, ssm = ssm_ops.ssm_state_update(
-                    ssm, mi, decay, xdt, bm, cm, active, live_list,
-                    interpret=kernel_interpret)
+            if use_kernel:  # the products, and ONE call between them
+                g, ssm, conv = ssm_ops.ssm_layer_step(
+                    ssm, conv, mi, *_project(cfg, lp, u), small, active,
+                    live_list, eps=cfg.rms_norm_eps,
+                    n_groups=cfg.mamba_n_groups, interpret=kernel_interpret)
             else:
-                y, ssm = ssm_ops.ssm_state_update_jnp(
-                    ssm, mi, decay, xdt, bm, cm, active)
-            y = y + (lp["d"].astype(f32)[None, :, None] * xh
-                     ).reshape(b, h * p)
-            out = _gated_out(cfg, lp, y, z)
+                g, ssm, win = recurrent_step_jnp(
+                    cfg, lp, *_project_in(cfg, lp, u), ssm, win, mi, active)
+            out = g @ lp["w_out"].astype(cdt)
             x = x + (cfg.residual_multiplier * out).astype(x.dtype)
-        return (x, pk, pv, ssm), win
+        return (x, pk, pv, ssm, conv), win
 
     def attn_layer(carry, lp, ai):
-        x, pk, pv, ssm = carry
+        x, pk, pv, *state = carry
         with jax.named_scope("attention"):
             u = rms_norm(x, lp["in_norm"], cfg.rms_norm_eps)
             q, k = lax.optimization_barrier(
@@ -692,15 +721,19 @@ def decode_step_paged(cfg: GraniteHybridConfig, params: Params,
                                      scale=cfg.attention_multiplier)[:, 0]
             out = attn.astype(cdt) @ lp["wo"].astype(cdt)
             x = x + (cfg.residual_multiplier * out).astype(x.dtype)
-        return x, pk, pv, ssm
+        return (x, pk, pv, *state)
 
-    # every slot's recurrent state rides the carry (the kernel updates it in
-    # place); the windows are scanned over and rebuilt, 57 MB at 64 slots
-    carry = (x, pool["k"], pool["v"], slot_state["ssm"])
-    (x, pk, pv, ssm), conv = _scan_periods(
-        cfg, params, carry, mamba_layer, attn_layer, slot_state["conv"])
+    # every slot's recurrent state rides the carry (updated in place).  With
+    # the kernel so do the windows, and it writes the decoding rows' alone;
+    # without it they are scanned over and rebuilt
+    conv = slot_state["conv"]
+    carry = (x, pool["k"], pool["v"], slot_state["ssm"],
+             conv if use_kernel else None)
+    (x, pk, pv, ssm, kept), rebuilt = _scan_periods(
+        cfg, params, carry, mamba_layer, attn_layer,
+        None if use_kernel else conv)
     return (_head(cfg, params, x), {"k": pk, "v": pv},
-            {"ssm": ssm, "conv": conv})
+            {"ssm": ssm, "conv": kept if use_kernel else rebuilt})
 
 
 # -- the family seam (models/family.py) -------------------------------------------------

@@ -489,6 +489,53 @@ def test_hybrid_family_programs_compile_at_cell_shapes(one_chip):
     assert prefill.memory_analysis().temp_size_in_bytes < 512 << 20
 
 
+def _loop_bodies_with(text, kernel):
+    """``[(fusions, kernel calls)]`` of the compiled program's computations
+    (a scan's loop body is one) that call the Pallas kernel named ``kernel``
+    themselves."""
+    import re
+
+    found = []
+    for comp in re.split(r"\n(?=(?:ENTRY )?%[\w.\-]+ \([^\n]*\) -> )", text):
+        calls = re.findall(
+            rf"%{kernel}[\w.\-]* = [^\n]*custom_call_target=\"tpu_custom_call\"",
+            comp)
+        if calls and not comp.startswith("%fused"):
+            found.append((len(re.findall(r" fusion\(", comp)), len(calls)))
+    return found
+
+
+def test_a_mamba_layers_decode_step_is_its_products_and_one_call(one_chip):
+    """The decode step with the kernels, at the cell's shapes: the loop body
+    of a run of Mamba layers holds ONE ``ssm_state_update`` call and at most
+    ten fusions (the two norms' three each, the in-projection, the ``dt``
+    product, the out-projection, the feed-forward's two products).  The
+    row-wise work between the projections (convolution, ``silu``, delta,
+    decay, the window's write-back, the gated norm: sixteen fusions more, a
+    copy and a slice, before they moved into the call) shows here if an edit
+    spills it back out, and not first in a trace of the chip."""
+    from ray_tpu.models import granite_hybrid as gh
+
+    cfg, params, pool, state = _granite_cell(one_chip)
+
+    def i32(*shape):
+        return _spec(shape, jnp.int32, one_chip)
+
+    def step(params, tokens, pool, table, lengths, active, state):
+        return gh.decode_step_paged(
+            cfg, params, tokens, pool, table, lengths, use_kernel=True,
+            active=active, slot_state=state)
+
+    b, w = 64, 32
+    text = jax.jit(step, donate_argnums=(2, 6)).lower(
+        params, i32(b), pool, i32(b, w), i32(b), i32(b), state
+    ).compile().as_text()
+    bodies = _loop_bodies_with(text, "ssm_state_update")
+    assert len(bodies) == 2, bodies      # a period's two runs of Mamba layers
+    for fusions, calls in bodies:
+        assert calls == 1 and fusions <= 10, bodies
+
+
 # -- flash attention: the 1.14 B train shape and the 8 B widths ----------------
 
 _FLASH = {"train_1b": (8, 2048, 16, 8), "llama3_8b": (1, 2048, 32, 8)}

@@ -323,6 +323,80 @@ def test_ssm_state_update_kernel_matches_jnp(active, dtype):
     assert not np.asarray(y1)[~live].any()
 
 
+@pytest.mark.parametrize("active", [
+    (1, 1, 1, 1, 1, 1), (1, 0, 1, 1, 0, 0), (0, 0, 0, 0, 0, 0)],
+    ids=["all", "some", "none"])
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+def test_ssm_layer_step_kernel_matches_the_jnp_branch(active, dtype):
+    """The fused call (convolution, ``silu``, delta and decay, the state's
+    update, ``D x``, the gate and its norm: everything between the two
+    projections) against ``recurrent_step_jnp``, the model's own CPU branch,
+    from the same projected rows."""
+    cfg = gh.GraniteHybridConfig.tiny(param_dtype=dtype, compute_dtype=dtype)
+    rows, li = len(active), 1
+    nm, i = cfg.count("mamba"), cfg.d_inner
+    mp = gh.init_params(cfg, jax.random.PRNGKey(11))["mamba"]
+    mp = dict(mp, norm=1 + 0.1 * jax.random.normal(
+        jax.random.PRNGKey(12), mp["norm"].shape).astype(dtype),
+        d=jax.random.uniform(jax.random.PRNGKey(13), mp["d"].shape,
+                             minval=0.5, maxval=1.5).astype(dtype))
+    lp = jax.tree.map(lambda a: a[li], mp)
+    ks = jax.random.split(jax.random.PRNGKey(sum(active)), 5)
+    state = gh.init_slot_state(cfg, rows)
+    ssm = jax.random.normal(ks[0], state["ssm"].shape)
+    win = ssm_ops.pack_window(jax.random.normal(
+        ks[1], (nm, rows, cfg.mamba_d_conv - 1, cfg.conv_width))
+    ).astype(dtype)
+    assert win.shape == state["conv"].shape
+    proj = jax.random.normal(ks[2], (rows, i + cfg.conv_width)).astype(dtype)
+    dt = jax.random.normal(ks[3], (rows, cfg.mamba_n_heads)).astype(dtype)
+    act = jnp.asarray(active, jnp.int32)
+    y0, s0, w0 = gh.recurrent_step_jnp(
+        cfg, lp, proj[:, :i], proj[:, i:], dt, ssm, win[li], li, act)
+    small = ssm_ops.prepare_layer_params(
+        mp["conv_w"], mp["conv_b"], mp["dt_bias"], mp["a_log"], mp["d"],
+        mp["norm"], cfg.mamba_d_head)
+    y1, s1, w1 = ssm_ops.ssm_layer_step(
+        ssm, win, li, proj, dt, small, act, eps=cfg.rms_norm_eps,
+        interpret=True)
+    assert y1.dtype == y0.dtype == dtype and y1.shape == (rows, i)
+    tol = 1e-5 if dtype == jnp.float32 else 2e-2
+    f32 = np.float32
+    live = np.asarray(active, bool)
+    np.testing.assert_allclose(np.asarray(y1, f32)[live],
+                               np.asarray(y0, f32)[live], rtol=tol, atol=tol)
+    np.testing.assert_allclose(np.asarray(s1), np.asarray(s0),
+                               rtol=tol, atol=tol)
+    np.testing.assert_array_equal(np.asarray(w1[li], f32),
+                                  np.asarray(w0, f32))
+    if live.any():  # the step did something, and the window moved on
+        assert np.abs(np.asarray(y1, f32)[live]).max() > 0.1
+        np.testing.assert_array_equal(
+            np.asarray(ssm_ops.unpack_window(w1[li], cfg.conv_width))[live, -1],
+            np.asarray(proj[:, i:])[live])
+    # a row that does not decode, and every other layer: bit for bit
+    np.testing.assert_array_equal(np.asarray(s1)[:, ~live],
+                                  np.asarray(ssm)[:, ~live])
+    np.testing.assert_array_equal(np.asarray(w1, f32)[:, ~live],
+                                  np.asarray(win, f32)[:, ~live])
+    others = [j for j in range(nm) if j != li]
+    np.testing.assert_array_equal(np.asarray(s1)[others],
+                                  np.asarray(ssm)[others])
+    np.testing.assert_array_equal(np.asarray(w1, f32)[others],
+                                  np.asarray(win, f32)[others])
+    assert not np.asarray(y1, f32)[~live].any()
+
+
+def test_ssm_layer_step_refuses_what_it_does_not_compute():
+    with pytest.raises(NotImplementedError, match="8 B/C groups"):
+        ssm_ops.ssm_layer_step(
+            jnp.zeros((1, 2, 2, 16, 128)), jnp.zeros((1, 2, 3, 16, 128)), 0,
+            jnp.zeros((2, 288)), jnp.zeros((2, 8)), {}, jnp.ones(2, jnp.int32),
+            eps=1e-5, n_groups=8)
+    assert "heads" in ssm_ops.layer_step_unsupported(128, 64, 128)
+    assert ssm_ops.layer_step_unsupported(64, 64, 128) is None
+
+
 @pytest.mark.parametrize("nh,kv", [(8, 2), (4, 4), (32, 8)])
 def test_paged_attention_kernel_at_heads_of_64(nh, kv):
     from ray_tpu.models.llama import _paged_attend
@@ -412,7 +486,8 @@ def test_configuration_file_is_the_published_model_whole():
     state = jax.eval_shape(lambda: gh.init_slot_state(cfg, 64))
     assert state["ssm"].shape == (36, 64, 32, 128, 128)
     assert state["ssm"].dtype == jnp.float32
-    assert state["conv"].shape == (36, 64, 3 * 4352)
+    # three taps of 4352 channels, 128 a row, the rows whole memory tiles
+    assert state["conv"].shape == (36, 64, 3, 48, 128)
     pool = jax.eval_shape(lambda: gh.init_paged_cache(cfg, 8, 16))
     assert sum(int(np.prod(x.shape[2:])) * x.shape[0] * 2 // 16
                for x in pool.values()) == 8192  # bytes a cached position
