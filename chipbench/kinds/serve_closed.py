@@ -18,4 +18,5 @@ def run(cell, args) -> dict:
 
 
 correct = serving.serving_correct
+compared = serving.compared
 device = serving.device_block

@@ -17,4 +17,5 @@ def run(cell, args) -> dict:
 
 
 correct = serving.serving_correct
+compared = serving.compared
 device = serving.device_block

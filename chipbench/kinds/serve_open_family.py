@@ -113,15 +113,15 @@ def judge(rows: list) -> dict:
         return sum(v) / len(v) if v else 0.0
 
     worst, short_mean, long_mean = max(gaps), mean(short_gaps), mean(long_gaps)
+    checks = (("short_mean_logit_gap", "mean gap of the short probes",
+               short_mean, len(short_gaps), REF_MEAN_TOL),
+              ("long_mean_logit_gap", "mean gap behind a document",
+               long_mean, len(long_gaps), REF_LONG_MEAN_TOL),
+              ("max_logit_gap", "largest gap", worst, len(gaps), REF_MAX_TOL))
     why = [f"{name} {got:.4f} over {n} tokens (limit {limit})"
-           for name, got, n, limit in (
-               ("mean gap of the short probes", short_mean, len(short_gaps),
-                REF_MEAN_TOL),
-               ("mean gap behind a document", long_mean, len(long_gaps),
-                REF_LONG_MEAN_TOL),
-               ("largest gap", worst, len(gaps), REF_MAX_TOL))
-           if got > limit]
+           for _, name, got, n, limit in checks if got > limit]
     return {"ok": not why, "max_logit_gap": worst,
+            "compared": [[key, got, limit] for key, _, got, _, limit in checks],
             "short_mean_logit_gap": short_mean,
             "long_mean_logit_gap": long_mean, "short_tokens": len(short_gaps),
             "long_tokens": len(long_gaps),
@@ -354,6 +354,7 @@ def run(cell, args) -> dict:
 
 
 correct = serving.serving_correct
+compared = serving.compared
 device = serving.device_block
 
 

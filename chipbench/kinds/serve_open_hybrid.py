@@ -112,14 +112,13 @@ def judge(rows: list, state: dict) -> dict:
 
     worst = max(gaps)
     ssm = state["ssm"]
+    checks = (("prompt_mean_logit_gap", "mean gap behind a prompt",
+               mean(prompt), len(prompt), REF_PROMPT_MEAN_TOL),
+              ("decode_mean_logit_gap", "mean gap of the long-decode probes",
+               mean(decode), len(decode), REF_DECODE_MEAN_TOL),
+              ("max_logit_gap", "largest gap", worst, len(gaps), REF_MAX_TOL))
     why = [f"{name} {got:.5f} over {n} tokens (limit {limit})"
-           for name, got, n, limit in (
-               ("mean gap behind a prompt", mean(prompt), len(prompt),
-                REF_PROMPT_MEAN_TOL),
-               ("mean gap of the long-decode probes", mean(decode),
-                len(decode), REF_DECODE_MEAN_TOL),
-               ("largest gap", worst, len(gaps), REF_MAX_TOL))
-           if got > limit]
+           for _, name, got, n, limit in checks if got > limit]
     why = ("served tokens give up reference logit: " + "; ".join(why)
            if why else "")
     if not (ssm["finite"] and ssm["rel_err"] <= REF_STATE_TOL):
@@ -127,6 +126,8 @@ def judge(rows: list, state: dict) -> dict:
                 f"{state['positions']} positions is {ssm['rel_err']:.4f} "
                 f"of its norm off the reference's (limit {REF_STATE_TOL})")
     return {"ok": not why, "max_logit_gap": worst,
+            "compared": [[key, got, limit] for key, _, got, _, limit in checks]
+            + [["state_rel_err", ssm["rel_err"], REF_STATE_TOL]],
             "prompt_mean_logit_gap": mean(prompt),
             "decode_mean_logit_gap": mean(decode),
             "prompt_tokens": len(prompt), "decode_tokens": len(decode),
@@ -370,18 +371,17 @@ def _deployed(cell, rehearse: bool, body):
         sweep_processes()
 
 
-_capture_trace = serving.capture_trace
+_parse_trace = serving.parse_trace
 
 
-def capture_with_regions(pid: int, duration_s: float, workdir: str):
-    """``serving.capture_trace``, and from the trace's file, while it is
+def parse_with_regions(got: dict) -> dict:
+    """``serving.parse_trace``, and from the trace's file, while it is
     there, what ``trace_reduce.load`` does not keep: the stats of the
-    engine's dispatch regions (``hybrid_rows``)."""
-    got = _capture_trace(pid, duration_s, workdir)
-    if got is not None:
-        got["regions"] = hybrid_rows.regions(got["path"])
-        log("trace regions: " + ", ".join(
-            f"{len(v)} {k}" for k, v in got["regions"].items()))
+    engine's dispatch regions (``hybrid_rows``).  Both after the drain."""
+    got = _parse_trace(got)
+    got["regions"] = hybrid_rows.regions(got["path"])
+    log("trace regions: " + ", ".join(
+        f"{len(v)} {k}" for k, v in got["regions"].items()))
     return got
 
 
@@ -392,17 +392,18 @@ def run(cell, args) -> dict:
     traffic = cell.traffic
     if args.rehearse:
         traffic = serving.toy_traffic(traffic)
-    # ``_measure`` looks the capture up in its module when the time comes
-    serving.capture_trace = capture_with_regions
+    # ``_measure`` looks the parse up in its module when the time comes
+    serving.parse_trace = parse_with_regions
     try:
         return _deployed(
             cell, args.rehearse, lambda replica: serving._measure(
                 cell, args, replica, traffic, float(args.seconds)))
     finally:
-        serving.capture_trace = _capture_trace
+        serving.parse_trace = _parse_trace
 
 
 correct = serving.serving_correct
+compared = serving.compared
 device = serving.device_block
 
 

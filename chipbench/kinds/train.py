@@ -239,6 +239,13 @@ def correct(evidence: dict, rehearse: bool):
     return not why, steps, len(bad), "; ".join(why)
 
 
+def compared(evidence: dict) -> list:
+    """``[name, number, limit]`` of what ``correct`` holds to a limit."""
+    return [[f"loss_gap_sequence_{c['sequence']}",
+             abs(c["loss"] - c["reference"]), LOSS_TOL]
+            for c in evidence["checks"]]
+
+
 def device(evidence: dict) -> dict:
     from chipbench import serving
 

@@ -3,7 +3,8 @@ roofline.  The bytes the kernel MUST move in the traced decode runs over what
 the chip's HBM moves in the kernel's self time in them.
 
 Bytes: ``model_math_granite_hybrid.ssm_kernel_bytes``: a decoding row's
-recurrent state read and written once a Mamba layer a token-step.  Decoding
+recurrent state and (since PR 44 put the whole layer-step into the kernel)
+its convolution window, read and written once a Mamba layer a token-step.  Decoding
 rows a token-step: COUNTED over the dispatches the trace holds
 (``hybrid_rows.rows``: the ``slots`` and ``chunk`` of every
 ``engine.decode_dispatch`` region, which are what the engine books as

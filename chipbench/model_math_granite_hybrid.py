@@ -99,8 +99,7 @@ def slot_state_bytes(cfg: dict) -> int:
     """What one sequence holds that does not page: state and window, every
     Mamba layer."""
     nm, _ = _kinds(cfg)
-    window = (cfg["mamba_d_conv"] - 1) * conv_width(cfg) * WINDOW_BYTES
-    return nm * (state_values(cfg) * STATE_BYTES + window)
+    return nm * (state_values(cfg) * STATE_BYTES + window_bytes(cfg))
 
 
 def kv_bytes_per_position(cfg: dict, bytes_per_value: int = 2) -> int:
@@ -109,14 +108,24 @@ def kv_bytes_per_position(cfg: dict, bytes_per_value: int = 2) -> int:
             * bytes_per_value)
 
 
+def window_bytes(cfg: dict) -> int:
+    """One sequence's convolution window in ONE Mamba layer: its last
+    ``K - 1`` inputs, the real channels (the program pads a tap's 4,352
+    channels to 48 rows of 128; padding is its cost, not work)."""
+    return (cfg["mamba_d_conv"] - 1) * conv_width(cfg) * WINDOW_BYTES
+
+
 def ssm_kernel_bytes(cfg: dict, live_rows: float) -> float:
     """Bytes the state-update kernel must move in ONE token-step for
-    ``live_rows`` decoding rows: each row's state read and written once a
-    Mamba layer.  (Its other operands, 36 KB a row a layer, and the
-    convolution's window, which the program keeps outside the kernel, are
-    left out: the share reads the lower for it.)"""
+    ``live_rows`` decoding rows.  Since PR 44 the kernel is the whole
+    layer-step between a Mamba layer's two projections, so a row's state AND
+    its window are read and written once a layer inside it (before, the
+    window was kept outside the kernel and was not counted: 1.2% of the
+    state's bytes).  Its other operands, 25 KB a row a layer of projection in
+    and output out, are left out: the share reads the lower for it."""
     nm, _ = _kinds(cfg)
-    return float(live_rows) * nm * 2 * state_values(cfg) * STATE_BYTES
+    return float(live_rows) * nm * 2 * (state_values(cfg) * STATE_BYTES
+                                        + window_bytes(cfg))
 
 
 def decode_step_bytes(cfg: dict, live_rows: float,

@@ -61,9 +61,8 @@ def params_held(cfg: dict) -> int:
 
 
 def decode_weight_bytes(cfg: dict, bytes_per_param: int = 2) -> int:
-    """Weights one decode token-step reads: all of them less the embedding
-    table (read a row a sequence).  Every held expert counts: the program
-    reads them whether or not a token chose them."""
+    """Weights one decode token-step reads where every held expert is
+    wanted: all of them less the embedding table (read a row a sequence)."""
     return (params_held(cfg) - embedding_params(cfg)) * bytes_per_param
 
 
@@ -78,10 +77,22 @@ def latent_bytes(cfg: dict, bytes_per_value: int = 2) -> int:
     return latent_values(cfg) * bytes_per_value
 
 
-def decode_step_bytes(cfg: dict, live_positions: float) -> float:
-    """Bytes one decode token-step must read: the weights once and the
-    latent of every live position in every layer."""
+def routed_expert_bytes(cfg: dict, bytes_per_param: int = 2) -> int:
+    """The routed experts this chip holds, every expert layer."""
+    return ((cfg["num_hidden_layers"] - cfg["first_k_dense_replace"])
+            * cfg["n_routed_experts"] * expert_params(cfg) * bytes_per_param)
+
+
+def decode_step_bytes(cfg: dict, live_positions: float,
+                      experts_hit: float = 1.0) -> float:
+    """Bytes one decode token-step must read: the weights once, of the held
+    routed experts only the share ``experts_hit`` that some decoding row
+    chose, and the latent of every live position in every layer.  An expert
+    no row chose is no work, whatever reads it: the share then reads the same
+    whether the program reads every held expert (it does) or skips the
+    unwanted, and a decode that skips them cannot read over 100."""
     return (decode_weight_bytes(cfg)
+            - (1.0 - experts_hit) * routed_expert_bytes(cfg)
             + live_positions * cfg["num_hidden_layers"] * latent_bytes(cfg))
 
 

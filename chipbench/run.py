@@ -94,18 +94,28 @@ def run(args) -> int:
         device.update(busy_s=s["busy_s"], window_s=s["window_s"])
         result["breakdown"] = {"device_ops": s["device_ops"],
                                "idle_gaps": s["idle_gaps"]}
+    # every number ``correct`` compared, beside its limit: last in the
+    # line, and the last lines of standard error
+    checks = kind.compared(evidence)
     if args.rehearse:
         # a CPU run's numbers never stand under a device metric's name
         log(f"a chip run would print: correct={result['correct']} "
             f"attempted={result['attempted']} failed={result['failed']} "
-            f"metrics={sorted(metrics)} device keys={sorted(device)}")
+            f"metrics={sorted(metrics)} device keys={sorted(device)} "
+            f"compared={[name for name, _, _ in checks]}")
         print("[chipbench] rehearsal complete: no result line", flush=True)
         return 3
     if device["platform"] != "tpu" or device["count"] != cell.chips:
         raise BenchError(f"the workers saw {device['count']} x "
                          f"{device['platform']}, the cell asks {cell.chips} "
                          "TPU chip(s)")
+    result["compared"] = {name: {"value": value, "limit": limit}
+                          for name, value, limit in checks}
     sys.stdout.flush()
+    for name, value, limit in checks:
+        print(f"[chipbench] compared {name}: {value} (limit {limit})",
+              file=sys.stderr)
+    sys.stderr.flush()
     print(json.dumps(result), flush=True)
     return 0
 

@@ -270,6 +270,7 @@ class Replica:
         log(f"float32 reference: {rows}; worst gap {worst:.4f} "
             f"(tolerance {REF_LOGIT_TOL})")
         return {"ok": ok, "max_logit_gap": worst, "probes": rows,
+                "compared": [["max_logit_gap", worst, REF_LOGIT_TOL]],
                 "why": None if ok else f"served tokens give up {worst} "
                                        "reference logit"}
 
@@ -380,12 +381,30 @@ def _measure(cell, args, replica, traffic, seconds) -> dict:
     with open(os.path.join(workdir, "rows.json")) as f:
         evidence["rows"] = json.load(f)["rows"]
     # one line a later reader of the log can take any statistic from
-    log("window rows [key, prompt, asked, due s, ttft ms, last ms]: " + json.dumps(
-        [[r["key"], r["prompt_len"], r["max_tokens"], round(r["due"], 3),
-          round((r["first"] - r["due"]) * 1e3, 1),
-          round((r["last"] - r["due"]) * 1e3, 1)]
-         for r in evidence["rows"] if r["phase"] == "window" and r["ok"]
-         and r.get("due") is not None]))
+    # (steady.py does): got is what tpot divides by
+    log("window rows [key, prompt, asked, due s, ttft ms, last ms, got]: "
+        + json.dumps(
+            [[r["key"], r["prompt_len"], r["max_tokens"], round(r["due"], 3),
+              round((r["first"] - r["due"]) * 1e3, 1),
+              round((r["last"] - r["due"]) * 1e3, 1), r["got"]]
+             for r in evidence["rows"] if r["phase"] == "window" and r["ok"]
+             and r.get("due") is not None]))
+    gap = spec.stream_gap(evidence["rows"], seconds)
+    if gap is not None:
+        # a run that stalls says so (steady.py prints it beside the run)
+        log("stream gap [longest pause ms, at s, stream key, streams paused "
+            "within 50 ms of it, streams live then]: " + json.dumps(
+                [round(gap["max_ms"], 1), round(gap["at_s"], 3), gap["key"],
+                 gap["paused"], gap["live"]]))
+    if evidence["trace"]:
+        t0 = time.monotonic()
+        try:
+            evidence["trace"] = parse_trace(evidence["trace"])
+            log(f"trace parsed in {time.monotonic() - t0:.1f}s, after the "
+                "drain")
+        except Exception as e:  # noqa: BLE001 - the readers then find nothing
+            log(f"trace not readable: {type(e).__name__}: {e}")
+            evidence["trace"] = None
     evidence["cache_files_gained"] = gained
     evidence["reference"] = replica.check_reference(args.seed)
     after = replica.handle.device_report.remote().result(timeout_s=120)
@@ -397,11 +416,11 @@ def _measure(cell, args, replica, traffic, seconds) -> dict:
 
 def capture_trace(pid: int, duration_s: float, workdir: str):
     """One XPlane capture of the worker that holds the chip, through the
-    program's own ``state.jax_profile``; returns the reduced trace's planes
-    (or None, and says why)."""
+    program's own ``state.jax_profile``; returns ``{"path": the .xplane.pb}``
+    (or None, and says why).  The file is NOT parsed here: the HTTP proxy
+    lives in this process, and seconds of parsing inside the window are
+    seconds in which the window's streams wait (``parse_trace``)."""
     from ray_tpu.util import state
-
-    from chipbench import trace_reduce
 
     logdir = os.path.join(workdir, "trace")
     try:
@@ -412,10 +431,32 @@ def capture_trace(pid: int, duration_s: float, workdir: str):
             return None
         log(f"trace: {files[0]} ({os.path.getsize(files[0])} bytes, "
             f"{duration_s:.1f}s asked)")
-        return {"path": files[0], "planes": trace_reduce.load(files[0])}
+        return {"path": files[0]}
     except Exception as e:  # noqa: BLE001 - the readers then find nothing
         log(f"trace capture failed: {type(e).__name__}: {e}")
         return None
+
+
+def parse_trace(got: dict) -> dict:
+    """The captured file -> ``planes`` as ``trace_reduce.load`` gives them.
+    Called once the load has drained and the ledger is read: nothing of the
+    window waits for it."""
+    from chipbench import trace_reduce
+
+    got["planes"] = trace_reduce.load(got["path"])
+    return got
+
+
+def compared(evidence: dict) -> list:
+    """``[name, number, limit]`` of every number ``serving_correct`` holds
+    to a limit, for the result line and the log's last lines: the failed
+    requests, and what the reference check's verdict says it compared (the
+    check that judged states them, ``compared`` in its verdict: one list;
+    a check that broke off before it had a number states none)."""
+    return [["failed_requests", sum(
+        1 for r in evidence["rows"] if r["phase"] == "window"
+        and not r.get("cut") and not r["ok"]), 0]] + [
+            list(c) for c in evidence["reference"].get("compared", ())]
 
 
 def serving_correct(evidence: dict, rehearse: bool):

@@ -127,3 +127,52 @@ def ttft_ms(evidence: dict) -> list:
         f"p50 {percentile(vals, 50):.1f} p90 {percentile(vals, 90):.1f} "
         f"p95 {percentile(vals, 95):.1f} max {max(vals):.1f}")
     return vals
+
+
+def tpot_ms(evidence: dict) -> list:
+    """The pace of every stream of an open loop's window that got two tokens
+    or more: (last token - first token) / (tokens - 1), client's clock, in
+    ms, whatever the framing of ``decode_chunk``."""
+    if "tpot_ms" in evidence:  # several readers, one reckoning and one line
+        return evidence["tpot_ms"]
+    rows = [r for r in evidence.get("rows", ()) if r["phase"] == "window"
+            and r["ok"] and r["got"] >= 2]
+    if not rows or evidence["traffic"]["loop"] != "open":
+        return []
+    vals = evidence["tpot_ms"] = [
+        (r["last"] - r["first"]) / (r["got"] - 1) * 1e3 for r in rows]
+    log(f"tpot_ms over {len(vals)} streams: mean "
+        f"{sum(vals) / len(vals):.3f} p50 {percentile(vals, 50):.3f} p90 "
+        f"{percentile(vals, 90):.3f} p95 {percentile(vals, 95):.3f} max "
+        f"{max(vals):.3f}")
+    return vals
+
+
+def stream_gap(rows, seconds: float = float("inf"), near_s: float = 0.05):
+    """The longest pause between two frames of any stream, the ramp's too,
+    that ended after the window's start and began before its end
+    (``seconds``): ``{"max_ms", "at_s" (when it began, from the window's
+    start), "key" (the stream), "paused" (streams, this one too, with a pause
+    at least half as long that began within ``near_s`` of it), "live"
+    (streams that had begun and not ended when it began)}``; or None where
+    no stream has two frames there.  One stream that waits is a row's own
+    business; many that wait together are a stall of the engine, the proxy
+    or the machine.  (A stall at the window's very start holds no stream of
+    the WINDOW: the ramp's streams show it, and the window's first requests'
+    times to first token.)"""
+    streams = [(r["key"], [f[0] for f in r["frames"]]) for r in rows
+               if len(r.get("frames") or ()) >= 2]
+    worst = None
+    for key, ts in streams:
+        for a, b in zip(ts, ts[1:]):
+            if b > 0 and a < seconds and (worst is None or b - a > worst[0]):
+                worst = (b - a, a, key)
+    if worst is None:
+        return None
+    length, at, key = worst
+    paused = sum(1 for _, ts in streams if any(
+        abs(a - at) <= near_s and b - a >= 0.5 * length
+        for a, b in zip(ts, ts[1:])))
+    live = sum(1 for _, ts in streams if ts[0] <= at < ts[-1])
+    return {"max_ms": length * 1e3, "at_s": at, "key": key, "paused": paused,
+            "live": live}

@@ -10,6 +10,13 @@ from chipbench import spec
 NAME = re.compile(r"^[A-Za-z0-9_][A-Za-z0-9_.\-]{0,63}$")
 UNIT = re.compile(r"^[A-Za-z0-9_/%.\-]{1,16}$")
 SOURCES = {"device_trace", "program_span", "program_counter", "host_clock"}
+# the contract's widths, which ``reduced`` may never name: a hidden,
+# intermediate, latent, state or projection size, a key that ends in ``_dim``
+# or ``_rank``, a head size, an expansion factor, the experts a token
+WIDTH = re.compile(r"(_dim|_rank)$|hidden_size|intermediate_size|head_size|"
+                   r"d_state|d_head|d_conv|d_inner|expand|proj_size|"
+                   r"experts_per_tok|num_attention_heads|num_key_value_heads|"
+                   r"n_heads")
 
 
 @pytest.fixture(scope="module")
@@ -79,7 +86,7 @@ def test_every_file_a_cell_names_exists(bench):
                 spec.BENCH, "layer_metrics", m["name"] + ".py")), m["name"]
         for key in cell.config_entry["reduced"]:
             assert key in cell.config["reduced"], key
-            assert not key.endswith(("_dim", "_rank", "_size"))
+            assert not WIDTH.search(key), key  # vocab_size is no width
 
 
 def test_moves_is_reported_wherever_the_layer_metric_is(bench):
@@ -107,8 +114,46 @@ def test_configuration_files_state_their_cut(bench):
         assert f["source"] == c["source"]
         for key in ("reduced", "assumed", "deployment", "chips", "published"):
             assert key in f, (c["name"], key)
-        # no width is cut: Mistral-7B-v0.3 as published
-        assert (f["hidden_size"], f["intermediate_size"], f["head_dim"],
-                f["num_attention_heads"], f["num_key_value_heads"],
-                f["vocab_size"]) == (4096, 14336, 128, 32, 8, 32768)
         assert set(c["reduced"]) == set(f["reduced"])
+        # no width is cut, whatever the architecture: a cut key is listed
+        # with its published value, and none of them is a width
+        assert set(f["published"]) == set(f["reduced"]), c["name"]
+        widths = [k for k, v in f.items()
+                  if WIDTH.search(k) and isinstance(v, (int, float))]
+        assert widths and "hidden_size" in widths, c["name"]
+        assert not [k for k in f["published"] if WIDTH.search(k)], c["name"]
+        if "Mistral-7B-v0.3" in c["source"]:  # as published
+            assert (f["hidden_size"], f["intermediate_size"], f["head_dim"],
+                    f["num_attention_heads"], f["num_key_value_heads"],
+                    f["vocab_size"]) == (4096, 14336, 128, 32, 8, 32768)
+
+
+def test_the_serving_gates_are_the_pace_mean_the_tail_and_the_first_token(bench):
+    """PR 46: ``tpot_mean_ms`` in every serving cell, the tail only where a
+    window holds ten streams beyond its percentile, ``tpot_p95_ms`` per
+    layer; every cell's traffic says how many requests a window holds."""
+    e2e = {m["name"]: m for m in bench["end_to_end"]}
+    assert "tpot_p95_ms" not in e2e
+    serving = [w["name"] for w in bench["workloads"]
+               if spec.Cell(w["name"]).traffic.get("loop") == "open"]
+    assert sorted(e2e["tpot_mean_ms"]["workloads"]) == sorted(serving)
+    assert sorted(e2e["ttft_mean_ms"]["workloads"]) == sorted(serving)
+    assert e2e["tpot_mean_ms"]["bound"] <= 0.03
+    assert e2e["ttft_mean_ms"]["bound"] <= 0.10
+    # every serving cell has ONE tail of the pace, at the highest of the two
+    # percentiles that has ten streams beyond it in its window
+    tails = {"tpot_p90_ms": 0.10, "tpot_p85_ms": 0.15}
+    for name in serving:
+        requests = (spec.Cell(name).traffic["arrivals"]["rate_per_s"]
+                    * bench["run_seconds"])
+        mine = [t for t in tails if name in e2e[t]["workloads"]]
+        want = next(t for t, beyond in tails.items()
+                    if requests * beyond >= 10)
+        assert mine == [want], (name, requests)
+    for t in tails:
+        assert e2e[t]["bound"] <= 0.05
+    per = {m["name"]: m for m in bench["per_layer"]}
+    for name in ("tpot_p95_ms", "stream_gap_max_ms"):
+        assert per[name]["moves"] == "tpot_mean_ms"
+        assert per[name]["source"] == "host_clock"
+        assert sorted(per[name]["workloads"]) == sorted(serving)

@@ -15,10 +15,10 @@ SERVING = ["m7b-d16.chat_steady", "pangu-ep16.docqa_warm",
 ENTRIES = {
     "device_wait_share_pct": ("%", "higher", "program_counter",
                               "admission and batching (llm/paged.py)",
-                              "tpot_p95_ms"),
+                              "tpot_mean_ms"),
     "engine_idle_share_pct": ("%", "lower", "program_counter",
                               "admission and batching (llm/paged.py)",
-                              "tpot_p95_ms"),
+                              "tpot_mean_ms"),
     "trace_write_s": ("s", "lower", "host_clock",
                       "process layout (raylet, zygote, warmup, compile)",
                       "setup_s"),

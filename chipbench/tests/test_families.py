@@ -76,7 +76,7 @@ def test_the_cell_is_in_the_lists_of_the_readers_that_hold_for_it(bench):
     cell = spec.Cell(CELL)
     assert cell.kind == "serve_open_family"
     assert {m["name"] for m in cell.metrics("end_to_end")} == {
-        "ttft_mean_ms", "tpot_p95_ms", "setup_s"}
+        "ttft_mean_ms", "tpot_mean_ms", "tpot_p90_ms", "setup_s"}
     names = {m["name"] for m in cell.metrics("per_layer")}
     assert {"paged_attn_share_pct", "mla_decode_roofline_pct",
             "moe_ffn_share_pct", "moe_expert_live_pct",
@@ -236,8 +236,17 @@ def test_new_readers_on_recorded_evidence(evidence):
                 mm.mla_kernel_bytes(cfg, live) / 819e9)
     assert _read("mla_decode_roofline_pct", evidence) == pytest.approx(
         100 * least * 4 / 0.002)
-    assert _read("decode_hbm_roofline_pct", evidence) == pytest.approx(
-        100 * mm.decode_step_bytes(cfg, live) / (0.005 * 819e9))
+    # the held experts count by the share a token-step's rows hit (2,600 of
+    # 6,400): 4 expert layers x 16 held x 3 x 7680 x 2048 weights, bf16
+    got = _read("decode_hbm_roofline_pct", evidence)
+    assert got == pytest.approx(
+        100 * mm.decode_step_bytes(cfg, live, 2600 / 6400)
+        / (0.005 * 819e9))
+    assert mm.routed_expert_bytes(cfg) == 4 * 16 * 3 * 7680 * 2048 * 2
+    assert mm.decode_step_bytes(cfg, live) - mm.decode_step_bytes(
+        cfg, live, 2600 / 6400) == pytest.approx(
+            (1 - 2600 / 6400) * mm.routed_expert_bytes(cfg))
+    assert got < 100 * mm.decode_step_bytes(cfg, live) / (0.005 * 819e9)
 
 
 def test_new_readers_find_nothing_on_a_program_without_their_sources(evidence):

@@ -31,8 +31,12 @@ def test_model_math_against_counts_worked_by_hand(cfg):
     assert math_.state_values(cfg) == 64 * 64 * 128
     assert math_.slot_state_bytes(cfg) == 36 * (2_097_152 + 3 * 4352 * 2)
     assert math_.kv_bytes_per_position(cfg) == 8192
-    # 20 decoding rows: each row's 2 MB state in and out, 36 layers
-    assert math_.ssm_kernel_bytes(cfg, 20) == 20 * 36 * 2 * 2_097_152
+    # 20 decoding rows: each row's 2 MB state and its window (3 taps of
+    # 4,352 channels, bf16) in and out, 36 layers: the kernel is the whole
+    # layer-step since PR 44
+    assert math_.window_bytes(cfg) == 3 * 4352 * 2
+    assert math_.ssm_kernel_bytes(cfg, 20) == 20 * 36 * 2 * (
+        2_097_152 + 26_112)
     assert math_.decode_step_bytes(cfg, 0, 0) == 2 * 3_191_396_096
     one = math_.prefill_flops(cfg, 1)
     assert one == pytest.approx(
@@ -65,7 +69,7 @@ def test_the_cell_is_in_the_lists_of_the_readers_that_hold_for_it():
     assert entry["reduced"] == [] and entry["source"] == spec.Cell(
         CELL).config["source"]
     e2e = {m["name"] for m in spec.Cell(CELL).metrics("end_to_end")}
-    assert e2e == {"ttft_mean_ms", "tpot_p95_ms", "setup_s"}
+    assert e2e == {"ttft_mean_ms", "tpot_mean_ms", "tpot_p90_ms", "setup_s"}
 
 
 # -- the readers on recorded evidence --------------------------------------------------
@@ -132,9 +136,9 @@ def _read(name, evidence):
 
 def test_new_readers_on_recorded_evidence(evidence, cfg):
     hbm, peak = 819e9, 197e12
-    # 4 token-steps traced, 20 rows x 36 layers x 4 MiB each, in 32 ms of
-    # kernel: 12.08 GB at 377 GB/s
-    want = 100 * 4 * 20 * 36 * 2 * 2_097_152 / (2 * KERNEL_S * hbm)
+    # 4 token-steps traced, 20 rows x 36 layers x (4 MiB of state + 51 KB of
+    # window) each, in 32 ms of kernel: 12.23 GB at 382 GB/s
+    want = 100 * 4 * 20 * 36 * 2 * (2_097_152 + 26_112) / (2 * KERNEL_S * hbm)
     got = _read("ssm_decode_roofline_pct", evidence)
     assert got == pytest.approx(want) and 0 < got < 100
     busy = 2 * (KERNEL_S + 0.0002 + REST_S) + PREFILL_S
@@ -167,7 +171,8 @@ def test_the_rows_are_counted_over_the_traced_dispatches(evidence, cfg):
         {"slots": 14, "chunk": 1, "pages": 200}]
     assert hybrid_rows.rows(evidence) == 10
     assert hybrid_rows.positions(evidence) == 150 * 16
-    want = 100 * 4 * 10 * 36 * 2 * 2_097_152 / (2 * KERNEL_S * 819e9)
+    want = 100 * 4 * 10 * 36 * 2 * (2_097_152 + 26_112) / (
+        2 * KERNEL_S * 819e9)
     assert _read("ssm_decode_roofline_pct", evidence) == pytest.approx(want)
     chunks = [(0, 256, False), (256, 256, False), (512, 100, True)]
     assert sum(math_.chunk_flops(cfg, *c) for c in chunks) == pytest.approx(

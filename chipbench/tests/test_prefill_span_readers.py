@@ -90,4 +90,4 @@ def test_benchmark_json_lists_the_readers(name, better, source):
     assert entry["better"] == better and entry["source"] == source
     assert entry["moves"] == "ttft_mean_ms" and entry["unit"] == "%"
     assert entry["layer"] == "model step, prefill (models/llama.py)"
-    assert entry["workloads"] == [CELL]
+    assert CELL in entry["workloads"]  # whichever other cells report it

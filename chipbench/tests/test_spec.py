@@ -51,7 +51,7 @@ def test_new_cell_mix_configuration_and_metric_are_found_with_no_edit(copy):
     bench["workloads"].append({"name": "new.chat_burst", "config": "new-config",
                                "traffic": "chat_burst", "chips": 1, "why": "test"})
     for m in bench["end_to_end"]:
-        if m["name"] in ("ttft_mean_ms", "tpot_p95_ms"):
+        if m["name"] in ("ttft_mean_ms", "tpot_mean_ms"):
             m["workloads"].append("new.chat_burst")
     bench["per_layer"].append({
         "name": "slots_active_mean", "unit": "slots", "better": "higher",
@@ -63,7 +63,7 @@ def test_new_cell_mix_configuration_and_metric_are_found_with_no_edit(copy):
     assert cell.kind == "serve_open" and cell.config["engine"]["max_batch_size"] == 32
     assert cell.traffic["arrivals"]["process"] == "gamma"
     assert {m["name"] for m in cell.metrics("end_to_end")} == {
-        "ttft_mean_ms", "tpot_p95_ms", "setup_s"}
+        "ttft_mean_ms", "tpot_mean_ms", "setup_s"}
     assert [m["name"] for m in cell.metrics("per_layer")] == ["slots_active_mean"]
     got = spec.read_metrics(cell, "per_layer", "layer_metrics", {
         "util_samples": [{"slots_active": 10}, {"slots_active": 30}]})
