@@ -102,9 +102,10 @@ class ModelFamily:
 
 def family_of(model_config: Any) -> ModelFamily:
     """The family whose config type ``model_config`` is an instance of."""
-    from ray_tpu.models import granite_hybrid, llama, pangu_moe
+    from ray_tpu.models import granite_hybrid, kimi_linear, llama, pangu_moe
 
-    families = (llama.FAMILY, pangu_moe.FAMILY, granite_hybrid.FAMILY)
+    families = (llama.FAMILY, pangu_moe.FAMILY, granite_hybrid.FAMILY,
+                kimi_linear.FAMILY)
     for fam in families:
         if isinstance(model_config, fam.config_type):
             return fam

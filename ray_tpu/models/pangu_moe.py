@@ -610,6 +610,17 @@ def _dense_ffn(cfg: PanguMoEConfig, h, lp):
             * (h @ lp["w_up"].astype(cdt))) @ lp["w_down"].astype(cdt)
 
 
+def decode_booking(cfg: PanguMoEConfig, g, live):
+    """``DECODE_COUNTERS`` of one expert layer's token-step: ``g [T,
+    n_held]`` the held experts' gates (``moe_ffn``'s), ``live [T]`` the rows
+    that decode."""
+    chose = (g > 0) & (live[:, None] > 0)
+    return jnp.stack([
+        cfg.n_held * (live.max() > 0).astype(jnp.int32),
+        chose.any(axis=0).sum().astype(jnp.int32),
+        chose.sum().astype(jnp.int32)])
+
+
 def _ffn_sublayer(cfg: PanguMoEConfig, x, lp, is_moe: bool, live=None,
                   interpret: bool = False, layer=None):
     """``x + N_post(FFN(N_pre(x)))`` of ``x [B, T, d]``; for an expert layer
@@ -622,11 +633,7 @@ def _ffn_sublayer(cfg: PanguMoEConfig, x, lp, is_moe: bool, live=None,
         with jax.named_scope("moe"):
             y, g = moe_ffn(cfg, h, lp, interpret, layer)
         if live is not None:
-            chose = (g > 0) & (live[:, None] > 0)
-            booked = jnp.stack([
-                cfg.n_held * (live.max() > 0).astype(jnp.int32),
-                chose.any(axis=0).sum().astype(jnp.int32),
-                chose.sum().astype(jnp.int32)])
+            booked = decode_booking(cfg, g, live)
     else:
         y = _dense_ffn(cfg, h, lp)
     y = rms_norm(y.reshape(b, t, d), lp["post_mlp_norm"], cfg.rms_norm_eps)

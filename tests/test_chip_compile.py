@@ -536,6 +536,125 @@ def test_a_mamba_layers_decode_step_is_its_products_and_one_call(one_chip):
         assert calls == 1 and fusions <= 10, bodies
 
 
+# -- the Kimi-Linear family at its cell's shapes -------------------------------------
+# Kimi-Linear-48B-A3B as one chip of EP16: 20 KDA layers whose state is 64
+# slots of [32, 128, 128] float32 a layer, 7 MLA layers of 32 heads over 7,000
+# blocks of 16 positions of a 640-wide latent row, 26 expert layers of 16
+# held experts at d 2304 and f 1024.
+
+
+def _kimi_cell(sh):
+    from ray_tpu.models import kimi_linear as kl
+
+    cfg = kl.KimiLinearConfig()
+
+    def specs(fn):
+        return jax.tree.map(lambda x: _spec(x.shape, x.dtype, sh),
+                            jax.eval_shape(fn))
+
+    return (cfg,
+            specs(lambda: kl.init_params(cfg, jax.random.PRNGKey(0))),
+            specs(lambda: kl.init_paged_cache(cfg, 7000, 16)),
+            specs(lambda: kl.init_slot_state(cfg, 64)))
+
+
+@pytest.mark.parametrize("slots", [64, 16])
+def test_kda_state_update_kernel_compiles_at_cell_shapes(one_chip, slots):
+    from ray_tpu.ops.kda_state_update import kda_state_update
+
+    f32 = jnp.float32
+    vec = (slots, 32, 128)
+    names = _kernel_instructions(
+        kda_state_update, _spec((20, slots, 32, 128, 128), f32, one_chip),
+        _spec((), jnp.int32, one_chip), _spec(vec, BF16, one_chip),
+        _spec(vec, BF16, one_chip), _spec(vec, BF16, one_chip),
+        _spec(vec, f32, one_chip), _spec((slots, 32), f32, one_chip),
+        _spec((slots,), jnp.int32, one_chip))
+    assert any("kda_state_update" in n for n in names), names
+
+
+def test_latent_and_grouped_kernels_compile_at_the_kimi_widths(one_chip):
+    """The three kernels the family shares with pangu, at 32 heads, ``d``
+    2304 and ``f`` 1024: the latent decode kernel over the 384-block table,
+    a 256-token chunk's attention, the grouped product of a chunk's pairs
+    against the 26 expert layers' stacks."""
+    from ray_tpu.ops.mla_paged_attention import mla_paged_decode_attention
+    from ray_tpu.ops.mla_prefill_attention import mla_prefill_attention
+    from ray_tpu.ops.moe_grouped_ffn import moe_grouped_ffn
+
+    def i32(*shape):
+        return _spec(shape, jnp.int32, one_chip)
+
+    _assert_kernel(
+        functools.partial(mla_paged_decode_attention, value_width=512,
+                          scale=192 ** -0.5),
+        _spec((64, 32, 640), BF16, one_chip),
+        _spec((7, 7000, 16, 640), BF16, one_chip), i32(), i32(64, 384),
+        i32(64), i32(64))
+    _assert_kernel(
+        functools.partial(mla_prefill_attention, scale=192 ** -0.5),
+        _spec((256, 32 * 256), BF16, one_chip),
+        _spec((6 * 1024, 640), BF16, one_chip),
+        _spec((32, 128, 512), BF16, one_chip),
+        _spec((32, 512, 128), BF16, one_chip), i32())
+    names = _kernel_instructions(
+        moe_grouped_ffn, _spec((256, 2304), BF16, one_chip),
+        _spec((26, 2304, 16 * 1024), BF16, one_chip),
+        _spec((26, 2304, 16 * 1024), BF16, one_chip),
+        _spec((26, 16 * 1024, 2304), BF16, one_chip), i32(), i32(16),
+        _spec((256,), jnp.float32, one_chip))
+    assert any("moe_grouped_ffn_up" in n for n in names), names
+    assert any("moe_grouped_ffn_down" in n for n in names), names
+
+
+def test_kimi_family_programs_compile_at_cell_shapes(one_chip, monkeypatch):
+    """The engine's two programs with the slot state beside the latent pool
+    and the counters: both kernels in the decode program, by name, and no
+    grouped product in its 64 rows; a 256-token chunk with its attention
+    kernel and the experts' grouped product; neither program keeps a copy of
+    a stacked weight, of the 2.7 GB state or of the pool.  (The model asks
+    the backend, which is the CPU here, so the test answers for it.)"""
+    import types
+
+    from ray_tpu.llm.engine import _MAX_STOP_IDS
+    from ray_tpu.llm.paged import PagedJaxLLMEngine
+    from ray_tpu.models.family import family_of
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    cfg, params, pool, state = _kimi_cell(one_chip)
+    eng = types.SimpleNamespace(
+        cfg=cfg, family=family_of(cfg), max_seq=cfg.max_seq_len, mesh=None,
+        _rope=None, _use_kernel=True, _kernel_interpret=False, _tp_plan=None,
+        _tp_prefill_plan=None)
+    key = jax.eval_shape(lambda: jax.random.PRNGKey(0))
+    key = _spec(key.shape, key.dtype, one_chip)
+
+    def i32(*shape):
+        return _spec(shape, jnp.int32, one_chip)
+
+    b, w = 64, 32
+    decode = jax.jit(
+        functools.partial(PagedJaxLLMEngine._decode_chunk_impl, eng),
+        donate_argnums=(2, 12), static_argnums=11).lower(
+            params, i32(b), pool, i32(b, w), i32(b), i32(b), i32(b),
+            i32(b, _MAX_STOP_IDS), key, _spec((b,), jnp.float32, one_chip),
+            i32(b), 2, state).compile()
+    names = _custom_call_names(decode.as_text())
+    assert "kda_state_update" in names and "mla_paged_attention" in names
+    assert "moe_grouped_ffn" not in names
+    assert decode.memory_analysis().temp_size_in_bytes < 256 << 20
+    prefill = jax.jit(
+        functools.partial(PagedJaxLLMEngine._prefill_chunk_impl, eng),
+        donate_argnums=(2, 9)).lower(
+            params, i32(1, 256), pool, i32(1, 384), i32(), i32(), key,
+            _spec((1,), jnp.float32, one_chip), i32(1), state,
+            (i32(), i32())).compile()
+    names = _custom_call_names(prefill.as_text())
+    assert "mla_prefill_attention" in names
+    assert "moe_grouped_ffn_up" in names and "moe_grouped_ffn_down" in names
+    assert prefill.memory_analysis().temp_size_in_bytes < 512 << 20
+
+
 # -- flash attention: the 1.14 B train shape and the 8 B widths ----------------
 
 _FLASH = {"train_1b": (8, 2048, 16, 8), "llama3_8b": (1, 2048, 32, 8)}

@@ -80,11 +80,14 @@ def test_kernel_agrees_with_the_expanded_form(cfg, case):
         assert (np.asarray(again) == np.asarray(got)).all()
 
 
-@pytest.mark.parametrize("block_q,heads", [(8, 1), (16, 2), (32, 4), (64, 3)])
-def test_kernel_tilings_agree(cfg, block_q, heads):
+@pytest.mark.parametrize("block_q,heads,n_heads", [
+    (8, 1, 4), (16, 2, 4), (32, 4, 4), (64, 3, 4), (32, 4, 32)])
+def test_kernel_tilings_agree(cfg, block_q, heads, n_heads):
     """Query blocks narrower than the chunk (pairs above the diagonal are
     skipped, pairs on it masked), and head groups of each size: a group of 3
-    does not divide 4 heads and falls to 2."""
+    does not divide 4 heads and falls to 2.  At the toy config's 4 heads and
+    at 32 (``models/kimi_linear.py``'s; pangu's are 128)."""
+    cfg = dataclasses.replace(cfg, n_heads=n_heads)
     p0, c, wt = 24, 32, 8
     pool, row, lp, q_nope, q_rope = _inputs(cfg, p0, c, wt, seed=1)
     want = pm._attend_tiles_expanded(cfg, q_nope, q_rope, pool, 0, row,

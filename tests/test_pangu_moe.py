@@ -156,19 +156,38 @@ def _routing(cfg, h, router, name):
             "no_token_on_a_held_expert": zero}[name]
 
 
-@pytest.mark.parametrize("rows", [pm.GROUPED_MIN_ROWS,
-                                  pm.GROUPED_MIN_ROWS + 136])
-@pytest.mark.parametrize("routing", [
-    "the_routers_own", "every_token_on_one_held_expert",
-    "every_token_on_8_held_experts", "no_token_on_a_held_expert"])
-def test_grouped_form_equals_dense_form(sparse, routing, rows):
+_ROUTINGS = ("the_routers_own", "every_token_on_one_held_expert",
+             "every_token_on_8_held_experts", "no_token_on_a_held_expert")
+
+
+def _kimi_widths():
+    """Two expert layers of two held experts at ``d`` 2304 and ``f`` 1024
+    (``models/kimi_linear.py``'s widths; pangu's are 7680 and 2048): the
+    down-projection's column tile of 1,920 does not divide 2,304 and falls
+    to 1,152."""
+    cfg = pm.PanguMoEConfig.tiny(dim=2304, moe_ffn_dim=1024,
+                                 n_routed_experts=32, experts_held=(4, 6))
+    ks = jax.random.split(jax.random.PRNGKey(6), 4)
+    d, ef = cfg.dim, cfg.n_held * cfg.moe_ffn_dim
+    return cfg, {
+        "router": jax.random.normal(ks[0], (2, d, 32)) * 0.02,
+        "we_gate": jax.random.normal(ks[1], (2, d, ef)) * 0.02,
+        "we_up": jax.random.normal(ks[2], (2, d, ef)) * 0.02,
+        "we_down": jax.random.normal(ks[3], (2, ef, d)) * 0.02}
+
+
+@pytest.mark.parametrize("routing, rows, widths", [
+    (routing, rows, "toy") for routing in _ROUTINGS
+    for rows in (pm.GROUPED_MIN_ROWS, pm.GROUPED_MIN_ROWS + 136)] + [
+        ("the_routers_own", pm.GROUPED_MIN_ROWS, "d2304_f1024")])
+def test_grouped_form_equals_dense_form(sparse, routing, rows, widths):
     """The same sum over the chosen pairs alone, whatever the routing: groups
     that span row tiles, a chunk with more pairs than the buffers hold (none
     is dropped), a chunk with none (exactly zero).  Through a layer of the
     stack, as the prefill program reads it, and through one layer's own
-    leaves."""
-    cfg, params = sparse
-    stack = params["moe"]
+    leaves.  At the toy widths, and once at the Kimi family's."""
+    cfg, stack = ((sparse[0], sparse[1]["moe"]) if widths == "toy"
+                  else _kimi_widths())
     h = jax.random.normal(jax.random.PRNGKey(rows), (rows, cfg.dim))
     g = _routing(cfg, h, stack["router"][1], routing)
     lp = {k: stack[k][1] for k in pm.HELD_EXPERT_LEAVES}
@@ -179,10 +198,12 @@ def test_grouped_form_equals_dense_form(sparse, routing, rows):
     assert (pairs > rows) == (routing == "every_token_on_8_held_experts")
     if pairs == 0:
         assert not np.asarray(got).any() and not np.asarray(want).any()
-    np.testing.assert_allclose(got, want, atol=1e-6)
+    # float32 sums of 2,304 terms where the toy's are 64
+    tol = 1e-6 if widths == "toy" else 1e-5
+    np.testing.assert_allclose(got, want, atol=tol)
     assert float(jnp.abs(want).max()) > 1e-3 or pairs == 0
     np.testing.assert_allclose(
-        pm._routed_grouped(cfg, h, g, lp, None, True), want, atol=1e-6)
+        pm._routed_grouped(cfg, h, g, lp, None, True), want, atol=tol)
 
 
 def test_sort_pairs_by_hand():
@@ -260,13 +281,16 @@ def test_router_against_a_hand_written_case():
     np.testing.assert_allclose(g[1:, 0], np.asarray(gates)[1:, 1])
 
 
-def test_latent_kernel_in_interpret_mode_against_the_gather_path():
+@pytest.mark.parametrize("heads", [4, 32])
+def test_latent_kernel_in_interpret_mode_against_the_gather_path(heads):
     """(e) the Pallas kernel over the latent pool, live pages only: rows of
     different lengths, an idle row, table columns past a row's pages that
-    point at a block of NaN (never read)."""
+    point at a block of NaN (never read).  At the toy config's 4 heads and
+    at 32 (``models/kimi_linear.py`` calls it with 32, pangu with 128)."""
     from ray_tpu.ops.mla_paged_attention import mla_paged_decode_attention
 
-    cfg = pm.PanguMoEConfig.tiny(kv_lora_rank=128, qk_rope_head_dim=8)
+    cfg = pm.PanguMoEConfig.tiny(kv_lora_rank=128, qk_rope_head_dim=8,
+                                 n_heads=heads)
     assert cfg.cache_width == 256
     rng = np.random.RandomState(0)
     nb, b, wt = 24, 4, 8
