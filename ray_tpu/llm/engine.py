@@ -31,8 +31,12 @@ from ray_tpu.llm.config import GenerationConfig, LLMConfig
 # stop-token ids travel to the device as a fixed-width padded row per slot
 _MAX_STOP_IDS = 8
 # top-k sampling cap: the kth threshold comes from lax.top_k(logits, 64)
-# instead of a full [B, V] sort — the sort was milliseconds per decode step
-# at V=32k on TPU, the top-64 is microseconds
+# instead of a full [B, V] sort.  On a v5e the top-64 over [64, V] is no
+# small thing either: 0.2 ms + 10.9 ns a column (0.42 ms a token-step at
+# V = 19,200, 0.56 at 32,768, 1.29 at 100,352, 1.94 at 163,840), and the
+# categorical draw's noise and argmax 1.6 ns a column more (0.03 to 0.26 ms;
+# traced decode programs, PERF.md section 5, PR 48), which is why both run
+# only in a token-step some row of which asks for them (`_sampler_gates`)
 _MAX_TOP_K = 64
 
 
@@ -47,30 +51,75 @@ class _Request:
     error: Optional[str] = None
 
 
-def _masked_scaled(logits, temps, top_ks):
-    """Temperature-scaled, top-k-masked logits [B, V] — the categorical
-    branch's pre-softmax shape, shared by sampling and the speculative
-    verifier (target/draft distributions MUST match what non-speculative
-    sampling would draw from).  temps <= 0 rows divide by 1.0 (a benign
-    placeholder — those rows are greedy and never read the scaled value;
-    the old ``max(temps, 1e-6)`` scaled logits by 1e6, a needless
+def _sampler_gates(temps, top_ks, live=None):
+    """``(draws, top_k)``: whether some row draws its token (``temps`` > 0),
+    and whether some row that draws asks for top-k — the two scalars the
+    conditionals of ``_sample`` and ``_masked_scaled`` branch on.  ``live``
+    [B] (> 0: the row decodes; None: every row does) keeps a slot whose
+    request has left, and whose ``temps`` / ``top_ks`` are whatever that
+    request asked, from holding either true.  A greedy row's top-k is
+    never read, so it asks for none."""
+    draws = temps > 0.0
+    if live is not None:
+        draws = draws & (live > 0)
+    return draws.any(), (draws & (top_ks > 0)).any()
+
+
+def _scaled(logits, temps):
+    """Logits over their row's temperature.  temps <= 0 rows divide by 1.0
+    (a benign placeholder — those rows are greedy and never read the scaled
+    value; the old ``max(temps, 1e-6)`` scaled logits by 1e6, a needless
     overflow hazard on the never-used branch)."""
-    t = jnp.where(temps > 0.0, temps, 1.0)[:, None]
-    scaled = logits / t
-    # kth-largest via a capped top-k (not a full [B, V] sort — V=32k sorts
-    # cost milliseconds per step on TPU; see _MAX_TOP_K)
-    kmax = min(_MAX_TOP_K, logits.shape[-1])
+    return logits / jnp.where(temps > 0.0, temps, 1.0)[:, None]
+
+
+def _kth_largest(scaled, top_ks):
+    """[B, 1]: each row's ``top_ks``-th largest value, from a capped top-k
+    (see _MAX_TOP_K)."""
+    kmax = min(_MAX_TOP_K, scaled.shape[-1])
     topv, _ = jax.lax.top_k(scaled, kmax)
     idx = jnp.clip(top_ks - 1, 0, kmax - 1)
-    kth = jnp.take_along_axis(topv, idx[:, None], axis=-1)
+    return jnp.take_along_axis(topv, idx[:, None], axis=-1)
+
+
+def _mask_below(scaled, top_ks, kth):
     return jnp.where((top_ks[:, None] > 0) & (scaled < kth), -1e30, scaled)
 
 
+def _masked_scaled(logits, temps, top_ks, live=None):
+    """Temperature-scaled, top-k-masked logits [B, V] — the categorical
+    branch's pre-softmax shape, as the speculative verifier needs it
+    (target/draft distributions MUST match what non-speculative sampling
+    would draw from: ``_sample`` builds its own from the same three
+    functions).
+
+    The kth-largest value is computed only where ``_sampler_gates`` says a
+    row asks for it: otherwise the threshold is -inf, under which the mask
+    is the identity.  The conditional yields the [B, 1] threshold alone."""
+    kth = jax.lax.cond(
+        _sampler_gates(temps, top_ks, live)[1],
+        # scaled again inside, from the head's own output: closing over the
+        # scaled logits would make them a second [B, V] array to write
+        lambda: _kth_largest(_scaled(logits, temps), top_ks),
+        lambda: jnp.full((logits.shape[0], 1), -jnp.inf,
+                         jnp.result_type(logits.dtype, temps.dtype)))
+    return _mask_below(_scaled(logits, temps), top_ks, kth)
+
+
 @jax.named_scope("sample")
-def _sample(logits, key, temps, top_ks):
+def _sample(logits, key, temps, top_ks, live=None):
     """Sample [B] token ids from [B, V] logits with *per-slot* traced
     sampling params — one compiled program serves any mix of greedy /
-    temperature / top-k callers sharing the decode batch.
+    temperature / top-k callers sharing the decode batch, and pays for
+    what its rows ask (``_sampler_gates``, ``live`` as there): a
+    token-step whose rows are all greedy is one argmax; where some row
+    draws, the scaling and the categorical draw come on top; only where
+    such a row asks for top-k does the top-k over the vocabulary run.
+    Each is a branch of ONE conditional that yields the [B] ids, so no
+    [B, V] array leaves a branch and each fuses as straight-line code
+    (two nested conditionals, the inner one yielding the threshold, read
+    5 to 15% slower than the ungated formula on an all-top-k batch: the
+    compiler moved the mask into the inner one and wrote it out).
 
     temps [B] float32 (<= 0 -> greedy); top_ks [B] int32 (<= 0 -> off).
 
@@ -78,22 +127,37 @@ def _sample(logits, key, temps, top_ks):
     scaling, no top-k perturbation, and no dependence on ``key`` (the
     categorical draw happens on the other branch of the select; greedy
     rows ignore it entirely) — the enabling precondition for speculative
-    decoding's greedy bit-parity pin (tests/test_specdec.py).
+    decoding's greedy bit-parity pin (tests/test_specdec.py).  A row that
+    draws gets the draw of ``key`` over its own masked logits whatever its
+    neighbours ask: the key is the caller's, split outside the conditional,
+    and without a top-k of its own the mask is the identity for it.
     """
-    greedy = jnp.argmax(logits, axis=-1).astype(jnp.int32)
-    masked = _masked_scaled(logits, temps, top_ks)
-    sampled = jax.random.categorical(key, masked, axis=-1).astype(jnp.int32)
-    return jnp.where(temps <= 0.0, greedy, sampled)
+    def greedy():
+        return jnp.argmax(logits, axis=-1).astype(jnp.int32)
+
+    def draw(masked):
+        sampled = jax.random.categorical(key, masked, axis=-1)
+        return jnp.where(temps <= 0.0, greedy(), sampled.astype(jnp.int32))
+
+    def draw_top_k():
+        scaled = _scaled(logits, temps)
+        return draw(_mask_below(scaled, top_ks, _kth_largest(scaled, top_ks)))
+
+    draws, top_k = _sampler_gates(temps, top_ks, live)
+    return jax.lax.switch(
+        draws.astype(jnp.int32) + top_k.astype(jnp.int32),
+        (greedy, lambda: draw(_scaled(logits, temps)), draw_top_k))
 
 
-def _sample_dist(logits, temps, top_ks):
+def _sample_dist(logits, temps, top_ks, live=None):
     """The probability distribution [B, V] that ``_sample`` draws from:
     post temperature/top-k softmax for temps > 0 rows, an exact one-hot
     at the argmax for greedy rows.  The one-hot form makes speculative
     rejection sampling COLLAPSE to exact greedy verification — accept iff
     the draft token is the target argmax, corrections/bonus tokens are
     the argmax — with no separate greedy branch in the verifier."""
-    probs = jax.nn.softmax(_masked_scaled(logits, temps, top_ks), axis=-1)
+    probs = jax.nn.softmax(_masked_scaled(logits, temps, top_ks, live),
+                           axis=-1)
     one_hot = jax.nn.one_hot(jnp.argmax(logits, axis=-1), logits.shape[-1],
                              dtype=probs.dtype)
     return jnp.where(temps[:, None] <= 0.0, one_hot, probs)

@@ -321,7 +321,9 @@ _COUNTERS = ("steps", "prefill_chunks", "prefill_kernel_chunks",
              "prefill_padded_tokens", "prefix_hit_tokens",
              "prefill_live_pages", "prefill_visited_pages",
              "decode_dispatches", "decode_dispatches_pipelined",
-             "decode_token_steps", "decode_table_pages", "decode_live_pages",
+             "decode_token_steps", "decode_sampled_token_steps",
+             "decode_topk_token_steps", "decode_table_pages",
+             "decode_live_pages",
              "decode_rows", "decode_live_rows", "decode_joins",
              "tokens_emitted", "preemptions", "kv_demotions")
 _TRACKED_MAX = 4096
@@ -956,7 +958,12 @@ class PagedJaxLLMEngine:
         visits: what the tile's rounding and the chunk's padding add);
         ``decode_dispatches``, ``decode_dispatches_pipelined``
         (the previous chunk was still in flight at the dispatch, so the
-        device never waited for the host), ``decode_token_steps``;
+        device never waited for the host), ``decode_token_steps``,
+        ``decode_sampled_token_steps`` / ``decode_topk_token_steps`` (the
+        token-steps dispatched while some decoding row's request has a
+        temperature above 0, and those where such a row asks for top-k
+        too: over ``decode_token_steps``, how often the sampler's draw
+        and its top-k ran at all, ``engine._sampler_gates``);
         ``decode_table_pages`` / ``decode_live_pages`` (per dispatch: the
         padded block table handed to the decode program, ``max_batch`` x
         its bucketed width, and the blocks the decoding slots really hold:
@@ -1131,7 +1138,9 @@ class PagedJaxLLMEngine:
             if slot:
                 slot, booked = booked[:1], booked[1:]
             key, sub = jax.random.split(key)
-            ids = _sample(logits, sub, temps, top_ks)
+            # a row that ended, in this chunk or before it, asks nothing of
+            # the sampler whatever its mirrors still hold
+            ids = _sample(logits, sub, temps, top_ks, active)
             emitted = jnp.where(active > 0, ids, -1)
             lengths = lengths + active
             remaining = remaining - active
@@ -1190,7 +1199,7 @@ class PagedJaxLLMEngine:
                 *(m.at[slot].set(spec_on) for m in spec))
 
     def _draft_propose_impl(self, params, tokens, pool, table, lengths,
-                            key, temps, top_ks):
+                            key, temps, top_ks, active):
         """k+1 autoregressive draft steps per slot: step j feeds the
         running token at position lengths+j and samples the next proposal.
         Steps 0..k-1 yield the k proposals; step k exists only to WRITE
@@ -1213,8 +1222,8 @@ class PagedJaxLLMEngine:
                 self._draft_cfg, params, tok, pool, table, cur,
                 rope_cache=self._draft_rope)
             key, sub = jax.random.split(key)
-            ids = _sample(logits, sub, temps, top_ks)
-            q = _sample_dist(logits, temps, top_ks)
+            ids = _sample(logits, sub, temps, top_ks, active)
+            q = _sample_dist(logits, temps, top_ks, active)
             return (ids, pool, key), (ids, q)
 
         (_, pool, key), (drafted, qdist) = jax.lax.scan(
@@ -1257,7 +1266,7 @@ class PagedJaxLLMEngine:
             tp_plan=self._tp_verify_plan)
         # per-position target distributions under each slot's sampling
         # params — exactly what non-speculative _sample would draw from
-        pdist = jax.vmap(lambda lg: _sample_dist(lg, temps, top_ks),
+        pdist = jax.vmap(lambda lg: _sample_dist(lg, temps, top_ks, active),
                          in_axes=1, out_axes=1)(logits)  # [B, k+1, V]
         d = drafted.T  # [B, k]
         # zero the draft distribution for non-spec slots: acceptance is
@@ -2189,6 +2198,14 @@ class PagedJaxLLMEngine:
         c = self._c
         c["decode_dispatches"] += 1
         c["decode_token_steps"] += steps
+        # how often the sampler's two conditionals engage (engine.py
+        # _sampler_gates), by the host's view of the rows it dispatched
+        draws = [g for g in (self._slot_req[s].gen for s in active)
+                 if g.temperature > 0]
+        if draws:
+            c["decode_sampled_token_steps"] += steps
+            if any(g.top_k > 0 for g in draws):
+                c["decode_topk_token_steps"] += steps
         c["decode_table_pages"] += self.max_batch * w
         c["decode_live_pages"] += pages
         c["decode_rows"] += self.max_batch * steps
@@ -2244,7 +2261,7 @@ class PagedJaxLLMEngine:
             self._draft_propose(
                 self._draft_params, self._d_next, self._draft_pool,
                 self._put(dtable), self._d_lengths, self._d_key,
-                self._d_temp, self._d_topk)
+                self._d_temp, self._d_topk, self._d_active)
         (em_dev, acc_dev, self._d_next, self.pool, self._d_lengths,
          self._d_active, self._d_remaining, self._d_key) = \
             self._spec_verify(
@@ -2618,7 +2635,7 @@ class PagedJaxLLMEngine:
                     np.asarray(out[0])
                     pout = self._draft_propose(
                         self._draft_params, zi(b), self._draft_pool,
-                        zi(b, w), zi(b), key, zf(b), zi(b))
+                        zi(b, w), zi(b), key, zf(b), zi(b), zi(b))
                     self._draft_pool = pout[2]
                     np.asarray(pout[0])
                     # fully-degraded fallback: chunked decode at k+1

@@ -294,10 +294,12 @@ def test_join_program_compiles_at_cell_shapes(one_chip):
 # the kernel has tests of its own).  The seam moves models/llama.py behind a
 # table of functions
 # and must change nothing the device runs.  A PR that changes what
-# models/llama.py computes replaces these (print the digests below).
+# models/llama.py computes replaces these (print the digests below); PR 48
+# did, for the sampler's conditional (llm/engine.py), which both
+# programs end in.
 _LLAMA_HLO_BEFORE_THE_SEAM = {
-    "decode": "8c1a995b1c164c2bbee173418c2c250903ee79e25c3e8dc8dd072601e7965155",
-    "prefill": "275cc165814222833ab09c0d4e8204abf45080899e425dc7519458cf6132e3db",
+    "decode": "de9acd447f92dd14111112d90d20458dd3532760599dd9f1fbbf72232655bc7d",
+    "prefill": "8960792292dc1c1357a282ec0640bfeaa015d77463266408165d83732c91d2f1",
 }
 
 
@@ -312,6 +314,61 @@ def test_llama_programs_lower_to_the_hlo_from_before_the_family_seam(one_chip):
         if "tpu_custom_call" not in line).encode()).hexdigest()
         for name, lo in (("decode", decode), ("prefill", prefill))}
     assert got == _LLAMA_HLO_BEFORE_THE_SEAM
+
+
+def _outside_conditionals(text):
+    """The instructions of a compiled program's text that run whenever the
+    program does: those of the computations reached from ``ENTRY`` by every
+    edge but a conditional's branches."""
+    import re
+
+    bodies, name, entry = {}, None, None
+    for line in text.split("\n"):
+        m = re.match(r"(ENTRY )?%([\w.\-]+) \(.*\{$", line)
+        if m:
+            name = m.group(2)
+            bodies[name] = []
+            if m.group(1):
+                entry = name
+        elif line.startswith("}"):
+            name = None
+        elif name is not None:
+            bodies[name].append(line)
+    seen, todo = set(), [entry]
+    while todo:
+        name = todo.pop()
+        if name in seen:
+            continue
+        seen.add(name)
+        for line in bodies[name]:
+            line = re.sub(r"(branch_computations=\{[^}]*\}"
+                          r"|(true|false)_computation=%[\w.\-]+)", "", line)
+            for m in re.finditer(
+                    r"(?:calls|to_apply|body|condition)=%([\w.\-]+)"
+                    r"|called_computations=\{([^}]*)\}", line):
+                todo += [m.group(1)] if m.group(1) else \
+                    [c.strip().lstrip("%") for c in m.group(2).split(",")]
+    return [line for name in seen for line in bodies[name]]
+
+
+def test_decode_program_keeps_the_samplers_top_k_inside_a_conditional(
+        one_chip):
+    """The Mistral cell's decode program as the chip's compiler leaves it:
+    the top-64 over ``[64, 32768]`` is in the program, and only in a
+    conditional's branch (``engine._sampler_gates``: a token-step whose
+    rows are all greedy runs none of it), and so is the draw's generator."""
+    from ray_tpu.llm import LLMConfig
+
+    cfg, params, pool = _cell_model(one_chip, one_chip)
+    text = _engine_programs(cfg, params, pool, one_chip, 64, 32,
+                            LLMConfig().decode_chunk, 256,
+                            264)[0].compile().as_text()
+    always = "\n".join(_outside_conditionals(text))
+    assert " while(" in always and "tpu_custom_call" in always
+    for mark in ('custom_call_target="TopK"', "/top_k", "jit(_gumbel)"):
+        assert mark not in always, f"{mark} runs in every token-step"
+        assert mark in text, f"{mark} is not in the program at all"
+    assert " conditional(" in always
 
 
 # -- the latent-attention expert family at its cell's shapes ---------------------
