@@ -19,8 +19,16 @@ expert) pairs sorted by expert, ``ops/moe_grouped_ffn.py``), at 64, 128, 256,
    the gated up kernel, the down kernel, the rows added to their tokens as
    the program does it and as XLA's scatter-add), and with ``--tiles`` the
    two kernels at other tiles.
+4. ``--decode``: a decode token-step's layer-call instead, at both expert
+   families' widths (pangu's above; ``kimi-linear-ep16.reason_steady``'s:
+   hidden 2304, 16 of 256 experts of width 1024): 64 rows of which 4, 8,
+   16, 32 or 64 decode, the dense form over all 64 (what the decode program
+   ran until PR 49) against ``moe_ffn(..., live=...)``, the grouped product
+   over the live rows' pairs, with the held experts those rows hit beside
+   it: what a hit share costs.  And the grouped form at other row tiles
+   (the program's is ``pangu_moe._row_tile``).
 
-    python benchmarks/moe_prefill_bench.py [--tiles]
+    python benchmarks/moe_prefill_bench.py [--tiles | --decode]
 
 Needs a TPU: a time from the Pallas interpreter says nothing.  ``--rehearse``
 walks the same control flow on the CPU (interpret mode, toy sizes, no time
@@ -30,6 +38,7 @@ printed) and exits 3.
 from __future__ import annotations
 
 import argparse
+import dataclasses
 import json
 import os
 import sys
@@ -85,7 +94,7 @@ def _layer_chain(cfg, rows_from: int, interpret: bool):
               if k not in pm.HELD_EXPERT_LEAVES}
         for i in range(CALLS):
             li = i % LAYERS
-            y, _ = pm.moe_ffn(
+            y, _, _ = pm.moe_ffn(
                 cfg, h, {**{k: v[li] for k, v in lp.items()},
                          **{k: stack[k] for k in pm.HELD_EXPERT_LEAVES}},
                 interpret, layer=li)
@@ -93,6 +102,76 @@ def _layer_chain(cfg, rows_from: int, interpret: bool):
         return h
 
     return jax.jit(chain)
+
+
+def decode_rows(cfg, stack, interpret: bool, rows: int = 64,
+                tiles=(16, 32, 64, 128)):
+    """One line a count of live rows: ms a layer-call of a decode
+    token-step's expert layer, dense over all ``rows`` against grouped over
+    the live rows' pairs (at the program's row tile, then at ``tiles``),
+    the held experts hit and the layer-calls that took the grouped product
+    (of ``CALLS``) beside them, and the largest gap between the two forms'
+    live rows."""
+    import jax
+    import jax.numpy as jnp
+
+    from ray_tpu.models import pangu_moe as pm
+
+    def chain(form, tile=None):
+        def run(h, live, stack):
+            pm.GROUPED_MIN_ROWS = 1 << 30  # a chunk's rule never answers
+            if tile:
+                pm._row_tile = lambda t: tile  # read while this traces
+            lp = {k: v for k, v in stack.items()
+                  if k not in pm.HELD_EXPERT_LEAVES}
+            hit = took = 0
+            for i in range(CALLS):
+                li = i % LAYERS
+                y, g, grouped = pm.moe_ffn(
+                    cfg, h, {**{k: v[li] for k, v in lp.items()},
+                             **{k: stack[k] for k in pm.HELD_EXPERT_LEAVES}},
+                    interpret, layer=li,
+                    live=live if form == "grouped" else None)
+                hit += ((g > 0) & (live[:, None] > 0)).any(0).sum()
+                took += grouped
+                h = h + (y * 1e-3).astype(h.dtype)
+            return h, y, hit, took
+
+        return jax.jit(run)
+
+    threshold, row_tile = pm.GROUPED_MIN_ROWS, pm._row_tile
+    h = jax.random.normal(jax.random.PRNGKey(rows), (rows, cfg.dim),
+                          cfg.compute_dtype)
+    out = []
+    for n in (4, 8, 16, 32, 64):
+        # the live rows scattered over the slots, as a serving batch's are
+        live = jnp.zeros((rows,), jnp.int32).at[
+            jax.random.permutation(jax.random.PRNGKey(n), rows)[:n]].set(1)
+        row = {"rows": rows, "live": n}
+        last = {}
+        for name, form, tile in [("dense", "dense", None),
+                                 ("grouped", "grouped", None)] + [
+                (f"grouped_tile_{t}", "grouped", t) for t in tiles]:
+            fn = chain(form, tile)
+            _, y, hit, took = jax.block_until_ready(fn(h, live, stack))
+            pm._row_tile = row_tile
+            last[name] = y
+            if name == "grouped":
+                row["experts_hit_of_%d" % cfg.n_held] = round(
+                    float(hit) / CALLS, 2)
+                row["grouped_calls_of_%d" % CALLS] = int(took)
+            if not interpret:
+                row[f"{name}_ms"] = round(
+                    _time(fn, h, live, stack) / CALLS * 1e3, 4)
+        pm.GROUPED_MIN_ROWS = threshold
+        alive = live > 0
+        row["gap_live_rows"] = float(
+            jnp.abs(jnp.where(alive[:, None],
+                              last["grouped"] - last["dense"], 0)).max()
+            / jnp.abs(last["dense"]).max())
+        print(json.dumps(row), flush=True)
+        out.append(row)
+    return out
 
 
 def check(cfg, stack, t: int, interpret: bool):
@@ -114,7 +193,7 @@ def check(cfg, stack, t: int, interpret: bool):
              "eight_held": z.at[:, :8].set(0.31), "none": z}
     dense = jax.jit(lambda h, g, lp: pm._routed_dense(cfg, h, g, lp, 1))
     grouped = jax.jit(
-        lambda h, g, lp: pm._routed_grouped(cfg, h, g, lp, 1, interpret))
+        lambda h, g, lp: pm._routed_grouped(cfg, h, g, lp, 1, interpret)[0])
     out = {}
     for name, g in cases.items():
         want, got = dense(h, g, lp), grouped(h, g, lp)
@@ -134,7 +213,7 @@ def parts(cfg, stack, t: int, interpret: bool, tiles=None):
     from ray_tpu.models import pangu_moe as pm
     from ray_tpu.ops.moe_grouped_ffn import group_visits, grouped_matmul
 
-    tm = pm.GROUPED_ROW_TILE
+    tm = pm._row_tile(t)
     m = -(-t // tm) * tm
     h = jax.random.normal(jax.random.PRNGKey(t), (t, cfg.dim),
                           cfg.compute_dtype)
@@ -220,6 +299,8 @@ def main() -> int:
     ap.add_argument("--rehearse", action="store_true")
     ap.add_argument("--tiles", action="store_true",
                     help="time the two kernels at other tiles too")
+    ap.add_argument("--decode", action="store_true",
+                    help="a decode token-step's layer-call by its live rows")
     args = ap.parse_args()
     if args.rehearse:
         os.environ.setdefault("JAX_PLATFORMS", "cpu")
@@ -243,6 +324,28 @@ def main() -> int:
     else:
         cfg = pm.PanguMoEConfig()
         widths = (64, 128, 256, 384, 512, 1024)
+    if args.decode:
+        # the second family's widths (models/kimi_linear.py), under the same
+        # expert layer
+        kimi = (dataclasses.replace(cfg, dim=256) if args.rehearse else
+                dataclasses.replace(cfg, dim=2304, moe_ffn_dim=1024,
+                                    routed_scaling_factor=2.446))
+        for name, c in (("pangu", cfg), ("kimi", kimi)):
+            print(json.dumps({"device": {"platform": dev.platform,
+                                         "kind": dev.device_kind},
+                              "widths": name, "dim": c.dim,
+                              "held": c.n_held,
+                              "expert_width": c.moe_ffn_dim,
+                              "weights_read_ms": round(
+                                  3 * c.n_held * c.dim * c.moe_ffn_dim * 2
+                                  / PEAK_BYTES * 1e3, 3)}), flush=True)
+            stack = _weights(c)
+            decode_rows(c, stack, args.rehearse)
+            del stack
+        if args.rehearse:
+            print("rehearsal only: no time was measured", file=sys.stderr)
+            return 3
+        return 0
     stack = _weights(cfg)
     print(json.dumps({"device": {"platform": dev.platform,
                                  "kind": dev.device_kind},

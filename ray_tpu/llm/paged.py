@@ -994,7 +994,8 @@ class PagedJaxLLMEngine:
         ``warmup()`` returned: anything above zero ran inside serving);
         and the family's ``decode_counters``, booked by the decode program
         itself a token-step (the expert family: ``moe_experts_held``,
-        ``moe_experts_hit``, ``moe_pairs_here``, see models/pangu_moe.py).
+        ``moe_experts_hit``, ``moe_pairs_here``, ``moe_grouped_calls``, see
+        models/pangu_moe.py).
         """
         out = dict(self._c)
         out["drains"] = dict(self._drains)

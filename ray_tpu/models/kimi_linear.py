@@ -41,7 +41,9 @@ same in float32, position by position).  ``N``: RMSNorm, eps
   ``n_routed_experts``, the ``n_experts_per_tok`` largest (one group), gates
   ``routed_scaling_factor * s_e / sum_chosen s``; ``y = Shared(h) + sum_{e
   chosen and HELD} gate_e Expert_e(h)``: what absent experts would add is
-  left out, in the reference alike, and the partial result goes on.
+  left out, in the reference alike, and the partial result goes on.  A
+  decode token-step hands it ``active``: the held experts are multiplied
+  for the decoding rows' pairs alone (``moe_ffn``'s ``live``).
 
 **Two kinds of state** (models/family.py).  The pool pages the MLA layers'
 latent rows.  A KDA layer's state does not page: the engine keeps it a SLOT
@@ -453,8 +455,9 @@ def _mla_latent(cfg, h, lp):
 
 def _ffn(cfg, params, x, li, dense: bool, live=None, interpret=False):
     """``x + FFN(N(x))`` of ``x [T, d]`` for layer ``li`` (its own number,
-    from 0); for an expert layer also the decode counters of rows ``live
-    [T]`` (None: not booked)."""
+    from 0); for an expert layer of a decode token-step (``live [T]``: the
+    rows that decode, which alone reach the held experts; None: a prompt
+    chunk) also its decode counters."""
     with jax.named_scope("ffn"):
         h = rms_norm(x, lax.dynamic_index_in_dim(
             params["norms"]["ffn"], li, 0, keepdims=False), cfg.rms_norm_eps)
@@ -468,8 +471,10 @@ def _ffn(cfg, params, x, li, dense: bool, live=None, interpret=False):
                   else lax.dynamic_index_in_dim(v, mi, 0, keepdims=False))
               for k, v in mp.items()}
         with jax.named_scope("moe"):
-            y, g = pm.moe_ffn(cfg, h, lp, interpret, layer=mi)
-        booked = None if live is None else pm.decode_booking(cfg, g, live)
+            y, g, grouped = pm.moe_ffn(cfg, h, lp, interpret, layer=mi,
+                                       live=live)
+        booked = (None if live is None
+                  else pm.decode_booking(cfg, g, live, grouped))
         return x + y.astype(x.dtype), booked
 
 
@@ -708,7 +713,7 @@ def decode_step_paged(cfg: KimiLinearConfig, params: Params,
     bit, whatever its token is.  ``use_kernel``: ``kda_state_update`` for the
     decoding rows and the latent decode kernel over their live pages; else
     ``jax.numpy`` over every row.  Returns ``(logits [B, V] float32, pool,
-    slot_state, counters int32 [3]: DECODE_COUNTERS)``."""
+    slot_state, counters int32: DECODE_COUNTERS)``."""
     del rope_cache, mesh, tp_plan
     b = tokens.shape[0]
     bs = pool["ckv"].shape[2]

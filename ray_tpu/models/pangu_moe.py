@@ -34,18 +34,28 @@ this chip computes the shared expert and its own experts' part of the sum, and
 what absent experts would add is left out (in the reference alike): that
 partial result goes on to the next layer.  Nothing here stands in for the
 other chips or their exchange.  The held experts' part has two forms of one
-sum, and ``moe_ffn`` chooses by its static row count.  Below
-``GROUPED_MIN_ROWS`` (decode's batch, a narrow prompt chunk): ONE gated
-feed-forward of width ``held x moe_ffn_dim`` whose hidden units are scaled by
-their expert's gate (zero where the token did not choose it): exact at any
-number of rows an expert, at the price of reading every held expert's weights
-whether or not a token chose it (``moe_experts_hit`` over
-``moe_experts_held`` says how many were wanted) and of every token times
-every held expert, which is free while the weights' read bounds the product.
-From ``GROUPED_MIN_ROWS`` rows on that product costs more than the read, and
-the chosen (token, expert) pairs are sorted by expert and multiplied a group
-at a time against their expert's block of the same weights
-(``ops/moe_grouped_ffn.py``).  ``vocab_slice`` is the
+sum.  DENSE: one gated feed-forward of width ``held x moe_ffn_dim`` whose
+hidden units are scaled by their expert's gate (zero where the token did not
+choose it): exact at any number of rows an expert, at the price of reading
+every held expert's weights whether or not a token chose it and of every
+token times every held expert.  GROUPED: the chosen (token, expert) pairs
+sorted by expert and multiplied a group at a time against their expert's
+block of the same weights (``ops/moe_grouped_ffn.py``), whose grid visits the
+experts that have rows and fetches no other.  ``moe_ffn`` takes the grouped
+form wherever its kernel applies and there is something to win by it: a
+prompt chunk from ``GROUPED_MIN_ROWS`` rows on (below, a chunk hits every
+held expert and the weights' read bounds both forms), and EVERY decode
+token-step (``live`` given), whose rows hit a part of the held experts only
+(``moe_experts_hit`` over ``moe_experts_held``; ``moe_grouped_calls`` counts
+the layer-calls that went so).  **In decode the live mask comes first**: the
+batch's slots that do not decode still hold a stale token that routes
+somewhere (64 slots hit 87% of 16 held experts, the 6 to 25 that decode a
+fifth to two fifths), so their held gates are zeroed BEFORE the pairs are
+sorted and no expert is read for them.  A dead row's routed part is then
+zero; nothing reads a dead row's hidden state (the engine's
+``_decode_chunk_impl`` emits -1 and keeps the old token where ``active`` is
+0, a dead row's cache write lands where no live row reads, and a slot state
+is kept bit for bit).  ``vocab_slice`` is the
 range of the published vocabulary's rows held: a sliced vocabulary is a
 smaller vocabulary, ids and logits are over the slice.
 
@@ -71,17 +81,21 @@ Params = Dict[str, Any]
 
 # engine counters the decode program books a token-step (family seam's
 # ``decode_counters``), summed over the expert layers: held experts (where any
-# row decodes), held experts that at least one decoding row chose, and
-# (row, held expert) pairs
-DECODE_COUNTERS = ("moe_experts_held", "moe_experts_hit", "moe_pairs_here")
+# row decodes), held experts that at least one decoding row chose, (row, held
+# expert) pairs, and the layer-calls (with a decoding row) whose held experts
+# ran as the grouped product: ``moe_experts_held / n_held`` where none took
+# the dense one for want of room in the pairs' buffer
+DECODE_COUNTERS = ("moe_experts_held", "moe_experts_hit", "moe_pairs_here",
+                   "moe_grouped_calls")
 
 # KV positions one step of a prefill chunk's attention attends (a grid step of
 # the kernel, an iteration of the ``jax.numpy`` loop)
 PREFILL_KV_TILE = 1024
 
-# rows from which on an expert layer's routed part runs as a grouped product
-# over the chosen pairs (``moe_ffn``), and the row tile of its kernel.  ms a
-# layer-call on a v5e, dense / grouped (benchmarks/moe_prefill_bench.py;
+# rows from which on a PROMPT CHUNK's expert layers run their routed part as a
+# grouped product over the chosen pairs (``moe_ffn``; a decode token-step
+# does at any width), and the row tile of its kernel.  ms a layer-call on a
+# v5e, dense / grouped (benchmarks/moe_prefill_bench.py;
 # PERF.md section 6, PR 40): 128 rows 2.32 / 2.33, 256 rows 2.51 / 2.42, 384
 # rows 3.43 / 2.52, 512 rows 4.46 / 2.60, 1,024 rows 8.86 / 3.23
 GROUPED_MIN_ROWS = 256
@@ -473,17 +487,27 @@ def held_gates(cfg: PanguMoEConfig, gates, idx):
 
 def grouped_ffn_from(cfg: PanguMoEConfig,
                      interpret: bool = False) -> Optional[int]:
-    """The row count from which on ``moe_ffn`` computes the held experts'
-    part as a grouped product over the chosen (token, expert) pairs; None:
-    never (no expert layer, or no kernel for this backend and these widths:
-    on a TPU an expert's block must be whole lane tiles; the interpreter
-    takes any width)."""
+    """The row count from which on ``moe_ffn`` computes a prompt chunk's held
+    experts' part as a grouped product over the chosen (token, expert) pairs;
+    None: never, in no program (no expert layer, or no kernel for this
+    backend and these widths: on a TPU an expert's block must be whole lane
+    tiles; the interpreter takes any width)."""
     if not cfg.n_moe_layers:
         return None
     if not interpret and (jax.default_backend() != "tpu"
                           or cfg.dim % 128 or cfg.moe_ffn_dim % 128):
         return None
     return GROUPED_MIN_ROWS
+
+
+def takes_grouped(cfg: PanguMoEConfig, rows: int, interpret: bool,
+                  decode: bool) -> bool:
+    """Whether ``rows`` rows' held experts run as the grouped product: where
+    its kernel applies, a decode token-step always (its rows choose a part
+    of the held experts, and the kernel reads no other), a prompt chunk from
+    ``grouped_ffn_from`` rows on."""
+    first = grouped_ffn_from(cfg, interpret)
+    return first is not None and (decode or rows >= first)
 
 
 def _routed_dense(cfg: PanguMoEConfig, h, g, lp, layer=None):
@@ -544,18 +568,27 @@ def _add_to_tokens(rows, tok, live, t: int):
     return y
 
 
+def _row_tile(t: int) -> int:
+    """The grouped kernel's row tile for ``t`` rows: ``GROUPED_ROW_TILE``,
+    or the rows themselves (in whole 16-row tiles of bfloat16) where they
+    are fewer: decode's 64 rows are one tile."""
+    return min(GROUPED_ROW_TILE, -(-t // 16) * 16)
+
+
 def _routed_grouped(cfg: PanguMoEConfig, h, g, lp, layer, interpret: bool):
     """``_routed_dense``'s sum over the pairs the router chose alone: the
     pairs sorted by expert, their tokens' rows gathered, the three products
     a group at a time against that expert's block of the weights as they lie
     (``ops/moe_grouped_ffn.py``), each pair's row added to its token in
-    float32 (``_add_to_tokens``).  The buffers hold ``T`` pairs, twice what
-    uniform routing sends to a sixteenth of the experts; a chunk with more
-    takes the dense product: no pair is ever dropped."""
+    float32 (``_add_to_tokens``).  The buffers hold ``T`` pairs (in whole row
+    tiles), twice what uniform routing sends to a sixteenth of the experts;
+    rows with more take the dense product: no pair is ever dropped.  Returns
+    ``([T, d] float32, whether the grouped product answered)``."""
     from ray_tpu.ops.moe_grouped_ffn import moe_grouped_ffn
 
     t, d = h.shape
-    m = -(-t // GROUPED_ROW_TILE) * GROUPED_ROW_TILE
+    tm = _row_tile(t)
+    m = -(-t // tm) * tm
     tok, gates, sizes, pairs = sort_pairs(g, m)
 
     if layer is None:
@@ -566,29 +599,40 @@ def _routed_grouped(cfg: PanguMoEConfig, h, g, lp, layer, interpret: bool):
         del g
         out = moe_grouped_ffn(
             jnp.take(h, tok, axis=0), *(lp[k] for k in HELD_EXPERT_LEAVES),
-            layer, sizes, gates, tm=GROUPED_ROW_TILE, interpret=interpret)
+            layer, sizes, gates, tm=tm, interpret=interpret)
         return _add_to_tokens(out, tok, jnp.arange(m) < pairs, t)
 
-    return lax.cond(pairs <= m, grouped,
-                    lambda h, g: _routed_dense(cfg, h, g, lp, layer), h, g)
+    fits = pairs <= m
+    return lax.cond(fits, grouped,
+                    lambda h, g: _routed_dense(cfg, h, g, lp, layer),
+                    h, g), fits
 
 
-def moe_ffn(cfg: PanguMoEConfig, h, lp, interpret: bool = False, layer=None):
+def moe_ffn(cfg: PanguMoEConfig, h, lp, interpret: bool = False, layer=None,
+            live=None):
     """The expert layer's feed-forward of ``h [T, d]``: the shared expert
     plus this chip's experts' part of the routed sum.  Returns ``(y [T, d]
-    float32, g [T, n_held])``.  ``layer``: ``lp``'s ``HELD_EXPERT_LEAVES``
-    are whole STACKS of layers and this scalar picks the one meant (a kernel
-    cannot read a layer sliced out of its stack without a copy of it); None:
-    they are one layer's, as every other leaf is.
+    float32, g [T, n_held], grouped)``: ``grouped`` a boolean scalar, whether
+    the held experts' part ran as the grouped product.  ``layer``: ``lp``'s
+    ``HELD_EXPERT_LEAVES`` are whole STACKS of layers and this scalar picks
+    the one meant (a kernel cannot read a layer sliced out of its stack
+    without a copy of it); None: they are one layer's, as every other leaf
+    is.  ``live [T]``: the rows are a decode token-step's and those with 0
+    do not decode: their held gates are zeroed (in ``g`` too) before
+    anything is made of them, so their routed part is zero and no expert is
+    read on their account.
 
-    The routed part has two forms of one sum, chosen by the static ``T``:
-    from ``grouped_ffn_from`` rows on, where every-token-times-every-held-
-    expert costs more than reading the experts' weights, a grouped product
-    over the chosen pairs (``interpret``: its kernel in the interpreter);
-    below, and wherever that kernel does not apply, the dense product."""
+    The routed part has two forms of one sum (``takes_grouped``): a decode
+    token-step's rows, and a prompt chunk's from ``grouped_ffn_from`` rows on
+    (where every-token-times-every-held-expert costs more than reading the
+    experts' weights), take a grouped product over the chosen pairs
+    (``interpret``: its kernel in the interpreter); a narrower chunk, and
+    everything where that kernel does not apply, the dense product."""
     cdt = cfg.compute_dtype
     gates, idx = route(cfg, h, lp["router"])
     g = held_gates(cfg, gates, idx)
+    if live is not None:
+        g = jnp.where(live[:, None] > 0, g, 0.0)
     # the two down-projections leave the matrix unit in float32 and are
     # summed there: the sum of up to nine experts' terms is rounded once, by
     # the norm that follows
@@ -596,12 +640,12 @@ def moe_ffn(cfg: PanguMoEConfig, h, lp, interpret: bool = False, layer=None):
                      * (h @ lp["ws_up"].astype(cdt)),
                      lp["ws_down"].astype(cdt),
                      preferred_element_type=jnp.float32)
-    first = grouped_ffn_from(cfg, interpret)
-    if first is not None and h.shape[0] >= first:
-        routed = _routed_grouped(cfg, h, g, lp, layer, interpret)
+    if takes_grouped(cfg, h.shape[0], interpret, live is not None):
+        routed, grouped = _routed_grouped(cfg, h, g, lp, layer, interpret)
     else:
         routed = _routed_dense(cfg, h, g, lp, layer)
-    return shared + routed, g
+        grouped = jnp.zeros((), bool)
+    return shared + routed, g, grouped
 
 
 def _dense_ffn(cfg: PanguMoEConfig, h, lp):
@@ -610,30 +654,33 @@ def _dense_ffn(cfg: PanguMoEConfig, h, lp):
             * (h @ lp["w_up"].astype(cdt))) @ lp["w_down"].astype(cdt)
 
 
-def decode_booking(cfg: PanguMoEConfig, g, live):
+def decode_booking(cfg: PanguMoEConfig, g, live, grouped):
     """``DECODE_COUNTERS`` of one expert layer's token-step: ``g [T,
-    n_held]`` the held experts' gates (``moe_ffn``'s), ``live [T]`` the rows
-    that decode."""
+    n_held]`` the held experts' gates and ``grouped`` the form they took
+    (``moe_ffn``'s), ``live [T]`` the rows that decode."""
     chose = (g > 0) & (live[:, None] > 0)
+    any_live = live.max() > 0
     return jnp.stack([
-        cfg.n_held * (live.max() > 0).astype(jnp.int32),
+        cfg.n_held * any_live.astype(jnp.int32),
         chose.any(axis=0).sum().astype(jnp.int32),
-        chose.sum().astype(jnp.int32)])
+        chose.sum().astype(jnp.int32),
+        (grouped & any_live).astype(jnp.int32)])
 
 
 def _ffn_sublayer(cfg: PanguMoEConfig, x, lp, is_moe: bool, live=None,
                   interpret: bool = False, layer=None):
     """``x + N_post(FFN(N_pre(x)))`` of ``x [B, T, d]``; for an expert layer
-    also the decode counters of rows ``live [B * T]`` (None: not booked).
-    ``interpret``, ``layer``: ``moe_ffn``'s."""
+    of a decode token-step (``live [B * T]``: the rows that decode; None: a
+    prompt chunk) also its decode counters.  ``interpret``, ``layer``:
+    ``moe_ffn``'s."""
     b, t, d = x.shape
     h = rms_norm(x, lp["mlp_norm"], cfg.rms_norm_eps).reshape(b * t, d)
     booked = None
     if is_moe:
         with jax.named_scope("moe"):
-            y, g = moe_ffn(cfg, h, lp, interpret, layer)
+            y, g, grouped = moe_ffn(cfg, h, lp, interpret, layer, live)
         if live is not None:
-            booked = decode_booking(cfg, g, live)
+            booked = decode_booking(cfg, g, live, grouped)
     else:
         y = _dense_ffn(cfg, h, lp)
     y = rms_norm(y.reshape(b, t, d), lp["post_mlp_norm"], cfg.rms_norm_eps)
@@ -648,13 +695,13 @@ def _head(cfg: PanguMoEConfig, params, x):
 
 
 def _held_whole(cfg: PanguMoEConfig, stack, is_moe: bool, rows: int,
-                interpret: bool):
+                interpret: bool, decode: bool = False):
     """``(the stack's leaves a layer scan slices, the held experts' leaves it
-    must leave whole)``: where ``rows`` rows take the grouped form, its
-    kernel reads a layer's experts out of their stack in place (``moe_ffn``'s
-    ``layer``); else everything is scanned and the second is empty."""
-    first = grouped_ffn_from(cfg, interpret)
-    if not is_moe or first is None or rows < first:
+    must leave whole)``: where ``rows`` rows (``decode``: of a token-step)
+    take the grouped form, its kernel reads a layer's experts out of their
+    stack in place (``moe_ffn``'s ``layer``); else everything is scanned and
+    the second is empty."""
+    if not is_moe or not takes_grouped(cfg, rows, interpret, decode):
         return stack, {}
     return ({k: v for k, v in stack.items() if k not in HELD_EXPERT_LEAVES},
             {k: stack[k] for k in HELD_EXPERT_LEAVES})
@@ -755,8 +802,12 @@ def decode_step_paged(cfg: PanguMoEConfig, params: Params,
     form.  The contract of ``llama.decode_step_paged``; ``use_kernel``: the
     Pallas kernel over the latent pool (the live pages of the decoding rows
     only), else a gather of the table's span.  Returns (logits [B, V]
-    float32, pool, counters int32 [3]: ``DECODE_COUNTERS`` of this
-    token-step over the rows with ``active`` != 0 (None: all)).
+    float32, pool, counters int32: ``DECODE_COUNTERS`` of this
+    token-step over the rows with ``active`` != 0 (None: all)).  The expert
+    layers compute the held experts' part for those rows alone
+    (``moe_ffn``'s ``live``): a row with ``active`` 0 comes out with its
+    routed part zero, and its logits mean nothing (they never did: its token
+    is stale).
     """
     del mesh, tp_plan  # the family supplies no tensor-parallel layout
     cos, sin = (rope_cache if rope_cache is not None
@@ -776,7 +827,8 @@ def decode_step_paged(cfg: PanguMoEConfig, params: Params,
     booked = jnp.zeros((len(DECODE_COUNTERS),), jnp.int32)
 
     for stack, first, is_moe in _stacks(cfg, params):
-        stack, whole = _held_whole(cfg, stack, is_moe, b, kernel_interpret)
+        stack, whole = _held_whole(cfg, stack, is_moe, b, kernel_interpret,
+                                   decode=True)
 
         def body(carry, inp, is_moe=is_moe, whole=whole, first=first):
             x, ckv, booked = carry

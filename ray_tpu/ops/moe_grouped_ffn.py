@@ -4,14 +4,22 @@ a layer.
 
 ``models/pangu_moe.py`` keeps its held experts side by side: expert ``j`` is
 columns ``[j f, (j + 1) f)`` of ``we_gate`` / ``we_up`` ``[layers, d, e f]``
-and rows ``[j f, (j + 1) f)`` of ``we_down`` ``[layers, e f, d]`` (the decode
-program multiplies by a layer of them as one feed-forward of width ``e f``;
+and rows ``[j f, (j + 1) f)`` of ``we_down`` ``[layers, e f, d]`` (the dense
+form multiplies by a layer of them as one feed-forward of width ``e f``;
 a ``[d, e, f]`` view costs a copy of every expert a layer-call, and so does a
 layer sliced out of its stack ahead of a kernel).  A prompt chunk of a few
 hundred tokens chooses a few rows an expert, so every-row-times-every-expert
 does ``e`` times the chosen products.  Here the (row, expert) pairs the
 router chose lie sorted by expert, ``group_sizes[j]`` rows for expert ``j``,
-and a row meets its own expert's block alone.
+and a row meets its own expert's block alone.  A decode token-step runs it
+too, for the other reason: its few live rows choose a PART of the held
+experts, the grid visits the groups that have rows, and an expert no live
+row chose is never fetched, so the step reads the hit share of the weights
+where the dense form reads them all.  That only holds because the caller
+zeroes the gates of the batch's slots that do not decode BEFORE it sorts
+the pairs (``pangu_moe.moe_ffn``'s ``live``): a dead slot's stale token
+routes somewhere too, and 64 slots between them choose nearly every held
+expert.
 
 The installed ``megablox.gmm`` wants ``[groups, k, n]``; its group metadata,
 dynamic count of grid steps and mask at a group's edge are followed here,

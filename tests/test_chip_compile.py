@@ -416,17 +416,20 @@ def test_latent_prefill_kernel_compiles_at_cell_shapes(one_chip, c):
         _spec((), jnp.int32, one_chip))
 
 
-@pytest.mark.parametrize("rows", [256, 512, 1024])
+@pytest.mark.parametrize("rows", [64, 256, 512, 1024])
 def test_grouped_expert_kernel_compiles_at_cell_shapes(one_chip, rows):
     """The grouped product of a chunk's (token, expert) pairs, ``rows`` of
-    them at most, against the 16 held experts as they lie in the four expert
+    them at most (64: a decode token-step's, one row tile), against the 16
+    held experts as they lie in the four expert
     layers' stacks: gate and up ``[4, 7680, 16 x 2048]`` (k 7680, n 2048, an
     expert its columns), down ``[4, 16 x 2048, 7680]`` (k 2048, n 7680, an
     expert its rows)."""
+    from ray_tpu.models.pangu_moe import _row_tile
     from ray_tpu.ops.moe_grouped_ffn import moe_grouped_ffn
 
     names = _kernel_instructions(
-        moe_grouped_ffn, _spec((rows, 7680), BF16, one_chip),
+        functools.partial(moe_grouped_ffn, tm=_row_tile(rows)),
+        _spec((rows, 7680), BF16, one_chip),
         _spec((4, 7680, 16 * 2048), BF16, one_chip),
         _spec((4, 7680, 16 * 2048), BF16, one_chip),
         _spec((4, 16 * 2048, 7680), BF16, one_chip),
@@ -440,15 +443,20 @@ def test_latent_family_programs_compile_at_cell_shapes(one_chip, monkeypatch):
     """Decode over the 9,216-position table with the kernel in it, named; a
     1,024-token prefill chunk with its attention kernel in it, named, and no
     score tile among its temporaries.  The expert layers' grouped product
-    (named) is in the prefill chunk and not in decode's 64 rows: the model
-    asks the backend, which is the CPU here, so the test answers for it."""
+    (named) is in both (the model asks the backend, which is the CPU here,
+    so the test answers for it), and the decode program, which holds the
+    dense product too for a token-step whose pairs overflow the buffer,
+    keeps no copy of a layer's held experts (1.5 GB) for either."""
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
     cfg, params, pool = _pangu_cell(one_chip)
     decode, prefill = _engine_programs(cfg, params, pool, one_chip, 64, 1024,
                                        2, 1024, 641)
-    text = decode.compile().as_text()
-    assert "tpu_custom_call" in text and "mla_paged_attention" in text
-    assert "moe_grouped_ffn" not in text
+    compiled = decode.compile()
+    names = _custom_call_names(compiled.as_text())
+    assert "mla_paged_attention" in names
+    assert "moe_grouped_ffn_up" in names and "moe_grouped_ffn_down" in names
+    assert "conditional" in compiled.as_text()
+    assert compiled.memory_analysis().temp_size_in_bytes < 64 << 20
     compiled = prefill.compile()
     names = _custom_call_names(compiled.as_text())
     assert "mla_prefill_attention" in names
@@ -666,11 +674,12 @@ def test_latent_and_grouped_kernels_compile_at_the_kimi_widths(one_chip):
 
 def test_kimi_family_programs_compile_at_cell_shapes(one_chip, monkeypatch):
     """The engine's two programs with the slot state beside the latent pool
-    and the counters: both kernels in the decode program, by name, and no
-    grouped product in its 64 rows; a 256-token chunk with its attention
-    kernel and the experts' grouped product; neither program keeps a copy of
-    a stacked weight, of the 2.7 GB state or of the pool.  (The model asks
-    the backend, which is the CPU here, so the test answers for it.)"""
+    and the counters: both kernels in the decode program, by name, and the
+    experts' grouped product over its 64 rows' live pairs; a 256-token chunk
+    with its attention kernel and the experts' grouped product; neither
+    program keeps a copy of a stacked weight, of the 2.7 GB state or of the
+    pool.  (The model asks the backend, which is the CPU here, so the test
+    answers for it.)"""
     import types
 
     from ray_tpu.llm.engine import _MAX_STOP_IDS
@@ -698,7 +707,7 @@ def test_kimi_family_programs_compile_at_cell_shapes(one_chip, monkeypatch):
             i32(b), 2, state).compile()
     names = _custom_call_names(decode.as_text())
     assert "kda_state_update" in names and "mla_paged_attention" in names
-    assert "moe_grouped_ffn" not in names
+    assert "moe_grouped_ffn_up" in names and "moe_grouped_ffn_down" in names
     assert decode.memory_analysis().temp_size_in_bytes < 256 << 20
     prefill = jax.jit(
         functools.partial(PagedJaxLLMEngine._prefill_chunk_impl, eng),

@@ -171,7 +171,9 @@ def test_prompt_chunks_then_decode_match_the_reference(model, programs, plen):
             jnp.asarray([5, toks[plen + i], 9], jnp.int32), pool,
             jnp.asarray([3, plen + i, 8], jnp.int32), state)
         np.testing.assert_allclose(logits[1], want[plen + i], atol=TOL)
+        assert booked.shape == (len(kl.DECODE_COUNTERS),)
         assert int(booked[0]) == cfg.n_held * cfg.n_moe_layers
+        assert int(booked[3]) == 0  # no kernel here: the dense product
         assert 0 <= int(booked[1]) <= int(booked[2]) <= (
             cfg.n_experts_per_tok * cfg.n_moe_layers)
     # the rows that did not decode: bit for bit
@@ -266,7 +268,7 @@ def test_shares_of_the_expert_layer_add_up_to_the_whole(model):
         held = dict(lp, we_gate=lp["we_gate"][:, lo * f:(lo + 8) * f],
                     we_up=lp["we_up"][:, lo * f:(lo + 8) * f],
                     we_down=lp["we_down"][lo * f:(lo + 8) * f])
-        y, g = pm.moe_ffn(share, h, held)
+        y, g, _ = pm.moe_ffn(share, h, held)
         total = total + (y - shared)
         pairs += int((g > 0).sum())
     np.testing.assert_allclose(total + shared, want, atol=TOL)
@@ -450,7 +452,9 @@ def test_the_server_holds_a_slots_state_against_the_recurrence(model):
 def test_engine_with_both_kernels_interpreted_matches_the_jnp_path(model,
                                                                    alone):
     """``kda_state_update`` and the latent decode kernel in the interpreter,
-    a prompt chunk's attention through its kernel too."""
+    a prompt chunk's attention through its kernel too; and the expert
+    layers' grouped product in every token-step (one live row's pairs fit
+    its buffer), which the engine's counters say."""
     cfg, params = model
     prompt = _tokens(40, seed=81)
     eng = _engine(cfg, params, paged_attention_kernel="interpret",
@@ -458,6 +462,62 @@ def test_engine_with_both_kernels_interpreted_matches_the_jnp_path(model,
     assert eng._use_kernel and eng._kernel_interpret
     out = eng.generate([prompt], GenerationConfig(max_new_tokens=6))[0]
     assert out == alone(prompt, 6)
+    c = eng.counters()
+    assert c["moe_grouped_calls"] * cfg.n_held == c["moe_experts_held"] > 0
+
+
+@pytest.mark.parametrize("live", [[0, 1, 0, 1, 1, 0], [1] * 6])
+def test_decode_step_with_grouped_expert_layers_equals_dense(model, live):
+    """A token-step of six slots through the whole decode program, its
+    expert layers (after every layer but the first, under KDA and MLA mixers
+    alike) grouped and dense: the live rows' logits, state and cache rows
+    agree whatever the dead slots hold, the dead rows' state is kept bit
+    for bit, and ``moe_grouped_calls`` counts a layer-call exactly where the
+    live rows' pairs fit the buffer (16 for six rows; a row lands on one of
+    the 8 held experts of 32 on average: three live rows' 12 pairs at most
+    always fit, six rows' need not)."""
+    cfg, params = model
+    b = len(live)
+    active = jnp.asarray(live, jnp.int32)
+    alive = np.asarray(live) > 0
+    pool = {"ckv": jax.random.normal(
+        jax.random.PRNGKey(3), kl.init_paged_cache(cfg, 4 * b + 1, 16)[
+            "ckv"].shape) * 0.3}
+    state = jax.tree.map(
+        lambda x: jax.random.normal(jax.random.PRNGKey(4), x.shape,
+                                    jnp.float32).astype(x.dtype) * 0.1,
+        kl.init_slot_state(cfg, b))
+    table = jnp.arange(1, 4 * b + 1, dtype=jnp.int32).reshape(b, 4)
+    lengths = jnp.asarray([5, 17, 40, 33, 9, 60][:b], jnp.int32)
+
+    def run(dead, interpret):
+        toks = jnp.where(active > 0, jnp.asarray(_tokens(b, seed=91)), dead)
+        return jax.jit(lambda t: kl.decode_step_paged(
+            cfg, params, t, pool, table, lengths, slot_state=state,
+            active=active, kernel_interpret=interpret))(toks)
+
+    want, pool_w, state_w, booked_w = run(7, False)
+    got, pool_g, state_g, booked = run(7, True)
+    np.testing.assert_allclose(got[alive], want[alive], atol=TOL)
+    mine = np.asarray(table)[alive].ravel()
+    np.testing.assert_allclose(pool_g["ckv"][:, mine], pool_w["ckv"][:, mine],
+                               atol=TOL)
+    for k in state:
+        np.testing.assert_allclose(state_g[k][:, alive], state_w[k][:, alive],
+                                   atol=TOL)
+        np.testing.assert_array_equal(state_g[k][:, ~alive],
+                                      state[k][:, ~alive])
+    assert booked.tolist()[:3] == booked_w.tolist()[:3]
+    assert booked_w.tolist()[3] == 0
+    assert 0 < booked.tolist()[3] <= cfg.n_moe_layers
+    if not alive.all():
+        assert booked.tolist()[3] == cfg.n_moe_layers
+        again, pool_a, state_a, booked_a = run(
+            jnp.arange(200, 200 + b), True)
+        np.testing.assert_array_equal(again[alive], got[alive])
+        np.testing.assert_array_equal(pool_a["ckv"][:, mine],
+                                      pool_g["ckv"][:, mine])
+        assert booked_a.tolist() == booked.tolist()
 
 
 @pytest.mark.parametrize("option, match", [

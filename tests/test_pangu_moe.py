@@ -121,7 +121,7 @@ def test_shares_of_the_expert_layer_add_up_to_the_whole(model):
         held = dict(lp, we_gate=lp["we_gate"][:, lo * f:(lo + 4) * f],
                     we_up=lp["we_up"][:, lo * f:(lo + 4) * f],
                     we_down=lp["we_down"][lo * f:(lo + 4) * f])
-        y, g = pm.moe_ffn(share, h, held)
+        y, g, _ = pm.moe_ffn(share, h, held)
         # one share's result in the reference too
         np.testing.assert_allclose(
             y, ref.moe_layer(share, h, lambda n, *i, w=held: w[n][i]),
@@ -192,10 +192,11 @@ def test_grouped_form_equals_dense_form(sparse, routing, rows, widths):
     g = _routing(cfg, h, stack["router"][1], routing)
     lp = {k: stack[k][1] for k in pm.HELD_EXPERT_LEAVES}
     want = pm._routed_dense(cfg, h, g, lp)
-    got = jax.jit(lambda h, g: pm._routed_grouped(
+    got, fits = jax.jit(lambda h, g: pm._routed_grouped(
         cfg, h, g, stack, 1, True))(h, g)
     pairs = int((g > 0).sum())
     assert (pairs > rows) == (routing == "every_token_on_8_held_experts")
+    assert bool(fits) == (pairs <= rows)
     if pairs == 0:
         assert not np.asarray(got).any() and not np.asarray(want).any()
     # float32 sums of 2,304 terms where the toy's are 64
@@ -203,7 +204,7 @@ def test_grouped_form_equals_dense_form(sparse, routing, rows, widths):
     np.testing.assert_allclose(got, want, atol=tol)
     assert float(jnp.abs(want).max()) > 1e-3 or pairs == 0
     np.testing.assert_allclose(
-        pm._routed_grouped(cfg, h, g, lp, None, True), want, atol=tol)
+        pm._routed_grouped(cfg, h, g, lp, None, True)[0], want, atol=tol)
 
 
 def test_sort_pairs_by_hand():
@@ -216,28 +217,35 @@ def test_sort_pairs_by_hand():
     assert gates.tolist() == [.25, 2., .5, .75, 1., 0, 0, 0]
 
 
-@pytest.mark.parametrize("rows, grouped", [
-    (pm.GROUPED_MIN_ROWS - 128, False), (pm.GROUPED_MIN_ROWS, True)])
+@pytest.mark.parametrize("rows, decode, grouped", [
+    (pm.GROUPED_MIN_ROWS - 128, False, False),
+    (pm.GROUPED_MIN_ROWS, False, True), (64, True, True)])
 def test_expert_layer_takes_the_grouped_form_by_its_rows_alone(sparse, rows,
+                                                               decode,
                                                                grouped):
-    """Below the threshold (decode's 64 rows, a narrow chunk) ``moe_ffn`` is
+    """By its rows, or by ``live``.  A prompt chunk below the threshold is
     the dense product and lowers to no kernel; from it on, where the kernel
     applies (here: the interpreter), the grouped product, with the same
-    result.  On this backend without the interpreter: dense at any width."""
+    result; a decode token-step's rows (``live`` given, all of them live
+    here) take the grouped product at any width.  On this backend without
+    the interpreter: dense at any width, in either program."""
     cfg, params = sparse
     lp = jax.tree.map(lambda x: x[0], params["moe"])
     h = jax.random.normal(jax.random.PRNGKey(9), (rows, cfg.dim))
+    live = jnp.ones((rows,), jnp.int32) if decode else None
     assert pm.grouped_ffn_from(cfg) is None
     assert pm.grouped_ffn_from(cfg, interpret=True) == pm.GROUPED_MIN_ROWS
-    plain = jax.jit(lambda h: pm.moe_ffn(cfg, h, lp))
+    plain = jax.jit(lambda h: pm.moe_ffn(cfg, h, lp, live=live))
     text = plain.lower(h).as_text()
     assert "custom_call" not in text and "pallas" not in text
-    jaxpr = str(jax.make_jaxpr(lambda h: pm.moe_ffn(cfg, h, lp, True))(h))
+    jaxpr = str(jax.make_jaxpr(
+        lambda h: pm.moe_ffn(cfg, h, lp, True, live=live))(h))
     assert ("pallas_call" in jaxpr) == grouped
-    y, g = pm.moe_ffn(cfg, h, lp, True)
-    want, g_want = plain(h)
+    y, g, took = pm.moe_ffn(cfg, h, lp, True, live=live)
+    want, g_want, took_want = plain(h)
     np.testing.assert_allclose(y, want, atol=1e-6)
     np.testing.assert_array_equal(g, g_want)
+    assert bool(took) == grouped and not bool(took_want)
 
 
 def test_prefill_chunk_with_grouped_expert_layers_equals_dense(sparse):
@@ -256,6 +264,128 @@ def test_prefill_chunk_with_grouped_expert_layers_equals_dense(sparse):
     (want, pool_want), (got, pool_got) = run(False), run(True)
     np.testing.assert_allclose(got, want, atol=TOL)
     np.testing.assert_allclose(pool_got["ckv"], pool_want["ckv"], atol=TOL)
+
+
+# -- the grouped form in the decode program: the live rows' pairs alone -----------
+
+
+def _decode_batch(cfg, live, seed, dead_tokens):
+    """A token-step of ``len(live)`` rows over a random latent pool, a row
+    its own four blocks: ``(tokens, pool, table, lengths, active)``; the
+    rows with ``live`` 0 hold ``dead_tokens``."""
+    b = len(live)
+    rng = np.random.RandomState(seed)
+    pool = {"ckv": jax.random.normal(
+        jax.random.PRNGKey(seed), (cfg.n_layers, 4 * b + 1, BS,
+                                   cfg.cache_width)) * 0.3}
+    table = jnp.arange(1, 4 * b + 1, dtype=jnp.int32).reshape(b, 4)
+    active = np.asarray(live, np.int32)
+    toks = np.where(active > 0, rng.randint(1, 256, size=b), dead_tokens)
+    return (jnp.asarray(toks, jnp.int32), pool, table,
+            jnp.asarray(rng.randint(3, 4 * BS - 1, size=b), jnp.int32),
+            jnp.asarray(active))
+
+
+def _decode(cfg, params, batch, interpret):
+    toks, pool, table, lengths, active = batch
+    return jax.jit(lambda t: pm.decode_step_paged(
+        cfg, params, t, pool, table, lengths, active=active,
+        kernel_interpret=interpret))(toks)
+
+
+_LIVE = [1, 0, 0, 1, 1, 0, 0, 0, 1, 0, 0, 0]
+
+
+def test_decode_step_with_grouped_expert_layers_equals_dense(sparse):
+    """(a), (d) A token-step of twelve slots of which four decode, through
+    the whole decode program with its expert layers grouped and dense: the
+    live rows' logits and cache rows agree, every expert layer-call took the
+    grouped product (``moe_grouped_calls``) and the other counters are the
+    dense program's.  And what the dead slots hold (a stale token: any id,
+    routed anywhere) reaches nothing a live row reads: bit for bit."""
+    cfg, params = sparse
+    live = np.asarray(_LIVE) > 0
+    batch = _decode_batch(cfg, _LIVE, 31, dead_tokens=7)
+    want, pool_want, booked_want = _decode(cfg, params, batch, False)
+    got, pool_got, booked = _decode(cfg, params, batch, True)
+    np.testing.assert_allclose(got[live], want[live], atol=TOL)
+    mine = np.asarray(batch[2])[live].ravel()
+    np.testing.assert_allclose(pool_got["ckv"][:, mine],
+                               pool_want["ckv"][:, mine], atol=TOL)
+    assert booked.tolist() == booked_want.tolist()[:3] + [cfg.n_moe_layers]
+    assert booked_want.tolist()[3] == 0
+    assert booked.tolist()[0] == cfg.n_held * cfg.n_moe_layers
+    assert 0 < booked.tolist()[1] <= booked.tolist()[2] <= (
+        4 * cfg.n_experts_per_tok * cfg.n_moe_layers)
+    other = _decode_batch(cfg, _LIVE, 31, dead_tokens=np.arange(100, 112))
+    again, pool_again, booked_again = _decode(cfg, params, other, True)
+    np.testing.assert_array_equal(again[live], got[live])
+    np.testing.assert_array_equal(pool_again["ckv"][:, mine],
+                                  pool_got["ckv"][:, mine])
+    assert booked_again.tolist() == booked.tolist()
+
+
+def test_decode_group_sizes_count_the_live_rows_pairs_alone(sparse,
+                                                            monkeypatch):
+    """(b) The kernel is handed the live rows' pairs and no other: its
+    ``group_sizes`` are the live rows' choices by expert, an expert that
+    dead rows alone chose has size 0, and a dead row's routed part is
+    exactly zero (its output is the shared expert's)."""
+    from ray_tpu.ops import moe_grouped_ffn as kernel
+
+    cfg, params = sparse
+    lp = jax.tree.map(lambda x: x[0], params["moe"])
+    h = jax.random.normal(jax.random.PRNGKey(17), (len(_LIVE), cfg.dim))
+    live = jnp.asarray(_LIVE, jnp.int32)
+    seen = []
+    real = kernel.moe_grouped_ffn
+
+    def spy(xs, w_gate, w_up, w_down, layer, group_sizes, row_gates, **kw):
+        seen.append(np.asarray(group_sizes))
+        return real(xs, w_gate, w_up, w_down, layer, group_sizes, row_gates,
+                    **kw)
+
+    monkeypatch.setattr(kernel, "moe_grouped_ffn", spy)
+    y, g, took = pm.moe_ffn(cfg, h, lp, True, live=live)
+    every = np.asarray(pm.held_gates(cfg, *pm.route(cfg, h, lp["router"])))
+    alive = np.asarray(_LIVE) > 0
+    assert bool(took) and len(seen) == 1
+    np.testing.assert_array_equal(seen[0], (every[alive] > 0).sum(0))
+    dead_only = (every[~alive] > 0).any(0) & ~(every[alive] > 0).any(0)
+    assert dead_only.any() and not seen[0][dead_only].any()
+    np.testing.assert_array_equal(np.asarray(g)[~alive], 0)
+    shared_only = pm.moe_ffn(cfg, h, lp, True, live=jnp.zeros_like(live))[0]
+    np.testing.assert_allclose(np.asarray(y)[~alive],
+                               np.asarray(shared_only)[~alive], atol=1e-6)
+    assert np.abs(np.asarray(y - shared_only)[alive]).max() > 1e-3
+
+
+@pytest.mark.parametrize("live, fits", [([1] * 12, False),
+                                        ([1, 0, 1, 0, 0, 1] + [0] * 6, True)])
+def test_decode_rows_with_more_pairs_than_the_buffer_take_the_dense_product(
+        sparse, monkeypatch, live, fits):
+    """(c) Every row on four held experts (all it may choose): twelve live
+    rows make 48 pairs for a buffer of 16, take the dense product with no
+    pair dropped, and ``moe_grouped_calls`` does not count those
+    layer-calls; with three live rows of the twelve the same routing fits
+    (12 pairs): the dead rows' pairs are not there to overflow it."""
+    cfg, params = sparse
+
+    def all_held(cfg, h, router):
+        idx = jnp.broadcast_to(jnp.arange(18, 22), (h.shape[0], 4))
+        return jnp.full(idx.shape, 0.4, jnp.float32) + h[:, :4] * 0.01, idx
+
+    monkeypatch.setattr(pm, "route", all_held)
+    alive = np.asarray(live) > 0
+    batch = _decode_batch(cfg, live, 41, dead_tokens=3)
+    want, _, booked_want = _decode(cfg, params, batch, False)
+    got, _, booked = _decode(cfg, params, batch, True)
+    np.testing.assert_allclose(got[alive], want[alive], atol=TOL)
+    pairs = 4 * int(alive.sum()) * cfg.n_moe_layers
+    assert booked.tolist() == [cfg.n_held * cfg.n_moe_layers,
+                               4 * cfg.n_moe_layers, pairs,
+                               cfg.n_moe_layers if fits else 0]
+    assert booked_want.tolist() == booked.tolist()[:3] + [0]
 
 
 def test_router_against_a_hand_written_case():
@@ -356,6 +486,7 @@ def test_engine_serves_the_family_and_books_its_counters(model):
     assert c["moe_experts_held"] >= 5 * cfg.n_held * cfg.n_moe_layers
     assert 0 < c["moe_experts_hit"] <= c["moe_experts_held"]
     assert c["moe_experts_hit"] <= c["moe_pairs_here"]
+    assert c["moe_grouped_calls"] == 0  # no kernel on this backend: dense
     # a second request with the same first 32 tokens is a prefix hit
     again = eng.generate([prompt[:32] + _tokens(5, seed=12)],
                          GenerationConfig(max_new_tokens=2))
@@ -372,6 +503,11 @@ def test_engine_kernel_interpret_path_matches_gather(model):
     kernel = _engine(cfg, params, paged_attention_kernel="interpret")
     assert kernel._use_kernel and kernel._kernel_interpret
     assert kernel.generate([prompt], gen) == gather.generate([prompt], gen)
+    # one live row of the four: its pairs (four at most) fit the buffer of
+    # 16, so every expert layer-call of every token-step went grouped
+    c = kernel.counters()
+    assert c["moe_grouped_calls"] * cfg.n_held == c["moe_experts_held"] > 0
+    assert gather.counters()["moe_grouped_calls"] == 0
 
 
 def test_host_tier_round_trip_on_the_latent_pool(model):
