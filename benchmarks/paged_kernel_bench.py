@@ -85,7 +85,7 @@ def prefill_main(args) -> int:
         extra = {} if tile is None else {"kv_tile": tile}
         fn = jax.jit(
             lambda p, t, pl, tb, p0, extra=extra: llama.prefill_chunk_paged(
-                cfg, p, t, pl, tb, p0, rope_cache=rope, **extra),
+                cfg, p, t, pl, tb, p0, rope_cache=rope, **extra)[:2],
             donate_argnums=2)
         for c in (64, 256):
             tokens = jnp.asarray(rng.integers(1, cfg.vocab_size, (1, c)),

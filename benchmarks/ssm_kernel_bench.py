@@ -1,28 +1,30 @@
-"""The state-space decode kernels alone, at granite-4.0-h-micro's shapes.
+"""The state-space decode kernel alone, at granite-4.0-h-micro's shapes.
 
-``ops/ssm_state_update.py`` on the engine's leaf (36 Mamba layers x 64 slots
-x [32, 128, 128] float32, 4.8 GB) with 8, 20, 40 and 64 of the 64 rows
-decoding: ms a layer-call and the share of the HBM peak its bytes (a live
-row's 2 MB state read and written) are moved at.  One program a reading: a
-loop over the 36 layers, ``--reps`` times, each call taking the state the
-last one returned, so nothing overlaps and nothing is elided.  Beside it the
-``jax.numpy`` form at 20 live rows, which moves every slot's state whoever
-decodes.
+``SSM_LAYER_STEP``: a whole layer-step's work between the in-projection's
+output and the out-projection's input on the engine's leaves (36 Mamba layers
+x 64 slots x [32, 128, 128] float32, 4.8 GB, and the windows) with 8, 20, 40
+and 64 of the 64 rows decoding, as the model runs it on the chip (``fused``:
+``ops/ssm_state_update.py ssm_layer_step``, one call, the live rows alone)
+and as it runs it anywhere else (``jnp``:
+``granite_hybrid.recurrent_step_jnp``: convolution, ``silu``, ``delta``,
+``decay``, the window's write-back and the gated norm in ``jax.numpy`` over
+every slot, round ``ssm_state_update_jnp``, which moves every slot's state
+whoever decodes); with the fused form's largest gap to the ``jnp`` one from
+the same inputs, as a share of the largest value.  One program a reading: a
+loop over the 36 layers, ``--reps`` times, each call taking the leaves the
+last one returned, so nothing overlaps and nothing is elided.
 
-A second reading, ``SSM_LAYER_STEP``: a whole layer-step's work between the
-in-projection's output and the out-projection's input at the same live
-rows, as the model ran it before the fused call (``unfused``: convolution,
-``silu``, ``delta``, ``decay``, the window's write-back and the gated norm in
-``jax.numpy`` over every slot, round the state-update kernel) and as it runs
-it now (``fused``: ``ssm_layer_step``, one call); with the fused form's
-largest gap to the unfused one from the same inputs, as a share of the
-largest value.
+``SSM_STATE_JNP``: ``ssm_state_update_jnp`` alone at 20 live rows: ms a
+layer-call and the share of the HBM peak the LIVE rows' bytes (2 MB read and
+written a row) are moved at.  (The kernel of the state's update alone, which
+this reading stood beside, went at PR 50; PERF.md section 6, PRs 36 and 44,
+keeps what it measured.)
 
     python benchmarks/ssm_kernel_bench.py [--reps 8] [--slots 64]
 
-Prints ``SSM_KERNEL {json}`` and ``SSM_LAYER_STEP {json}`` a reading.  A time comes only from a chip: on
-another backend it exits 2 (``--rehearse`` walks it at toy size in interpret
-mode and exits 3).
+Prints ``SSM_STATE_JNP {json}`` and ``SSM_LAYER_STEP {json}`` a reading.  A
+time comes only from a chip: on another backend it exits 2 (``--rehearse``
+walks it at toy size in interpret mode and exits 3).
 """
 
 from __future__ import annotations
@@ -42,9 +44,13 @@ HBM_BYTES_PER_S = 819e9  # chipbench/peaks.json, TPU v5 lite
 
 def layer_step_readings(ops, jax, jnp, np, args, dims, platform):
     """The ``SSM_LAYER_STEP`` readings (the module's docstring)."""
+    from ray_tpu.models import granite_hybrid as gh
+
     layers, slots, heads, p, n = dims
-    i, k = heads * p, 4
-    cw = i + 2 * n
+    # the model's own CPU branch is the ``jnp`` form: a config of these widths
+    cfg = gh.GraniteHybridConfig(dim=heads * p // 2, mamba_n_heads=heads,
+                                 mamba_d_head=p, mamba_d_state=n)
+    i, k, cw = cfg.d_inner, cfg.mamba_d_conv, cfg.conv_width
     bf16, f32 = jnp.bfloat16, jnp.float32
     ks = iter(jax.random.split(jax.random.PRNGKey(1), 12))
 
@@ -67,68 +73,43 @@ def layer_step_readings(ops, jax, jnp, np, args, dims, platform):
 
         def fused(state, win, li, active, live):
             return ops.ssm_layer_step(
-                state, win, li, proj, dt, small, active, live, eps=1e-5,
-                interpret=args.rehearse)
+                state, win, li, proj, dt, small, active, live,
+                eps=cfg.rms_norm_eps, interpret=args.rehearse)
 
-        def unfused(state, win, li, active, live):
-            lp = {name: jax.lax.dynamic_index_in_dim(a, li, 0, keepdims=False)
-                  for name, a in mp.items()}
-            held = jax.lax.dynamic_index_in_dim(win, li, 0, keepdims=False)
-            z, xbc = proj[:, :i], proj[:, i:]
-            seq = jnp.concatenate([held, xbc], axis=1)
-            w = lp["conv_w"].astype(f32)
-            acc = lp["conv_b"].astype(f32)[None, :]
-            for j in range(k):
-                acc = acc + w[j][None, :] * seq[
-                    :, j * cw:(j + 1) * cw].astype(f32)
-            held = jnp.where((active != 0)[:, None], seq[:, cw:], held)
-            xbc = jax.nn.silu(acc).astype(bf16)
-            xm, bm, cm = xbc[:, :i], xbc[:, i:i + n], xbc[:, i + n:]
-            delta = jax.nn.softplus(dt.astype(f32)
-                                    + lp["dt_bias"].astype(f32))
-            a = -jnp.exp(lp["a_log"].astype(f32))
-            xh = xm.astype(f32).reshape(slots, heads, p)
-            decay = jnp.broadcast_to(jnp.exp(delta * a)[..., None],
-                                     (slots, heads, p)).reshape(slots, i)
-            xdt = (delta[..., None] * xh).reshape(slots, i)
-            y, state = ops.ssm_state_update(
-                state, li, decay, xdt, bm, cm, active, live,
-                interpret=args.rehearse)
-            y = y + (lp["d"].astype(f32)[None, :, None] * xh
-                     ).reshape(slots, i)
-            g = y * jax.nn.silu(z.astype(f32))
-            g = g * jax.lax.rsqrt(jnp.mean(g * g, -1, keepdims=True) + 1e-5)
-            y = (g * lp["norm"].astype(f32)).astype(bf16)
+        def plain(state, win, li, active, live):
+            del live  # every slot's state moves
+            lp, held = jax.tree.map(
+                lambda a: jax.lax.dynamic_index_in_dim(a, li, 0,
+                                                       keepdims=False),
+                (mp, win))
+            y, state, held = gh.recurrent_step_jnp(
+                cfg, lp, proj[:, :i], proj[:, i:], dt, state, held, li,
+                active)
             return y, state, jax.lax.dynamic_update_index_in_dim(
                 win, held, li, 0)
 
-        return {"fused": fused, "unfused": unfused}
-
-    def windows(flat):
-        return {"fused": ops.pack_window(flat),
-                "unfused": flat.reshape(*flat.shape[:2], -1)}
+        return {"fused": fused, "jnp": plain}
 
     # the two forms from the same inputs: one layer-call on a leaf of two
     # layers (a whole leaf a form does not fit beside the other's), every
     # row live
     every = jnp.ones(slots, jnp.int32)
     s0 = jax.random.normal(next(ks), ops.state_shape(2, slots, heads, p, n))
-    flat = jax.random.normal(next(ks), (layers, slots, k - 1, cw)).astype(bf16)
+    wins = ops.pack_window(jax.random.normal(
+        next(ks), (layers, slots, k - 1, cw)).astype(bf16))
     two = forms({name: a[:2] for name, a in mp.items()})
-    one = {name: jax.jit(step)(s0, windows(flat[:2])[name], 1, every,
-                               ops.live_rows(every))
+    one = {name: jax.jit(step)(s0, wins[:2], 1, every, ops.live_rows(every))
            for name, step in two.items()}
-    top = float(jnp.abs(one["unfused"][0].astype(f32)).max())
+    top = float(jnp.abs(one["jnp"][0].astype(f32)).max())
     gaps = {
         "y": float(jnp.abs(one["fused"][0].astype(f32)
-                           - one["unfused"][0].astype(f32)).max()) / top,
-        "state": float(jnp.abs(one["fused"][1] - one["unfused"][1]).max()
-                       / jnp.abs(one["unfused"][1]).max()),
-        "window": float(jnp.abs(
-            ops.unpack_window(one["fused"][2], cw).reshape(2, slots, -1)
-            .astype(f32) - one["unfused"][2].astype(f32)).max())}
+                           - one["jnp"][0].astype(f32)).max()) / top,
+        "state": float(jnp.abs(one["fused"][1] - one["jnp"][1]).max()
+                       / jnp.abs(one["jnp"][1]).max()),
+        "window": float(jnp.abs(one["fused"][2].astype(f32)
+                                - one["jnp"][2].astype(f32)).max())}
     del one, s0
-    steps, wins = forms(mp), windows(flat)
+    steps = forms(mp)
     shape = ops.state_shape(layers, slots, heads, p, n)
 
     def program(step):
@@ -150,7 +131,7 @@ def layer_step_readings(ops, jax, jnp, np, args, dims, platform):
         active = jnp.asarray(active)
         for name, step in steps.items():
             fn = program(step)
-            state, win, acc = fn(jnp.zeros(shape, f32), jnp.copy(wins[name]),
+            state, win, acc = fn(jnp.zeros(shape, f32), jnp.copy(wins),
                                  active)
             jax.block_until_ready(acc)          # compiled, warm
             t0 = time.perf_counter()
@@ -161,7 +142,7 @@ def layer_step_readings(ops, jax, jnp, np, args, dims, platform):
                 "form": name, "live_rows": live, "slots": slots,
                 "us_per_layer_step": call_s * 1e6,
                 "finite": bool(np.isfinite(float(acc))),
-                "gap_to_unfused": gaps if name == "fused" else None,
+                "gap_to_jnp": gaps if name == "fused" else None,
                 "platform": platform}), flush=True)
 
 
@@ -193,43 +174,33 @@ def main() -> int:
     c = jax.random.normal(ks[3], (slots, n))
     row_bytes = 2 * 4 * heads * p * n
 
-    def program(step):
-        def run(state, active):
-            def rep(_, carry):
-                def layer(li, carry):
-                    state, acc = carry
-                    y, state = step(state, li, decay, xdt, b, c, active)
-                    return state, acc + y[:, :8].sum()
-                return jax.lax.fori_loop(0, layers, layer, carry)
-            return jax.lax.fori_loop(0, args.reps, rep,
-                                     (state, jnp.float32(0)))
-        return jax.jit(run, donate_argnums=0)
+    def run(state, active):
+        def layer(i, carry):
+            state, acc = carry
+            y, state = ops.ssm_state_update_jnp(state, i % layers, decay, xdt,
+                                                b, c, active)
+            return state, acc + y[:, :8].sum()
+        return jax.lax.fori_loop(0, layers * args.reps, layer,
+                                 (state, jnp.float32(0)))
 
-    kernel = program(lambda *a: ops.ssm_state_update(
-        *a, interpret=args.rehearse))
-    plain = program(ops.ssm_state_update_jnp)
-    state = jnp.zeros(shape, jnp.float32)
-    readings = [("kernel", kernel, live) for live in
-                sorted({slots // 8, slots * 5 // 16, slots * 5 // 8, slots})]
-    readings.append(("jnp", plain, slots * 5 // 16))
-    for name, fn, live in readings:
-        rng = np.random.default_rng(live)
-        active = np.zeros(slots, np.int32)
-        active[rng.choice(slots, live, replace=False)] = 1
-        active = jnp.asarray(active)
-        state, acc = fn(state, active)          # compile, warm
-        jax.block_until_ready(acc)
-        t0 = time.perf_counter()
-        state, acc = fn(state, active)
-        jax.block_until_ready(acc)
-        call_s = (time.perf_counter() - t0) / (layers * args.reps)
-        print("SSM_KERNEL " + json.dumps({
-            "form": name, "live_rows": live, "slots": slots,
-            "ms_per_layer_call": call_s * 1e3,
-            "us_per_live_row": call_s * 1e6 / live,
-            "hbm_peak_pct": 100 * live * row_bytes / call_s / HBM_BYTES_PER_S,
-            "finite": bool(np.isfinite(float(acc))),
-            "platform": platform}), flush=True)
+    fn = jax.jit(run, donate_argnums=0)
+    live = slots * 5 // 16
+    active = np.zeros(slots, np.int32)
+    active[np.random.default_rng(live).choice(slots, live, replace=False)] = 1
+    active = jnp.asarray(active)
+    state, acc = fn(jnp.zeros(shape, jnp.float32), active)  # compile, warm
+    jax.block_until_ready(acc)
+    t0 = time.perf_counter()
+    state, acc = fn(state, active)
+    jax.block_until_ready(acc)
+    call_s = (time.perf_counter() - t0) / (layers * args.reps)
+    print("SSM_STATE_JNP " + json.dumps({
+        "live_rows": live, "slots": slots,
+        "ms_per_layer_call": call_s * 1e3,
+        "us_per_live_row": call_s * 1e6 / live,
+        "hbm_peak_pct": 100 * live * row_bytes / call_s / HBM_BYTES_PER_S,
+        "finite": bool(np.isfinite(float(acc))),
+        "platform": platform}), flush=True)
     del state  # 4.8 GB: the next readings hold a leaf of their own
     layer_step_readings(ops, jax, jnp, np, args,
                         (layers, slots, heads, p, n), platform)

@@ -332,18 +332,6 @@ _TRACKED_MAX = 4096
 _JOIN_ROW = 5 + _MAX_STOP_IDS
 
 
-def _prefill_kernel_args(family, cfg, use_kernel: bool,
-                         interpret: bool) -> Dict[str, bool]:
-    """What the family's ``prefill_chunk`` is told of the engine's kernel
-    choice: nothing where the family has no prefill kernel (its
-    ``prefill_kernel_fits`` is None: the function takes no such arguments)."""
-    fits = family.prefill_kernel_fits
-    if fits is None:
-        return {}
-    return {"use_kernel": bool(use_kernel and fits(cfg)),
-            "kernel_interpret": interpret}
-
-
 def _bucket_pow2(n: int, lo: int = 1) -> int:
     b = lo
     while b < n:
@@ -535,15 +523,16 @@ class PagedJaxLLMEngine:
         pkey = key if key is not None else jax.random.PRNGKey(0)
         self._rep = None  # replicated sharding over the mesh, if any
         decode_out = prefill_out = None  # out_shardings: jit's default
+        # the state a SLOT holds, {leaf: [layers, max_batch, ...]} ({}: the
+        # family's only state is the pool; under a mesh too, where such a
+        # family has no param_specs, as the refusal above has it)
+        self.slot_state: Dict[str, jnp.ndarray] = (
+            fam.init_slot_state(cfg, self.max_batch)
+            if fam.init_slot_state else {})
         if self.mesh is None:
             self.params = (params if params is not None
                            else fam.init_params(cfg, pkey))
             self.pool = fam.init_paged_cache(cfg, nb, self.bs)
-            # the state a SLOT holds, {leaf: [layers, max_batch, ...]}
-            # (None: the family's only state is the pool); one device only,
-            # as the refusal above has it
-            self.slot_state = (None if fam.init_slot_state is None
-                               else fam.init_slot_state(cfg, self.max_batch))
         else:
             from jax.sharding import NamedSharding, PartitionSpec
 
@@ -581,11 +570,10 @@ class PagedJaxLLMEngine:
             # uploaded array and a fed-back one keyed different ones, so
             # warmup() compiled programs serving never ran and serving
             # compiled its own inside the request path.
-            self.slot_state = None  # such a family has no param_specs
             self._rep = NamedSharding(self.mesh, PartitionSpec())
             rep = self._rep
-            decode_out = (rep, rep, pool_sh, rep, rep, rep, rep)
-            prefill_out = (rep, pool_sh, rep)
+            decode_out = (rep, rep, pool_sh, rep, rep, rep, rep, {})
+            prefill_out = (rep, pool_sh, rep, {})
         # --- planner-routed TP collectives (tentpole, ISSUE 20) ---------
         # decode's per-layer allreduces are KiB-scale and latency-bound —
         # the α-β planner's flat/tree regime.  Plan once per program kind
@@ -713,19 +701,20 @@ class PagedJaxLLMEngine:
             self._use_kernel = bool(want)
         # whether a prefill chunk's attention runs in a kernel of the
         # family's too (counter prefill_kernel_chunks)
-        self._prefill_kernel = _prefill_kernel_args(
-            fam, cfg, self._use_kernel, False).get("use_kernel", False)
+        self._prefill_kernel = bool(
+            self._use_kernel and fam.prefill_kernel_fits is not None
+            and fam.prefill_kernel_fits(cfg))
         # the chunk width from which on the family's expert layers run as a
         # grouped product (counter prefill_grouped_chunks); None: never
         self._prefill_grouped_from = (
             fam.prefill_grouped_from(cfg, self._kernel_interpret)
             if fam.prefill_grouped_from else None)
-        stateful = self.slot_state is not None
+        # the pool and the slot state are donated and recaptured
         self._decode = jax.jit(self._decode_chunk_impl,
-                               donate_argnums=(2, 12) if stateful else 2,
-                               static_argnums=11, out_shardings=decode_out)
+                               donate_argnums=(2, 12), static_argnums=11,
+                               out_shardings=decode_out)
         self._prefill_chunk = jax.jit(self._prefill_chunk_impl,
-                                      donate_argnums=(2, 9) if stateful else 2,
+                                      donate_argnums=(2, 9),
                                       out_shardings=prefill_out)
         # one row into (or out of) the decode mirrors; every output placed
         # as the decode program's are, so each feeds the other's executable
@@ -852,9 +841,8 @@ class PagedJaxLLMEngine:
         # engine-owned HBM by N× on sharded replicas, making chip
         # telemetry and the disagg router's free-HBM digests lie.
         kv_bytes = device_telemetry.tree_nbytes_per_device(self.pool)
-        if self.slot_state is not None:  # engine-owned state, like the pool
-            kv_bytes += device_telemetry.tree_nbytes_per_device(
-                self.slot_state)
+        # engine-owned state, like the pool
+        kv_bytes += device_telemetry.tree_nbytes_per_device(self.slot_state)
         if self._spec is not None:
             kv_bytes += device_telemetry.tree_nbytes_per_device(
                 self._draft_pool)
@@ -904,7 +892,7 @@ class PagedJaxLLMEngine:
             "pending": pending,
             "counters": self.counters(),
         }
-        if self.slot_state is not None:
+        if self.slot_state:
             # what does not page: one fixed-size state a slot, and no prefix
             # hit for its family whatever enable_prefix_caching says
             row["slot_state"] = {
@@ -1119,25 +1107,23 @@ class PagedJaxLLMEngine:
 
     def _decode_chunk_impl(self, params, tokens, pool, table, lengths, active,
                            remaining, stops, key, temps, top_ks, n_steps,
-                           state=None):
-        """Multi-step paged decode (mirrors the static engine's program; the
-        host guarantees every active slot's table covers lengths + n_steps
-        tokens of appends).  ``state``: the family's slot state (None: it
-        has none), carried through the token-steps beside the pool and
-        returned last."""
+                           state):
+        """Multi-step paged decode (the host guarantees every active slot's
+        table covers lengths + n_steps tokens of appends).  ``state``: the
+        family's slot state ({}: it has none), carried through the
+        token-steps beside the pool and returned last.  First of what it
+        returns: the emitted tokens ``[n_steps, B]``, or where the family
+        books ``decode_counters`` the pair of them and the counters of every
+        token-step ``[n_steps, len(decode_counters)]`` (``_collect_locked``
+        tells them apart: the programs' result names are pinned)."""
 
         def one(carry, _):
-            tokens, pool, lengths, active, remaining, key, *slot = carry
-            # a family with a slot state returns it third; one with
-            # decode_counters returns them after
-            logits, pool, *booked = self.family.decode_step(
+            tokens, pool, lengths, active, remaining, key, state = carry
+            logits, pool, state, booked = self.family.decode_step(
                 self.cfg, params, tokens, pool, table, lengths,
                 rope_cache=self._rope, use_kernel=self._use_kernel,
                 mesh=self.mesh, kernel_interpret=self._kernel_interpret,
-                tp_plan=self._tp_plan, active=active,
-                **({"slot_state": slot[0]} if slot else {}))
-            if slot:
-                slot, booked = booked[:1], booked[1:]
+                tp_plan=self._tp_plan, active=active, slot_state=state)
             key, sub = jax.random.split(key)
             # a row that ended, in this chunk or before it, asks nothing of
             # the sampler whatever its mirrors still hold
@@ -1150,34 +1136,32 @@ class PagedJaxLLMEngine:
                                    | (lengths + 1 >= self.max_seq))
             active = active * (1 - done.astype(active.dtype))
             tokens = jnp.where(active > 0, ids, tokens)
-            carry = (tokens, pool, lengths, active, remaining, key, *slot)
-            return carry, ((emitted, booked[0]) if booked else emitted)
+            carry = (tokens, pool, lengths, active, remaining, key, state)
+            return carry, (emitted, booked)
 
-        carry = (tokens, pool, lengths, active, remaining, key)
-        if state is not None:
-            carry += (state,)
-        carry, emitted = jax.lax.scan(one, carry, None, length=n_steps)
-        tokens, pool, lengths, active, remaining, key, *slot = carry
-        return (emitted, tokens, pool, lengths, active, remaining, key, *slot)
+        carry = (tokens, pool, lengths, active, remaining, key, state)
+        carry, (emitted, booked) = jax.lax.scan(one, carry, None,
+                                                length=n_steps)
+        tokens, pool, lengths, active, remaining, key, state = carry
+        return (emitted if booked is None else (emitted, booked), tokens,
+                pool, lengths, active, remaining, key, state)
 
     def _prefill_chunk_impl(self, params, tokens, pool, table, p0,
-                            sample_idx, key, temp, top_k, state=None,
-                            where=None):
+                            sample_idx, key, temp, top_k, state, where):
         """One chunk; also samples the token at chunk-local position
         ``sample_idx`` (the caller uses it only on the final chunk).
         ``state`` / ``where``: the family's slot state and the chunk's
-        ``(slot, real tokens)`` (None: the family has none); the state is
-        returned last."""
-        logits, pool, *slot = self.family.prefill_chunk(
+        ``(slot, real tokens)`` (``_slot_args``; {} and Nones: the family
+        has none); the state is returned last."""
+        slot, take = where
+        logits, pool, state = self.family.prefill_chunk(
             self.cfg, params, tokens, pool, table, p0, rope_cache=self._rope,
-            tp_plan=self._tp_prefill_plan,
-            **_prefill_kernel_args(self.family, self.cfg, self._use_kernel,
-                                   self._kernel_interpret),
-            **({} if state is None else
-               {"slot_state": state, "slot": where[0], "take": where[1]}))
+            tp_plan=self._tp_prefill_plan, use_kernel=self._use_kernel,
+            kernel_interpret=self._kernel_interpret, slot_state=state,
+            slot=slot, take=take)
         key, sub = jax.random.split(key)
         ids = _sample(logits[:, sample_idx], sub, temp, top_k)
-        return (ids, pool, key, *slot)
+        return ids, pool, key, state
 
     def _join_impl(self, mirrors, ids, row, temp):
         """One row of the decode mirrors, set on the device: ``mirrors`` as
@@ -1219,7 +1203,7 @@ class PagedJaxLLMEngine:
         def one(carry, j):
             tok, pool, key = carry
             cur = jnp.minimum(lengths + j, self.max_seq - 1)
-            logits, pool = self._draft_family.decode_step(
+            logits, pool, _, _ = self._draft_family.decode_step(
                 self._draft_cfg, params, tok, pool, table, cur,
                 rope_cache=self._draft_rope)
             key, sub = jax.random.split(key)
@@ -1671,15 +1655,14 @@ class PagedJaxLLMEngine:
                 with tracing.region("engine.prefill_chunk", tokens=take,
                                     bucket=c, is_last=int(is_last), p0=p0,
                                     grouped=grouped, rid=req.request_id):
-                    ids, self.pool, self._d_key, *state = self._prefill_chunk(
-                        self.params, self._put(tokens), self.pool,
-                        self._put(table), self._put(p0, np.int32),
-                        self._put(sample_idx, np.int32), self._d_key,
-                        self._put([req.gen.temperature], np.float32),
-                        self._put([req.gen.top_k], np.int32),
-                        *self._slot_args(slot, take))
-                    if state:
-                        self.slot_state = state[0]
+                    ids, self.pool, self._d_key, self.slot_state = (
+                        self._prefill_chunk(
+                            self.params, self._put(tokens), self.pool,
+                            self._put(table), self._put(p0, np.int32),
+                            self._put(sample_idx, np.int32), self._d_key,
+                            self._put([req.gen.temperature], np.float32),
+                            self._put([req.gen.top_k], np.int32),
+                            self.slot_state, self._slot_args(slot, take)))
                 if self._empty_since is not None:
                     self._book_empty_locked()
                 self._chunk_mark = self._c["decode_dispatches"]
@@ -1728,16 +1711,12 @@ class PagedJaxLLMEngine:
                 budget -= take
                 self._tel_prefill_spent += take
 
-    def _slot_args(self, slot: Optional[int] = None, take: int = 0) -> tuple:
-        """What a dispatch hands its program beyond the pool: the family's
-        slot state and, for a prefill chunk (``slot`` given), the chunk's
-        ``(slot, real tokens)``; nothing for a family without one."""
-        if self.slot_state is None:
-            return ()
-        if slot is None:
-            return (self.slot_state,)
-        return (self.slot_state,
-                (self._put(slot, np.int32), self._put(take, np.int32)))
+    def _slot_args(self, slot: int, take: int) -> tuple:
+        """A prompt chunk's ``(slot, real tokens)`` for the program; an empty
+        slot state passes none (two Nones: no parameter of the program)."""
+        if not self.slot_state:
+            return (None, None)
+        return (self._put(slot, np.int32), self._put(take, np.int32))
 
     def _mark_dirty(self, cause: str):
         """The device mirrors are stale; ``cause`` (the first since the
@@ -2181,18 +2160,7 @@ class PagedJaxLLMEngine:
                     table, active)
                 steps = self._spec_k + 1
             else:
-                (em_dev, self._d_next, self.pool, self._d_lengths,
-                 self._d_active, self._d_remaining, self._d_key, *slot) = \
-                    self._decode(
-                        self.params, self._d_next, self.pool,
-                        self._put(table), self._d_lengths,
-                        self._d_active, self._d_remaining,
-                        self._d_stops, self._d_key,
-                        self._d_temp, self._d_topk, chunk,
-                        *self._slot_args())
-                if slot:
-                    self.slot_state = slot[0]
-                self._book_tp_collectives("decode", chunk)
+                em_dev = self._decode_locked(table, chunk)
                 acc_dev, spec_slots, steps = None, (), chunk
         prev, self._inflight = (self._inflight,
                                 (em_dev, active, spec_slots, acc_dev))
@@ -2216,6 +2184,21 @@ class PagedJaxLLMEngine:
         elif self._empty_since is not None:
             self._book_empty_locked()
         return prev
+
+    def _decode_locked(self, table, steps: int):
+        """Dispatch the decode program over ``table`` for ``steps``
+        token-steps and recapture what it carries (mirrors, pool, slot
+        state).  Returns the emitted tokens, still on the device, as
+        ``_collect_locked`` takes them."""
+        (em_dev, self._d_next, self.pool, self._d_lengths,
+         self._d_active, self._d_remaining, self._d_key,
+         self.slot_state) = self._decode(
+            self.params, self._d_next, self.pool, self._put(table),
+            self._d_lengths, self._d_active, self._d_remaining,
+            self._d_stops, self._d_key, self._d_temp, self._d_topk, steps,
+            self.slot_state)
+        self._book_tp_collectives("decode", steps)
+        return em_dev
 
     def _spec_step_locked(self, table, active: List[int]):
         """One speculative decode cycle: draft proposes k tokens per
@@ -2241,15 +2224,7 @@ class PagedJaxLLMEngine:
             if self._slot_req[s] is not None
             and self._slot_req[s].spec_enabled)
         if not spec_slots:
-            (em_dev, self._d_next, self.pool, self._d_lengths,
-             self._d_active, self._d_remaining, self._d_key) = \
-                self._decode(
-                    self.params, self._d_next, self.pool,
-                    self._put(table), self._d_lengths, self._d_active,
-                    self._d_remaining, self._d_stops, self._d_key,
-                    self._d_temp, self._d_topk, k + 1)
-            self._book_tp_collectives("decode", k + 1)
-            return em_dev, None, ()
+            return self._decode_locked(table, k + 1), None, ()
         # the draft table reuses the TARGET table's bucketed width:
         # block counts track each other (same ensure/trim formulas),
         # and one shared width means one propose compile per verify
@@ -2377,9 +2352,10 @@ class PagedJaxLLMEngine:
                    "block_size": self.bs,
                    # what the sequence holds that does not page: the slot's
                    # own index of every leaf, [layers, ...]
-                   **({} if self.slot_state is None else {"slot_state": {
+                   **({"slot_state": {
                        n: np.asarray(x[:, req.slot])
-                       for n, x in self.slot_state.items()}}),
+                       for n, x in self.slot_state.items()}}
+                      if self.slot_state else {}),
                    "emitted": [int(t) for t in req.out_tokens],
                    "gen": {"max_new_tokens": g.max_new_tokens,
                            "temperature": g.temperature,
@@ -2448,7 +2424,7 @@ class PagedJaxLLMEngine:
                 f"handoff carries cache leaves {sorted(leaves)}, the "
                 f"{self.family.name} family's pool has "
                 f"{list(self.cache_leaves)}")
-        if self.slot_state is not None and (
+        if self.slot_state and (
                 slot_state is None
                 or set(slot_state) != set(self.slot_state)
                 or any(tuple(np.shape(slot_state[n]))
@@ -2486,7 +2462,7 @@ class PagedJaxLLMEngine:
                 self.pool, jnp.asarray(idx), padded)
             if self._empty_since is not None:
                 self._book_empty_locked()  # the scatter is a dispatch
-            if self.slot_state is not None:
+            if self.slot_state:
                 self.slot_state = self._import_slot(
                     self.slot_state, jnp.int32(slot),
                     {n: jnp.asarray(np.asarray(slot_state[n], dtype=x.dtype))
@@ -2644,14 +2620,13 @@ class PagedJaxLLMEngine:
                     steps = k + 1
                 else:
                     steps = chunk
-                out = self._decode(
-                    self.params, zi(b), self.pool, zi(b, w), zi(b), zi(b),
-                    zi(b), stops, key, zf(b), zi(b), steps,
-                    *self._slot_args())
-                self.pool = out[2]
-                if self.slot_state is not None:  # active=0: as it was
-                    self.slot_state = out[7]
-                jax.block_until_ready(out[0])  # compile + run to completion
+                # active=0: a slot state comes back as it was
+                em, _, self.pool, _, _, _, _, self.slot_state = (
+                    self._decode(
+                        self.params, zi(b), self.pool, zi(b, w), zi(b),
+                        zi(b), zi(b), stops, key, zf(b), zi(b), steps,
+                        self.slot_state))
+                jax.block_until_ready(em)  # compile + run to completion
                 decode_widths.append(w)
                 if w >= w_cap:
                     break
@@ -2668,11 +2643,10 @@ class PagedJaxLLMEngine:
             while True:
                 c = min(c, c_cap)
                 # no real token: a slot state stays as it was
-                ids, self.pool, _, *slot = self._prefill_chunk(
+                ids, self.pool, _, self.slot_state = self._prefill_chunk(
                     self.params, zi(1, c), self.pool, zi(1, self._prefill_w),
-                    zi(), zi(), key, zf(1), zi(1), *self._slot_args(0, 0))
-                if slot:
-                    self.slot_state = slot[0]
+                    zi(), zi(), key, zf(1), zi(1), self.slot_state,
+                    self._slot_args(0, 0))
                 np.asarray(ids)
                 if self._spec is not None:
                     self._draft_pool = self._draft_prefill(
@@ -2714,21 +2688,19 @@ class PagedJaxLLMEngine:
 
         def run(params, pool, toks, table, last, length):
             # a family with a slot state runs on one slot of its own here
-            own = ({} if self.slot_state is None else dict(
-                slot_state=self.family.init_slot_state(self.cfg, 1),
-                slot=jnp.int32(0), take=jnp.int32(n)))
-            _, pool, *state = self.family.prefill_chunk(
+            init = self.family.init_slot_state
+            _, pool, state = self.family.prefill_chunk(
                 self.cfg, params, toks, pool, table, jnp.int32(0),
                 rope_cache=self._rope, tp_plan=self._tp_prefill_plan,
-                **_prefill_kernel_args(self.family, self.cfg,
-                                       self._use_kernel,
-                                       self._kernel_interpret), **own)
-            logits, pool, *_ = self.family.decode_step(
+                use_kernel=self._use_kernel,
+                kernel_interpret=self._kernel_interpret,
+                slot_state=init(self.cfg, 1) if init else {},
+                slot=jnp.int32(0), take=jnp.int32(n))
+            logits, pool, _, _ = self.family.decode_step(
                 self.cfg, params, last, pool, table, length,
                 rope_cache=self._rope, use_kernel=self._use_kernel,
                 mesh=self.mesh, kernel_interpret=self._kernel_interpret,
-                tp_plan=self._tp_plan,
-                **({"slot_state": state[0]} if state else {}))
+                tp_plan=self._tp_plan, slot_state=state)
             return logits[0], pool
 
         with self._lock:

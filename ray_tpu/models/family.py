@@ -1,42 +1,34 @@
 """The family seam: what a model family supplies to the paged engine.
 
 ``PagedJaxLLMEngine`` (``llm/paged.py``) owns scheduling, the block manager,
-the prefix cache, sampling and the two device programs' framing
-(``_decode_chunk_impl`` / ``_prefill_chunk_impl``).  Everything that depends
-on the architecture comes through one :class:`ModelFamily`: the parameters,
-the paged cache's pytree, the per-chunk and per-token-step forward functions,
-whether a decode kernel exists, and the plain float32 reference the served
-tokens are held against.  ``LLMConfig.model_config``'s TYPE picks the family
-(:func:`family_of`); no option names one.
+the prefix cache, sampling and the two device programs' framing.  Everything
+that depends on the architecture comes through one :class:`ModelFamily`;
+``LLMConfig.model_config``'s TYPE picks it (:func:`family_of`), no option
+names one.
 
 **Two kinds of state.**  The paged cache is a dict of arrays, every leaf
-``[layers, blocks, block_size, width]``: the engine copies, demotes, exports
-and imports it leaf by leaf and never looks inside a block.  A Llama block is
-keys and values (leaves ``k`` and ``v``); a latent-attention block is one leaf
-``ckv``.  It holds what grows with a sequence, a POSITION at a time.
-
-A family may also declare a **slot state** (``init_slot_state``): a dict of
-arrays, every leaf ``[layers, max_batch, ...]``, one fixed-size value a
-sequence that does not page (a state-space layer's recurrent state and its
-convolution's window).  The engine owns it beside the pool and gives it to
-both forward functions: ``prefill_chunk`` also takes ``slot_state``, ``slot``
-(the engine's slot of the sequence) and ``take`` (the chunk's count of REAL
-tokens: the rest of its power-of-two bucket is padding and must not advance
-the state), starts from zeros where ``p0 == 0`` (a re-used slot is never
-cleared) and returns the state as its third value; ``decode_step`` also takes
-``slot_state`` and returns it third, and must leave a row with ``active ==
-0`` untouched, because decode dispatches run between a sequence's prompt
-chunks.  For such a family the engine refuses a prefix hit whatever
+``[layers, blocks, block_size, width]`` (Llama: ``k`` and ``v``; latent
+attention: ``ckv``): what grows with a sequence, a POSITION at a time.  The
+engine copies, demotes, exports and imports it leaf by leaf and never looks
+inside a block.  A family may also declare a **slot state**
+(``init_slot_state``): a dict of arrays, every leaf ``[layers, max_batch,
+...]``, one fixed-size value a sequence (a state-space layer's recurrent
+state and its convolution's window); a family without one has ``{}``.  For a
+family with one the engine refuses a prefix hit whatever
 ``enable_prefix_caching`` says (no snapshot of the state exists at a block
-boundary), rebuilds the state by recompute after a preemption, and carries
-the slot's leaves in ``export_request`` / ``import_request``.  Where the
-family also gives ``reference_slot_state``, ``LLMServer.reference_state_check``
-holds a live slot's leaves against the plain float32 recurrence, as
-``reference_check`` holds served tokens against ``reference_logits``.
+boundary), rebuilds the state by recompute after a preemption and carries
+the slot's leaves in ``export_request`` / ``import_request``.
 
-A family that leaves ``decode_window`` or ``param_specs`` empty has no
-speculative verification window, or no tensor/pipeline-parallel layout: the
-engine refuses such a configuration at construction and names the family.
+**One signature.**  Both forward functions take and return the same things
+for every family (the fields' comments).  A state a family does not have is
+``{}`` and counters it does not book are None: empty pytrees, no parameter
+and no result of the engine's programs.  A keyword a family has no use for
+it ignores.  ``slot`` is the engine's slot of the chunk's sequence, ``take``
+the chunk's count of REAL tokens (the rest of its bucket is padding and must
+not advance the state); the state starts from zeros where ``p0 == 0`` (a
+re-used slot is never cleared), and ``decode_step`` leaves a row with
+``active == 0`` untouched: decode dispatches run between a sequence's prompt
+chunks.
 """
 
 from __future__ import annotations
@@ -56,15 +48,16 @@ class ModelFamily:
     # (cfg, max_seq) -> (cos, sin) device arrays the forward functions take
     rope_cache: Callable
     # (cfg, params, tokens [1, C], pool, table [1, W], p0, *, rope_cache,
-    #  tp_plan[, use_kernel, kernel_interpret: where prefill_kernel_fits]
-    #  [, slot_state, slot, take: where init_slot_state])
-    #  -> (logits [1, C, V] f32, pool[, slot_state])
+    #  tp_plan, use_kernel, kernel_interpret, slot_state, slot, take)
+    #  -> (logits [1, C, V] f32, pool, slot_state)
+    # ``use_kernel`` is the engine's choice of the decode kernel: the family
+    # combines it with its own ``prefill_kernel_fits``
     prefill_chunk: Callable
     # (cfg, params, tokens [B], pool, table [B, W], lengths [B], *,
-    #  rope_cache, use_kernel, mesh, kernel_interpret, tp_plan, active
-    #  [, slot_state: where init_slot_state])
-    #  -> (logits [B, V] f32, pool[, slot_state]
-    #      [, counters i32[len(decode_counters)]])
+    #  rope_cache, use_kernel, mesh, kernel_interpret, tp_plan, active,
+    #  slot_state)
+    #  -> (logits [B, V] f32, pool, slot_state,
+    #      counters i32[len(decode_counters)] or None)
     decode_step: Callable
     # (cfg) -> bool: the decode kernel applies on this backend
     kernel_supported: Callable
@@ -88,7 +81,7 @@ class ModelFamily:
     # None where they never do; None: the family has no expert layers
     prefill_grouped_from: Optional[Callable] = None
     # engine counters a decode token-step books: names of the int32 vector
-    # ``decode_step`` returns as its third value (summed over the chunk)
+    # ``decode_step`` returns as its fourth value (summed over the chunk)
     decode_counters: Tuple[str, ...] = ()
     # (cfg, max_batch) -> {leaf: [layers, max_batch, ...]}: the state a SLOT
     # holds (module docstring); None: the family's only state is the pool

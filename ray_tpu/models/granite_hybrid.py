@@ -496,18 +496,21 @@ def ssd_chunked(cfg: GraniteHybridConfig, x, delta, a, bm, cm, s0):
 
 def prefill_chunk_paged(cfg: GraniteHybridConfig, params: Params,
                         tokens: jnp.ndarray, pool: Dict[str, jnp.ndarray],
-                        table: jnp.ndarray, p0: jnp.ndarray,
-                        rope_cache=None, tp_plan=None, *, slot_state,
-                        slot, take):
+                        table: jnp.ndarray, p0: jnp.ndarray, *,
+                        rope_cache=None, tp_plan=None,
+                        use_kernel: bool = False,
+                        kernel_interpret: bool = False, slot_state, slot,
+                        take):
     """One chunk of one sequence (the Llama chunk's contract, models/llama.py)
     plus the slot's state: ``slot`` is the engine's slot, ``take`` the count
     of REAL tokens in ``tokens [1, C]``.  The state comes in from the slot
     (zeros where ``p0 == 0``) and the state after token ``take - 1`` goes
-    back; ``take == 0`` (warm-up) leaves the slot as it was.  Returns
+    back; ``take == 0`` (warm-up) leaves the slot as it was.  The chunk has
+    no kernel of its own (``use_kernel`` is not read).  Returns
     ``(logits [1, C, V] float32, pool, slot_state)``."""
     from ray_tpu.models.llama import PREFILL_KV_TILE, _prefill_attend_tiles
 
-    del rope_cache, tp_plan
+    del rope_cache, tp_plan, use_kernel, kernel_interpret
     _, c = tokens.shape
     bs = pool["k"].shape[2]
     cdt = cfg.compute_dtype
@@ -649,14 +652,15 @@ def recurrent_step_jnp(cfg: GraniteHybridConfig, lp, z, xbc, dt, ssm, win,
 
 def decode_step_paged(cfg: GraniteHybridConfig, params: Params,
                       tokens: jnp.ndarray, pool: Dict[str, jnp.ndarray],
-                      table: jnp.ndarray, lengths: jnp.ndarray,
+                      table: jnp.ndarray, lengths: jnp.ndarray, *,
                       rope_cache=None, use_kernel: bool = False, mesh=None,
                       kernel_interpret: bool = False, tp_plan=None,
-                      active: Optional[jnp.ndarray] = None, *, slot_state):
+                      active: Optional[jnp.ndarray] = None, slot_state):
     """One token for every slot (the Llama step's contract) plus the slots'
     state: a row with ``active == 0`` keeps its recurrent state and its
     convolution window bit for bit, whatever its token is.  Returns
-    ``(logits [B, V] float32, pool, slot_state)``."""
+    ``(logits [B, V] float32, pool, slot_state, None)``: the family books
+    no counters."""
     from ray_tpu.models.llama import _paged_attend
 
     del rope_cache, mesh, tp_plan
@@ -733,7 +737,7 @@ def decode_step_paged(cfg: GraniteHybridConfig, params: Params,
         cfg, params, carry, mamba_layer, attn_layer,
         None if use_kernel else conv)
     return (_head(cfg, params, x), {"k": pk, "v": pv},
-            {"ssm": ssm, "conv": kept if use_kernel else rebuilt})
+            {"ssm": ssm, "conv": kept if use_kernel else rebuilt}, None)
 
 
 # -- the family seam (models/family.py) -------------------------------------------------

@@ -589,10 +589,10 @@ def kda_chunked(q, k, v, g, beta, s0, chunk: int):
 
 def prefill_chunk_paged(cfg: KimiLinearConfig, params: Params,
                         tokens: jnp.ndarray, pool: Dict[str, jnp.ndarray],
-                        table: jnp.ndarray, p0: jnp.ndarray,
+                        table: jnp.ndarray, p0: jnp.ndarray, *,
                         rope_cache=None, tp_plan=None,
                         use_kernel: bool = False,
-                        kernel_interpret: bool = False, *, slot_state, slot,
+                        kernel_interpret: bool = False, slot_state, slot,
                         take, kv_tile: int = PREFILL_KV_TILE):
     """One chunk of one sequence (``pangu_moe.prefill_chunk_paged``'s
     contract: the chunk's latent rows written to the pool, attention over
@@ -604,6 +604,7 @@ def prefill_chunk_paged(cfg: KimiLinearConfig, params: Params,
     leaves the slot as it was).  ``kv_tile`` is for tests.  Returns ``(logits
     [1, C, V] float32, pool, slot_state)``."""
     del rope_cache, tp_plan
+    use_kernel = use_kernel and pm.prefill_kernel_fits(cfg)
     _, c = tokens.shape
     bs = pool["ckv"].shape[2]
     if kv_tile % bs:
@@ -703,10 +704,10 @@ def kernel_supported(cfg: KimiLinearConfig) -> bool:
 
 def decode_step_paged(cfg: KimiLinearConfig, params: Params,
                       tokens: jnp.ndarray, pool: Dict[str, jnp.ndarray],
-                      table: jnp.ndarray, lengths: jnp.ndarray,
+                      table: jnp.ndarray, lengths: jnp.ndarray, *,
                       rope_cache=None, use_kernel: bool = False, mesh=None,
                       kernel_interpret: bool = False, tp_plan=None,
-                      active: Optional[jnp.ndarray] = None, *, slot_state):
+                      active: Optional[jnp.ndarray] = None, slot_state):
     """One token for every slot (``pangu_moe.decode_step_paged``'s contract
     over the latent pool, in absorbed form) plus the slots' state: a row with
     ``active == 0`` keeps its KDA state and its convolution window bit for

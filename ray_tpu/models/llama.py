@@ -79,19 +79,6 @@ class LlamaConfig:
         return cls(**kw)
 
     @classmethod
-    def llama3_70b(cls, **kw) -> "LlamaConfig":
-        return cls(
-            dim=8192, n_layers=80, n_heads=64, n_kv_heads=8, ffn_dim=28672, **kw
-        )
-
-    @classmethod
-    def llama32_1b(cls, **kw) -> "LlamaConfig":
-        return cls(
-            dim=2048, n_layers=16, n_heads=32, n_kv_heads=8, ffn_dim=8192,
-            tie_embeddings=True, **kw
-        )
-
-    @classmethod
     def tiny(cls, **kw) -> "LlamaConfig":
         """Test-sized config: runs in milliseconds on a CPU mesh."""
         kw.setdefault("vocab_size", 256)
@@ -561,12 +548,12 @@ def paged_kernel_supported(cfg: LlamaConfig) -> bool:
 
 def decode_step_paged(cfg: LlamaConfig, params: Params, tokens: jnp.ndarray,
                       pool: Dict[str, jnp.ndarray], table: jnp.ndarray,
-                      lengths: jnp.ndarray,
+                      lengths: jnp.ndarray, *,
                       rope_cache: Optional[tuple] = None,
                       use_kernel: bool = False, mesh=None,
                       kernel_interpret: bool = False,
                       tp_plan: Optional[TPPlan] = None,
-                      active: Optional[jnp.ndarray] = None):
+                      active: Optional[jnp.ndarray] = None, slot_state=None):
     """One-token decode for every slot, KV in a paged pool.
 
     tokens [B] int32; table [B, W] block ids covering each slot's sequence
@@ -582,8 +569,11 @@ def decode_step_paged(cfg: LlamaConfig, params: Params, tokens: jnp.ndarray,
     With ``mesh``, the kernel runs under shard_map with kv heads sharded
     over the "tensor" axis, so it composes with TP.  With ``tp_plan``, the per-layer partial-sum
     reductions route through the planner's chosen algorithm explicitly
-    (see :class:`TPPlan`).  Returns (logits [B, V] fp32, updated pool).
+    (see :class:`TPPlan`).  Returns (logits [B, V] fp32, updated pool, ``{}``,
+    None): the family seam's four (models/family.py), of which this family
+    has no slot state (``slot_state`` is not read) and books no counters.
     """
+    del slot_state
     if rope_cache is None:
         cos, sin = rope_frequencies(cfg.head_dim, cfg.max_seq_len, cfg.rope_theta)
         cos, sin = jnp.asarray(cos), jnp.asarray(sin)
@@ -673,7 +663,7 @@ def decode_step_paged(cfg: LlamaConfig, params: Params, tokens: jnp.ndarray,
         x = rms_norm(x, params["final_norm"], cfg.rms_norm_eps)
         head = params["embed"].T if cfg.tie_embeddings else params["lm_head"]
         logits = (x @ head.astype(cdt)).astype(jnp.float32)
-    return logits, {"k": ks, "v": vs}
+    return logits, {"k": ks, "v": vs}, {}, None
 
 
 def decode_window_paged(cfg: LlamaConfig, params: Params,
@@ -779,9 +769,12 @@ def decode_window_paged(cfg: LlamaConfig, params: Params,
 
 def prefill_chunk_paged(cfg: LlamaConfig, params: Params, tokens: jnp.ndarray,
                         pool: Dict[str, jnp.ndarray], table: jnp.ndarray,
-                        p0: jnp.ndarray,
+                        p0: jnp.ndarray, *,
                         rope_cache: Optional[tuple] = None,
                         tp_plan: Optional[TPPlan] = None,
+                        use_kernel: bool = False,
+                        kernel_interpret: bool = False, slot_state=None,
+                        slot=None, take=None,
                         kv_tile: int = PREFILL_KV_TILE):
     """Prefill ONE chunk of a single sequence into its pool blocks.
 
@@ -796,8 +789,12 @@ def prefill_chunk_paged(cfg: LlamaConfig, params: Params, tokens: jnp.ndarray,
     ``_prefill_attend_tiles`` visits the table's first cdiv(p0 + C, kv_tile)
     tiles and no others.  ``kv_tile`` is for tests (several tiles at a tiny
     ``max_seq_len``); every caller in the tree leaves the default.
-    Returns (logits [1, C, V] fp32, updated pool).
+    Returns (logits [1, C, V] fp32, updated pool, ``{}``): the family seam's
+    three (models/family.py).  The chunk has no kernel of its own and the
+    family no slot state: ``use_kernel``, ``kernel_interpret``,
+    ``slot_state``, ``slot`` and ``take`` are not read.
     """
+    del use_kernel, kernel_interpret, slot_state, slot, take
     if rope_cache is None:
         cos, sin = rope_frequencies(cfg.head_dim, cfg.max_seq_len, cfg.rope_theta)
         cos, sin = jnp.asarray(cos), jnp.asarray(sin)
@@ -863,7 +860,7 @@ def prefill_chunk_paged(cfg: LlamaConfig, params: Params, tokens: jnp.ndarray,
         x = rms_norm(x, params["final_norm"], cfg.rms_norm_eps)
         head = params["embed"].T if cfg.tie_embeddings else params["lm_head"]
         logits = (x @ head.astype(cdt)).astype(jnp.float32)
-    return logits, {"k": ks, "v": vs}
+    return logits, {"k": ks, "v": vs}, {}
 
 
 def flops_per_token(cfg: LlamaConfig, seq_len: int) -> float:

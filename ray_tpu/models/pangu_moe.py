@@ -724,10 +724,11 @@ def _stacks(cfg: PanguMoEConfig, params):
 
 def prefill_chunk_paged(cfg: PanguMoEConfig, params: Params,
                         tokens: jnp.ndarray, pool: Dict[str, jnp.ndarray],
-                        table: jnp.ndarray, p0: jnp.ndarray,
+                        table: jnp.ndarray, p0: jnp.ndarray, *,
                         rope_cache: Optional[tuple] = None, tp_plan=None,
                         use_kernel: bool = False,
-                        kernel_interpret: bool = False,
+                        kernel_interpret: bool = False, slot_state=None,
+                        slot=None, take=None,
                         kv_tile: int = PREFILL_KV_TILE):
     """Prefill ONE chunk of a single sequence into its pool blocks.
 
@@ -735,13 +736,17 @@ def prefill_chunk_paged(cfg: PanguMoEConfig, params: Params,
     multiple of the block size, tail padded), ``p0`` the global position of
     the first (a multiple of the block size), table ``[1, W]`` covering
     ``[0, p0 + C)``.  The chunk's latent rows are written to the pool and
-    attention reads the whole prefix back a tile at a time: ``use_kernel``:
+    attention reads the whole prefix back a tile at a time: ``use_kernel``
+    (the engine's choice of the decode kernel) where ``prefill_kernel_fits``:
     inside the Pallas kernel (``_attend_kernel``), else in ``jax.numpy``
     (``_attend_tiles_expanded``).  ``kv_tile`` is for tests (a toy prefix
     spans several tiles only at a small one); every caller in the tree
-    leaves the default.  Returns (logits [1, C, V] float32, pool).
+    leaves the default.  Returns (logits [1, C, V] float32, pool, ``{}``):
+    the family has no slot state.
     """
-    del tp_plan  # the family supplies no tensor-parallel layout
+    # no tensor-parallel layout, no slot state
+    del tp_plan, slot_state, slot, take
+    use_kernel = use_kernel and prefill_kernel_fits(cfg)
     cos, sin = (rope_cache if rope_cache is not None
                 else make_rope_cache(cfg, cfg.max_seq_len))
     b, c = tokens.shape
@@ -788,28 +793,30 @@ def prefill_chunk_paged(cfg: PanguMoEConfig, params: Params,
         n = jax.tree.leaves(stack)[0].shape[0]
         (x, ckv), _ = lax.scan(body, (x, ckv),
                                (stack, first + jnp.arange(n)))
-    return _head(cfg, params, x), {"ckv": ckv}
+    return _head(cfg, params, x), {"ckv": ckv}, {}
 
 
 def decode_step_paged(cfg: PanguMoEConfig, params: Params,
                       tokens: jnp.ndarray, pool: Dict[str, jnp.ndarray],
-                      table: jnp.ndarray, lengths: jnp.ndarray,
+                      table: jnp.ndarray, lengths: jnp.ndarray, *,
                       rope_cache: Optional[tuple] = None,
                       use_kernel: bool = False, mesh=None,
                       kernel_interpret: bool = False, tp_plan=None,
-                      active: Optional[jnp.ndarray] = None):
+                      active: Optional[jnp.ndarray] = None, slot_state=None):
     """One-token decode for every slot over the latent pool, in absorbed
     form.  The contract of ``llama.decode_step_paged``; ``use_kernel``: the
     Pallas kernel over the latent pool (the live pages of the decoding rows
     only), else a gather of the table's span.  Returns (logits [B, V]
-    float32, pool, counters int32: ``DECODE_COUNTERS`` of this
+    float32, pool, ``{}`` (no slot state), counters int32:
+    ``DECODE_COUNTERS`` of this
     token-step over the rows with ``active`` != 0 (None: all)).  The expert
     layers compute the held experts' part for those rows alone
     (``moe_ffn``'s ``live``): a row with ``active`` 0 comes out with its
     routed part zero, and its logits mean nothing (they never did: its token
     is stale).
     """
-    del mesh, tp_plan  # the family supplies no tensor-parallel layout
+    # no tensor-parallel layout, no slot state
+    del mesh, tp_plan, slot_state
     cos, sin = (rope_cache if rope_cache is not None
                 else make_rope_cache(cfg, cfg.max_seq_len))
     b = tokens.shape[0]
@@ -866,7 +873,7 @@ def decode_step_paged(cfg: PanguMoEConfig, params: Params,
         n = jax.tree.leaves(stack)[0].shape[0]
         (x, ckv, booked), _ = lax.scan(
             body, (x, ckv, booked), (stack, first + jnp.arange(n)))
-    return _head(cfg, params, x[:, 0]), {"ckv": ckv}, booked
+    return _head(cfg, params, x[:, 0]), {"ckv": ckv}, {}, booked
 
 
 def _prefill_visited_pages(p0: int, chunk: int, block_size: int) -> int:

@@ -28,15 +28,16 @@ reduction.  With the state as ``[head, p, n]`` the same sum is 4,096 lane
 reductions a row, several times what the row's 5 us of HBM traffic allows.
 :func:`pack_state` / :func:`unpack_state` convert.
 
-**Two calls, one loop.**  :func:`ssm_state_update` is the state's update
-alone (``decay``, ``dt * x``, ``B`` and ``C`` computed by the caller for every
-slot).  :func:`ssm_layer_step` is what the model's decode step calls: the
-same loop over the live rows, and inside a row's turn EVERYTHING a Mamba
-layer does between its in-projection and its out-projection: the four-tap
-convolution over the slot's window and ``silu``, ``delta`` and ``decay``, the
-state's update, ``D x``, the gate and its norm.  As ``jax.numpy`` that was
-some twenty operations a layer on arrays that fit in fast memory, a launch
-each, over all 64 slots, and the layer's windows rebuilt whoever decoded.
+**One call a layer.**  :func:`ssm_layer_step` is what the model's decode step
+calls: the loop over the live rows, and inside a row's turn EVERYTHING a
+Mamba layer does between its in-projection and its out-projection: the
+four-tap convolution over the slot's window and ``silu``, ``delta`` and
+``decay``, the state's update, ``D x``, the gate and its norm.  As
+``jax.numpy`` that was some twenty operations a layer on arrays that fit in
+fast memory, a launch each, over all 64 slots, and the layer's windows rebuilt
+whoever decoded.  (A kernel of the state's update alone, the rest left to
+``jax.numpy``, came first and went at PR 50: PERF.md section 6, PRs 36 and
+44, has its measurements.)
 The window (the last 3 inputs, bf16) is the second leaf the call takes whole
 and aliases: **at rest ``[layers, rows, taps, tiles, 128]``**, a tap's
 channels 128 a sublane row and the rows padded to whole 16-row memory tiles
@@ -113,11 +114,12 @@ def ssm_state_update_jnp(state, layer, decay, xdt, b, c, active):
 
 
 def _each_live_row(rows_ref, n, fetches, stores, compute):
-    """The loop both kernels run: ``compute(r, slot)`` for the ``n`` live
-    rows ``r = rows_ref[k]``, row ``k + 1``'s copies in (``fetches(k, slot)``:
-    a list of async copies into buffer ``slot``) started before row ``k`` is
-    computed and row ``k``'s copies out (``stores(k, slot)``) waited for only
-    when row ``k + 2`` needs the buffer."""
+    """The loop this kernel and ``ops/kda_state_update.py``'s run:
+    ``compute(r, slot)`` for the ``n`` live rows ``r = rows_ref[k]``, row
+    ``k + 1``'s copies in (``fetches(k, slot)``: a list of async copies into
+    buffer ``slot``) started before row ``k`` is computed and row ``k``'s
+    copies out (``stores(k, slot)``) waited for only when row ``k + 2`` needs
+    the buffer."""
 
     def start(copies):
         for c in copies:
@@ -188,79 +190,9 @@ def _update_tiles(ibuf, obuf, slot, decay_at, xdt_at, bmat, cmat, y_at,
     lax.fori_loop(0, tiles // unroll, some_tiles, 0)
 
 
-def _kernel(rows_ref, n_ref, layer_ref, decay_ref, xdt_ref, b_ref, c_ref,
-            s_in, y_ref, s_out, ibuf, obuf, isem, osem, *, unroll):
-    """One grid step: a loop over the live rows.  ibuf / obuf ``[2, T, N,
-    128]``: a row's state as it came and as it leaves; isem / osem ``[2]``."""
-    li = layer_ref[0]
-
-    def fetch(k, slot):
-        return [pltpu.make_async_copy(
-            s_in.at[li, rows_ref[k]], ibuf.at[slot], isem.at[slot])]
-
-    def store(k, slot):
-        return [pltpu.make_async_copy(
-            obuf.at[slot], s_out.at[li, rows_ref[k]], osem.at[slot])]
-
-    def compute(r, slot):
-        def y_at(t, value):
-            y_ref[r, pl.ds(t, 1), :] = value
-
-        _update_tiles(
-            ibuf, obuf, slot, lambda t: decay_ref[r, pl.ds(t, 1), :],
-            lambda t: xdt_ref[r, pl.ds(t, 1), :],
-            _columns(b_ref[pl.ds(r, 1), :]), _columns(c_ref[pl.ds(r, 1), :]),
-            y_at, unroll)
-
-    _each_live_row(rows_ref, n_ref[0], fetch, store, compute)
-
-
 def _whole(shape):
     """The BlockSpec of an operand taken whole into fast memory."""
     return pl.BlockSpec(shape, lambda i, *_: (0,) * len(shape))
-
-
-def ssm_state_update(state, layer, decay, xdt, b, c, active, live=None, *,
-                     interpret=False):
-    """:func:`ssm_state_update_jnp` as a Pallas kernel, the leaf updated in
-    place (input and output aliased: the caller donates it).  ``live``:
-    :func:`live_rows` of ``active``, where the caller has it already (once a
-    token-step, not once a layer)."""
-    _, r, t, n, lanes = state.shape
-    rows, n_live = live_rows(active) if live is None else live
-    f32 = jnp.float32
-
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=3,
-        grid=(1,),
-        in_specs=[_whole((r, t, lanes)), _whole((r, t, lanes)), _whole((r, n)),
-                  _whole((r, n)), pl.BlockSpec(memory_space=pl.ANY)],
-        out_specs=[_whole((r, t, lanes)), pl.BlockSpec(memory_space=pl.ANY)],
-        scratch_shapes=[
-            pltpu.VMEM((2, t, n, lanes), state.dtype),
-            pltpu.VMEM((2, t, n, lanes), state.dtype),
-            pltpu.SemaphoreType.DMA((2,)),
-            pltpu.SemaphoreType.DMA((2,)),
-        ],
-    )
-    y, state = pl.pallas_call(
-        functools.partial(_kernel, unroll=4 if t % 4 == 0 else 1),
-        grid_spec=grid_spec,
-        out_shape=[jax.ShapeDtypeStruct((r, t, lanes), f32),
-                   jax.ShapeDtypeStruct(state.shape, state.dtype)],
-        input_output_aliases={7: 1},  # the leaf, after the 3 prefetched
-        compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("arbitrary",),
-            vmem_limit_bytes=64 << 20),
-        interpret=interpret,
-        name="ssm_state_update",  # the kernel's name in a profiler trace
-    )(rows, n_live.reshape(1), jnp.asarray(layer, jnp.int32).reshape(1),
-      decay.astype(f32).reshape(r, t, lanes),
-      xdt.astype(f32).reshape(r, t, lanes), b.astype(f32), c.astype(f32),
-      state)
-    # a row that did not decode was never written: whatever its lanes hold
-    y = jnp.where((active != 0)[:, None], y.reshape(r, t * lanes), 0.0)
-    return y, state
 
 
 # -- the whole layer-step between the two projections ------------------------------

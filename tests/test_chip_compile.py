@@ -221,17 +221,18 @@ def test_decode_chunk_program_transposes_no_weights(one_chip, quantum):
 
     cfg, params, pool = _cell_model(one_chip, one_chip)
     steps = LLMConfig().decode_chunk if quantum == "default" else quantum
-    compiled = _engine_programs(cfg, params, pool, one_chip, 64, 32, steps,
-                                256, 264)[0].compile()
+    compiled = _engine_programs(cfg, params, pool, {}, one_chip, 64, 32,
+                                steps, 256, 264)[0].compile()
     assert "tpu_custom_call" in compiled.as_text()
     assert compiled.memory_analysis().temp_size_in_bytes < 64 << 20
 
 
-def _engine_programs(cfg, params, pool, sh, b, w, steps, c, prefill_w):
+def _engine_programs(cfg, params, pool, state, sh, b, w, steps, c, prefill_w):
     """The engine's own two programs, lowered: ``(decode chunk of ``steps``
     over batch ``b`` and a ``w``-block table, prefill chunk of ``c`` tokens
-    over a ``prefill_w``-block table)``.  ``self`` is a stand-in: nothing
-    can be placed on a described device."""
+    over a ``prefill_w``-block table)``.  ``state``: the family's slot state
+    (``{}``: it has none).  ``self`` is a stand-in: nothing can be placed on
+    a described device."""
     import types
 
     from ray_tpu.llm.engine import _MAX_STOP_IDS
@@ -250,15 +251,16 @@ def _engine_programs(cfg, params, pool, sh, b, w, steps, c, prefill_w):
 
     decode = jax.jit(
         functools.partial(PagedJaxLLMEngine._decode_chunk_impl, eng),
-        donate_argnums=2, static_argnums=11).lower(
+        donate_argnums=(2, 12), static_argnums=11).lower(
             params, i32(b), pool, i32(b, w), i32(b), i32(b), i32(b),
             i32(b, _MAX_STOP_IDS), key, _spec((b,), jnp.float32, sh), i32(b),
-            steps)
+            steps, state)
     prefill = jax.jit(
         functools.partial(PagedJaxLLMEngine._prefill_chunk_impl, eng),
-        donate_argnums=2).lower(
+        donate_argnums=(2, 9)).lower(
             params, i32(1, c), pool, i32(1, prefill_w), i32(), i32(), key,
-            _spec((1,), jnp.float32, sh), i32(1))
+            _spec((1,), jnp.float32, sh), i32(1), state,
+            (i32(), i32()) if state else (None, None))
     return decode, prefill
 
 
@@ -287,33 +289,64 @@ def test_join_program_compiles_at_cell_shapes(one_chip):
     assert compiled.memory_analysis().temp_size_in_bytes < 1 << 20
 
 
-# what the two programs of the Llama family lowered to on the commit before
-# the family seam (PR 31), at the Mistral cell's shapes: sha256 of the
-# StableHLO text less the line of the Pallas kernel's call (its payload
-# carries the source lines of its callers, which any edit above them moves;
-# the kernel has tests of its own).  The seam moves models/llama.py behind a
-# table of functions
-# and must change nothing the device runs.  A PR that changes what
-# models/llama.py computes replaces these (print the digests below); PR 48
-# did, for the sampler's conditional (llm/engine.py), which both
-# programs end in.
-_LLAMA_HLO_BEFORE_THE_SEAM = {
-    "decode": "de9acd447f92dd14111112d90d20458dd3532760599dd9f1fbbf72232655bc7d",
-    "prefill": "8960792292dc1c1357a282ec0640bfeaa015d77463266408165d83732c91d2f1",
+# what the engine's two programs lower to for each family, at the shapes of
+# the family's cell: sha256 of the StableHLO text less the line of a Pallas
+# kernel's call (its payload carries the source lines of its callers, which
+# any edit above them moves; the kernels have tests of their own).  Llama's
+# two are from the commit before the family seam (PR 31), which moved
+# models/llama.py behind a table of functions; the other six are from the
+# commit before PR 50 gave the seam's functions one signature (an empty slot
+# state and absent counters are empty pytrees: no parameter, no result).
+# Neither may change anything the device runs.  A PR that changes what a
+# family computes replaces its two (print ``got`` below); PR 48 did, for the
+# sampler's conditional (llm/engine.py), which every program ends in.
+_PROGRAM_HLO = {
+    ("llama", "decode"):
+        "de9acd447f92dd14111112d90d20458dd3532760599dd9f1fbbf72232655bc7d",
+    ("llama", "prefill"):
+        "8960792292dc1c1357a282ec0640bfeaa015d77463266408165d83732c91d2f1",
+    ("pangu_moe", "decode"):
+        "6191fbf70e2e97344535d9f0ad50a4ef6b17d8d94e844b4ebf0d308fa930b7c6",
+    ("pangu_moe", "prefill"):
+        "ae2f8b4925fb4f7e79b9b375f79a57446b52470b7d6e91406c1aa5cd9d410ef9",
+    ("granite_hybrid", "decode"):
+        "a6f39b63cf8c7af3ed627a912165c577b279b1e779c983a2cf06ed48c88c49ec",
+    ("granite_hybrid", "prefill"):
+        "37f39b95e186e892bc277d876efb77810283e3fd363e8d5ddecf698b06b895a6",
+    ("kimi_linear", "decode"):
+        "38e933947dfd2a19f6449661a89d36854ed0e73ed6fbb23029cc662ac3af848b",
+    ("kimi_linear", "prefill"):
+        "828bbe5abe38ba2d403c5dd1b7db1371ddb660ae4c47f0d76952ffefeec6ee1a",
 }
 
 
-def test_llama_programs_lower_to_the_hlo_from_before_the_family_seam(one_chip):
+def _family_programs(family, sh):
+    """``{"decode", "prefill"}`` of ``family``'s cell, lowered, at the shapes
+    its program test compiles: 64 rows, two token-steps."""
+    if family == "llama":
+        return _engine_programs(*_cell_model(sh, sh), {}, sh, 64, 32, 2, 256,
+                                264)
+    if family == "pangu_moe":
+        return _engine_programs(*_pangu_cell(sh), sh, 64, 1024, 2, 1024, 641)
+    if family == "granite_hybrid":
+        return _engine_programs(*_granite_cell(sh), sh, 64, 32, 2, 256, 264)
+    return _engine_programs(*_kimi_cell(sh), sh, 64, 32, 2, 256, 384)
+
+
+@pytest.mark.parametrize("family,program", sorted(_PROGRAM_HLO))
+def test_family_programs_lower_to_the_pinned_hlo(one_chip, monkeypatch,
+                                                 family, program):
     import hashlib
 
-    cfg, params, pool = _cell_model(one_chip, one_chip)
-    decode, prefill = _engine_programs(cfg, params, pool, one_chip, 64, 32,
-                                       2, 256, 264)
-    got = {name: hashlib.sha256("\n".join(
-        line for line in lo.as_text().split("\n")
+    if family in ("pangu_moe", "kimi_linear"):
+        # the expert layers ask the backend, which is the CPU here
+        monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    lowered = dict(zip(("decode", "prefill"),
+                       _family_programs(family, one_chip)))[program]
+    got = hashlib.sha256("\n".join(
+        line for line in lowered.as_text().split("\n")
         if "tpu_custom_call" not in line).encode()).hexdigest()
-        for name, lo in (("decode", decode), ("prefill", prefill))}
-    assert got == _LLAMA_HLO_BEFORE_THE_SEAM
+    assert got == _PROGRAM_HLO[family, program], (family, program, got)
 
 
 def _outside_conditionals(text):
@@ -360,7 +393,7 @@ def test_decode_program_keeps_the_samplers_top_k_inside_a_conditional(
     from ray_tpu.llm import LLMConfig
 
     cfg, params, pool = _cell_model(one_chip, one_chip)
-    text = _engine_programs(cfg, params, pool, one_chip, 64, 32,
+    text = _engine_programs(cfg, params, pool, {}, one_chip, 64, 32,
                             LLMConfig().decode_chunk, 256,
                             264)[0].compile().as_text()
     always = "\n".join(_outside_conditionals(text))
@@ -385,7 +418,7 @@ def _pangu_cell(sh):
     params = jax.tree.map(lambda x: _spec(x.shape, x.dtype, sh), shapes)
     pool = {"ckv": _spec((cfg.n_layers, 33000, 16, cfg.cache_width), BF16,
                          sh)}
-    return cfg, params, pool
+    return cfg, params, pool, {}
 
 
 @pytest.mark.parametrize("w", [64, 1024])
@@ -448,9 +481,7 @@ def test_latent_family_programs_compile_at_cell_shapes(one_chip, monkeypatch):
     dense product too for a token-step whose pairs overflow the buffer,
     keeps no copy of a layer's held experts (1.5 GB) for either."""
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
-    cfg, params, pool = _pangu_cell(one_chip)
-    decode, prefill = _engine_programs(cfg, params, pool, one_chip, 64, 1024,
-                                       2, 1024, 641)
+    decode, prefill = _family_programs("pangu_moe", one_chip)
     compiled = decode.compile()
     names = _custom_call_names(compiled.as_text())
     assert "mla_paged_attention" in names
@@ -485,19 +516,41 @@ def _granite_cell(sh):
             specs(lambda: gh.init_slot_state(cfg, 64)))
 
 
-@pytest.mark.parametrize("slots", [64, 16])
-def test_ssm_state_update_kernel_compiles_at_cell_shapes(one_chip, slots):
-    """The cell's 64 slots, and a quarter of them (the leaf is taken whole
-    and the loop runs over the live rows: nothing is sized by the slots)."""
-    from ray_tpu.ops.ssm_state_update import ssm_state_update
+def _ssm_layer_step_args(slots, sh):
+    """``(eps, ssm_layer_step's operands as shapes)`` at the cell's widths
+    and ``slots`` slots: the two leaves, a layer's index, the in-projection's
+    rows and ``dt``, the stacked small parameters, ``active``."""
+    from ray_tpu.models import granite_hybrid as gh
+    from ray_tpu.ops.ssm_state_update import prepare_layer_params
 
-    f32 = jnp.float32
-    _assert_kernel(
-        ssm_state_update, _spec((36, slots, 32, 128, 128), f32, one_chip),
-        _spec((), jnp.int32, one_chip), _spec((slots, 4096), f32, one_chip),
-        _spec((slots, 4096), f32, one_chip), _spec((slots, 128), f32, one_chip),
-        _spec((slots, 128), f32, one_chip),
-        _spec((slots,), jnp.int32, one_chip))
+    cfg = gh.GraniteHybridConfig()
+
+    def operands():
+        mp = gh.init_params(cfg, jax.random.PRNGKey(0))["mamba"]
+        state = gh.init_slot_state(cfg, slots)
+        return (state["ssm"], state["conv"], jnp.int32(0),
+                jnp.zeros((slots, cfg.d_inner + cfg.conv_width),
+                          cfg.compute_dtype),
+                jnp.zeros((slots, cfg.mamba_n_heads), cfg.compute_dtype),
+                prepare_layer_params(
+                    mp["conv_w"], mp["conv_b"], mp["dt_bias"], mp["a_log"],
+                    mp["d"], mp["norm"], cfg.mamba_d_head),
+                jnp.zeros(slots, jnp.int32))
+
+    return cfg.rms_norm_eps, jax.tree.map(
+        lambda x: _spec(x.shape, x.dtype, sh), jax.eval_shape(operands))
+
+
+@pytest.mark.parametrize("slots", [64, 16])
+def test_ssm_layer_step_kernel_compiles_at_cell_shapes(one_chip, slots):
+    """The cell's 64 slots, and a quarter of them (the leaves are taken whole
+    and the loop runs over the live rows: nothing is sized by the slots)."""
+    from ray_tpu.ops.ssm_state_update import ssm_layer_step
+
+    eps, args = _ssm_layer_step_args(slots, one_chip)
+    names = _kernel_instructions(functools.partial(ssm_layer_step, eps=eps),
+                                 *args)
+    assert any("ssm_state_update" in n for n in names), names
 
 
 def test_paged_decode_attention_compiles_at_heads_of_64(one_chip):
@@ -518,39 +571,11 @@ def test_hybrid_family_programs_compile_at_cell_shapes(one_chip):
     a stacked weight or of the 4.8 GB state (before the layers indexed their
     weights out of the whole stacks and the in-projection was split at a lane
     tile, the temporaries were 2.7 and 7.7 GB)."""
-    import types
-
-    from ray_tpu.llm.engine import _MAX_STOP_IDS
-    from ray_tpu.llm.paged import PagedJaxLLMEngine
-    from ray_tpu.models.family import family_of
-
-    cfg, params, pool, state = _granite_cell(one_chip)
-    eng = types.SimpleNamespace(
-        cfg=cfg, family=family_of(cfg), max_seq=cfg.max_seq_len, mesh=None,
-        _rope=None, _use_kernel=True, _kernel_interpret=False, _tp_plan=None,
-        _tp_prefill_plan=None)
-    key = jax.eval_shape(lambda: jax.random.PRNGKey(0))
-    key = _spec(key.shape, key.dtype, one_chip)
-
-    def i32(*shape):
-        return _spec(shape, jnp.int32, one_chip)
-
-    b, w = 64, 32
-    decode = jax.jit(
-        functools.partial(PagedJaxLLMEngine._decode_chunk_impl, eng),
-        donate_argnums=(2, 12), static_argnums=11).lower(
-            params, i32(b), pool, i32(b, w), i32(b), i32(b), i32(b),
-            i32(b, _MAX_STOP_IDS), key, _spec((b,), jnp.float32, one_chip),
-            i32(b), 2, state).compile()
+    decode, prefill = (lo.compile() for lo in
+                       _family_programs("granite_hybrid", one_chip))
     names = _custom_call_names(decode.as_text())
     assert "ssm_state_update" in names and "paged_attention" in names
     assert decode.memory_analysis().temp_size_in_bytes < 256 << 20
-    prefill = jax.jit(
-        functools.partial(PagedJaxLLMEngine._prefill_chunk_impl, eng),
-        donate_argnums=(2, 9)).lower(
-            params, i32(1, 256), pool, i32(1, 264), i32(), i32(), key,
-            _spec((1,), jnp.float32, one_chip), i32(1), state,
-            (i32(), i32())).compile()
     assert prefill.memory_analysis().temp_size_in_bytes < 512 << 20
 
 
@@ -680,41 +705,13 @@ def test_kimi_family_programs_compile_at_cell_shapes(one_chip, monkeypatch):
     program keeps a copy of a stacked weight, of the 2.7 GB state or of the
     pool.  (The model asks the backend, which is the CPU here, so the test
     answers for it.)"""
-    import types
-
-    from ray_tpu.llm.engine import _MAX_STOP_IDS
-    from ray_tpu.llm.paged import PagedJaxLLMEngine
-    from ray_tpu.models.family import family_of
-
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
-    cfg, params, pool, state = _kimi_cell(one_chip)
-    eng = types.SimpleNamespace(
-        cfg=cfg, family=family_of(cfg), max_seq=cfg.max_seq_len, mesh=None,
-        _rope=None, _use_kernel=True, _kernel_interpret=False, _tp_plan=None,
-        _tp_prefill_plan=None)
-    key = jax.eval_shape(lambda: jax.random.PRNGKey(0))
-    key = _spec(key.shape, key.dtype, one_chip)
-
-    def i32(*shape):
-        return _spec(shape, jnp.int32, one_chip)
-
-    b, w = 64, 32
-    decode = jax.jit(
-        functools.partial(PagedJaxLLMEngine._decode_chunk_impl, eng),
-        donate_argnums=(2, 12), static_argnums=11).lower(
-            params, i32(b), pool, i32(b, w), i32(b), i32(b), i32(b),
-            i32(b, _MAX_STOP_IDS), key, _spec((b,), jnp.float32, one_chip),
-            i32(b), 2, state).compile()
+    decode, prefill = (lo.compile() for lo in
+                       _family_programs("kimi_linear", one_chip))
     names = _custom_call_names(decode.as_text())
     assert "kda_state_update" in names and "mla_paged_attention" in names
     assert "moe_grouped_ffn_up" in names and "moe_grouped_ffn_down" in names
     assert decode.memory_analysis().temp_size_in_bytes < 256 << 20
-    prefill = jax.jit(
-        functools.partial(PagedJaxLLMEngine._prefill_chunk_impl, eng),
-        donate_argnums=(2, 9)).lower(
-            params, i32(1, 256), pool, i32(1, 384), i32(), i32(), key,
-            _spec((1,), jnp.float32, one_chip), i32(1), state,
-            (i32(), i32())).compile()
     names = _custom_call_names(prefill.as_text())
     assert "mla_prefill_attention" in names
     assert "moe_grouped_ffn_up" in names and "moe_grouped_ffn_down" in names
@@ -795,23 +792,18 @@ def test_kernel_name_reaches_the_compiled_instruction(one_chip, kernel):
 
         args = _paged_args(16, 8, one_chip, one_chip, one_chip)
     elif kernel == "ssm_state_update":
-        from ray_tpu.ops.ssm_state_update import ssm_state_update
+        from ray_tpu.ops.ssm_state_update import ssm_layer_step
 
-        def fn(state, decay, xdt, b, c, active):
-            def body(state, li):
+        eps, args = _ssm_layer_step_args(8, one_chip)
+
+        def fn(state, window, _, proj, dt, prep, active):
+            def body(leaves, li):
                 with jax.named_scope("ssm"):
-                    y, state = ssm_state_update(state, li, decay, xdt, b, c,
-                                                active)
-                return state, y
+                    y, *leaves = ssm_layer_step(*leaves, li, proj, dt, prep,
+                                                active, eps=eps)
+                return tuple(leaves), y
 
-            return jax.lax.scan(body, state, jnp.arange(2))
-
-        f32 = jnp.float32
-        args = (_spec((2, 8, 32, 128, 128), f32, one_chip),
-                _spec((8, 4096), f32, one_chip),
-                _spec((8, 4096), f32, one_chip),
-                _spec((8, 128), f32, one_chip), _spec((8, 128), f32, one_chip),
-                _spec((8,), jnp.int32, one_chip))
+            return jax.lax.scan(body, (state, window), jnp.arange(2))
     else:
         from ray_tpu.ops.flash_attention import flash_attention
 

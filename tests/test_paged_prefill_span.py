@@ -56,8 +56,8 @@ def _fill_prefix(chunk_fn, params, pool, table, tokens, upto):
     """Positions [0, upto) through the function under test, a block a call
     (one program); the reference judges what the chunk after it reads."""
     for p0 in range(0, upto, BS):
-        _, pool = chunk_fn(params, tokens[None, p0:p0 + BS], pool, table,
-                           jnp.int32(p0), kv_tile=TILE)
+        _, pool, _ = chunk_fn(params, tokens[None, p0:p0 + BS], pool, table,
+                              jnp.int32(p0), kv_tile=TILE)
     return pool
 
 
@@ -88,8 +88,8 @@ def test_chunk_matches_float32_reference(model, chunk_fn, p0, c):
     pool = llama.init_paged_kv_cache(cfg, NB, BS)
     pool = _fill_prefix(chunk_fn, params, pool, table, tokens, p0)
     before = jax.tree.map(np.asarray, pool)
-    logits, pool = chunk_fn(params, tokens[None, p0:], pool, table,
-                            jnp.int32(p0), kv_tile=TILE)
+    logits, pool, _ = chunk_fn(params, tokens[None, p0:], pool, table,
+                               jnp.int32(p0), kv_tile=TILE)
     want = np.asarray(llama_reference.reference_logits(cfg, params, tokens))
     np.testing.assert_allclose(np.asarray(logits[0]), want[p0:], atol=TOL)
     # the chunk's blocks hold its K and V; no other block was touched
@@ -117,14 +117,14 @@ def test_tiles_past_the_live_prefix_are_never_read(model, chunk_fn, p0, c):
     poison_block = NB - 1
     assert poison_block not in table
     pool = {n: a.at[:, poison_block].set(jnp.nan) for n, a in pool.items()}
-    clean, _ = chunk_fn(params, tokens[None, p0:], pool, table,
-                        jnp.int32(p0), kv_tile=TILE)
+    clean, _, _ = chunk_fn(params, tokens[None, p0:], pool, table,
+                           jnp.int32(p0), kv_tile=TILE)
     poisoned = table.copy()
     first_dead = math.ceil((p0 + c) / TILE) * TILE // BS
     assert first_dead < WIDTH
     poisoned[0, first_dead:] = poison_block
-    got, after = chunk_fn(params, tokens[None, p0:], pool, poisoned,
-                          jnp.int32(p0), kv_tile=TILE)
+    got, after, _ = chunk_fn(params, tokens[None, p0:], pool, poisoned,
+                             jnp.int32(p0), kv_tile=TILE)
     assert np.isfinite(np.asarray(got)).all()
     np.testing.assert_array_equal(np.asarray(got), np.asarray(clean))
     mine = table[0, :(p0 + c) // BS]
@@ -140,8 +140,8 @@ def test_prompt_that_fills_max_seq_runs_every_tile(model, chunk_fn):
     pool = llama.init_paged_kv_cache(cfg, NB, BS)
     p0 = MAX_SEQ - 64
     pool = _fill_prefix(chunk_fn, params, pool, table, tokens, p0)
-    logits, _ = chunk_fn(params, tokens[None, p0:], pool, table,
-                         jnp.int32(p0), kv_tile=TILE)
+    logits, _, _ = chunk_fn(params, tokens[None, p0:], pool, table,
+                            jnp.int32(p0), kv_tile=TILE)
     want = np.asarray(llama_reference.reference_logits(cfg, params, tokens))
     np.testing.assert_allclose(np.asarray(logits[0]), want[p0:], atol=TOL)
 

@@ -47,7 +47,7 @@ def _prefill(cfg, params, pool, toks, table, p0=0, chunk=16):
     for i in range(0, len(toks), chunk):
         part = toks[i:i + chunk]
         pad = part + [0] * (chunk - len(part))
-        logits, pool = pm.prefill_chunk_paged(
+        logits, pool, _ = pm.prefill_chunk_paged(
             cfg, params, jnp.asarray([pad], jnp.int32), pool, table,
             jnp.int32(p0 + i), rope_cache=rope, kv_tile=16)
         logits = logits[0, :len(part)]
@@ -78,7 +78,7 @@ def test_chunked_prefill_then_decode_agrees_with_reference(model, prefix_hit):
     np.testing.assert_allclose(logits, want[:12], atol=TOL)
     # decode, teacher-forced with fixed tokens
     for step, tok in enumerate(more):
-        logits, pool, booked = pm.decode_step_paged(
+        logits, pool, _, booked = pm.decode_step_paged(
             cfg, params, jnp.asarray([tok], jnp.int32), pool, table,
             jnp.asarray([44 + step], jnp.int32))
         np.testing.assert_allclose(logits[0], want[12 + step], atol=TOL)
@@ -96,7 +96,7 @@ def test_absorbed_attention_equals_expanded_on_the_same_cache(model):
     more, _ = _prefill(cfg, params, pool_39, toks[32:], table, p0=32)
     _, pool_39 = _prefill(cfg, params, pool_39, toks[32:39] + [0], table,
                           p0=32, chunk=8)
-    dec, _, _ = pm.decode_step_paged(
+    dec, _, _, _ = pm.decode_step_paged(
         cfg, params, jnp.asarray(toks[39:], jnp.int32), pool_39, table,
         jnp.asarray([39], jnp.int32))
     np.testing.assert_allclose(dec[0], more[-1], atol=TOL)
@@ -261,7 +261,7 @@ def test_prefill_chunk_with_grouped_expert_layers_equals_dense(sparse):
     run = lambda interpret: pm.prefill_chunk_paged(  # noqa: E731
         cfg, params, toks, pool, table, jnp.int32(0), rope_cache=rope,
         kernel_interpret=interpret)
-    (want, pool_want), (got, pool_got) = run(False), run(True)
+    (want, pool_want, _), (got, pool_got, _) = run(False), run(True)
     np.testing.assert_allclose(got, want, atol=TOL)
     np.testing.assert_allclose(pool_got["ckv"], pool_want["ckv"], atol=TOL)
 
@@ -306,8 +306,8 @@ def test_decode_step_with_grouped_expert_layers_equals_dense(sparse):
     cfg, params = sparse
     live = np.asarray(_LIVE) > 0
     batch = _decode_batch(cfg, _LIVE, 31, dead_tokens=7)
-    want, pool_want, booked_want = _decode(cfg, params, batch, False)
-    got, pool_got, booked = _decode(cfg, params, batch, True)
+    want, pool_want, _, booked_want = _decode(cfg, params, batch, False)
+    got, pool_got, _, booked = _decode(cfg, params, batch, True)
     np.testing.assert_allclose(got[live], want[live], atol=TOL)
     mine = np.asarray(batch[2])[live].ravel()
     np.testing.assert_allclose(pool_got["ckv"][:, mine],
@@ -318,7 +318,7 @@ def test_decode_step_with_grouped_expert_layers_equals_dense(sparse):
     assert 0 < booked.tolist()[1] <= booked.tolist()[2] <= (
         4 * cfg.n_experts_per_tok * cfg.n_moe_layers)
     other = _decode_batch(cfg, _LIVE, 31, dead_tokens=np.arange(100, 112))
-    again, pool_again, booked_again = _decode(cfg, params, other, True)
+    again, pool_again, _, booked_again = _decode(cfg, params, other, True)
     np.testing.assert_array_equal(again[live], got[live])
     np.testing.assert_array_equal(pool_again["ckv"][:, mine],
                                   pool_got["ckv"][:, mine])
@@ -378,8 +378,8 @@ def test_decode_rows_with_more_pairs_than_the_buffer_take_the_dense_product(
     monkeypatch.setattr(pm, "route", all_held)
     alive = np.asarray(live) > 0
     batch = _decode_batch(cfg, live, 41, dead_tokens=3)
-    want, _, booked_want = _decode(cfg, params, batch, False)
-    got, _, booked = _decode(cfg, params, batch, True)
+    want, _, _, booked_want = _decode(cfg, params, batch, False)
+    got, _, _, booked = _decode(cfg, params, batch, True)
     np.testing.assert_allclose(got[alive], want[alive], atol=TOL)
     pairs = 4 * int(alive.sum()) * cfg.n_moe_layers
     assert booked.tolist() == [cfg.n_held * cfg.n_moe_layers,
