@@ -41,7 +41,7 @@ import uuid
 from typing import Any, Dict, List, Optional, Sequence
 
 from ray_tpu.llm.config import GenerationConfig, LLMConfig
-from ray_tpu.llm.serve import LLMServer, _jax_backend
+from ray_tpu.llm.serve import LLMServer, _warm_up
 
 _HANDOFF_TIMEOUT_S = 600.0  # covers first-request jit compiles
 
@@ -68,8 +68,7 @@ class PrefillServer:
                                              speculative_config=None)
         self._config = llm_config
         self._engine = make_engine(llm_config, params)
-        if _jax_backend() == "tpu":
-            self._engine.warmup()
+        _warm_up(self._engine)
         self._inflight = 0
         self._lock = threading.Lock()
 

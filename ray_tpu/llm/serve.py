@@ -11,6 +11,7 @@ requests share one decode batch (continuous batching across callers).
 from __future__ import annotations
 
 import contextlib
+import gc
 import threading
 import time
 from typing import Any, Dict, List, Optional, Sequence  # noqa: F401
@@ -24,6 +25,25 @@ def _jax_backend() -> str:
     import jax
 
     return jax.default_backend()
+
+
+def _warm_up(engine) -> None:
+    """On a TPU backend: compile every decode (B, W) bucket before serving
+    traffic — a bucket transition otherwise costs a multi-second XLA
+    compile inside the latency path (vLLM warms shapes at startup the same
+    way) — then move the heap as it stands out of the cyclic collector's
+    reach (``gc.freeze``, as vLLM does after start-up): tracing and
+    compiling leave some hundred thousand long-lived containers (a family
+    whose layers differ in shape keeps a jaxpr a layer a program), and a
+    full collection that walks them holds the GIL, and so the engine loop
+    and every stream, for a quarter of a second (235 ms measured for the
+    17 programs of a 17-layer hybrid stack, PERF.md section 6, PR 51).
+    What is allocated from here on is collected as before."""
+    if _jax_backend() != "tpu":
+        return
+    engine.warmup()
+    gc.collect()
+    gc.freeze()
 
 
 class LLMServer:
@@ -47,12 +67,7 @@ class LLMServer:
         # when draft_params is None): per-adapter draft merges apply to
         # what actually runs, not the constructor argument
         self._draft_params = self._engine._draft_params
-        if _jax_backend() == "tpu":
-            # compile every decode (B, W) bucket before serving traffic —
-            # a bucket transition otherwise costs a multi-second XLA
-            # compile inside the latency path (vLLM warms shapes at
-            # startup the same way)
-            self._engine.warmup()
+        _warm_up(self._engine)
         self._engines: Dict[Optional[str], Any] = {None: self._engine}
         self._engine_gen: Dict[Optional[str], int] = {None: 0}
         self._engine_order: list = []  # adapter LRU (base never evicted)

@@ -13,7 +13,8 @@ engine copies, demotes, exports and imports it leaf by leaf and never looks
 inside a block.  A family may also declare a **slot state**
 (``init_slot_state``): a dict of arrays, every leaf ``[layers, max_batch,
 ...]``, one fixed-size value a sequence (a state-space layer's recurrent
-state and its convolution's window); a family without one has ``{}``.  For a
+state and its convolution's window; a window-attention layer's ring of its
+sequence's last positions); a family without one has ``{}``.  For a
 family with one the engine refuses a prefix hit whatever
 ``enable_prefix_caching`` says (no snapshot of the state exists at a block
 boundary), rebuilds the state by recompute after a preemption and carries
@@ -95,10 +96,16 @@ class ModelFamily:
 
 def family_of(model_config: Any) -> ModelFamily:
     """The family whose config type ``model_config`` is an instance of."""
-    from ray_tpu.models import granite_hybrid, kimi_linear, llama, pangu_moe
+    from ray_tpu.models import (
+        granite_hybrid,
+        kimi_linear,
+        laguna,
+        llama,
+        pangu_moe,
+    )
 
     families = (llama.FAMILY, pangu_moe.FAMILY, granite_hybrid.FAMILY,
-                kimi_linear.FAMILY)
+                kimi_linear.FAMILY, laguna.FAMILY)
     for fam in families:
         if isinstance(model_config, fam.config_type):
             return fam

@@ -466,10 +466,14 @@ def _attend_kernel(cfg: PanguMoEConfig, q_nope, q_rope, pool, li, row, p0,
 
 def route(cfg: PanguMoEConfig, h, router):
     """The router, over ALL ``n_routed_experts``: ``(gates [T, k] float32,
-    experts [T, k])`` of inputs ``h [T, d]``: sigmoid scores in float32, the
-    k largest (no groups), normalised over the chosen, times the scaling
-    factor."""
-    s = jax.nn.sigmoid(h.astype(jnp.float32) @ router)
+    experts [T, k])`` of inputs ``h [T, d]``: scores in float32 (a sigmoid
+    each, or where the config says ``router_score = "softmax"`` a softmax
+    over all of them), the k largest (no groups), normalised over the
+    chosen, times the scaling factor."""
+    logits = h.astype(jnp.float32) @ router
+    s = (jax.nn.softmax(logits, axis=-1)
+         if getattr(cfg, "router_score", "sigmoid") == "softmax"
+         else jax.nn.sigmoid(logits))
     top, idx = lax.top_k(s, cfg.n_experts_per_tok)
     gates = cfg.routed_scaling_factor * top / (
         top.sum(-1, keepdims=True) + 1e-20)

@@ -237,7 +237,8 @@ def _unpair_heads(out, kv: int):
 
 
 def paged_decode_attention(q, pk_all, pv_all, li, table, lengths,
-                           active=None, interpret=False, scale=None):
+                           active=None, interpret=False, scale=None,
+                           name="paged_attention"):
     """GQA paged decode attention.
 
     q [B, nh, hd] (unscaled); pk/pv [L, NB, bs, kv*hd]; li scalar layer id;
@@ -249,7 +250,9 @@ def paged_decode_attention(q, pk_all, pv_all, li, table, lengths,
     kv-head count is derived from the pool's folded last dim, so per-shard
     calls under shard_map (kv heads sharded over "tensor") need no extra
     plumbing.  ``scale`` multiplies the scores (None: ``1 / sqrt(hd)``).
-    Heads of 64 are read two a 128-lane tile (:func:`_pair_heads`).
+    Heads of 64 are read two a 128-lane tile (:func:`_pair_heads`).  ``name``:
+    the call's name in a profiler trace, for a caller whose layers of two
+    kinds are to be told apart there.
     Returns [B, nh*hd] fp32, numerically matching
     `_paged_attend` on the active rows.
     """
@@ -262,7 +265,7 @@ def paged_decode_attention(q, pk_all, pv_all, li, table, lengths,
                              f"of kv heads, not {kv64}")
         out = paged_decode_attention(
             _pair_heads(q, kv64), pk_all, pv_all, li, table, lengths, active,
-            interpret, scale)
+            interpret, scale, name)
         b, nh = q.shape[:2]
         return _unpair_heads(out.reshape(b, nh, 128), kv64).reshape(b, nh * 64)
     b, nh, hd = q.shape
@@ -302,7 +305,7 @@ def paged_decode_attention(q, pk_all, pv_all, li, table, lengths,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",)),
         interpret=interpret,
-        name="paged_attention",  # the kernel's name in a profiler trace
+        name=name,  # the kernel's name in a profiler trace
     )(jnp.asarray(li, jnp.int32).reshape(1), table, lengths,
       active.astype(jnp.int32), q, pk_all, pv_all)
     return out.reshape(b, nh * hd)

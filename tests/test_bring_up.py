@@ -216,6 +216,35 @@ def test_warmup_says_what_it_compiled():
     assert rep["prefill_chunks"] == [16, 32] and rep["seconds"] > 0
 
 
+@pytest.mark.parametrize("backend", ["cpu", "tpu"])
+def test_a_server_freezes_the_heap_behind_warmup_on_a_tpu_alone(
+        monkeypatch, backend):
+    """PR 51: a full collection walked what ``warmup()`` left (a quarter of
+    a second with every stream waiting); behind it the heap is frozen, on a
+    TPU backend, and nowhere else (no warm-up there, nothing frozen)."""
+    import gc
+
+    from ray_tpu.llm import serve as llm_serve
+
+    calls = []
+
+    class Engine:
+        def warmup(self):
+            calls.append(gc.get_freeze_count())
+
+    monkeypatch.setattr(llm_serve, "_jax_backend", lambda: backend)
+    before = gc.get_freeze_count()
+    try:
+        llm_serve._warm_up(Engine())
+        if backend == "tpu":
+            assert calls == [before]  # warmed first, frozen behind it
+            assert gc.get_freeze_count() > before
+        else:
+            assert calls == [] and gc.get_freeze_count() == before
+    finally:
+        gc.unfreeze()
+
+
 @pytest.mark.parametrize("tp", [1, 4])
 def test_serving_after_warmup_compiles_nothing(tp):
     """On the chip, TP=4 requests took 20 times one device's: warmup()

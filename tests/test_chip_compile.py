@@ -296,7 +296,8 @@ def test_join_program_compiles_at_cell_shapes(one_chip):
 # two are from the commit before the family seam (PR 31), which moved
 # models/llama.py behind a table of functions; the other six are from the
 # commit before PR 50 gave the seam's functions one signature (an empty slot
-# state and absent counters are empty pytrees: no parameter, no result).
+# state and absent counters are empty pytrees: no parameter, no result);
+# Laguna's two are as PR 51 brought the family.
 # Neither may change anything the device runs.  A PR that changes what a
 # family computes replaces its two (print ``got`` below); PR 48 did, for the
 # sampler's conditional (llm/engine.py), which every program ends in.
@@ -317,6 +318,10 @@ _PROGRAM_HLO = {
         "38e933947dfd2a19f6449661a89d36854ed0e73ed6fbb23029cc662ac3af848b",
     ("kimi_linear", "prefill"):
         "828bbe5abe38ba2d403c5dd1b7db1371ddb660ae4c47f0d76952ffefeec6ee1a",
+    ("laguna", "decode"):
+        "0de0cd764de18378afdcbc460c8b4108bff4f8d96c836e83cdcea8d90d1bfeb3",
+    ("laguna", "prefill"):
+        "d365de9187b903372f195972f9f3668f832bce2f07a62549c12a99350caa4efd",
 }
 
 
@@ -330,6 +335,8 @@ def _family_programs(family, sh):
         return _engine_programs(*_pangu_cell(sh), sh, 64, 1024, 2, 1024, 641)
     if family == "granite_hybrid":
         return _engine_programs(*_granite_cell(sh), sh, 64, 32, 2, 256, 264)
+    if family == "laguna":
+        return _engine_programs(*_laguna_cell(sh), sh, 64, 256, 2, 256, 1096)
     return _engine_programs(*_kimi_cell(sh), sh, 64, 32, 2, 256, 384)
 
 
@@ -338,7 +345,7 @@ def test_family_programs_lower_to_the_pinned_hlo(one_chip, monkeypatch,
                                                  family, program):
     import hashlib
 
-    if family in ("pangu_moe", "kimi_linear"):
+    if family in ("pangu_moe", "kimi_linear", "laguna"):
         # the expert layers ask the backend, which is the CPU here
         monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
     lowered = dict(zip(("decode", "prefill"),
@@ -716,6 +723,78 @@ def test_kimi_family_programs_compile_at_cell_shapes(one_chip, monkeypatch):
     assert "mla_prefill_attention" in names
     assert "moe_grouped_ffn_up" in names and "moe_grouped_ffn_down" in names
     assert prefill.memory_analysis().temp_size_in_bytes < 512 << 20
+
+
+# Laguna-S-2.1 as one chip of EP16 at 17 of 48 layers: 12 window layers of 72
+# heads whose last 512 positions are a ring a slot ([12, 64, 512, 1024] twice),
+# 5 full layers of 48 heads over 10,000 blocks of 16 positions, both over 8 KV
+# heads of 128; 16 expert layers of 16 held experts at d 3072 and f 1024.
+
+
+def _laguna_cell(sh):
+    import dataclasses
+
+    from ray_tpu.models import laguna as lg
+
+    cfg = dataclasses.replace(
+        lg.LagunaConfig(), layer_types=lg.LagunaConfig().layer_types[:17])
+
+    def specs(fn):
+        return jax.tree.map(lambda x: _spec(x.shape, x.dtype, sh),
+                            jax.eval_shape(fn))
+
+    return (cfg,
+            specs(lambda: lg.init_params(cfg, jax.random.PRNGKey(0))),
+            specs(lambda: lg.init_paged_cache(cfg, 10000, 16)),
+            specs(lambda: lg.init_slot_state(cfg, 64)))
+
+
+@pytest.mark.parametrize("heads,layers,blocks,page,w", [
+    (72, 12, 64 * 4, 128, 4),      # a window layer: the ring as 4 pages a slot
+    (48, 5, 10000, 16, 64),        # a full layer, a short table
+    (48, 5, 10000, 16, 2048),      # ... the widest: 17,408 positions' bucket
+])
+def test_paged_decode_attention_compiles_at_groups_of_9_and_6(
+        one_chip, heads, layers, blocks, page, w):
+    """72 and 48 query heads over 8 KV heads: groups of 9 and of 6 rows of a
+    query block, no multiple of a sublane tile, under the name the caller
+    gives."""
+    from ray_tpu.ops.paged_attention import paged_decode_attention
+
+    def i32(*shape):
+        return _spec(shape, jnp.int32, one_chip)
+
+    pool = _spec((layers, blocks, page, 8 * 128), BF16, one_chip)
+    names = _kernel_instructions(
+        functools.partial(paged_decode_attention,
+                          name="window_paged_attention"),
+        _spec((64, heads, 128), BF16, one_chip), pool, pool, i32(),
+        i32(64, w), i32(64), i32(64))
+    assert names and all("window_paged_attention" in n for n in names), names
+
+
+def test_laguna_family_programs_compile_at_cell_shapes(one_chip, monkeypatch):
+    """The engine's two programs with the ring beside the pool and the
+    counters: the decode kernel under both names in the decode program (the
+    window layers' calls and the full layers' are told apart in a device
+    trace) and the experts' grouped product over its 64 rows' live pairs; a
+    256-token chunk with the experts' grouped product and no attention kernel;
+    neither program keeps a copy of a stacked weight, of a 0.8 GB ring leaf
+    or of the pool.  (The model asks the backend, which is the CPU here, so
+    the test answers for it.)"""
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    decode, prefill = (lo.compile() for lo in
+                       _family_programs("laguna", one_chip))
+    names = _custom_call_names(decode.as_text()).split()
+    assert any(n.startswith("window_paged_attention") for n in names), names
+    assert any(n.startswith("paged_attention") for n in names), names
+    assert any("moe_grouped_ffn_up" in n for n in names), names
+    assert any("moe_grouped_ffn_down" in n for n in names), names
+    assert decode.memory_analysis().temp_size_in_bytes < 128 << 20
+    names = _custom_call_names(prefill.as_text())
+    assert "paged_attention" not in names
+    assert "moe_grouped_ffn_up" in names and "moe_grouped_ffn_down" in names
+    assert prefill.memory_analysis().temp_size_in_bytes < 256 << 20
 
 
 # -- flash attention: the 1.14 B train shape and the 8 B widths ----------------

@@ -95,7 +95,7 @@ def test_benchmark_json_lists_the_cell_and_its_readers():
     entry = next(c for c in bench["configs"]
                  if c["name"] == "kimi-linear-48b-a3b-ep16")
     assert entry["reduced"] == ["num_experts"]
-    assert len(bench["workloads"]) == 5
+    assert len(bench["workloads"]) >= 5
     assert all(w["chips"] == 1 for w in bench["workloads"])
 
 
